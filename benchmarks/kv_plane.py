@@ -6,7 +6,7 @@ Prints ONE JSON line:
   {"relay_gbps": ..., "device_gbps": ..., "speedup": ..., "bytes": ...}
 
 Runs on whatever backend jax initialises (CPU fallback via
-``JAX_PLATFORMS=cpu``, same conftest trick).
+``JAX_PLATFORMS=cpu``).
 """
 
 from __future__ import annotations
@@ -17,9 +17,6 @@ import os
 import time
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
 
 from dynamo_tpu.disagg.ici import DevicePlane            # noqa: E402
 from dynamo_tpu.disagg.protocol import kv_from_wire, kv_to_wire  # noqa: E402

@@ -33,14 +33,13 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
-sys.path.insert(0, str(REPO / "tests"))
 
 from .datagen import PrefixDatasetConfig, generate_prefix_dataset  # noqa: E402
 from .loadgen import closed_loop  # noqa: E402
 
 
 def _byte_tokenizer_json() -> str:
-    from test_llm_pipeline import byte_tokenizer  # noqa: PLC0415
+    from dynamo_tpu.llm.tokenizer import byte_tokenizer  # noqa: PLC0415
 
     return byte_tokenizer().to_json_str()
 

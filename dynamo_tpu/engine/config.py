@@ -153,9 +153,10 @@ class EngineConfig:
     # run-ahead: how many scheduled windows may be in flight before the
     # engine loop waits for a landing. >1 dispatches window N+1 (decode
     # input tokens read from the device ring) while window N's sampled
-    # tokens are still being fetched — on a remote-PJRT TPU one sync is
-    # ~64 ms vs a ~3 ms decode step, so the sync must never sit on the
-    # dispatch path. 1 = classic synchronous loop (pp engines force 1).
+    # tokens are still being fetched, so a host sync never sits on the
+    # dispatch path (premise: one sync ~64 ms vs a ~3 ms decode step —
+    # measured on an earlier transport; re-measured by chip_smoke.py, see
+    # CHANGES). 1 = classic synchronous loop (pp engines force 1).
     pipeline_depth: int = 2
     # decode block lookahead: best-effort extra blocks reserved past each
     # window so autopilot table/valid_until deltas (2 host uploads each)

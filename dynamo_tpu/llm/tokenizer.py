@@ -104,6 +104,29 @@ class Tokenizer:
         return DetokenizerStream(self, prompt_ids)
 
 
+def byte_tokenizer(vocab_size: int = 256, **kw) -> Tokenizer:
+    """A byte-level tokenizer built in memory: one token per byte, no
+    merges — multi-byte UTF-8 codepoints split across tokens.  What every
+    deployment without a checkpoint serves with (tests, benchmarks,
+    ``chip_smoke.py``); ``.to_json_str()`` gives the ``--tokenizer`` file.
+
+    ``vocab_size`` > 256 pads the vocabulary with placeholder tokens
+    (`` t<id>``) that encoding never produces but decoding renders, so a
+    model with a larger output vocabulary — random weights sample any id —
+    streams visible text for every token."""
+    from tokenizers import Tokenizer as HFTok
+    from tokenizers import decoders, models, pre_tokenizers
+
+    alphabet = sorted(pre_tokenizers.ByteLevel.alphabet())
+    vocab = {c: i for i, c in enumerate(alphabet)}
+    for i in range(len(vocab), vocab_size):
+        vocab[f"\u0120t{i}"] = i  # U+0120 is the byte-level space
+    tok = HFTok(models.BPE(vocab=vocab, merges=[]))
+    tok.pre_tokenizer = pre_tokenizers.ByteLevel(add_prefix_space=False)
+    tok.decoder = decoders.ByteLevel()
+    return Tokenizer(tok, **kw)
+
+
 class DetokenizerStream:
     """Incremental detokenization with UTF-8 boundary handling.
 

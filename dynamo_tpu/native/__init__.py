@@ -2,8 +2,9 @@
 
 Role-equivalent to the reference's native crates for token hashing and the
 router radix index (ref: lib/tokens/src/lib.rs, kv_router/indexer.rs:224).
-Builds the .so with g++ on first use if missing; every entry point has a
-pure-Python fallback, so the framework runs (slower) without a toolchain.
+Builds the .so with g++ on first use when it is missing or older than its
+source; every entry point has a pure-Python implementation too, and
+:func:`implementation` says which one a process ended up with.
 """
 
 from __future__ import annotations
@@ -30,32 +31,53 @@ _lib_lock = threading.Lock()
 _build_failed = False
 
 
+_SRC_PATH = os.path.join(_NATIVE_DIR, "src", "dynamo_native.cpp")
+
+
+def _stale() -> bool:
+    """The binary is untracked (``.gitignore``): missing on a fresh checkout
+    and possibly older than the source on a lived-in one."""
+    try:
+        return os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return True
+
+
 def _build() -> bool:
     try:
+        # the source hashes with the xxhash header pyarrow vendors
         import pyarrow
 
-        src = os.path.join(_NATIVE_DIR, "src", "dynamo_native.cpp")
+        tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
         cmd = [
             os.environ.get("CXX", "g++"), "-O3", "-fPIC", "-shared",
             "-std=c++17", "-Wall", f"-I{pyarrow.get_include()}",
-            "-o", _SO_PATH, src,
+            "-o", tmp, _SRC_PATH,
         ]
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO_PATH)  # atomic: concurrent starters race safely
+        log.info("native library built from source: %s", _SO_PATH)
         return True
     except Exception as e:
-        log.warning("native build failed (%s) — using Python fallbacks", e)
+        detail = getattr(e, "stderr", b"") or b""
+        log.warning(
+            "native build FAILED (%s %s) — token hashing and the router "
+            "index run their Python implementations", e,
+            detail.decode(errors="replace")[-400:],
+        )
         return False
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library, or None."""
+    """Load the native library — (re)building it first when the binary is
+    missing or older than its source — or None."""
     global _lib, _build_failed
     if _lib is not None or _build_failed:
         return _lib
     with _lib_lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_SO_PATH) and not _build():
+        if _stale() and not _build():
             _build_failed = True
             return None
         try:
@@ -94,6 +116,13 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def implementation() -> str:
+    """``"native"`` or ``"python"`` — which implementation of token hashing
+    and the router prefix index this process runs.  Workers log it at
+    start-up: the fallback is a different program, not a detail."""
+    return "native" if available() else "python"
 
 
 # ------------------------------ hashing -----------------------------------

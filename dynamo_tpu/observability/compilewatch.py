@@ -7,7 +7,7 @@ ROADMAP item 2 (multichip) needs before it can claim a clean steady state.
 
 Attribution works because ``/jax/core/compile/backend_compile_duration``
 fires *synchronously on the compiling thread*, exactly once per real
-backend compile (cache hits fire nothing — verified on jax 0.4.37). Each
+backend compile (cache hits fire nothing). Each
 jitted function the engine builds is wrapped by :func:`label`, which sets
 a thread-local tag around the call; a compile event observed inside a
 labelled call is attributed to that function, anything else lands in the
@@ -196,7 +196,7 @@ def install() -> None:
     _watch.install()
     if _remat_handler is None:
         _remat_handler = RematLogHandler(_watch)
-        for name in ("jax", "jax._src", "absl"):
+        for name in ("jax", "absl"):  # children propagate up to these
             logging.getLogger(name).addHandler(_remat_handler)
 
 

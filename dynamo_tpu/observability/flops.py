@@ -45,30 +45,31 @@ PEAK_FLOPS_INT8 = {
 # fp8 rides the same 8-bit MXU datapath as int8 on the generations that
 # have it (MFU with quantized weights is measured against this roofline).
 PEAK_FLOPS_FP8 = PEAK_FLOPS_INT8
-DEFAULT_PEAK = 197e12        # v5e — the BASELINE.md target platform
-DEFAULT_PEAK_INT8 = 394e12   # v5e 8-bit rate
-CPU_PEAK = 1e12        # nominal, so CPU-fallback MFU fields stay defined
 
 
 def peak_flops(device_kind: str, platform: str,
-               dtype: str = "bfloat16") -> float:
+               dtype: str = "bfloat16") -> Optional[float]:
     """Per-chip peak FLOP/s for a device kind string (e.g. ``"TPU v5e"``).
 
-    Longest-key match over the table; unknown TPU kinds fall back to the
-    v5e number, non-TPU platforms to the nominal CPU peak. fp32 halves a
+    Longest-key match over the table.  A TPU kind the table does not hold
+    is an error — add it with its source; a default would turn every
+    utilisation computed from it into a made-up number.  A non-TPU platform
+    has no peak (``None``): MFU is then absent, not nominal.  fp32 halves a
     TPU's MXU rate; ``"int8"``/``"fp8"`` select the doubled 8-bit table
     (bf16 inputs are the spec-sheet number)."""
     if platform != "tpu":
-        return CPU_PEAK
+        return None
     kind = (device_kind or "").lower()
-    if dtype in ("int8", "fp8", "float8_e4m3fn"):
-        table, peak = PEAK_FLOPS_INT8, DEFAULT_PEAK_INT8
-    else:
-        table, peak = PEAK_FLOPS, DEFAULT_PEAK
+    table = (PEAK_FLOPS_INT8 if dtype in ("int8", "fp8", "float8_e4m3fn")
+             else PEAK_FLOPS)
     for key in sorted(table, key=len, reverse=True):
         if key in kind:
             peak = table[key]
             break
+    else:
+        raise ValueError(
+            f"no published peak FLOP/s for TPU device kind {device_kind!r}; "
+            f"add it to observability/flops.py with its source")
     if dtype in ("float32", "f32"):
         peak /= 2.0
     return peak

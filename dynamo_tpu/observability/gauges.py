@@ -16,15 +16,20 @@ from typing import Dict
 class EngineObsGauges:
     def __init__(self, registry, engine):
         self._engine = engine
-        self._g_mfu = registry.gauge(
-            "engine_mfu",
-            "live model-FLOPs utilization over the trailing window "
-            "(goodput FLOPs / peak; attention term included)",
-        )
-        self._g_mfu_class = registry.gauge(
-            "engine_mfu_by_class",
-            "live MFU split by step class", ["step"]
-        )
+        # MFU needs the device's published peak: where there is none (any
+        # non-TPU platform) the gauges do not exist — absent, not zero
+        self._g_mfu = self._g_mfu_class = None
+        obs = getattr(engine, "obs", None)
+        if obs is not None and obs.peak_flops:
+            self._g_mfu = registry.gauge(
+                "engine_mfu",
+                "live model-FLOPs utilization over the trailing window "
+                "(goodput FLOPs / peak; attention term included)",
+            )
+            self._g_mfu_class = registry.gauge(
+                "engine_mfu_by_class",
+                "live MFU split by step class", ["step"]
+            )
         self._g_goodput = registry.gauge(
             "engine_goodput_tok_s",
             "real tokens landed per second over the trailing window",
@@ -72,11 +77,10 @@ class EngineObsGauges:
         snap = self._engine.obs_snapshot()
         if not snap:
             return {}
-        self._g_mfu.set(snap.get("mfu", 0.0))
-        self._g_mfu_class.labels(step="prefill").set(
-            snap.get("mfu_prefill", 0.0))
-        self._g_mfu_class.labels(step="decode").set(
-            snap.get("mfu_decode", 0.0))
+        if self._g_mfu is not None:
+            self._g_mfu.set(snap["mfu"])
+            self._g_mfu_class.labels(step="prefill").set(snap["mfu_prefill"])
+            self._g_mfu_class.labels(step="decode").set(snap["mfu_decode"])
         self._g_goodput.set(snap.get("goodput_tok_s", 0.0))
         self._g_pad_waste.set(snap.get("padding_waste_ratio", 0.0))
         self._g_waste.labels(cause="padding").set(

@@ -109,7 +109,7 @@ class StepStats:
         flops_model: FlopsModel,
         *,
         n_chips: int = 1,
-        peak_flops: float = 1e12,
+        peak_flops: Optional[float] = None,
         window_s: float = 10.0,
         capacity: int = 8192,
         jsonl_path: str = "",
@@ -117,7 +117,8 @@ class StepStats:
     ):
         self.flops_model = flops_model
         self.n_chips = max(1, n_chips)
-        self.peak_flops = max(peak_flops, 1.0)
+        # None = the platform has no published peak: MFU keys are absent
+        self.peak_flops = peak_flops
         self.window_s = window_s
         self.capacity = capacity
         self.jsonl_path = jsonl_path
@@ -238,13 +239,17 @@ class StepStats:
             # elapsed: window span, floored at the warmup mark so a
             # freshly-reset recorder doesn't divide by ~0
             elapsed = min(self.window_s, max(now - self._t_start, 1e-9))
-            denom = elapsed * self.peak_flops * self.n_chips
             dispatched = max(w.flops_dispatched, 0.0)
-            snap = {
-                "mfu": w.flops_goodput / denom,
-                "mfu_prefill": w.flops_goodput_prefill / denom,
-                "mfu_decode": w.flops_goodput_decode / denom,
-                "mfu_dispatched": dispatched / denom,
+            snap = {}
+            if self.peak_flops:
+                denom = elapsed * self.peak_flops * self.n_chips
+                snap = {
+                    "mfu": w.flops_goodput / denom,
+                    "mfu_prefill": w.flops_goodput_prefill / denom,
+                    "mfu_decode": w.flops_goodput_decode / denom,
+                    "mfu_dispatched": dispatched / denom,
+                }
+            snap.update({
                 "goodput_tok_s": w.goodput_tokens / elapsed,
                 "padding_waste_ratio": (
                     w.flops_padding_waste / dispatched if dispatched else 0.0),
@@ -256,7 +261,7 @@ class StepStats:
                 "total_goodput_tokens": float(self.total_goodput_tokens),
                 "spec_drafted": float(w.spec_drafted),
                 "spec_accepted": float(w.spec_accepted),
-            }
+            })
             self._snap_cache = snap
             self._snap_cache_t = now
             return dict(snap)

@@ -40,6 +40,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
 def _row_tile(t, q_start_ref, r, q_tile):
     """Clamp grid q-tile ``t`` into row ``r``'s own allotment.
 
@@ -300,6 +303,10 @@ def paged_attention_ragged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KV, Tq, G, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
     )(q_start, q_len, ctx_len, block_tables, *operands)
     return out.transpose(1, 0, 2, 3).reshape(Tq, H, hd)

@@ -88,51 +88,55 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, spec())
 
 
-# --------------------------- version compat -------------------------------
-#
-# jax moved shard_map from jax.experimental to the top level (renaming the
-# replication-check kwarg check_rep -> check_vma) and added lax.axis_size
-# along the way.  The serving code targets both: every shard_map in the
-# tree goes through this wrapper, and per-shard bodies take the ring size
-# from axis_size() below.
+# ------------------------------ shard_map ---------------------------------
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking disabled
-    (our bodies return pallas_call / collective outputs that carry no
-    replication info either way)."""
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with replication checking off — our bodies return
+    pallas_call / collective outputs that carry no replication info.  Every
+    shard_map in the tree goes through here so that choice has one home."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
-def axis_size(axis_name: Union[str, Tuple[str, ...]]) -> int:
-    """Size of a (possibly composite) mesh axis inside a shard_map body.
-
-    ``psum(1, axis)`` is constant-folded at trace time, so the result is a
-    static python int usable as a loop bound (``lax.axis_size`` does not
-    exist on older jax)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 # ------------------------------ meshes ------------------------------------
+
+
+def _mesh_devices(n: int, devices=None) -> np.ndarray:
+    """The ``n`` devices a mesh is built over.
+
+    Explicit ``devices`` are taken as given (leading ``n``).  Without them
+    the mesh takes the process's devices in enumeration order — which on
+    real chips must be ALL of them: a process opens every chip it can see,
+    so a mesh smaller than ``jax.devices()`` would leave chips held but
+    idle, and a second engine built the same way would land on the same
+    first chip.  Restrict what the process sees instead
+    (``utils.device_env.one_chip_env`` in the launcher) or pass ``devices``.
+    Virtual CPU devices are not chips; tests slice them freely."""
+    if devices is not None:
+        return np.asarray(devices).flatten()[:n]
+    visible = jax.devices()
+    if len(visible) < n:
+        raise ValueError(
+            f"mesh needs {n} devices, this process sees {len(visible)}")
+    if len(visible) > n and visible[0].platform != "cpu":
+        raise ValueError(
+            f"mesh needs {n} of the {len(visible)} "
+            f"{visible[0].platform} devices this process holds and no "
+            f"`devices` were given: every engine built this way would "
+            f"sit on device 0. Start the process with only its chips "
+            f"visible (dynamo_tpu.utils.device_env.one_chip_env) or pass "
+            f"devices explicitly.")
+    return np.asarray(visible[:n])
 
 
 def make_mesh(shape: Sequence[int], devices=None) -> Mesh:
     """The serving engine's canonical mesh: ``(dp, tp)`` for a 2-tuple,
     ``(dp, fsdp, tp)`` for a 3-tuple.
 
-    Takes the first ``prod(shape)`` devices in enumeration order so every
+    Devices come from :func:`_mesh_devices`: enumeration order, so every
     host in a multihost slice derives the identical mesh.
     """
     shape = tuple(int(s) for s in shape)
@@ -143,9 +147,8 @@ def make_mesh(shape: Sequence[int], devices=None) -> Mesh:
     else:
         raise ValueError(
             f"mesh shape must be (dp, tp) or (dp, fsdp, tp), got {shape}")
-    devices = np.asarray(devices if devices is not None else jax.devices())
     n = int(np.prod(shape))
-    return Mesh(devices.flatten()[:n].reshape(shape), axes)
+    return Mesh(_mesh_devices(n, devices).reshape(shape), axes)
 
 
 def make_flat_mesh(devices, axis_name: str = AXIS_SP) -> Mesh:
@@ -168,9 +171,8 @@ def make_axes_mesh(shape: Sequence[int], axis_names: Sequence[str],
     if unknown:
         raise ValueError(
             f"unknown mesh axis names {unknown}; canonical axes: {ALL_AXES}")
-    devices = np.asarray(devices if devices is not None else jax.devices())
     n = int(np.prod(shape))
-    return Mesh(devices.flatten()[:n].reshape(tuple(shape)),
+    return Mesh(_mesh_devices(n, devices).reshape(tuple(shape)),
                 tuple(axis_names))
 
 

@@ -38,7 +38,7 @@ def pipeline_stages(
     Returns the final-stage outputs ``[M, mb, ...]`` (replicated to every
     stage via a masked psum at the end).
     """
-    S = layout.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     M = x.shape[0]
     fwd = [(j, (j + 1) % S) for j in range(S)]

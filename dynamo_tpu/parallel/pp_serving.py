@@ -49,9 +49,7 @@ Cache = dict
 
 
 def make_pp_mesh(num_stages: int, devices=None) -> Mesh:
-    devices = np.asarray(devices if devices is not None else jax.devices())
-    return make_axes_mesh((num_stages,), (AXIS_PP,),
-                          devices=devices[:num_stages])
+    return make_axes_mesh((num_stages,), (AXIS_PP,), devices=devices)
 
 
 def init_pp_cache(cfg: ModelConfig, eng: EngineConfig) -> Cache:

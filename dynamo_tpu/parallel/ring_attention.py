@@ -78,7 +78,7 @@ def ring_attention(
     attends over the chunk that started on device ``(i - s) mod n`` while
     sending its current chunk to neighbor ``i+1``.
     """
-    n = layout.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     i = jax.lax.axis_index(axis_name)
     B, C, H, hd = q.shape
     scale = 1.0 / np.sqrt(hd)

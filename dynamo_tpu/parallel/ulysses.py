@@ -51,7 +51,7 @@ def ulysses_attention(
 
     Requires ``H % sp == 0`` and ``KV % sp == 0``.
     """
-    n = layout.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     B, C, H, hd = q.shape
     KV = k.shape[2]
     if H % n or KV % n:

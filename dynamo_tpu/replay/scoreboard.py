@@ -519,7 +519,8 @@ def build_scoreboard(
     chip_seconds = elapsed * run.chips
     per_1m = (chip_seconds / (out_tokens / 1e6)) if out_tokens else None
     peak = peak_flops(run.device_kind, run.platform)
-    try:
+    ideal_s = None  # no roofline where the platform has no published peak
+    if peak:
         from ..engine.config import ModelConfig
 
         fm = FlopsModel(ModelConfig.tiny())
@@ -527,8 +528,6 @@ def build_scoreboard(
             fm.sequence_flops(o.isl, max(len(o.tokens), 1))
             for o in completed
         ) / peak
-    except Exception:
-        ideal_s = None
     ideal_per_1m = ((ideal_s / (out_tokens / 1e6))
                     if (ideal_s is not None and out_tokens) else None)
 

@@ -85,11 +85,9 @@ def build_output(args):
         if dp * tp > 1:
             # stream each checkpoint shard straight onto device shards —
             # peak host memory stays at one tensor, not the whole model
-            import jax
-
             from .engine import model as model_lib
 
-            mesh = model_lib.make_mesh((dp, tp), jax.devices())
+            mesh = model_lib.make_mesh((dp, tp))
             params = load_hf_params_sharded(args.weights, model_cfg, mesh)
         else:
             params = load_hf_params(args.weights, model_cfg)
@@ -246,6 +244,10 @@ def parse_args(argv=None):
 
 def main(argv=None) -> None:
     args = parse_args(argv)
+    if args.out == "engine":  # the only output that compiles
+        from .utils.device_env import configure_compile_cache
+
+        configure_compile_cache()
     engine = build_output(args)
     tokenizer = build_tokenizer(args)
     if args.inp == "text":

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import json
 import os
 from typing import Optional
 
@@ -22,6 +23,7 @@ from .llm.tokenizer import Tokenizer
 from .runtime.component import DistributedRuntime
 from .serving import ServeOptions, load_tokenizer, run_until_shutdown, serve_engine
 from .utils.config import RuntimeConfig
+from .utils.device_env import configure_compile_cache
 from .utils.logging import get_logger
 
 log = get_logger("worker")
@@ -192,11 +194,9 @@ async def run_worker(args: argparse.Namespace) -> None:
             model_cfg = model_config_from_hf(args.weights)
         if dp * tp > 1 and args.pp <= 1:
             # stream onto device shards (peak host memory = one tensor)
-            import jax
-
             from .engine import model as model_lib
 
-            mesh = model_lib.make_mesh((dp, tp), jax.devices())
+            mesh = model_lib.make_mesh((dp, tp))
             params = load_hf_params_sharded(
                 args.weights, model_cfg, mesh, weight_dtype)
         else:
@@ -433,8 +433,22 @@ async def run_worker(args: argparse.Namespace) -> None:
         )
         handler.kv_inject_addr = inject_served.instance.addr
 
-    log.info("worker ready: model=%s mode=%s engine=%s",
-             name, args.disagg_mode, eng_cfg)
+    report = engine.device_report()
+    if runtime.system_server is not None:
+        # GET /health → probes.engine: device, traced attention, compile
+        # and memory counters, live
+        runtime.system_server.register_probe(
+            "engine", lambda: {"healthy": True, **engine.device_report()}
+        )
+    log.info(
+        "worker ready: model=%s mode=%s device=%s native=%s "
+        "compile_cache=%s engine=%s",
+        name, args.disagg_mode,
+        json.dumps({"platform": report["platform"],
+                    "kind": report["device_kind"],
+                    "count": report["device_count"]}),
+        report["native"], report["compile_cache"]["dir"], eng_cfg,
+    )
     try:
         await run_until_shutdown(runtime, engine, served, kv_pub,
                                  metrics_pub)
@@ -448,7 +462,9 @@ async def run_worker(args: argparse.Namespace) -> None:
 
 
 def main(argv=None) -> None:
-    asyncio.run(run_worker(parse_args(argv)))
+    args = parse_args(argv)
+    configure_compile_cache()  # before anything compiles
+    asyncio.run(run_worker(args))
 
 
 if __name__ == "__main__":

@@ -8,20 +8,8 @@ import pytest
 from dynamo_tpu.llm.backend import Backend
 from dynamo_tpu.llm.preprocessor import Preprocessor, PromptTemplate
 from dynamo_tpu.llm.protocols import BackendOutput, PreprocessedRequest
-from dynamo_tpu.llm.tokenizer import Tokenizer
+from dynamo_tpu.llm.tokenizer import byte_tokenizer  # noqa: F401 — other test files import it from here
 from dynamo_tpu.runtime.context import Context
-
-
-def byte_tokenizer(**kw) -> Tokenizer:
-    from tokenizers import Tokenizer as HFTok
-    from tokenizers import decoders, models, pre_tokenizers
-
-    alphabet = sorted(pre_tokenizers.ByteLevel.alphabet())
-    vocab = {c: i for i, c in enumerate(alphabet)}
-    tok = HFTok(models.BPE(vocab=vocab, merges=[]))
-    tok.pre_tokenizer = pre_tokenizers.ByteLevel(add_prefix_space=False)
-    tok.decoder = decoders.ByteLevel()
-    return Tokenizer(tok, **kw)
 
 
 # ----------------------------- tokenizer ----------------------------------

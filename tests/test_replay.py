@@ -284,7 +284,8 @@ async def test_cluster_replay_scoreboard_and_determinism(tmp_path):
             assert row["slo_violation_rate"] is not None
         assert rep["prefix_hit_rate"] is not None and rep["prefix_hit_rate"] > 0
         assert rep["chip_seconds_per_1m_output_tokens"] > 0
-        assert rep["ideal_chip_seconds_per_1m_output_tokens"] > 0
+        # CPU run: no published peak, so no roofline figure
+        assert rep["ideal_chip_seconds_per_1m_output_tokens"] is None
         # preemption fired and was accounted
         assert rep["preempt"]["notices"] == 1
         assert [e["kind"] for e in rep["events_fired"]] == ["preempt"]
