@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import contextlib
 import itertools
 import time
 from collections import deque
@@ -26,7 +27,7 @@ import jax
 import numpy as np
 
 from .. import tracing
-from ..observability import compilewatch
+from ..observability import compilewatch, profiling
 from ..observability import flops as obs_flops
 from ..parallel import layout
 from ..observability.flops import FlopsModel
@@ -69,6 +70,67 @@ class Request:
     mm_positions: Optional[List[int]] = None
     mm_embeddings: Optional[np.ndarray] = None   # [len(mm_positions), D]
     mm_hash_token_ids: Optional[List[int]] = None
+
+
+# Engine-loop phases, as ``jax.profiler.TraceAnnotation`` names on the
+# thread that runs each: a /debug/profile capture shows them on the
+# profiler's clock beside the device ops (no-ops while nothing captures).
+PHASES = (
+    "engine.schedule", "engine.postprocess",      # event loop, busy
+    "engine.wait_land", "engine.wait_idle",       # event loop, waiting
+    "engine.dispatch",                            # dispatch thread
+    "engine.dispatch.prefill", "engine.dispatch.decode",
+    "engine.fetch", "engine.unpack",              # fetch thread
+)
+_phase = jax.profiler.TraceAnnotation
+
+
+class _LoopClock:
+    """Where the engine-loop task's wall time goes: seconds spent waiting
+    (``land``: for a window's results, ``idle``: for work, ``executor``:
+    for the dispatch thread) and, by difference, busy. ``handoff()`` closes
+    the account of one batch: its busy seconds ride the batch to its step
+    record as ``host_s``, its waits are added to ``wait_s``. So at any
+    moment ``sum(host_s handed off) + sum(wait_s.values())`` equals
+    ``t_handoff - t_start``."""
+
+    def __init__(self):
+        self.t_start = self.t_handoff = time.monotonic()
+        self.wait_s = {"land": 0.0, "idle": 0.0, "executor": 0.0}
+        self._open = dict(self.wait_s)  # waits since the last handoff
+
+    @contextlib.contextmanager
+    def waiting(self, what: str):
+        t0 = time.monotonic()
+        try:
+            yield
+        finally:
+            self._open[what] += time.monotonic() - t0
+
+    def handoff(self) -> float:
+        now = time.monotonic()
+        busy = now - self.t_handoff - sum(self._open.values())
+        for what, s in self._open.items():
+            self.wait_s[what] += s
+            self._open[what] = 0.0
+        self.t_handoff = now
+        return max(busy, 0.0)
+
+
+@contextlib.contextmanager
+def _dispatch_phase(name: str, obs_out):
+    """One ``_dispatch_prefill`` / ``_dispatch_decode`` call (dispatch
+    thread): a phase carrying the dispatch's ``bucket`` and ``rows``, and
+    its seconds as ``dispatch_s`` on the step record the call appends to
+    ``obs_out`` (None = recorder off: the bare phase)."""
+    n = len(obs_out) if obs_out is not None else 0
+    t0 = time.monotonic()
+    with _phase(name) as phase:
+        yield
+        if obs_out is not None and len(obs_out) > n:
+            rec = obs_out[-1]
+            rec.dispatch_s = time.monotonic() - t0
+            phase.set_metadata(bucket=rec.bucket, rows=rec.live_rows)
 
 
 class _BatchingFetcher:
@@ -133,7 +195,8 @@ class _BatchingFetcher:
             try:
                 # THE designed host sync: one device_get per window, on the
                 # fetcher thread, off the dispatch loop
-                got = jax.device_get(flat) if flat else []  # dynalint: disable=DT102
+                with _phase("engine.fetch"):
+                    got = jax.device_get(flat) if flat else []  # dynalint: disable=DT102
                 if flat and self._on_sync is not None:
                     self._on_sync()
                 res, exc = self._unpack(batch, handles, got), None
@@ -241,6 +304,8 @@ class EngineCore(AsyncEngine):
         # flight recorder (observability.StepStats) when enabled;
         # InferenceEngine builds it, the mocker leaves it None
         self.obs = None
+        # the loop task's busy/wait account (reset when the loop starts)
+        self.loop_clock = _LoopClock()
         # -- stall watchdog state (engine_config.stall_timeout_s > 0) --
         # per-seq recovery attempts; a seq over stall_seq_retries is failed
         # instead of requeued so one poisoned prompt can't loop forever
@@ -627,24 +692,26 @@ class EngineCore(AsyncEngine):
         end = time.monotonic()
         t_sched = seq.t_scheduled if seq is not None else None
         t_first = seq.t_first_token if seq is not None else None
-        tracer.record("worker.queue", context,
-                      start_mono=t_submit, end_mono=(t_sched or end))
+        q_attrs = None
+        if seq is not None:
+            q_attrs = {"prompt_tokens": seq.prompt_len,
+                       "cached_tokens": seq.cached_tokens}
+        tracer.record("worker.queue", context, start_mono=t_submit,
+                      end_mono=(t_sched or end), attrs=q_attrs)
         if t_sched is not None:
-            tracer.record("engine.prefill", context,
-                          start_mono=t_sched, end_mono=(t_first or end))
+            # the prompt-completing chunk's enqueue and its landing on the
+            # fetch thread split the span into: chunks before it, device
+            # queue + prefill program + D2H, and the hop to the first emit
+            events = [(t, name) for name, t in (
+                ("dispatched", seq.t_dispatched), ("landed", seq.t_landed),
+            ) if t is not None]
+            tracer.record("engine.prefill", context, start_mono=t_sched,
+                          end_mono=(t_first or end), events=events)
         if t_first is not None:
             attrs = {"num_tokens": len(seq.output_ids)}
             if getattr(self, "spec_stats", None) is not None:
                 attrs["spec_drafted"] = seq.spec_drafted
                 attrs["spec_accepted"] = seq.spec_accepted
-            if self.obs is not None:
-                osnap = self.obs.snapshot()
-                if "mfu" in osnap:  # absent off-TPU: no published peak
-                    attrs["mfu"] = round(osnap["mfu"], 6)
-                attrs["goodput_tok_s"] = round(osnap["goodput_tok_s"], 3)
-                attrs["padding_waste_ratio"] = round(
-                    osnap["padding_waste_ratio"], 6
-                )
             tracer.record("engine.decode", context, start_mono=t_first,
                           end_mono=end, attrs=attrs)
 
@@ -694,6 +761,7 @@ class EngineCore(AsyncEngine):
         raise NotImplementedError
 
     async def _run_loop(self) -> None:
+        self.loop_clock = _LoopClock()
         if self.pipeline_depth > 1:
             await self._run_loop_pipelined()
         else:
@@ -706,13 +774,16 @@ class EngineCore(AsyncEngine):
         tokens are observed one-plus windows behind for emission and stop
         checks. Landings are applied strictly in dispatch order."""
         inflight: Deque[Tuple[Any, Any]] = deque()
+        clock = self.loop_clock
 
         async def land_next() -> None:
             batch0, fut = inflight.popleft()
             try:
-                results = await asyncio.wait_for(
-                    self._landing(batch0, fut), self._stall_deadline(batch0)
-                )
+                with _phase("engine.wait_land"), clock.waiting("land"):
+                    results = await asyncio.wait_for(
+                        self._landing(batch0, fut),
+                        self._stall_deadline(batch0),
+                    )
             except asyncio.TimeoutError:
                 # the head landing blew its deadline: every younger window
                 # reads the wedged window's ring state, so the whole
@@ -730,18 +801,20 @@ class EngineCore(AsyncEngine):
                 self._abort_batch(batch0)
                 return
             self._stall_streak = 0
-            try:
-                self._postprocess(batch0, results)
-            except Exception:
-                log.exception("postprocess failed")
-            self._flush_kv_events()
+            with _phase("engine.postprocess"):
+                try:
+                    self._postprocess(batch0, results)
+                except Exception:
+                    log.exception("postprocess failed")
+                self._flush_kv_events()
 
         while not self._stopped:
             while inflight and inflight[0][1].done():
                 await land_next()
-            self._pressure_tick()
-            batch = self.scheduler.schedule()
-            self._mark_preempted_seats(batch)
+            with _phase("engine.schedule"):
+                self._pressure_tick()
+                batch = self.scheduler.schedule()
+                self._mark_preempted_seats(batch)
             if batch.is_empty:
                 if inflight:
                     await land_next()
@@ -763,11 +836,14 @@ class EngineCore(AsyncEngine):
                         log.exception("kvbm idle drain failed")
                 if self._stopped:
                     break
-                await self._wake.wait()
+                with _phase("engine.wait_idle"), clock.waiting("idle"):
+                    await self._wake.wait()
                 continue
             self._arm_stall_fault(batch)
+            batch.host_s = clock.handoff()
             try:
-                fut = await self._dispatch_batch_async(batch)
+                with clock.waiting("executor"):
+                    fut = await self._dispatch_batch_async(batch)
             except Exception:
                 log.exception("dispatch failed; aborting scheduled seqs")
                 self._abort_batch(batch)
@@ -1090,10 +1166,12 @@ class EngineCore(AsyncEngine):
         self._emit_finish(seq, "evacuated")
 
     async def _run_loop_sync(self) -> None:
+        clock = self.loop_clock
         while not self._stopped:
-            self._pressure_tick()
-            batch = self.scheduler.schedule()
-            self._mark_preempted_seats(batch)
+            with _phase("engine.schedule"):
+                self._pressure_tick()
+                batch = self.scheduler.schedule()
+                self._mark_preempted_seats(batch)
             if batch.is_empty:
                 # a waiting request that can never fit (pool smaller than its
                 # prompt) would hang forever — fail it rather than deadlock
@@ -1116,14 +1194,20 @@ class EngineCore(AsyncEngine):
                         log.exception("kvbm idle drain failed")
                 if self._stopped:
                     return
-                await self._wake.wait()
+                with _phase("engine.wait_idle"), clock.waiting("idle"):
+                    await self._wake.wait()
                 continue
             self._arm_stall_fault(batch)
+            batch.host_s = clock.handoff()
             inner = asyncio.ensure_future(self._execute_batch_async(batch))
             try:
-                results = await asyncio.wait_for(
-                    self._landing(batch, inner), self._stall_deadline(batch)
-                )
+                # one executor turn dispatches AND fetches here, so the
+                # whole wait is the landing's
+                with _phase("engine.wait_land"), clock.waiting("land"):
+                    results = await asyncio.wait_for(
+                        self._landing(batch, inner),
+                        self._stall_deadline(batch),
+                    )
             except asyncio.TimeoutError:
                 self._swallow_future(inner)
                 self._on_stall([batch])
@@ -1136,13 +1220,14 @@ class EngineCore(AsyncEngine):
                 self._abort_batch(batch)
                 continue
             self._stall_streak = 0
-            try:
-                self._postprocess(batch, results)
-            except Exception:
-                # bookkeeping must never kill the step loop — every queued
-                # request would hang forever
-                log.exception("postprocess failed")
-            self._flush_kv_events()
+            with _phase("engine.postprocess"):
+                try:
+                    self._postprocess(batch, results)
+                except Exception:
+                    # bookkeeping must never kill the step loop — every
+                    # queued request would hang forever
+                    log.exception("postprocess failed")
+                self._flush_kv_events()
             if self.kvbm is not None:
                 try:
                     await self.kvbm.tick()
@@ -1533,6 +1618,7 @@ class InferenceEngine(EngineCore):
             "compile_cache": compile_cache_stats(),
             "compile": compilewatch.snapshot(),
             "memory": memory,
+            "last_profile": profiling.last_capture(),
         }
 
     def _shutdown_executor(self) -> None:
@@ -1734,18 +1820,23 @@ class InferenceEngine(EngineCore):
         so the in-order device queue applies them before any work that
         could touch reused blocks. Preempted slots are marked by the loop
         at schedule() time — a batch can be empty yet carry preemptions."""
-        self._ap_flush_kills()
-        obs_out = (
-            batch.obs_records if self.obs is not None
-            and hasattr(batch, "obs_records") else None
-        )
-        prefill_handles = [
-            self._dispatch_prefill(c, obs_out) for c in batch.prefills
-        ]
-        decode_handle = (
-            self._dispatch_decode(batch.decode_rows, obs_out)
-            if batch.decode_rows else None
-        )
+        with _phase("engine.dispatch"):
+            self._ap_flush_kills()
+            obs_out = (
+                batch.obs_records if self.obs is not None
+                and hasattr(batch, "obs_records") else None
+            )
+            prefill_handles = []
+            for c in batch.prefills:
+                with _dispatch_phase("engine.dispatch.prefill", obs_out):
+                    prefill_handles.append(self._dispatch_prefill(c, obs_out))
+                if c.final and c.seq.t_first_token is None:
+                    c.seq.t_dispatched = time.monotonic()
+            decode_handle = None
+            if batch.decode_rows:
+                with _dispatch_phase("engine.dispatch.decode", obs_out):
+                    decode_handle = self._dispatch_decode(
+                        batch.decode_rows, obs_out)
         return prefill_handles, decode_handle
 
     def _ap_flush_kills(self) -> None:
@@ -1777,7 +1868,8 @@ class InferenceEngine(EngineCore):
             to_get.append(decode_handle[0])
         # designed sync point of the non-pipelined path: exactly one
         # device_get per executed batch, counted in num_fetch_syncs
-        got = jax.device_get(to_get) if to_get else []  # dynalint: disable=DT102
+        with _phase("engine.fetch"):
+            got = jax.device_get(to_get) if to_get else []  # dynalint: disable=DT102
         if to_get:
             self.num_fetch_syncs += 1
         return self._unpack_results(batch, handles, got)
@@ -1787,10 +1879,19 @@ class InferenceEngine(EngineCore):
         """Map fetched arrays back to per-seat sample lists. Decode sample
         columns follow the device seat map captured at dispatch, which may
         order (and pad) differently than the batch's row list."""
+        t_got = time.monotonic()
+        with _phase("engine.unpack"):
+            return self._unpack(batch, handles, got, t_got)
+
+    @hot_path
+    def _unpack(self, batch, handles, got, t_got: float):
         prefill_handles, decode_handle = handles
         prefill_samples = [
             int(np.asarray(g)[0]) for g in got[:len(prefill_handles)]
         ]
+        for chunk in batch.prefills:
+            if chunk.final and chunk.seq.t_first_token is None:
+                chunk.seq.t_landed = t_got
         decode_samples: List[List[int]] = []
         if decode_handle is not None:
             col_of = {}
@@ -1807,20 +1908,25 @@ class InferenceEngine(EngineCore):
                         for k in range(min(row.accepted, out.shape[0]))
                     ])
         if self.obs is not None:
-            self._obs_on_land(batch, decode_samples)
+            self._obs_on_land(batch, decode_samples, t_got)
         return prefill_samples, decode_samples
 
     @hot_path
-    def _obs_on_land(self, batch, decode_samples) -> None:
+    def _obs_on_land(self, batch, decode_samples, t_got: float) -> None:
         """Stamp landing time + realized goodput on this window's records
         and commit them to the flight recorder. Runs right after the
         window's one designed device_get, on already-fetched host ints —
-        no extra syncs."""
+        no extra syncs. The batch's host time (the loop's ``host_s``, this
+        thread's ``unpack_s`` since the get returned) goes on its decode
+        record, or its last record when it has none."""
         recs = getattr(batch, "obs_records", None)
         if not recs:
             return
         t_land = time.monotonic()
         emitted = sum(len(w) for w in decode_samples)
+        owner = next((r for r in recs if r.kind != PREFILL), recs[-1])
+        owner.host_s = batch.host_s
+        owner.unpack_s = t_land - t_got
         for rec in recs:
             rec.t_land = t_land
             if rec.kind != PREFILL:

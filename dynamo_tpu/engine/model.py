@@ -47,6 +47,25 @@ from .config import EngineConfig, ModelConfig
 Params = Dict[str, Any]
 Cache = Dict[str, jax.Array]
 
+# ``jax.named_scope`` names the step programs carry in every op's
+# ``op_name`` metadata (no layer index: one name per stage, summed over
+# layers and window steps). Metadata only — a scope changes no HLO op. The
+# tests and the benchmark's trace reader (benchmarks/chip/scopes.py) import
+# this tuple, so a stage is renamed here or nowhere.
+SCOPES = (
+    "embed",       # token gather (+ multimodal splice)
+    "qkv_proj",    # attn norm + q/k/v matmuls
+    "rope",
+    "kv_write",    # scatter of the chunk's K/V (and scales) into the cache
+    "attention",   # the Pallas call, the ring, or gather + einsum
+    "o_proj",      # output matmul + residual
+    "mlp",         # mlp norm, gate/up/down (or moe_ffn) + residual
+    "final_norm",
+    "lm_head",
+    "sample",
+    "ctl",         # token ring / autopilot control-state reads and deltas
+)
+
 
 # ------------------------------ init ------------------------------------
 
@@ -275,14 +294,19 @@ def _mm(x: jax.Array, w: Any) -> jax.Array:
     return x @ w
 
 
-def _layer_slice(stacked: Dict[str, Any], li: int) -> Dict[str, Any]:
+class _LayerSlice:
     """Static per-layer slice of the stacked param tree (a read, not a
-    copy); quantized ``{"q", "s"}`` leaves slice both members."""
-    return {
-        name: ({k: v[li] for k, v in w.items()} if isinstance(w, dict)
-               else w[li])
-        for name, w in stacked.items()
-    }
+    copy); quantized ``{"q", "s"}`` leaves slice both members. The slice is
+    taken where the weight is read, so it carries that stage's scope."""
+
+    def __init__(self, stacked: Dict[str, Any], li: int):
+        self._stacked, self._li = stacked, li
+
+    def __getitem__(self, name: str) -> Any:
+        w, li = self._stacked[name], self._li
+        if isinstance(w, dict):
+            return {k: v[li] for k, v in w.items()}
+        return w[li]
 
 
 def _dequant_leaf(w: Any, dtype) -> jax.Array:
@@ -548,6 +572,71 @@ def _paged_ragged_attention(
     return out.reshape(B, T, H, hd)
 
 
+def _layer_attention(
+    eng: EngineConfig, mesh, ring_mesh, ring_lay, use_pallas: bool,
+    q, k, v, lk, lv, lks, lvs, positions, block_tables,
+    seq_lens, q_len, ctx_len,
+) -> jax.Array:
+    """One layer's attention over the just-updated paged cache: the ring
+    (full fresh prompt, T-sharded), the Pallas decode / ragged kernel, or
+    the gathered-context einsum. Returns ``[B, T, H, hd]``."""
+    B, T = q.shape[:2]
+    KV, bs, hd = lk.shape[1], lk.shape[2], lk.shape[3]
+    W = block_tables.shape[1]
+    if ring_lay is not None:
+        from ..parallel.ring_attention import ring_attention
+
+        # the ring runs over the serving mesh itself — the sequence
+        # axis is the composite (dp, tp) [..fsdp] axes, so the K/V the
+        # scatter reshards into the head-sharded cache never crosses a
+        # mesh boundary (THE involuntary-remat source this replaces)
+        seq_spec = ring_lay.heads_seq()
+        return layout.shard_map(
+            functools.partial(
+                ring_attention, axis_name=ring_lay.seq_axes()
+            ),
+            mesh=ring_mesh,
+            in_specs=(seq_spec, seq_spec, seq_spec),
+            out_specs=seq_spec,
+        )(q, k, v)
+    if use_pallas and T == 1:
+        return _paged_decode_attention(
+            eng, mesh, q, lk, lv, block_tables, seq_lens,
+            lks=lks, lvs=lvs,
+        )
+    if use_pallas:
+        return _paged_ragged_attention(
+            eng, mesh, q, lk, lv, block_tables, q_len, ctx_len,
+            lks=lks, lvs=lvs,
+        )
+    # gather the full context for attention: [B, W*bs, KV, hd] with
+    # gathered position = w*bs + offset = absolute position
+    k_all = jnp.take(
+        lk, block_tables.reshape(-1), axis=0
+    ).reshape(B, W, KV, bs, hd).transpose(0, 1, 3, 2, 4).reshape(
+        B, W * bs, KV, hd
+    )
+    v_all = jnp.take(
+        lv, block_tables.reshape(-1), axis=0
+    ).reshape(B, W, KV, bs, hd).transpose(0, 1, 3, 2, 4).reshape(
+        B, W * bs, KV, hd
+    )
+    if lks is not None:
+        ks_all = jnp.take(
+            lks, block_tables.reshape(-1), axis=0
+        ).reshape(B, W, KV, bs).transpose(0, 1, 3, 2).reshape(
+            B, W * bs, KV
+        )
+        vs_all = jnp.take(
+            lvs, block_tables.reshape(-1), axis=0
+        ).reshape(B, W, KV, bs).transpose(0, 1, 3, 2).reshape(
+            B, W * bs, KV
+        )
+        k_all = quant.kv_dequantize(k_all, ks_all, q.dtype)
+        v_all = quant.kv_dequantize(v_all, vs_all, q.dtype)
+    return _attention(q, k_all, v_all, positions)
+
+
 def forward(
     cfg: ModelConfig,
     eng: EngineConfig,
@@ -595,24 +684,28 @@ def forward(
     else:
         h_pin = None
 
-    h = jnp.take(params["embed"], tokens, axis=0)  # [B, T, D]
-    if mm_embeds is not None:
-        # multimodal EPD: placeholder positions take the encode worker's
-        # precomputed embeddings instead of token embeddings (ref: the
-        # TRT-LLM EPD flow, request_handlers/handler_base.py:64-234 — the
-        # reference splices prompt embeddings the same way)
-        h = jnp.where(mm_mask[..., None], mm_embeds.astype(h.dtype), h)
-    if h_pin is not None:
-        h = jax.lax.with_sharding_constraint(h, h_pin)
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], tokens, axis=0)  # [B, T, D]
+        if mm_embeds is not None:
+            # multimodal EPD: placeholder positions take the encode worker's
+            # precomputed embeddings instead of token embeddings (ref: the
+            # TRT-LLM EPD flow, request_handlers/handler_base.py:64-234 —
+            # the reference splices prompt embeddings the same way)
+            h = jnp.where(mm_mask[..., None], mm_embeds.astype(h.dtype), h)
+        if h_pin is not None:
+            h = jax.lax.with_sharding_constraint(h, h_pin)
 
     # physical (block, offset) per (b, t); pads go to the trash block 0
-    pos_safe = jnp.maximum(positions, 0)
-    logical_block = pos_safe // bs                      # [B, T]
-    phys_block = jnp.take_along_axis(
-        block_tables, jnp.minimum(logical_block, W - 1), axis=1
-    )                                                   # [B, T]
-    scatter_block = jnp.where(positions >= 0, phys_block, 0).reshape(-1)
-    scatter_off = jnp.where(positions >= 0, pos_safe % bs, 0).reshape(-1)
+    with jax.named_scope("kv_write"):
+        pos_safe = jnp.maximum(positions, 0)
+        logical_block = pos_safe // bs                      # [B, T]
+        phys_block = jnp.take_along_axis(
+            block_tables, jnp.minimum(logical_block, W - 1), axis=1
+        )                                                   # [B, T]
+        scatter_block = jnp.where(
+            positions >= 0, phys_block, 0).reshape(-1)
+        scatter_off = jnp.where(
+            positions >= 0, pos_safe % bs, 0).reshape(-1)
 
     attn_class = attention_class(eng, T)
     use_pallas = (not use_ring
@@ -622,13 +715,14 @@ def forward(
                         False, (0, 0))
     seq_lens = q_len = ctx_len = None
     if use_pallas:
-        if T == 1:
-            seq_lens = jnp.maximum(positions[:, 0] + 1, 0)
-        else:
-            # valid tokens are a per-row prefix (the spec/prefill feed
-            # contract), so count + max give the ragged-kernel metadata
-            q_len = jnp.sum(positions >= 0, axis=1).astype(jnp.int32)
-            ctx_len = jnp.maximum(jnp.max(positions, axis=1) + 1, 0)
+        with jax.named_scope("attention"):
+            if T == 1:
+                seq_lens = jnp.maximum(positions[:, 0] + 1, 0)
+            else:
+                # valid tokens are a per-row prefix (the spec/prefill feed
+                # contract), so count + max give the ragged-kernel metadata
+                q_len = jnp.sum(positions >= 0, axis=1).astype(jnp.int32)
+                ctx_len = jnp.maximum(jnp.max(positions, axis=1) + 1, 0)
 
     # Unrolled layer loop (NOT lax.scan): each layer's cache buffer is
     # donated and scatter-updated in place; a scanned stacked cache is
@@ -642,17 +736,19 @@ def forward(
     new_vs: list = []
     stacked = params["layers"]
     for li in range(cfg.num_layers):
-        p = _layer_slice(stacked, li)
+        p = _LayerSlice(stacked, li)
         lk, lv = cache["k"][li], cache["v"][li]   # [NB, KV, bs, hd]
         lks = cache["ks"][li] if kv_quant else None  # [NB, KV, bs] f32
         lvs = cache["vs"][li] if kv_quant else None
 
-        x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
-        q = _mm(x, p["wq"]).reshape(B, T, H, hd)
-        k = _mm(x, p["wk"]).reshape(B, T, KV, hd)
-        v = _mm(x, p["wv"]).reshape(B, T, KV, hd)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("qkv_proj"):
+            x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
+            q = _mm(x, p["wq"]).reshape(B, T, H, hd)
+            k = _mm(x, p["wk"]).reshape(B, T, KV, hd)
+            v = _mm(x, p["wv"]).reshape(B, T, KV, hd)
+        with jax.named_scope("rope"):
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         if use_ring:
             # projections of the T-sharded chunk stay T-sharded — without
             # the pin the column-sharded wq/wk/wv propagate a head
@@ -663,127 +759,87 @@ def forward(
             v = jax.lax.with_sharding_constraint(v, qkv_pin)
 
         # scatter this chunk's K/V into the paged cache
-        k_upd = k.reshape(B * T, KV, hd)
-        v_upd = v.reshape(B * T, KV, hd)
-        if use_ring and lay is not None:
-            # the one real layout change on the ring path: T-sharded K/V
-            # re-lands on the cache's head sharding. GSPMD cannot
-            # synthesize the seq->heads transform in one hop (it falls
-            # back to involuntary full rematerialization), so stage it
-            # explicitly: a planned all-gather over the sequence axes,
-            # then a local slice onto the cache's tp sharding
-            repl_pin = NamedSharding(mesh, layout.spec(None, None, None))
-            upd_pin = NamedSharding(mesh, layout.spec(None, lay.tp, None))
-            k_upd = jax.lax.with_sharding_constraint(k_upd, repl_pin)
-            v_upd = jax.lax.with_sharding_constraint(v_upd, repl_pin)
-            k_upd = jax.lax.with_sharding_constraint(k_upd, upd_pin)
-            v_upd = jax.lax.with_sharding_constraint(v_upd, upd_pin)
-        if kv_quant:
-            # per-(token, head) scales: a token's stored bytes depend only
-            # on its own K/V, never on block placement — so spec-decode
-            # and chunked-prefill replays of the same tokens stay
-            # bit-exact regardless of which block a replay scatters to
-            k_upd, k_sc = quant.kv_quantize(k_upd, eng.kv_dtype)
-            v_upd, v_sc = quant.kv_quantize(v_upd, eng.kv_dtype)
-            lks = lks.at[scatter_block, :, scatter_off].set(k_sc)
-            lvs = lvs.at[scatter_block, :, scatter_off].set(v_sc)
-        lk = lk.at[scatter_block, :, scatter_off].set(k_upd)
-        lv = lv.at[scatter_block, :, scatter_off].set(v_upd)
-
-        if use_ring:
-            from ..parallel.ring_attention import ring_attention
-
-            # the ring runs over the serving mesh itself — the sequence
-            # axis is the composite (dp, tp) [..fsdp] axes, so the K/V the
-            # scatter reshards into the head-sharded cache never crosses a
-            # mesh boundary (THE involuntary-remat source this replaces)
-            seq_spec = ring_lay.heads_seq()
-            attn = layout.shard_map(
-                functools.partial(
-                    ring_attention, axis_name=ring_lay.seq_axes()
-                ),
-                mesh=ring_mesh,
-                in_specs=(seq_spec, seq_spec, seq_spec),
-                out_specs=seq_spec,
-            )(q, k, v)
-        elif use_pallas and T == 1:
-            attn = _paged_decode_attention(
-                eng, mesh, q, lk, lv, block_tables, seq_lens,
-                lks=lks, lvs=lvs,
-            )
-        elif use_pallas:
-            attn = _paged_ragged_attention(
-                eng, mesh, q, lk, lv, block_tables, q_len, ctx_len,
-                lks=lks, lvs=lvs,
-            )
-        else:
-            # gather the full context for attention: [B, W*bs, KV, hd] with
-            # gathered position = w*bs + offset = absolute position
-            k_all = jnp.take(
-                lk, block_tables.reshape(-1), axis=0
-            ).reshape(B, W, KV, bs, hd).transpose(0, 1, 3, 2, 4).reshape(
-                B, W * bs, KV, hd
-            )
-            v_all = jnp.take(
-                lv, block_tables.reshape(-1), axis=0
-            ).reshape(B, W, KV, bs, hd).transpose(0, 1, 3, 2, 4).reshape(
-                B, W * bs, KV, hd
-            )
+        with jax.named_scope("kv_write"):
+            k_upd = k.reshape(B * T, KV, hd)
+            v_upd = v.reshape(B * T, KV, hd)
+            if use_ring and lay is not None:
+                # the one real layout change on the ring path: T-sharded
+                # K/V re-lands on the cache's head sharding. GSPMD cannot
+                # synthesize the seq->heads transform in one hop (it falls
+                # back to involuntary full rematerialization), so stage it
+                # explicitly: a planned all-gather over the sequence axes,
+                # then a local slice onto the cache's tp sharding
+                repl_pin = NamedSharding(
+                    mesh, layout.spec(None, None, None))
+                upd_pin = NamedSharding(
+                    mesh, layout.spec(None, lay.tp, None))
+                k_upd = jax.lax.with_sharding_constraint(k_upd, repl_pin)
+                v_upd = jax.lax.with_sharding_constraint(v_upd, repl_pin)
+                k_upd = jax.lax.with_sharding_constraint(k_upd, upd_pin)
+                v_upd = jax.lax.with_sharding_constraint(v_upd, upd_pin)
             if kv_quant:
-                ks_all = jnp.take(
-                    lks, block_tables.reshape(-1), axis=0
-                ).reshape(B, W, KV, bs).transpose(0, 1, 3, 2).reshape(
-                    B, W * bs, KV
-                )
-                vs_all = jnp.take(
-                    lvs, block_tables.reshape(-1), axis=0
-                ).reshape(B, W, KV, bs).transpose(0, 1, 3, 2).reshape(
-                    B, W * bs, KV
-                )
-                k_all = quant.kv_dequantize(k_all, ks_all, q.dtype)
-                v_all = quant.kv_dequantize(v_all, vs_all, q.dtype)
-            attn = _attention(q, k_all, v_all, positions)
-        h = h + _mm(attn.reshape(B, T, H * hd), p["wo"])
+                # per-(token, head) scales: a token's stored bytes depend
+                # only on its own K/V, never on block placement — so
+                # spec-decode and chunked-prefill replays of the same
+                # tokens stay bit-exact regardless of which block a replay
+                # scatters to
+                k_upd, k_sc = quant.kv_quantize(k_upd, eng.kv_dtype)
+                v_upd, v_sc = quant.kv_quantize(v_upd, eng.kv_dtype)
+                lks = lks.at[scatter_block, :, scatter_off].set(k_sc)
+                lvs = lvs.at[scatter_block, :, scatter_off].set(v_sc)
+            lk = lk.at[scatter_block, :, scatter_off].set(k_upd)
+            lv = lv.at[scatter_block, :, scatter_off].set(v_upd)
 
-        x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        if cfg.is_moe:
-            from ..parallel.moe import moe_ffn
-
-            D = x.shape[-1]
-            out = moe_ffn(
-                x.reshape(B * T, D),
-                p["w_router"],
-                _dequant_leaf(p["w_gate"], x.dtype),
-                _dequant_leaf(p["w_up"], x.dtype),
-                _dequant_leaf(p["w_down"], x.dtype),
-                top_k=cfg.num_experts_per_token,
-                capacity_factor=cfg.moe_capacity_factor,
+        with jax.named_scope("attention"):
+            attn = _layer_attention(
+                eng, mesh, ring_mesh, ring_lay, use_pallas, q, k, v,
+                lk, lv, lks, lvs, positions, block_tables,
+                seq_lens, q_len, ctx_len,
             )
-            h = h + out.reshape(B, T, D)
-        else:
-            gate = jax.nn.silu(_mm(x, p["w_gate"]).astype(jnp.float32))
-            up = _mm(x, p["w_up"]).astype(jnp.float32)
-            if use_ring:
-                # ring chunks run the MLP sequence-parallel: activations
-                # stay T-sharded, the (small) weights all-gather — pin the
-                # intermediates so w_down's row sharding can't pull a
-                # head-style spec onto them
-                ff_pin = NamedSharding(
-                    ring_mesh,
-                    layout.spec(None, ring_lay.seq_axes(), None),
+        with jax.named_scope("o_proj"):
+            h = h + _mm(attn.reshape(B, T, H * hd), p["wo"])
+
+        with jax.named_scope("mlp"):
+            x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
+            if cfg.is_moe:
+                from ..parallel.moe import moe_ffn
+
+                D = x.shape[-1]
+                out = moe_ffn(
+                    x.reshape(B * T, D),
+                    p["w_router"],
+                    _dequant_leaf(p["w_gate"], x.dtype),
+                    _dequant_leaf(p["w_up"], x.dtype),
+                    _dequant_leaf(p["w_down"], x.dtype),
+                    top_k=cfg.num_experts_per_token,
+                    capacity_factor=cfg.moe_capacity_factor,
                 )
-                gate = jax.lax.with_sharding_constraint(gate, ff_pin)
-                up = jax.lax.with_sharding_constraint(up, ff_pin)
-            h = h + _mm((gate * up).astype(h.dtype), p["w_down"])
-        if h_pin is not None:
-            h = jax.lax.with_sharding_constraint(h, h_pin)
+                h = h + out.reshape(B, T, D)
+            else:
+                gate = jax.nn.silu(_mm(x, p["w_gate"]).astype(jnp.float32))
+                up = _mm(x, p["w_up"]).astype(jnp.float32)
+                if use_ring:
+                    # ring chunks run the MLP sequence-parallel:
+                    # activations stay T-sharded, the (small) weights
+                    # all-gather — pin the intermediates so w_down's row
+                    # sharding can't pull a head-style spec onto them
+                    ff_pin = NamedSharding(
+                        ring_mesh,
+                        layout.spec(None, ring_lay.seq_axes(), None),
+                    )
+                    gate = jax.lax.with_sharding_constraint(gate, ff_pin)
+                    up = jax.lax.with_sharding_constraint(up, ff_pin)
+                h = h + _mm((gate * up).astype(h.dtype), p["w_down"])
+            if h_pin is not None:
+                h = jax.lax.with_sharding_constraint(h, h_pin)
         new_k.append(lk)
         new_v.append(lv)
         if kv_quant:
             new_ks.append(lks)
             new_vs.append(lvs)
 
-    h = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("final_norm"):
+        h = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     out_cache: Cache = {"k": new_k, "v": new_v}
     if kv_quant:
         out_cache["ks"] = new_ks
@@ -796,17 +852,18 @@ def logits_fn(cfg: ModelConfig, params: Params, h: jax.Array) -> jax.Array:
             else params["lm_head"])
     # bf16 x bf16 -> f32 on the MXU; casting the [D, V] head to f32 first
     # would materialise ~1 GB in HBM every step
-    if isinstance(head, dict):
-        y = jax.lax.dot_general(
-            h, head["q"].astype(h.dtype),
-            (((h.ndim - 1,), (0,)), ((), ())),
+    with jax.named_scope("lm_head"):
+        if isinstance(head, dict):
+            y = jax.lax.dot_general(
+                h, head["q"].astype(h.dtype),
+                (((h.ndim - 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return y * head["s"][0]
+        return jax.lax.dot_general(
+            h, head.astype(h.dtype), (((h.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        return y * head["s"][0]
-    return jax.lax.dot_general(
-        h, head.astype(h.dtype), (((h.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
 
 
 # --------------------------- encode (embeddings) --------------------------
@@ -833,7 +890,7 @@ def encode_forward(
     h = jnp.take(params["embed"], tokens, axis=0)  # [B, T, D]
     stacked = params["layers"]
     for li in range(cfg.num_layers):
-        p = _layer_slice(stacked, li)
+        p = _LayerSlice(stacked, li)
         x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
         q = _mm(x, p["wq"]).reshape(B, T, H, hd)
         k = _mm(x, p["wk"]).reshape(B, T, KV, hd)
@@ -915,6 +972,7 @@ def _row_keys(
     return jax.vmap(mk)(seeds, positions, jnp.arange(seeds.shape[0]))
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jax.Array,      # [B, V] float32
     rng: jax.Array,
@@ -983,6 +1041,20 @@ def sample(
 # --------------------------- the step function ----------------------------
 
 
+def _last_logits(cfg: ModelConfig, params: Params, h: jax.Array,
+                 positions: jax.Array, last_idx: jax.Array):
+    """Logits [B, V] and absolute position [B] of each row's last valid
+    chunk token (``last_idx``) — the one a prefill step samples from."""
+    with jax.named_scope("lm_head"):
+        h_last = h[jnp.arange(h.shape[0]), last_idx]     # [B, D]
+    logits = logits_fn(cfg, params, h_last)              # [B, V]
+    with jax.named_scope("sample"):
+        pos_last = jnp.take_along_axis(
+            positions, last_idx[:, None], axis=1
+        )[:, 0]
+    return logits, pos_last
+
+
 def raw_step_fn(cfg: ModelConfig, eng: EngineConfig,
                 mesh: Optional[Mesh] = None,
                 ring_mesh: Optional[Mesh] = None):
@@ -1003,12 +1075,7 @@ def raw_step_fn(cfg: ModelConfig, eng: EngineConfig,
             cfg, eng, params, cache, tokens, positions, block_tables,
             mesh=mesh, ring_mesh=ring_mesh,
         )
-        B = tokens.shape[0]
-        h_last = h[jnp.arange(B), last_idx]          # [B, D]
-        logits = logits_fn(cfg, params, h_last)      # [B, V]
-        pos_last = jnp.take_along_axis(
-            positions, last_idx[:, None], axis=1
-        )[:, 0]
+        logits, pos_last = _last_logits(cfg, params, h, positions, last_idx)
         sampled = sample(
             logits, rng, temperature, top_k, top_p, seeds, pos_last
         )
@@ -1049,7 +1116,9 @@ def raw_multistep_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
                 cfg, eng, params, cache, tok, pos_eff, block_tables,
                 mesh=mesh,
             )
-            logits = logits_fn(cfg, params, h[:, 0])
+            with jax.named_scope("lm_head"):
+                h_last = h[:, 0]
+            logits = logits_fn(cfg, params, h_last)
             s = sample(
                 logits, rng_t, temperature, top_k, top_p, seeds, pos[:, 0]
             )
@@ -1126,34 +1195,41 @@ def raw_decode_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
     def window(params, cache, last_tok, tok_host, tok_src, slot_ids,
                positions, block_tables, valid_until, rngs,
                temperature, top_k, top_p, seeds):
-        tok = jnp.where(tok_src > 0, last_tok[slot_ids], tok_host)[:, None]
+        with jax.named_scope("ctl"):
+            tok = jnp.where(
+                tok_src > 0, last_tok[slot_ids], tok_host)[:, None]
         pos = positions
         outs = []
         for k in range(K):
-            pos_eff = jnp.where(pos < valid_until[:, None], pos, -1)
+            with jax.named_scope("ctl"):
+                pos_eff = jnp.where(pos < valid_until[:, None], pos, -1)
             cache, h = forward(
                 cfg, eng, params, cache, tok, pos_eff, block_tables,
                 mesh=mesh,
             )
-            logits = logits_fn(cfg, params, h[:, 0])
+            with jax.named_scope("lm_head"):
+                h_last = h[:, 0]
+            logits = logits_fn(cfg, params, h_last)
             s = sample(
                 logits, rngs[k], temperature, top_k, top_p, seeds,
                 pos[:, 0],
             )
             outs.append(s)
-            tok, pos = s[:, None], pos + 1
-        samples = jnp.stack(outs)                            # [K, B]
+            with jax.named_scope("ctl"):
+                tok, pos = s[:, None], pos + 1
         # write each row's last in-capacity sample back to its ring slot; a
         # row already at/over capacity (acc == 0 — e.g. a padding row whose
         # valid_until <= pos) produced ONLY garbage samples, so route its
         # write to the trash slot S instead of corrupting a live ring entry
-        acc = jnp.clip(valid_until - positions[:, 0], 0, K)  # [B]
-        final = jnp.take_along_axis(
-            samples, jnp.maximum(acc - 1, 0)[None, :], axis=0
-        )[0]
-        S = last_tok.shape[0] - 1
-        write_slots = jnp.where(acc > 0, slot_ids, S)
-        last_tok = last_tok.at[write_slots].set(final)
+        with jax.named_scope("ctl"):
+            samples = jnp.stack(outs)                            # [K, B]
+            acc = jnp.clip(valid_until - positions[:, 0], 0, K)  # [B]
+            final = jnp.take_along_axis(
+                samples, jnp.maximum(acc - 1, 0)[None, :], axis=0
+            )[0]
+            S = last_tok.shape[0] - 1
+            write_slots = jnp.where(acc > 0, slot_ids, S)
+            last_tok = last_tok.at[write_slots].set(final)
         return cache, last_tok, samples
 
     return window
@@ -1237,6 +1313,7 @@ def raw_ctl_delta_fn(Wcap: int):
     delta_f32 [n, 2]: temperature, top_p. Pad rows use slot = S (trash).
     """
 
+    @jax.named_scope("ctl")
     def apply(ctl, delta_i32, delta_f32):
         slots = delta_i32[:, 0]
         ctl = dict(ctl)
@@ -1271,38 +1348,44 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
 
     def window(params, cache, ctl, slot_rows):
         rows = slot_rows
-        tok = ctl["last_tok"][rows][:, None]
-        pos0 = ctl["pos"][rows]
-        vu = ctl["vu"][rows]
-        temp = ctl["temp"][rows]
-        tk = ctl["tk"][rows]
-        tp = ctl["tp"][rows]
-        sd = ctl["seed"][rows]
-        tables = ctl["tables"][rows]
-        pos = pos0[:, None]
+        with jax.named_scope("ctl"):
+            tok = ctl["last_tok"][rows][:, None]
+            pos0 = ctl["pos"][rows]
+            vu = ctl["vu"][rows]
+            temp = ctl["temp"][rows]
+            tk = ctl["tk"][rows]
+            tp = ctl["tp"][rows]
+            sd = ctl["seed"][rows]
+            tables = ctl["tables"][rows]
+            pos = pos0[:, None]
         outs = []
         for k in range(K):
-            rng_k = jax.random.fold_in(ctl["key"], ctl["ctr"] * K + k)
-            pos_eff = jnp.where(pos < vu[:, None], pos, -1)
+            with jax.named_scope("ctl"):
+                rng_k = jax.random.fold_in(ctl["key"], ctl["ctr"] * K + k)
+                pos_eff = jnp.where(pos < vu[:, None], pos, -1)
             cache, h = forward(
                 cfg, eng, params, cache, tok, pos_eff, tables, mesh=mesh,
             )
-            logits = logits_fn(cfg, params, h[:, 0])
+            with jax.named_scope("lm_head"):
+                h_last = h[:, 0]
+            logits = logits_fn(cfg, params, h_last)
             s = sample(logits, rng_k, temp, tk, tp, sd, pos[:, 0])
             outs.append(s)
-            tok, pos = s[:, None], pos + 1
-        samples = jnp.stack(outs)                          # [K, B]
-        acc = jnp.clip(vu - pos0, 0, K)                    # [B]
-        final = jnp.take_along_axis(
-            samples, jnp.maximum(acc - 1, 0)[None, :], axis=0
-        )[0]
-        S = ctl["last_tok"].shape[0] - 1
-        write_rows = jnp.where(acc > 0, rows, S)
+            with jax.named_scope("ctl"):
+                tok, pos = s[:, None], pos + 1
         ctl = dict(ctl)
-        ctl["last_tok"] = ctl["last_tok"].at[write_rows].set(final)
-        # duplicate trash rows accumulate zero (acc there is 0)
-        ctl["pos"] = ctl["pos"].at[rows].add(acc)
-        ctl["ctr"] = ctl["ctr"] + 1
+        with jax.named_scope("ctl"):
+            samples = jnp.stack(outs)                          # [K, B]
+            acc = jnp.clip(vu - pos0, 0, K)                    # [B]
+            final = jnp.take_along_axis(
+                samples, jnp.maximum(acc - 1, 0)[None, :], axis=0
+            )[0]
+            S = ctl["last_tok"].shape[0] - 1
+            write_rows = jnp.where(acc > 0, rows, S)
+            ctl["last_tok"] = ctl["last_tok"].at[write_rows].set(final)
+            # duplicate trash rows accumulate zero (acc there is 0)
+            ctl["pos"] = ctl["pos"].at[rows].add(acc)
+            ctl["ctr"] = ctl["ctr"] + 1
         return cache, ctl, samples
 
     return window
@@ -1363,73 +1446,76 @@ def raw_spec_window_fn(cfg: ModelConfig, eng: EngineConfig, k: int,
 
     def window(params, cache, ctl, slot_rows):
         rows = slot_rows                                   # [B]
-        tok0 = ctl["last_tok"][rows]
-        pos0 = ctl["pos"][rows]
-        vu = ctl["vu"][rows]
-        temp = ctl["temp"][rows]
-        tk = ctl["tk"][rows]
-        tp = ctl["tp"][rows]
-        sd = ctl["seed"][rows]
-        tables = ctl["tables"][rows]
-        hist = ctl["hist"]                                 # [S+1, Hcap+1]
-        S = ctl["last_tok"].shape[0] - 1
-        Hcap = hist.shape[1] - 1
-        live = vu > pos0
-        # keep the history coherent with the ring: the window's input token
-        # IS all_tokens[pos0] (defensive — joins already host-fill it)
-        hist = hist.at[
-            jnp.where(live, rows, S),
-            jnp.where(live, jnp.clip(pos0, 0, Hcap - 1), Hcap),
-        ].set(tok0)
-        drafts = propose_drafts(hist[rows], pos0, k, ngram_min, ngram_max)
-        drafts = jnp.where((temp <= 0.0)[:, None], drafts, -1)  # [B, k]
-        dvalid = jnp.cumprod(
-            (drafts >= 0).astype(jnp.int32), axis=1
-        ).astype(bool)
-        steps = jnp.arange(k + 1, dtype=jnp.int32)
-        toks = jnp.concatenate(
-            [tok0[:, None], jnp.where(dvalid, drafts, 0)], axis=1
-        )                                                  # [B, k+1]
-        pos = pos0[:, None] + steps[None, :]
-        feed = jnp.concatenate(
-            [jnp.ones_like(dvalid[:, :1]), dvalid], axis=1
-        ) & (pos < vu[:, None])
-        pos_eff = jnp.where(feed, pos, -1)
+        with jax.named_scope("ctl"):
+            tok0 = ctl["last_tok"][rows]
+            pos0 = ctl["pos"][rows]
+            vu = ctl["vu"][rows]
+            temp = ctl["temp"][rows]
+            tk = ctl["tk"][rows]
+            tp = ctl["tp"][rows]
+            sd = ctl["seed"][rows]
+            tables = ctl["tables"][rows]
+            hist = ctl["hist"]                                 # [S+1, Hcap+1]
+            S = ctl["last_tok"].shape[0] - 1
+            Hcap = hist.shape[1] - 1
+            live = vu > pos0
+            # keep the history coherent with the ring: the window's input token
+            # IS all_tokens[pos0] (defensive — joins already host-fill it)
+            hist = hist.at[
+                jnp.where(live, rows, S),
+                jnp.where(live, jnp.clip(pos0, 0, Hcap - 1), Hcap),
+            ].set(tok0)
+            drafts = propose_drafts(hist[rows], pos0, k, ngram_min, ngram_max)
+            drafts = jnp.where((temp <= 0.0)[:, None], drafts, -1)  # [B, k]
+            dvalid = jnp.cumprod(
+                (drafts >= 0).astype(jnp.int32), axis=1
+            ).astype(bool)
+            steps = jnp.arange(k + 1, dtype=jnp.int32)
+            toks = jnp.concatenate(
+                [tok0[:, None], jnp.where(dvalid, drafts, 0)], axis=1
+            )                                                  # [B, k+1]
+            pos = pos0[:, None] + steps[None, :]
+            feed = jnp.concatenate(
+                [jnp.ones_like(dvalid[:, :1]), dvalid], axis=1
+            ) & (pos < vu[:, None])
+            pos_eff = jnp.where(feed, pos, -1)
         cache, h = forward(
             cfg, eng, params, cache, toks, pos_eff, tables, mesh=mesh,
         )
         logits = logits_fn(cfg, params, h)                 # [B, k+1, V]
-        g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, k+1]
+        with jax.named_scope("sample"):
+            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, k+1]
         rng_w = jax.random.fold_in(ctl["key"], ctl["ctr"])
         s0 = sample(logits[:, 0], rng_w, temp, tk, tp, sd, pos0)
-        emitted = jnp.concatenate([s0[:, None], g[:, 1:]], axis=1)
-        # accept the longest draft prefix the target model reproduces; the
-        # query at index i (position pos0+i) verifies draft i
-        match = dvalid & (drafts == g[:, :k])              # [B, k]
-        a = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
-        cap = jnp.clip(vu - pos0, 0, k + 1)
-        n = jnp.minimum(a + 1, cap)                        # [B] emitted
-        final = jnp.take_along_axis(
-            emitted, jnp.maximum(n - 1, 0)[:, None], axis=1
-        )[:, 0]
         ctl = dict(ctl)
-        write_rows = jnp.where(n > 0, rows, S)
-        ctl["last_tok"] = ctl["last_tok"].at[write_rows].set(final)
-        # duplicate trash rows accumulate zero (n there is 0)
-        ctl["pos"] = ctl["pos"].at[rows].add(n)
-        # append the landed tokens to the history (emitted j is
-        # all_tokens[pos0+1+j]); rejects route to the trash cell
-        hv = steps[None, :] < n[:, None]
-        ctl["hist"] = hist.at[
-            jnp.where(hv, rows[:, None], S),
-            jnp.where(hv, jnp.clip(pos0[:, None] + 1 + steps[None, :],
-                                   0, Hcap - 1), Hcap),
-        ].set(emitted)
-        ctl["ctr"] = ctl["ctr"] + 1
-        ndraft = jnp.sum(dvalid.astype(jnp.int32), axis=1)
-        packed = jnp.concatenate(
-            [emitted.T, n[None, :], ndraft[None, :]], axis=0
-        ).astype(jnp.int32)                                # [k+3, B]
+        with jax.named_scope("ctl"):
+            emitted = jnp.concatenate([s0[:, None], g[:, 1:]], axis=1)
+            # accept the longest draft prefix the target model reproduces; the
+            # query at index i (position pos0+i) verifies draft i
+            match = dvalid & (drafts == g[:, :k])              # [B, k]
+            a = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
+            cap = jnp.clip(vu - pos0, 0, k + 1)
+            n = jnp.minimum(a + 1, cap)                        # [B] emitted
+            final = jnp.take_along_axis(
+                emitted, jnp.maximum(n - 1, 0)[:, None], axis=1
+            )[:, 0]
+            write_rows = jnp.where(n > 0, rows, S)
+            ctl["last_tok"] = ctl["last_tok"].at[write_rows].set(final)
+            # duplicate trash rows accumulate zero (n there is 0)
+            ctl["pos"] = ctl["pos"].at[rows].add(n)
+            # append the landed tokens to the history (emitted j is
+            # all_tokens[pos0+1+j]); rejects route to the trash cell
+            hv = steps[None, :] < n[:, None]
+            ctl["hist"] = hist.at[
+                jnp.where(hv, rows[:, None], S),
+                jnp.where(hv, jnp.clip(pos0[:, None] + 1 + steps[None, :],
+                                       0, Hcap - 1), Hcap),
+            ].set(emitted)
+            ctl["ctr"] = ctl["ctr"] + 1
+            ndraft = jnp.sum(dvalid.astype(jnp.int32), axis=1)
+            packed = jnp.concatenate(
+                [emitted.T, n[None, :], ndraft[None, :]], axis=0
+            ).astype(jnp.int32)                                # [k+3, B]
         return cache, ctl, packed
 
     return window
@@ -1444,6 +1530,7 @@ def raw_spec_hist_fill_fn():
     maintain the history on device with zero host uploads.
     """
 
+    @jax.named_scope("ctl")
     def fill(ctl, slots, rows):
         ctl = dict(ctl)
         ctl["hist"] = ctl["hist"].at[slots].set(rows)
@@ -1499,9 +1586,10 @@ def raw_ring_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
             params, cache, tokens, positions, block_tables, last_idx,
             rng, temperature, top_k, top_p, seeds,
         )
-        S = last_tok.shape[0] - 1  # trash slot
-        slot_eff = jnp.where(write_mask > 0, slot_ids, S)
-        last_tok = last_tok.at[slot_eff].set(sampled)
+        with jax.named_scope("ctl"):
+            S = last_tok.shape[0] - 1  # trash slot
+            slot_eff = jnp.where(write_mask > 0, slot_ids, S)
+            last_tok = last_tok.at[slot_eff].set(sampled)
         return cache, last_tok, sampled
 
     return prefill
@@ -1528,26 +1616,28 @@ def raw_packed_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
     base = raw_step_fn(cfg, eng, mesh)
 
     def prefill(params, cache, last_tok, pint, rng):
-        tokens = pint[:, :T]
-        tables = pint[:, T:T + W]
-        n = pint[0, T + W + 0]
-        start = pint[0, T + W + 1]
-        slot = pint[0, T + W + 2]
-        write = pint[0, T + W + 3]
-        top_k = pint[0:1, T + W + 4]
-        seed = pint[0:1, T + W + 5]
-        temp = pint[0:1, T + W + 6].astype(jnp.float32) / PP_QUANT
-        tp = pint[0:1, T + W + 7].astype(jnp.float32) / PP_QUANT
-        idx = jnp.arange(T, dtype=jnp.int32)
-        positions = jnp.where(idx < n, start + idx, -1)[None, :]
-        last_idx = jnp.maximum(n - 1, 0)[None]
+        with jax.named_scope("ctl"):
+            tokens = pint[:, :T]
+            tables = pint[:, T:T + W]
+            n = pint[0, T + W + 0]
+            start = pint[0, T + W + 1]
+            slot = pint[0, T + W + 2]
+            write = pint[0, T + W + 3]
+            top_k = pint[0:1, T + W + 4]
+            seed = pint[0:1, T + W + 5]
+            temp = pint[0:1, T + W + 6].astype(jnp.float32) / PP_QUANT
+            tp = pint[0:1, T + W + 7].astype(jnp.float32) / PP_QUANT
+            idx = jnp.arange(T, dtype=jnp.int32)
+            positions = jnp.where(idx < n, start + idx, -1)[None, :]
+            last_idx = jnp.maximum(n - 1, 0)[None]
         cache, sampled = base(
             params, cache, tokens, positions, tables, last_idx, rng,
             temp, top_k, tp, seed,
         )
-        S = last_tok.shape[0] - 1
-        slot_eff = jnp.where(write > 0, slot, S)[None]
-        last_tok = last_tok.at[slot_eff].set(sampled)
+        with jax.named_scope("ctl"):
+            S = last_tok.shape[0] - 1
+            slot_eff = jnp.where(write > 0, slot, S)[None]
+            last_tok = last_tok.at[slot_eff].set(sampled)
         return cache, last_tok, sampled
 
     return prefill
@@ -1599,12 +1689,7 @@ def make_mm_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
             cfg, eng, params, cache, tokens, positions, block_tables,
             mesh=mesh, mm_embeds=mm_embeds, mm_mask=mm_mask,
         )
-        B = tokens.shape[0]
-        h_last = h[jnp.arange(B), last_idx]
-        logits = logits_fn(cfg, params, h_last)
-        pos_last = jnp.take_along_axis(
-            positions, last_idx[:, None], axis=1
-        )[:, 0]
+        logits, pos_last = _last_logits(cfg, params, h, positions, last_idx)
         sampled = sample(
             logits, rng, temperature, top_k, top_p, seeds, pos_last
         )
@@ -1631,18 +1716,14 @@ def make_mm_ring_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
             cfg, eng, params, cache, tokens, positions, block_tables,
             mesh=mesh, mm_embeds=mm_embeds, mm_mask=mm_mask,
         )
-        B = tokens.shape[0]
-        h_last = h[jnp.arange(B), last_idx]
-        logits = logits_fn(cfg, params, h_last)
-        pos_last = jnp.take_along_axis(
-            positions, last_idx[:, None], axis=1
-        )[:, 0]
+        logits, pos_last = _last_logits(cfg, params, h, positions, last_idx)
         sampled = sample(
             logits, rng, temperature, top_k, top_p, seeds, pos_last
         )
-        S = last_tok.shape[0] - 1
-        slot_eff = jnp.where(write_mask > 0, slot_ids, S)
-        last_tok = last_tok.at[slot_eff].set(sampled)
+        with jax.named_scope("ctl"):
+            S = last_tok.shape[0] - 1
+            slot_eff = jnp.where(write_mask > 0, slot_ids, S)
+            last_tok = last_tok.at[slot_eff].set(sampled)
         return cache, last_tok, sampled
 
     return compilewatch.label(

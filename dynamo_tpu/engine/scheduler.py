@@ -216,6 +216,14 @@ class SchedSeq:
     # worker.queue / engine.prefill / engine.decode span windows from these
     t_scheduled: Optional[float] = None
     t_first_token: Optional[float] = None
+    # inside engine.prefill: when the prompt-completing chunk was enqueued
+    # on the device (dispatch thread) and when its sample landed on the host
+    # (fetch thread); the engine stamps both until the first token is out
+    t_dispatched: Optional[float] = None
+    t_landed: Optional[float] = None
+    # prompt tokens the prefix cache served at first admission
+    # (Scheduler._match_prefix) — the worker.queue span's hit count
+    cached_tokens: int = 0
     status: SeqStatus = SeqStatus.WAITING
     output_ids: List[int] = field(default_factory=list)
     block_table: List[int] = field(default_factory=list)
@@ -305,6 +313,9 @@ class ScheduledBatch:
     # commits at landing — riding the batch keeps attribution correct
     # with several pipelined windows in flight
     obs_records: List = field(default_factory=list)
+    # seconds the engine-loop task was busy since it handed the previous
+    # batch to the dispatch thread (stamped by the loop at handoff)
+    host_s: float = 0.0
 
     @property
     def decodes(self) -> List[SchedSeq]:
@@ -670,6 +681,8 @@ class Scheduler:
         seq.block_table = matched
         seq.num_computed = len(matched) * bs
         seq.num_sealed_blocks = len(matched)
+        if seq.t_scheduled is None:  # a re-match after preemption is not
+            seq.cached_tokens = seq.num_computed   # the request's hit
         if self.on_prefix_match is not None:
             self.on_prefix_match(queried_hashes, matched_hashes)
 

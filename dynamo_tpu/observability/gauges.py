@@ -34,6 +34,11 @@ class EngineObsGauges:
             "engine_goodput_tok_s",
             "real tokens landed per second over the trailing window",
         )
+        self._g_host_busy = registry.gauge(
+            "engine_host_busy_ratio",
+            "share of the trailing window the engine-loop task was busy "
+            "(StepRecord.host_s sums): what a faster device would uncover",
+        )
         self._g_pad_waste = registry.gauge(
             "engine_padding_waste_ratio",
             "fraction of dispatched FLOPs burnt on bucket padding",
@@ -82,6 +87,7 @@ class EngineObsGauges:
             self._g_mfu_class.labels(step="prefill").set(snap["mfu_prefill"])
             self._g_mfu_class.labels(step="decode").set(snap["mfu_decode"])
         self._g_goodput.set(snap.get("goodput_tok_s", 0.0))
+        self._g_host_busy.set(snap.get("host_busy_ratio", 0.0))
         self._g_pad_waste.set(snap.get("padding_waste_ratio", 0.0))
         self._g_waste.labels(cause="padding").set(
             snap.get("padding_waste_ratio", 0.0))

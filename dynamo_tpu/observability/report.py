@@ -78,6 +78,18 @@ def render_report(records: List[dict]) -> str:
             f"spec acceptance:   {spec_accepted}/{spec_drafted} "
             f"({100.0 * spec_accepted / spec_drafted:.1f}%)"
         )
+    batches = [r for r in records if r.get("host_s") or r.get("unpack_s")]
+    if batches:
+        # per batch: the loop's busy seconds and the fetch thread's sit on
+        # one record of it, the dispatch thread's on every record
+        n = len(batches)
+        host = sum(r["host_s"] for r in batches)
+        lines.append(
+            f"host per step:     loop {1e3 * host / n:.2f} ms  dispatch "
+            f"{1e3 * sum(r.get('dispatch_s', 0.0) for r in records) / n:.2f}"
+            f" ms  unpack {1e3 * sum(r['unpack_s'] for r in batches) / n:.2f}"
+            f" ms  (loop busy {_pct(host, wall).strip()} of wall)"
+        )
     return "\n".join(lines) + "\n"
 
 

@@ -17,6 +17,8 @@ host syncs to the hot path (dynalint-enforced).
 * ``padding_waste_ratio`` — dispatched FLOPs burnt on bucket padding
 * ``wasted_flops_ratio{cause=padding|spec_reject}`` — where the
   non-goodput FLOPs went
+* ``host_busy_ratio`` — share of the window the engine-loop task was busy
+  (``host_s`` sums), the floor a faster device would uncover
 
 The FLOPs accounting uses the shared analytic model
 (:mod:`.flops` — attention term included, not just ``2·N·params``).
@@ -33,6 +35,8 @@ from typing import Deque, Dict, Optional
 
 from ..utils.hotpath import hot_path
 from .flops import FlopsModel
+
+JSONL_FLUSH_S = 1.0  # most a JSONL capture waits before reaching the file
 
 # step classes
 PREFILL = "prefill"
@@ -56,6 +60,15 @@ class StepRecord:
     context_sum: int = 0      # sum of attended context over real tokens
     spec_drafted: int = 0
     spec_accepted: int = 0
+    # host seconds (``time.monotonic()`` differences, no device access).
+    # dispatch_s: the dispatch thread inside this record's _dispatch_prefill
+    # / _dispatch_decode. host_s, unpack_s are per BATCH and sit on its
+    # decode record (its last record when it has none): host_s the
+    # engine-loop task's busy time since it handed off the previous batch,
+    # unpack_s the fetch thread from the device_get's return to commit.
+    host_s: float = 0.0
+    dispatch_s: float = 0.0
+    unpack_s: float = 0.0
     # filled by StepStats.commit from the shared FLOPs model
     flops_dispatched: float = 0.0
     flops_real: float = 0.0
@@ -78,9 +91,11 @@ class _Window:
     flops_goodput_decode: float = 0.0
     spec_drafted: int = 0
     spec_accepted: int = 0
+    host_s: float = 0.0
 
     def add(self, r: StepRecord, sign: int = 1) -> None:
         self.steps += sign
+        self.host_s += sign * r.host_s
         self.goodput_tokens += sign * r.goodput_tokens
         self.real_tokens += sign * r.real_tokens
         self.padded_tokens += sign * r.padded_tokens
@@ -129,6 +144,7 @@ class StepStats:
         self._t_start = clock()       # window floor (reset at warmup end)
         self._warmup_done = False
         self._jsonl_fh = None
+        self._jsonl_flushed = 0.0   # clock() of the last flush
         # lifetime totals (never pruned) — survive window rollover
         self.total_steps = 0
         self.total_goodput_tokens = 0
@@ -191,7 +207,13 @@ class StepStats:
             if self._jsonl_fh is None:
                 self._jsonl_fh = open(self.jsonl_path, "a")
             self._jsonl_fh.write(line + "\n")
-            self._jsonl_fh.flush()
+            # buffered: a flush a second at most, the rest on close() — a
+            # crash loses under a second of records, a traced run keeps
+            # the syscall off every landing
+            now = self._clock()
+            if now - self._jsonl_flushed >= JSONL_FLUSH_S:
+                self._jsonl_fh.flush()
+                self._jsonl_flushed = now
 
     def close(self) -> None:
         with self._lock:
@@ -251,6 +273,7 @@ class StepStats:
                 }
             snap.update({
                 "goodput_tok_s": w.goodput_tokens / elapsed,
+                "host_busy_ratio": max(w.host_s, 0.0) / elapsed,
                 "padding_waste_ratio": (
                     w.flops_padding_waste / dispatched if dispatched else 0.0),
                 "spec_reject_waste_ratio": (

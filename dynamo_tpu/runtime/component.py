@@ -130,6 +130,7 @@ class DistributedRuntime:
             await srv.stop()
         await self.transport.close()
         await self.store.close()
+        tracing.get_tracer().close()  # buffered span export reaches disk
 
 
 class Namespace:

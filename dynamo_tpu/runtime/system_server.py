@@ -158,8 +158,10 @@ class SystemServer:
     async def _profile(self, request: web.Request) -> web.Response:
         """On-demand device profile: ``GET /debug/profile?ms=N`` captures a
         ``jax.profiler`` trace for N ms (clamped) into a TensorBoard-loadable
-        directory and returns its path. One capture at a time per process;
-        concurrent requests get 409."""
+        directory and returns its path. ``&python=1`` adds the profiler's
+        Python-frame tracer (off by default: it slows the host it
+        measures). One capture at a time per process; concurrent requests
+        get 409."""
         from ..observability import profiling
 
         try:
@@ -170,7 +172,8 @@ class SystemServer:
             )
         try:
             result = await profiling.capture(
-                ms, base_dir=request.query.get("dir", "")
+                ms, base_dir=request.query.get("dir", ""),
+                python=request.query.get("python", "0") == "1",
             )
         except profiling.ProfileBusyError as exc:
             return web.json_response({"error": str(exc)}, status=409)

@@ -93,6 +93,13 @@ class SpanCollector:
                 return
         self.add_exporter(JsonlSpanExporter(path))
 
+    def close(self) -> None:
+        """Close every exporter that holds a file (buffered JSONL lines
+        reach the disk here); a later export reopens it."""
+        for e in self._exporters:
+            if hasattr(e, "close"):
+                e.close()
+
     def attach_metrics(self, registry: Any,
                        name: str = "stage_latency_seconds") -> None:
         """Mint the per-stage latency histogram on ``registry`` and observe
@@ -178,11 +185,13 @@ class SpanCollector:
         status: str = "ok",
         status_detail: Optional[str] = None,
         root: bool = False,
+        events: Optional[List[tuple]] = None,
     ) -> Span:
         """Record an already-elapsed window from explicit monotonic stamps —
         the engine hot path stamps floats per sequence and attributes the
         queue/prefill/decode windows once, after the stream ends, instead of
-        carrying live span objects per token."""
+        carrying live span objects per token. ``events`` are ``(monotonic
+        stamp, name)`` points inside the window."""
         span = self.start_span(
             name, context, trace=trace, parent_span_id=parent_span_id,
             attrs=attrs, root=root,
@@ -192,6 +201,8 @@ class SpanCollector:
         span.start_unix = time.time() - (time.monotonic() - start_mono)
         span.status = status
         span.status_detail = status_detail
+        for t_mono, ev_name in events or ():
+            span.events.append((t_mono - start_mono, ev_name, None))
         span.end(end_mono)
         return span
 

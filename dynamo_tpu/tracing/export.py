@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from typing import Dict, List, Optional
 
 from .span import Span
@@ -30,14 +31,19 @@ class InMemorySpanExporter:
 
 
 class JsonlSpanExporter:
-    """One JSON object per line, flushed per span so a crashing process
-    loses at most the span being written. Open lazily: a configured-but-idle
+    """One JSON object per line, buffered: a flush a second at most and on
+    ``close()`` (``DistributedRuntime.shutdown`` closes the tracer's
+    exporters), so a crashing process loses under a second of spans and a
+    traced one pays no syscall per span. Open lazily: a configured-but-idle
     exporter never touches the filesystem."""
+
+    FLUSH_S = 1.0
 
     def __init__(self, path: str):
         self.path = path
         self._lock = threading.Lock()
         self._fh = None
+        self._flushed = 0.0   # monotonic stamp of the last flush
 
     def export(self, span: Span) -> None:
         line = json.dumps(span.to_dict(), separators=(",", ":"))
@@ -45,7 +51,10 @@ class JsonlSpanExporter:
             if self._fh is None:
                 self._fh = open(self.path, "a")
             self._fh.write(line + "\n")
-            self._fh.flush()
+            now = time.monotonic()
+            if now - self._flushed >= self.FLUSH_S:
+                self._fh.flush()
+                self._flushed = now
 
     def close(self) -> None:
         with self._lock:
@@ -57,31 +66,14 @@ class JsonlSpanExporter:
 class MetricsSpanExporter:
     """Observes every span's duration into
     ``stage_latency_seconds{stage=<span name>}`` on a MetricsRegistry
-    (LATENCY_BUCKETS by default — same buckets as TTFT/ITL).
-
-    Flight-recorder attributes the engine stamps on decode spans (``mfu``,
-    ``goodput_tok_s``, ``padding_waste_ratio``) additionally surface as
-    ``stage_obs{stage,attr}`` gauges — the per-request view of the live
-    recorder, without a second instrumentation path."""
-
-    OBS_ATTRS = ("mfu", "goodput_tok_s", "padding_waste_ratio")
+    (LATENCY_BUCKETS by default — same buckets as TTFT/ITL)."""
 
     def __init__(self, registry, name: str = "stage_latency_seconds"):
         self._hist = registry.histogram(
             name, "per-stage latency attributed from trace spans", ["stage"]
-        )
-        self._g_obs = registry.gauge(
-            "stage_obs",
-            "flight-recorder attributes carried on stage spans "
-            "(last exported span wins)", ["stage", "attr"]
         )
 
     def export(self, span: Span) -> None:
         dur: Optional[float] = span.duration_s
         if dur is not None:
             self._hist.labels(stage=span.name).observe(max(dur, 0.0))
-        attrs = span.attrs or {}
-        for key in self.OBS_ATTRS:
-            val = attrs.get(key)
-            if isinstance(val, (int, float)):
-                self._g_obs.labels(stage=span.name, attr=key).set(val)

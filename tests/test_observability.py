@@ -574,6 +574,40 @@ def test_report_golden():
     assert render_report([]) == "no step records\n"
 
 
+def test_report_prints_host_ms_per_step():
+    """Records that carry the host seconds add one line: mean per batch of
+    the loop's, the dispatch thread's and the fetch thread's time."""
+    recs = [dict(r) for r in _REPORT_RECORDS]
+    assert "host per step" not in render_report(recs)
+    # two batches: (prefill + decode) and the spec window
+    recs[0].update(dispatch_s=0.001)
+    recs[1].update(host_s=0.004, dispatch_s=0.002, unpack_s=0.0005)
+    recs[2].update(host_s=0.002, dispatch_s=0.003, unpack_s=0.0015)
+    wall = (max(r["t_land"] for r in recs)
+            - min(r["t_dispatch"] for r in recs))
+    line = render_report(recs).splitlines()[-1]
+    assert line == (
+        "host per step:     loop 3.00 ms  dispatch 3.00 ms  unpack 1.00 ms"
+        f"  (loop busy {100 * 0.006 / wall:.1f}% of wall)")
+
+
+def test_selfcheck_scopes_and_vocabulary():
+    """The benchmark's by-scope reducer passes its own checks, and the
+    vocabulary it spells out (it must run without JAX) is the model's."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip",
+        "selfcheck_scopes.py")
+    spec = importlib.util.spec_from_file_location("selfcheck_scopes", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod.VOCABULARY == model_lib.SCOPES
+    mod.check_hand_made()
+    assert mod.check_recorded()
+
+
 def test_report_cli_main(tmp_path, capsys):
     from dynamo_tpu.observability.report import main
 
@@ -582,3 +616,193 @@ def test_report_cli_main(tmp_path, capsys):
         "".join(json.dumps(r) + "\n" for r in _REPORT_RECORDS))
     assert main([str(path)]) == 0
     assert capsys.readouterr().out == _REPORT_GOLDEN
+
+
+# ---------------------------------------------------------------------------
+# Names on both clocks (PR 24): scopes in the step programs, engine-loop
+# phases in a capture, host seconds on the step records
+# ---------------------------------------------------------------------------
+
+def _tiny_engine_config(**kw):
+    return EngineConfig(block_size=4, num_blocks=64, max_num_seqs=4,
+                        max_num_batched_tokens=64, max_model_len=128,
+                        decode_buckets=(8,), prefill_buckets=(16,), **kw)
+
+
+def _lowered_step_program(which):
+    cfg, eng = ModelConfig.tiny(), _tiny_engine_config()
+    params = jax.eval_shape(
+        lambda: model_lib.init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: model_lib.init_cache(cfg, eng))
+    S, wcap = eng.max_num_seqs, 32
+    if which == "decode_window":
+        ctl = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype),
+            model_lib.init_ctl(eng, S, wcap))
+        fn, _ = model_lib.make_autopilot_fns(cfg, eng, 2, wcap)
+        args = (params, cache, ctl, jax.ShapeDtypeStruct((8,), jnp.int32))
+    else:
+        T, W = 16, 8
+        fn = model_lib.make_packed_prefill_fn(cfg, eng, T, W)
+        args = (params, cache,
+                jax.ShapeDtypeStruct((S + 1,), jnp.int32),
+                jax.ShapeDtypeStruct((1, T + W + model_lib.PP_SCALARS),
+                                     jnp.int32),
+                jax.ShapeDtypeStruct((2,), jnp.uint32))
+    # compilewatch.label wraps the jitted callable
+    return fn.__wrapped__.lower(*args)
+
+
+@pytest.mark.parametrize("which", ["decode_window", "packed_prefill"])
+def test_step_programs_carry_every_scope(which):
+    """Every name of model.SCOPES is a component of some op's ``op_name``
+    in the lowered program — a refactor that drops a stage's
+    ``jax.named_scope`` fails here, before a trace reads 0 for it."""
+    import re
+
+    text = _lowered_step_program(which).as_text(debug_info=True)
+    seen = set()
+    for op_name in re.findall(r'loc\("(jit\([^"]*)"', text):
+        seen.update(op_name.split("/"))
+    missing = [s for s in model_lib.SCOPES if s not in seen]
+    assert not missing, f"{which}: no op carries scope(s) {missing}"
+
+
+def _host_event_names(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    files = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    assert files, f"no .xplane.pb under {trace_dir}"
+    names = set()
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    return names
+
+
+@pytest.mark.anyio
+@pytest.mark.parametrize("python", [0, 1])
+async def test_profile_of_serving_engine_names_phases(tmp_path, python):
+    """A /debug/profile of a serving engine holds the engine-loop phases
+    as host events on the profiler's clock; Python frames ('$file:line fn'
+    events of the profiler's Python tracer) only with ``?python=1``. The
+    engine probe reports the capture as ``last_profile``."""
+    import asyncio
+    import time
+
+    import aiohttp
+
+    from dynamo_tpu.runtime.system_server import SystemServer
+
+    engine = InferenceEngine(ModelConfig.tiny(), _tiny_engine_config())
+    server = SystemServer(metrics=MetricsRegistry(), host="127.0.0.1",
+                          port=0)
+    await engine.start()
+    await server.start()
+    try:
+        captured = asyncio.Event()
+
+        async def traffic(tag):
+            i = 0
+            while i == 0 or not captured.is_set():
+                i += 1
+                req = Request(request_id=f"{tag}{i}",
+                              token_ids=[3 + i % 9, 1, 4],
+                              max_tokens=24, ignore_eos=True)
+                async for _ in engine.submit(req):
+                    pass
+
+        captured.set()
+        await traffic("warm")   # one request: compile outside the capture
+        captured.clear()
+        task = asyncio.create_task(traffic("p"))
+        t_before = time.monotonic()
+        async with aiohttp.ClientSession() as sess:
+            async with sess.get(
+                    f"http://127.0.0.1:{server.port}/debug/profile",
+                    params={"ms": "200", "dir": str(tmp_path),
+                            "python": str(python)}) as resp:
+                assert resp.status == 200
+                data = await resp.json()
+        captured.set()
+        await task
+        names = _host_event_names(data["trace_dir"])
+        for phase in ("engine.schedule", "engine.dispatch", "engine.fetch",
+                      "engine.postprocess"):
+            assert phase in names, f"{phase} missing from the host planes"
+        frames = [n for n in names if n.startswith("$")]
+        assert bool(frames) == bool(python), frames[:5]
+        last = engine.device_report()["last_profile"]
+        assert last["trace_dir"] == data["trace_dir"]
+        assert last["captured_ms"] >= 200
+        assert t_before <= last["t0_mono"] <= time.monotonic()
+        assert abs(last["t0_unix"] - time.time()) < 60
+    finally:
+        await server.stop()
+        await engine.stop()
+
+
+@pytest.mark.anyio
+async def test_step_records_carry_host_seconds(tmp_path, monkeypatch):
+    """host_s / dispatch_s / unpack_s are non-negative, survive the JSONL
+    round trip, and account for the loop: sum(host_s) plus the loop's three
+    wait totals is its wall time (to the last handoff)."""
+    path = tmp_path / "steps.jsonl"
+    monkeypatch.setenv("DYNTPU_OBS_STEPSTATS_PATH", str(path))
+    engine = InferenceEngine(ModelConfig.tiny(), _tiny_engine_config())
+    await engine.start()
+    try:
+        for prompt in ([5, 6, 7, 8, 9], [9, 8, 7], [1, 2, 3, 4, 5, 6]):
+            assert len(await _run(engine, prompt)) == 4
+        clock = engine.loop_clock
+        snap = engine.obs_snapshot()
+    finally:
+        await engine.stop()    # closes the recorder: buffered lines land
+    with open(path) as fh:
+        records = load_records(fh)
+    assert records and len(records) == snap["total_steps"]
+    for r in records:
+        assert r["host_s"] >= 0 and r["dispatch_s"] > 0 and r["unpack_s"] >= 0
+    decode = [r for r in records if r["kind"] == DECODE]
+    assert decode and all(r["host_s"] > 0 and r["unpack_s"] > 0
+                          for r in decode)
+    wall = clock.t_handoff - clock.t_start
+    accounted = sum(r["host_s"] for r in records) + sum(
+        clock.wait_s.values())
+    assert set(clock.wait_s) == {"land", "idle", "executor"}
+    assert accounted == pytest.approx(wall, rel=0.05)
+    assert 0.0 < snap["host_busy_ratio"] <= 1.0
+    gauges = EngineObsGauges(MetricsRegistry(), engine)
+    assert gauges.refresh()["host_busy_ratio"] == snap["host_busy_ratio"]
+
+
+def test_stepstats_jsonl_is_buffered_and_complete_on_close(tmp_path):
+    """No flush per record (one a second at most), nothing lost on
+    close()."""
+    path = tmp_path / "steps.jsonl"
+    stats, clock = _mk_stats(jsonl_path=str(path))
+    for i in range(5):
+        stats.commit(StepRecord(kind=DECODE, t_dispatch=100.0 + i,
+                                t_land=100.1 + i, padded_tokens=8,
+                                real_tokens=2, goodput_tokens=2,
+                                context_sum=12, host_s=0.001 * i))
+    with open(path) as fh:
+        assert len(load_records(fh)) < 5       # still in the buffer
+    clock["t"] += 1.5                          # a second on: one flush
+    stats.commit(StepRecord(kind=DECODE, t_dispatch=106.0, t_land=106.1,
+                            padded_tokens=8, real_tokens=2,
+                            goodput_tokens=2, context_sum=12))
+    with open(path) as fh:
+        assert len(load_records(fh)) == 6
+    stats.commit(StepRecord(kind=DECODE, t_dispatch=107.0, t_land=107.1,
+                            padded_tokens=8, real_tokens=2,
+                            goodput_tokens=2, context_sum=12))
+    stats.close()
+    with open(path) as fh:
+        records = load_records(fh)
+    assert len(records) == 7
+    assert [round(r["host_s"], 3) for r in records[:5]] == [
+        0.0, 0.001, 0.002, 0.003, 0.004]

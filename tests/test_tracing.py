@@ -240,6 +240,7 @@ def test_assembler_joins_and_dedupes(tracer, tmp_path):
                             "parent_span_id": root.span_id,
                             "name": "worker.ingress"}) + "\n")
 
+    tracer.close()  # the export is buffered: files are read after close
     spans = load_spans([path_a, path_b])
     assert len(spans) == 3  # duplicate child collapsed
     traces = group_traces(spans)
@@ -263,6 +264,7 @@ def test_assembler_cli(tracer, tmp_path, capsys):
     ctx = Context()
     tracer.start_span("router.select", ctx).end()
     tracer.start_span("frontend.request", trace=ctx.trace, root=True).end()
+    tracer.close()
 
     assert main([path]) == 0
     out = capsys.readouterr().out
@@ -529,6 +531,7 @@ async def test_e2e_trace_with_midstream_crash(cluster, tmp_path):
     assert 'stage="engine.decode"' in scrape
 
     # the offline assembler reproduces the same single-trace picture
+    cluster["tracer"].close()  # buffered export: read the file after close
     assembled = assemble_trace(
         group_traces(load_spans([cluster["jsonl"]]))[trace_id]
     )
@@ -569,3 +572,122 @@ async def test_debug_trace_endpoint(cluster):
                 assert r.status == 404
     finally:
         await server.stop()
+
+
+# ------------- PR 24: inside engine.prefill, buffered export -------------
+
+
+async def test_prefill_span_events_and_queue_hit_attrs(tracer):
+    """engine.prefill carries ``dispatched`` <= ``landed`` <= end (the
+    prompt-completing chunk's enqueue and its landing on the fetch thread);
+    worker.queue carries the prefix match made at admission."""
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.engine.engine import InferenceEngine
+
+    exporter = InMemorySpanExporter()
+    tracer.configure(sample_ratio=1.0)
+    tracer.add_exporter(exporter)
+    engine = InferenceEngine(
+        ModelConfig.tiny(),
+        EngineConfig(block_size=4, num_blocks=64, max_num_seqs=4,
+                     max_num_batched_tokens=64, max_model_len=128,
+                     decode_buckets=(8,), prefill_buckets=(16,)),
+    )
+    await engine.start()
+    prompt = list(range(3, 16))          # 13 tokens: 3 whole blocks of 4
+    try:
+        for _ in range(2):               # the second finds the first's blocks
+            async for _ in engine.generate(
+                    {"token_ids": prompt, "max_tokens": 4,
+                     "ignore_eos": True}, Context()):
+                pass
+    finally:
+        await engine.stop()
+    prefills = [s for s in exporter.spans if s.name == "engine.prefill"]
+    assert len(prefills) == 2
+    for span in prefills:
+        at = {name: off for off, name, _ in span.events}
+        assert set(at) == {"dispatched", "landed"}
+        assert 0.0 <= at["dispatched"] <= at["landed"] <= span.duration_s
+    queues = [s for s in exporter.spans if s.name == "worker.queue"]
+    assert [s.attrs["prompt_tokens"] for s in queues] == [13, 13]
+    assert [s.attrs["cached_tokens"] for s in queues] == [0, 12]
+    # events and attrs survive the JSONL form the benchmark reads
+    back = tracing.Span.from_dict(json.loads(json.dumps(
+        prefills[1].to_dict())))
+    assert [e[1] for e in back.events] == ["dispatched", "landed"]
+    decode = [s for s in exporter.spans if s.name == "engine.decode"]
+    assert decode and all(set(s.attrs) == {"num_tokens"} for s in decode)
+
+
+def test_jsonl_span_export_is_buffered_and_complete_on_close(tracer, tmp_path):
+    """No flush per span; ``SpanCollector.close()`` (runtime shutdown)
+    leaves every exported span on disk, and a later span reopens the
+    file."""
+    path = str(tmp_path / "spans.jsonl")
+    tracer.configure(sample_ratio=1.0)
+    tracer.add_jsonl(path)
+    t = time.monotonic()
+    for i in range(6):
+        tracer.record(f"stage.{i}", start_mono=t, end_mono=t + 0.001)
+    assert len(load_spans([path])) < 6       # the rest is in the buffer
+    tracer.close()
+    assert [s["name"] for s in load_spans([path])] == [
+        f"stage.{i}" for i in range(6)]
+    tracer.record("stage.late", start_mono=t, end_mono=t + 0.001)
+    tracer.close()
+    assert len(load_spans([path])) == 7
+
+
+async def test_worker_sigterm_path_flushes_span_export(tmp_path):
+    """The SIGTERM path every worker shares (``run_until_shutdown``: drain,
+    engine stop, runtime shutdown) closes the buffered exporter: all spans
+    of the requests served are in the file after the process exits."""
+    from dynamo_tpu.runtime.component import DistributedRuntime
+    from dynamo_tpu.utils.config import RuntimeConfig
+
+    from test_llm_pipeline import byte_tokenizer
+    from utils import ManagedProcess, free_port
+
+    tok = tmp_path / "tokenizer.json"
+    tok.write_text(byte_tokenizer().to_json_str())
+    spans_path = tmp_path / "spans.jsonl"
+    port = free_port()
+    store = ManagedProcess(
+        ["-m", "dynamo_tpu.runtime.store", "--host", "127.0.0.1",
+         "--port", str(port)], name="store", ready_pattern=r"listening")
+    worker = None
+    n = 5
+    try:
+        store.wait_ready(20)
+        worker = ManagedProcess(
+            ["-m", "dynamo_tpu.mocker", "--model-name", "mock",
+             "--tokenizer", str(tok), "--max-model-len", "512",
+             "--speedup-ratio", "50"],
+            name="mocker", ready_pattern=r"mocker ready",
+            env={"DYNTPU_STORE_ADDR": f"127.0.0.1:{port}",
+                 "DYNTPU_TRACE_SAMPLE_RATIO": "1",
+                 "DYNTPU_TRACE_EXPORT_PATH": str(spans_path)})
+        worker.wait_ready(60)
+        rt = await DistributedRuntime.from_settings(
+            RuntimeConfig(store_addr=f"127.0.0.1:{port}"))
+        try:
+            client = await (rt.namespace().component("backend")
+                            .endpoint("generate").client())
+            await client.wait_for_instances(1, timeout_s=10.0)
+            for i in range(n):
+                items = [item async for item in client.round_robin(
+                    {"token_ids": [7 + i, 8, 9, 10], "max_tokens": 6,
+                     "ignore_eos": True}, Context())]
+                assert items and items[-1]["finished"]
+            await client.stop()
+        finally:
+            await rt.shutdown()
+        assert worker.terminate(timeout_s=30.0) == 0
+    finally:
+        if worker is not None:
+            worker.kill()
+        store.terminate()
+    names = [s["name"] for s in load_spans([str(spans_path)])]
+    for stage in ("worker.queue", "engine.prefill", "engine.decode"):
+        assert names.count(stage) == n, (stage, names)
