@@ -131,7 +131,7 @@ def _kernel_check_class(B: int, T: int, spec_k: int = 4,
     q_tile, kv_tile = int(tile[0]), int(tile[1])
     if q_tile > 0 and T % q_tile:
         q_tile = 0  # tuned for a different chunk length — use the default
-    if kv_tile > 0 and bs % kv_tile:
+    if kv_tile > 0 and bs % kv_tile and kv_tile % bs:
         kv_tile = 0
     if T == 1:
         decode = jax.jit(functools.partial(

@@ -53,12 +53,14 @@ log = get_logger("autotune")
 CACHE_ENV = "DYNTPU_AUTOTUNE_CACHE"
 # set to 0 to skip the startup tile sweep (impl probe still runs)
 SWEEP_ENV = "DYNTPU_AUTOTUNE_SWEEP"
-CACHE_VERSION = 1
+CACHE_VERSION = 2   # 2: kv_tile counts whole pages a step (PR 25)
 
 # minimum second-to-minor tile dim per dtype (pallas_guide.md): kv_tile is
 # the second-to-last axis of the (1, KV, kv_tile, hd) K/V block.  Quantized
 # paged caches store 1-byte elements, whose native tile is (32, 128).
 _SUBLANE = {"float32": 8, "bfloat16": 16, "int8": 32, "fp8": 32}
+# a swept tile replaces the kernel default only when it is this much faster
+_SWEEP_MARGIN = 0.03
 
 
 def _time_attention(fn, args, iters: int = 20) -> float:
@@ -231,22 +233,34 @@ def tile_candidates(
 
     ``(0, 0)`` — the kernel default — is always first and always eligible,
     so the sweep can only ever match or beat the default.  q_tile must
-    divide the class's query window T (decode: always 1); kv_tile must
-    divide ``block_size`` and respect the dtype's minimum sublane tile
-    (f32: 8, bf16: 16) since it is the second-to-minor axis of the K/V
-    block DMA.
+    divide the class's query window T (decode: always 1).  kv_tile, the
+    key positions one step of a row's KV walk covers, is offered at half
+    and at twice the default's pages per step (``default_kv_tile`` of the
+    shapes a launch sees: more pages a step amortise the step's fixed
+    cost, fewer round a short context up less), and at the divisors of
+    ``block_size`` that respect the dtype's minimum sublane tile (f32: 8,
+    bf16: 16), since a sub-block tile is the second-to-minor axis of the
+    page slice its DMA takes.
     """
+    from ..ops.paged_attention import default_kv_tile
     from . import quant
 
     bs = engine_config.block_size
     # the K/V page DMA's sublane floor follows the *storage* dtype: the
     # model dtype for bf16 passthrough, the 1-byte tile for quantized KV
-    page_dtype = engine_config.kv_dtype \
-        if quant.is_quantized(engine_config.kv_dtype) else model_config.dtype
+    quantized = quant.is_quantized(engine_config.kv_dtype)
+    page_dtype = engine_config.kv_dtype if quantized else model_config.dtype
     sub = _sublane(page_dtype)
+    tp = engine_config.mesh_shape[-1]
+    default = default_kv_tile(
+        bs, max(1, model_config.num_kv_heads // tp), model_config.head_dim_,
+        quant.np_storage_dtype(page_dtype) if quantized else page_dtype)
     kv_tiles = [0] + [
         kt for kt in (8, 16, 32, 64, 128)
         if kt >= sub and kt < bs and bs % kt == 0
+    ] + [
+        kt for kt in (default // 2, default * 2)
+        if kt >= bs and kt % bs == 0 and kt != default
     ]
     if attn_class == "decode":
         q_tiles = [0]
@@ -262,7 +276,7 @@ def tile_candidates(
 def make_sweep_case(
     model_config: ModelConfig, engine_config: EngineConfig,
     attn_class: str, B: int, T: int, *,
-    W: int = 0, seed: int = 0, poison: bool = True,
+    W: int = 0, ctx: int = 0, seed: int = 0, poison: bool = True,
 ) -> dict:
     """A mixed ragged batch for one shape class's parity/timing runs.
 
@@ -271,6 +285,9 @@ def make_sweep_case(
     (spec/prefill), a dead seat whose table is all trash (block 0), and —
     with ``poison`` — NaN bits in the trash block and every partial block
     tail, so a tile candidate that mis-masks can never pass the gate.
+    ``W`` is the table's width, ``ctx`` the context of the full rows
+    (default: all of the table); columns past a context stay 0, the trash
+    block.
 
     With a quantized ``engine_config.kv_dtype`` the caches are quantized
     per (slot, head) and the case carries the parallel ``k_scale`` /
@@ -289,7 +306,9 @@ def make_sweep_case(
     dt = np.dtype("float32") if model_config.dtype != "bfloat16" else None
 
     rows = []  # (q_len, ctx_len)
-    full_ctx = W * bs
+    full_ctx = max(ctx or W * bs, T + 3)
+    if full_ctx > W * bs:
+        raise ValueError(f"ctx {ctx} does not fit a table of {W} blocks")
     for b in range(B):
         mode = b % 4
         if mode == 0:
@@ -362,10 +381,13 @@ def reference_ragged(
 ) -> np.ndarray:
     """Order-exact reference for one ``(q_tile, kv_tile)`` candidate.
 
-    Replays the kernel's per-(row, q-tile, kv-step) online-softmax
-    recurrence with the same ops, shapes, and reduction order through
-    plain jnp — so an interpret-mode run of the candidate must agree
-    **bit-for-bit** (assert with ``np.array_equal``; run both under
+    Replays the kernel's per-(row, q-tile, kv-tile) online-softmax
+    recurrence — one update per tile of ``kv_tile`` key positions, a tile
+    of several pages gathered into one operand as the kernel's DMAs do,
+    the walk ending at the q tile's causal frontier — with the same ops,
+    shapes, and reduction order through plain jnp, so an interpret-mode
+    run of the candidate must agree **bit-for-bit** (assert with
+    ``np.array_equal``; run both under
     ``XLA_FLAGS=--xla_disable_hlo_passes=fusion`` so XLA cannot re-fuse
     one side differently).  Different tile configs produce different —
     individually exact — references: tiling changes the accumulation
@@ -376,6 +398,8 @@ def reference_ragged(
     import jax
     import jax.numpy as jnp
 
+    from ..ops.paged_attention import default_kv_tile
+
     Tq, H, hd = q.shape
     KV = k_cache.shape[1]
     G = H // KV
@@ -385,8 +409,8 @@ def reference_ragged(
         q_tile = min(max_q_len, 128) if max_q_len % min(max_q_len, 128) == 0 \
             else max_q_len
     if kv_tile <= 0:
-        kv_tile = bs
-    splits = bs // kv_tile
+        kv_tile = default_kv_tile(bs, KV, hd, np.asarray(k_cache).dtype)
+    pieces, piece = max(1, kv_tile // bs), min(kv_tile, bs)
     scale = 1.0 / (hd ** 0.5)
     q4 = jnp.asarray(q).reshape(Tq, KV, G, hd).transpose(1, 0, 2, 3)
     kc = jnp.asarray(k_cache)
@@ -404,21 +428,30 @@ def reference_ragged(
             m = jnp.full((KV, q_tile * G, 1), -jnp.inf, jnp.float32)
             l = jnp.zeros((KV, q_tile * G, 1), jnp.float32)
             acc = jnp.zeros((KV, q_tile * G, hd), jnp.float32)
-            for w in range(W * splits):
-                if not (live and w * kv_tile <= max_vis):
-                    continue
+            for w in range(-(-(max_vis + 1) // kv_tile) if live else 0):
                 qf = q4[:, qs + t * q_tile: qs + (t + 1) * q_tile]
                 qf = qf.astype(jnp.float32).reshape(KV, q_tile * G, hd)
-                blk = int(tables[r, w // splits])
-                sl = slice((w % splits) * kv_tile,
-                           (w % splits + 1) * kv_tile)
-                k = kc[blk][:, sl].astype(jnp.float32)
-                v = vc[blk][:, sl].astype(jnp.float32)
-                if ks is not None:
-                    # same op order as the kernel: dequantize, THEN the
-                    # kvalid zeroing wipes trash/tail bits (NaN scales incl.)
-                    k = k * ks[blk][:, sl].astype(jnp.float32)[..., None]
-                    v = v * vs[blk][:, sl].astype(jnp.float32)[..., None]
+                kps, vps = [], []
+                for j in range(pieces):
+                    pos = w * kv_tile + j * piece
+                    # a tile rounds the walk up: columns past the table
+                    # reread its last entry (positions >= ctx_len, masked)
+                    blk = int(tables[r, min(pos // bs, W - 1)])
+                    sl = slice(pos % bs, pos % bs + piece)
+                    kp = kc[blk][:, sl].astype(jnp.float32)
+                    vp = vc[blk][:, sl].astype(jnp.float32)
+                    if ks is not None:
+                        # same op order as the kernel: dequantize, THEN
+                        # the kvalid zeroing wipes trash/tail bits (NaN
+                        # scales incl.)
+                        kp = kp * ks[blk][:, sl].astype(
+                            jnp.float32)[..., None]
+                        vp = vp * vs[blk][:, sl].astype(
+                            jnp.float32)[..., None]
+                    kps.append(kp)
+                    vps.append(vp)
+                k = kps[0] if pieces == 1 else jnp.concatenate(kps, axis=1)
+                v = vps[0] if pieces == 1 else jnp.concatenate(vps, axis=1)
                 kpos = w * kv_tile + jax.lax.broadcasted_iota(
                     jnp.int32, (1, kv_tile, 1), 1)
                 kvalid = kpos < cl
@@ -595,10 +628,14 @@ def _sweep_class_device(
     """Time every candidate on the live backend; pick the fastest eligible.
 
     Eligibility at runtime is numeric — each candidate must match the
-    gathered-einsum path within dtype tolerance on a clean (non-poisoned)
-    mixed ragged case.  W (the decode-window block-table width) is part of
-    the swept shape: candidates are timed at a shallow and a deep table
-    and scored on the sum, so a winner can't overfit one context depth.
+    naive reference within dtype tolerance on a clean (non-poisoned) mixed
+    ragged case.  The table is as wide as the engine's own
+    (``max_blocks_per_seq``) and candidates are timed at a context of a
+    few hundred and of a few thousand tokens, scored on the sum: the
+    sweep sees the cost it is tuning, and a winner can't overfit one
+    depth.  A candidate replaces the default only when it beats it by
+    more than ``_SWEEP_MARGIN``, so timing noise cannot flip the tile
+    from one start to the next.
     """
     import jax
     import jax.numpy as jnp
@@ -608,48 +645,50 @@ def _sweep_class_device(
 
     bs = engine_config.block_size
     cap = engine_config.max_blocks_per_seq
-    widths = sorted({max(2, min(8, cap)), max(2, min(32, cap))})
+    depths = sorted({min(cap * bs, max(384, 2 * T)),
+                     min(cap * bs, max(3072, 2 * T))})
     tol = 2e-2 if model_config.dtype == "bfloat16" else 2e-3
     if quant.is_quantized(engine_config.kv_dtype):
         tol = max(tol, 5e-2)  # quantization error rides the same anchor
+    # each depth's case, and what every candidate is held to on it, once
+    cases = []
+    for depth in depths:
+        case = make_sweep_case(
+            model_config, engine_config, attn_class, B, T,
+            W=cap, ctx=depth, poison=False)
+        args_np = case["args"]
+        ks_np, vs_np = case.get("k_scale"), case.get("v_scale")
+        kc_h, vc_h = args_np[1], args_np[2]
+        if ks_np is not None:
+            kc_h = quant.kv_dequantize_cache_np(kc_h, ks_np)
+            vc_h = quant.kv_dequantize_cache_np(vc_h, vs_np)
+        ref = reference_naive(args_np[0], kc_h, vc_h, *args_np[3:],
+                              block_size=bs)
+        mask = valid_slot_mask(args_np[4], args_np[5], args_np[0].shape[0])
+        args = tuple(jnp.asarray(a) for a in args_np)
+        if ks_np is not None:
+            args += (jnp.asarray(ks_np), jnp.asarray(vs_np))
+        cases.append((depth, args, ref, mask))
+    quantized = quant.is_quantized(engine_config.kv_dtype)
     results: List[dict] = []
     for q_tile, kv_tile in tile_candidates(
             model_config, engine_config, attn_class, T):
         entry = {"q_tile": q_tile, "kv_tile": kv_tile, "ms": {},
                  "eligible": True}
         total = 0.0
-        for W in widths:
-            case = make_sweep_case(
-                model_config, engine_config, attn_class, B, T,
-                W=W, poison=False)
-            q, kc, vc, tables, q_start, q_len, ctx_len = (
-                jnp.asarray(a) for a in case["args"])
-            ks_np, vs_np = case.get("k_scale"), case.get("v_scale")
-            # one throwaway wrapper per candidate BY DESIGN: each (q_tile,
-            # kv_tile) is a distinct static config, so no cache is shared
-            # and this cold startup sweep never runs in the serving loop
-            fn = jax.jit(functools.partial(  # dynalint: disable=DT203
-                paged_attention_ragged if ks_np is None else _ragged_scaled,
-                block_size=bs, max_q_len=T,
-                q_tile=q_tile, kv_tile=kv_tile,
-            ))
-            args = (q, kc, vc, tables, q_start, q_len, ctx_len)
-            if ks_np is not None:
-                args = args + (jnp.asarray(ks_np), jnp.asarray(vs_np))
+        # one throwaway wrapper per candidate BY DESIGN: each (q_tile,
+        # kv_tile) is a distinct static config, so no cache is shared
+        # and this cold startup sweep never runs in the serving loop
+        fn = jax.jit(functools.partial(  # dynalint: disable=DT203
+            _ragged_scaled if quantized else paged_attention_ragged,
+            block_size=bs, max_q_len=T,
+            q_tile=q_tile, kv_tile=kv_tile,
+        ))
+        for depth, args, ref, mask in cases:
             # no try: a tile the chip's compiler refuses is a kernel bug
             # to repair (or a candidate to take off the grid), not a
             # candidate to skip in silence
             out = np.asarray(fn(*args))
-            kc_h, vc_h = np.asarray(kc), np.asarray(vc)
-            if ks_np is not None:
-                kc_h = quant.kv_dequantize_cache_np(kc_h, ks_np)
-                vc_h = quant.kv_dequantize_cache_np(vc_h, vs_np)
-            ref = np.asarray(reference_naive(
-                np.asarray(q), kc_h, vc_h, np.asarray(tables),
-                np.asarray(q_start), np.asarray(q_len),
-                np.asarray(ctx_len), block_size=bs))
-            mask = valid_slot_mask(np.asarray(q_start), np.asarray(q_len),
-                                   out.shape[0])
             err = float(np.max(np.abs(
                 out.astype(np.float64)[mask] - ref[mask]), initial=0.0))
             if not np.isfinite(out.astype(np.float32)).all() \
@@ -658,7 +697,7 @@ def _sweep_class_device(
                 entry["reason"] = f"numeric gate failed (err {err:.2e})"
                 break
             ms = _time_attention(fn, args)
-            entry["ms"][f"W{W}"] = round(ms, 4)
+            entry["ms"][f"ctx{depth}"] = round(ms, 4)
             total += ms
         entry["total_ms"] = round(total, 4)
         results.append(entry)
@@ -668,8 +707,12 @@ def _sweep_class_device(
             f"{attn_class}: no (q_tile, kv_tile) candidate passed the "
             f"numeric gate against the naive reference: {results!r}")
     winner = min(eligible, key=lambda e: e["total_ms"])
+    default = results[0]
+    if default["eligible"] and \
+            winner["total_ms"] > (1.0 - _SWEEP_MARGIN) * default["total_ms"]:
+        winner = default
     return {
-        "B": B, "T": T, "widths": widths,
+        "B": B, "T": T, "width": cap, "depths": depths,
         "winner": (winner["q_tile"], winner["kv_tile"]),
         "candidates": results,
     }

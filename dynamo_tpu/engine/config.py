@@ -127,7 +127,9 @@ class EngineConfig:
     # (0, 0) = kernel defaults; engine/autotune.py's tile sweep fills these
     # with the fastest byte-parity-verified candidate per class (persisted
     # across runs via DYNTPU_AUTOTUNE_CACHE). q_tile must divide the class's
-    # query window (decode: 1); kv_tile must divide block_size.
+    # query window (decode: 1); kv_tile — the key positions one step of a
+    # row's KV walk covers — is a multiple of block_size (that many whole
+    # pages a step) or a divisor of it.
     attention_tile_decode: Tuple[int, int] = (0, 0)
     attention_tile_spec: Tuple[int, int] = (0, 0)
     attention_tile_prefill: Tuple[int, int] = (0, 0)
@@ -250,10 +252,11 @@ class EngineConfig:
                 raise ValueError(
                     f"attention_tile_{cls} must be (q_tile>=0, kv_tile>=0)"
                 )
-            if tile[1] > 0 and self.block_size % tile[1]:
+            if (tile[1] > 0 and self.block_size % tile[1]
+                    and tile[1] % self.block_size):
                 raise ValueError(
                     f"attention_tile_{cls} kv_tile {tile[1]} must divide "
-                    f"block_size {self.block_size}"
+                    f"block_size {self.block_size} or be a multiple of it"
                 )
         if self.attention_tile_decode[0] > 1:
             raise ValueError("decode q_tile must be 0 or 1 (one query/row)")

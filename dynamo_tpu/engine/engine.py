@@ -32,7 +32,7 @@ from ..observability import flops as obs_flops
 from ..parallel import layout
 from ..observability.flops import FlopsModel
 from ..observability.stepstats import (
-    DECODE, PREFILL, SPEC_VERIFY, StepRecord, StepStats,
+    DECODE, PREFILL, SPEC_VERIFY, StepRecord, StepStats, kv_blocks_walked,
 )
 from ..runtime import faults
 from ..runtime.context import Context
@@ -1444,6 +1444,11 @@ class InferenceEngine(EngineCore):
             # decode windows running on device-resident control state
             self._window_K = max(1, engine_config.decode_steps)
             self._ap_Wcap = engine_config.max_blocks_per_seq
+            # what StepRecord.kv_blocks_walked counts with: the decode
+            # kernel's tile as the window traces it (0 = the einsum path,
+            # which gathers the table's whole width)
+            self._decode_kv_tile = model_lib.decode_kv_tile(
+                model_config, engine_config, self.mesh)
             self._ap_window_fn, self._ap_delta_fn = (
                 model_lib.make_autopilot_fns(
                     model_config, engine_config, self._window_K,
@@ -2045,6 +2050,7 @@ class InferenceEngine(EngineCore):
                         )
                     )
                     self._stall_einsum_fallback = True
+                    self._decode_kv_tile = 0
                     log.warning(
                         "stall watchdog: decode:%d is the largest rung — "
                         "rebuilt the decode window on the einsum attention "
@@ -2334,13 +2340,22 @@ class InferenceEngine(EngineCore):
             # realized goodput (emitted tokens; spec accept counts) is
             # stamped at landing — only padded/real shapes are known here
             ctx = sum(K * r.base + K * (K + 1) // 2 for r in rows)
+            walked = 0
+            if self._decode_kv_tile and not spec:
+                # step k of the window attends base + k + 1 positions
+                walked = sum(kv_blocks_walked(
+                    [r.base + k + 1 for r in rows],
+                    kv_tile=self._decode_kv_tile, block_size=bs,
+                ) for k in range(K))
+            elif not spec:
+                walked = B * self._ap_Wcap * K
             obs_out.append(StepRecord(
                 kind=SPEC_VERIFY if spec else DECODE,
                 t_dispatch=time.monotonic(),
                 bucket=B,
                 rows=B, live_rows=len(rows),
                 padded_tokens=B * K, real_tokens=len(rows) * K,
-                context_sum=ctx,
+                context_sum=ctx, kv_blocks_walked=walked,
             ))
         fn = self._spec_window_fn if spec else self._ap_window_fn
         self.cache, self._ctl, samples = fn(

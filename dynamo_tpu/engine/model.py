@@ -398,9 +398,36 @@ def _class_tile(eng: EngineConfig, attn_class: str, T: int) -> Tuple[int, int]:
     q_tile, kv_tile = getattr(eng, f"attention_tile_{attn_class}", (0, 0))
     if q_tile > 0 and T % q_tile:
         q_tile = 0
-    if kv_tile > 0 and eng.block_size % kv_tile:
+    if kv_tile > 0 and eng.block_size % kv_tile and kv_tile % eng.block_size:
         kv_tile = 0
     return q_tile, kv_tile
+
+
+def _walk_tile(eng: EngineConfig, mesh: Optional[Mesh], kv_tile: int,
+               kv_heads: int, head_dim: int, page_dtype) -> int:
+    """``kv_tile`` with 0 resolved as the kernel resolves it for the shapes
+    a launch sees: under ``shard_map`` a shard's share of the KV heads."""
+    from ..ops.paged_attention import default_kv_tile
+
+    if kv_tile > 0:
+        return kv_tile
+    tp = mesh.shape.get(AXIS_TP, 1) if mesh is not None else 1
+    return default_kv_tile(eng.block_size, max(1, kv_heads // tp), head_dim,
+                           page_dtype)
+
+
+def decode_kv_tile(cfg: ModelConfig, eng: EngineConfig,
+                   mesh: Optional[Mesh]) -> int:
+    """Key positions one step of the decode kernel's KV walk covers in the
+    decode window as it is traced (``ATTENTION_TRACES["decode"]["tile"]``);
+    0 when the decode class runs the einsum path, which has no walk.  The
+    host's ``StepRecord.kv_blocks_walked`` counts with it."""
+    if resolve_attention_impl(eng, "decode") != "pallas":
+        return 0
+    page_dtype = quant.storage_dtype(eng.kv_dtype) \
+        if quant.is_quantized(eng.kv_dtype) else _dtype(cfg)
+    return _walk_tile(eng, mesh, _class_tile(eng, "decode", 1)[1],
+                      cfg.num_kv_heads, cfg.head_dim_, page_dtype)
 
 
 # What each attention shape class resolved to the last time a step program
@@ -457,7 +484,8 @@ def _paged_decode_attention(
     from ..ops.paged_attention import paged_attention_decode
 
     interpret = pallas_interpret(mesh)
-    kv_tile = _class_tile(eng, "decode", 1)[1]
+    kv_tile = _walk_tile(eng, mesh, _class_tile(eng, "decode", 1)[1],
+                         lk.shape[1], lk.shape[3], lk.dtype)
     _note_attention("decode", "pallas", interpret, (1, kv_tile))
     kernel = functools.partial(
         paged_attention_decode,
@@ -523,6 +551,8 @@ def _paged_ragged_attention(
     interpret = pallas_interpret(mesh)
     attn_class = attention_class(eng, T)
     q_tile, kv_tile = _class_tile(eng, attn_class, T)
+    kv_tile = _walk_tile(eng, mesh, kv_tile, lk.shape[1], lk.shape[3],
+                         lk.dtype)
     _note_attention(attn_class, "pallas", interpret, (q_tile, kv_tile))
     kernel = functools.partial(
         paged_attention_ragged,

@@ -44,6 +44,17 @@ DECODE = "decode"
 SPEC_VERIFY = "spec_verify"
 
 
+def kv_blocks_walked(contexts, *, kv_tile: int, block_size: int) -> int:
+    """KV pages one decode launch of the paged-attention kernel visits in a
+    layer, from host integers (``ops.paged_attention``: a row of context
+    ``c`` walks ``cdiv(c, kv_tile)`` tiles and fetches every page of each;
+    ``c == 0`` is a dead row and walks nothing).  A ``kv_tile`` below
+    ``block_size`` fetches a slice of one page per tile and counts it as
+    that page again.  The table's width does not appear."""
+    pages = max(1, kv_tile // block_size)
+    return sum(-(-int(c) // kv_tile) * pages for c in contexts)
+
+
 @dataclass
 class StepRecord:
     """One dispatched unit of device work, host-side metadata only."""
@@ -58,6 +69,12 @@ class StepRecord:
     real_tokens: int = 0      # tokens backed by real sequence positions
     goodput_tokens: int = 0   # tokens that advanced a sequence (landing)
     context_sum: int = 0      # sum of attended context over real tokens
+    # decode records: KV pages the attention launches of this window visit
+    # in ONE layer (kv_blocks_walked above over the same row contexts as
+    # context_sum, at the tile the window was traced with; the einsum path
+    # gathers every column of the table: rows x width). Times block_size
+    # over context_sum = how far the walk is from the tokens attended.
+    kv_blocks_walked: int = 0
     spec_drafted: int = 0
     spec_accepted: int = 0
     # host seconds (``time.monotonic()`` differences, no device access).

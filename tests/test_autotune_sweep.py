@@ -29,6 +29,7 @@ from dynamo_tpu.engine.autotune import (
     tile_candidates,
 )
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.ops.paged_attention import default_kv_tile
 
 pytestmark = pytest.mark.tune
 
@@ -90,13 +91,20 @@ def test_parity_check_catches_a_mismasking_candidate(monkeypatch):
 
 def test_tile_candidates_respect_shape_and_sublane_rules():
     mc, ec = _cfgs()
-    # decode (T=1): no q_tile axis, only kv sub-splits
+    # decode (T=1): no q_tile axis, only the KV walk's tile
     dec = tile_candidates(mc, ec, "decode", 1)
     assert dec[0] == (0, 0)
     assert all(qt == 0 for qt, _ in dec)
-    # every kv_tile divides block_size and respects the f32 sublane min
-    for _, kt in dec:
-        if kt:
+    # a kv_tile is a divisor of block_size that respects the f32 sublane
+    # min, or whole pages: half and twice the default's pages per step
+    default = default_kv_tile(ec.block_size, mc.num_kv_heads, mc.head_dim_,
+                              mc.dtype)
+    assert default % ec.block_size == 0 and default >= ec.block_size
+    kts = [kt for _, kt in dec if kt]
+    assert [kt for kt in kts if kt >= ec.block_size] == [
+        default // 2, default * 2]
+    for kt in kts:
+        if kt < ec.block_size:
             assert ec.block_size % kt == 0 and kt >= 8
     # prefill: q_tiles divide T and exclude the default
     pre = tile_candidates(mc, ec, "prefill", 32)
@@ -209,3 +217,40 @@ def test_autotune_attention_no_cache_no_tpu_is_defaults(monkeypatch):
     assert choice["tiles"] == {
         "decode": [0, 0], "spec": [0, 0], "prefill": [0, 0]}
     assert cfg.attention_tile_decode == (0, 0)
+
+
+# ---------------------------------------------------------------------------
+# the device sweep's control flow, on CPU (kernel interpreted, clock faked)
+
+
+@pytest.mark.parametrize("faster_by,expect", [(0.02, (0, 0)), (0.10, (0, 256))])
+def test_device_sweep_sees_the_engines_table_and_keeps_the_default(
+        monkeypatch, faster_by, expect):
+    """The sweep times every candidate at a table as wide as the engine's
+    own and at two context depths, gates each against the naive reference,
+    and lets a candidate replace the default only past the margin."""
+    import functools
+
+    import dynamo_tpu.ops.paged_attention as pa
+    from dynamo_tpu.engine import autotune
+
+    monkeypatch.setattr(pa, "paged_attention_ragged", functools.partial(
+        pa.paged_attention_ragged, interpret=True))
+    seen = []
+
+    def fake_clock(fn, args, iters=20):
+        tables = args[3]
+        seen.append(tuple(tables.shape))
+        kv_tile = fn.__wrapped__.keywords["kv_tile"]
+        return 1.0 - faster_by if kv_tile == 256 else 1.0
+
+    monkeypatch.setattr(autotune, "_time_attention", fake_clock)
+    mc, ec = _cfgs(max_model_len=4096, spec_mode="off", spec_k=0)
+    res = autotune._sweep_class_device(mc, ec, "decode", 4, 1)
+    assert res["width"] == ec.max_blocks_per_seq == 256
+    assert res["depths"] == [384, 3072]
+    assert set(seen) == {(4, 256)}            # never a narrower table
+    assert [c["kv_tile"] for c in res["candidates"]] == [0, 8, 64, 256]
+    assert all(c["eligible"] and set(c["ms"]) == {"ctx384", "ctx3072"}
+               for c in res["candidates"])
+    assert tuple(res["winner"]) == expect

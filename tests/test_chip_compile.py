@@ -1,5 +1,6 @@
 """The chip's compiler, asked without a chip: the ragged paged-attention
-kernel at Llama-3.2-1B widths in every shape class the engine can select.
+kernel at Llama-3.2-1B widths in every shape class the engine can select,
+and at the benchmark cells' own widths (Mistral-7B) in the decode class.
 
 Interpret-mode parity (every other kernel test) cannot see what Mosaic
 refuses — a block that overflows scoped VMEM, a slice off the dtype's tile.
@@ -63,9 +64,10 @@ def no_compile_cache():
 
 
 def _compile(one_chip, B, T, *, kv_dtype=jnp.bfloat16, quantized=False,
-             q_tile=0, H=MODEL.num_heads, KV=MODEL.num_kv_heads):
+             q_tile=0, kv_tile=0, H=MODEL.num_heads, KV=MODEL.num_kv_heads,
+             hd=MODEL.head_dim_):
     """Compile one launch for the described chip; returns the HLO text."""
-    hd, bs = MODEL.head_dim_, ENGINE.block_size
+    bs = ENGINE.block_size
     NB, W = ENGINE.num_blocks, ENGINE.max_blocks_per_seq
 
     def S(shape, dt):
@@ -85,7 +87,8 @@ def _compile(one_chip, B, T, *, kv_dtype=jnp.bfloat16, quantized=False,
     def launch(*a, **k):
         return paged_attention_ragged(
             *a, block_size=bs, max_q_len=T,
-            q_tile=1 if T == 1 else q_tile, interpret=False, **k)
+            q_tile=1 if T == 1 else q_tile, kv_tile=kv_tile,
+            interpret=False, **k)
 
     return jax.jit(launch).lower(*args, **kw).compile().as_text()
 
@@ -130,3 +133,31 @@ def test_tp4_shard_of_the_kernel_compiles(one_chip, no_compile_cache):
 def test_prefill_sweep_tiles_compile(one_chip, no_compile_cache, q_tile):
     # engine.autotune sweeps these at T 256 and raises on a refusal
     assert "tpu_custom_call" in _compile(one_chip, 4, 256, q_tile=q_tile)
+
+
+# the benchmark's configuration (benchmarks/chip/configs/mistral-7b-v0.3-l16):
+# H 32, KV 8, hd 128, bf16, block 16, table width 512; KV 2 is what one
+# device runs at --mesh 1,4
+@pytest.mark.parametrize("B", [8, 64])
+@pytest.mark.parametrize("H,KV", [(32, 8), (8, 2)])
+def test_mistral_decode_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, B, H, KV):
+    assert ENGINE.max_blocks_per_seq == 512
+    text = _compile(one_chip, B, 1, H=H, KV=KV, hd=128)
+    assert "tpu_custom_call" in text
+    # the kernel declares its scoped-VMEM limit and stays a single call
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("kv_tile", [64, 256])
+def test_mistral_decode_sweep_tiles_compile(
+        one_chip, no_compile_cache, kv_tile):
+    # engine.autotune offers half and twice the default's 8 pages a step
+    from dynamo_tpu.ops.paged_attention import (
+        VMEM_LIMIT_BYTES, default_kv_tile,
+    )
+    assert default_kv_tile(16, 8, 128, jnp.bfloat16) == 128
+    assert "tpu_custom_call" in _compile(
+        one_chip, 64, 1, hd=128, kv_tile=kv_tile)
+    # two slots of K and V at the largest offered tile, in bf16
+    assert 2 * 2 * 256 * 8 * 128 * 2 < VMEM_LIMIT_BYTES // 8

@@ -779,6 +779,74 @@ async def test_step_records_carry_host_seconds(tmp_path, monkeypatch):
     assert gauges.refresh()["host_busy_ratio"] == snap["host_busy_ratio"]
 
 
+@pytest.mark.parametrize("kv_tile,pages", [(128, 8), (16, 1), (32, 2),
+                                           (8, 1)])
+def test_kv_blocks_walked_counts_tiles_of_whole_pages(kv_tile, pages):
+    """A hand-made batch: the sum over live rows of cdiv(ctx, tile) x pages
+    per step, nothing for a dead row, and no table width anywhere."""
+    from dynamo_tpu.observability.stepstats import kv_blocks_walked
+
+    contexts = [1, 128, 129, 266, 0, 3000, 0, 16, 17]
+    expect = sum(-(-c // kv_tile) * pages for c in contexts)
+    assert kv_blocks_walked(contexts, kv_tile=kv_tile,
+                            block_size=16) == expect
+    assert kv_blocks_walked([0, 0], kv_tile=kv_tile, block_size=16) == 0
+    assert kv_blocks_walked([], kv_tile=kv_tile, block_size=16) == 0
+    if kv_tile == 128:   # by hand: 8 + 8 + 16 + 24 + 0 + 192 + 0 + 8 + 8
+        assert expect == 264
+
+
+@pytest.mark.anyio
+@pytest.mark.parametrize("impl", ["pallas", "einsum"])
+async def test_decode_records_carry_kv_blocks_walked(
+        impl, tmp_path, monkeypatch):
+    """The engine's decode records: with the Pallas decode class, pages per
+    layer from each row's context and the tile the window was traced with,
+    whatever the table's width (``max_model_len``); with the einsum class,
+    which gathers every column, rows x width."""
+    from dynamo_tpu.ops.paged_attention import default_kv_tile
+
+    walked = {}
+    for max_len in (128, 512):
+        path = tmp_path / f"steps-{max_len}.jsonl"
+        monkeypatch.setenv("DYNTPU_OBS_STEPSTATS_PATH", str(path))
+        cfg = dataclasses.replace(_tiny_engine_config(attention_impl=impl),
+                                  max_model_len=max_len)
+        engine = InferenceEngine(ModelConfig.tiny(), cfg)
+        await engine.start()
+        try:
+            for prompt in ([5, 6, 7, 8, 9], [9, 8, 7]):
+                assert len(await _run(engine, prompt, n=6)) == 6
+        finally:
+            await engine.stop()
+        with open(path) as fh:
+            records = load_records(fh)
+        decode = [r for r in records if r["kind"] == DECODE]
+        assert len(decode) >= 8
+        tile = engine._decode_kv_tile
+        if impl == "pallas":
+            # what the window was traced with: the kernel's default for the
+            # tiny model's shapes, 32 pages of 4
+            assert model_lib.ATTENTION_TRACES["decode"]["tile"] == [1, tile]
+            assert tile == default_kv_tile(4, 8, 8, jnp.float32) == 128
+        else:
+            assert tile == 0
+        for r in decode:
+            assert r["live_rows"] == 1 and r["context_sum"] > 0
+            if impl == "pallas":
+                # one live row of context ``context_sum`` (K = 1); the
+                # seven dead seats of the bucket walk nothing
+                assert r["kv_blocks_walked"] == \
+                    -(-r["context_sum"] // tile) * (tile // cfg.block_size)
+            else:
+                assert r["kv_blocks_walked"] == \
+                    r["rows"] * cfg.max_blocks_per_seq
+        assert all(r["kv_blocks_walked"] == 0 for r in records
+                   if r["kind"] != DECODE)
+        walked[max_len] = [r["kv_blocks_walked"] for r in decode]
+    assert (walked[128] == walked[512]) == (impl == "pallas")
+
+
 def test_stepstats_jsonl_is_buffered_and_complete_on_close(tmp_path):
     """No flush per record (one a second at most), nothing lost on
     close()."""

@@ -41,8 +41,11 @@ def _reference(q, k_cache, v_cache, tables, seq_lens, bs):
     return out
 
 
-@pytest.mark.parametrize("seq_lens", [[7, 33, 0, 16], [1, 1, 1, 1]])
-def test_decode_kernel_matches_dense(seq_lens):
+@pytest.mark.parametrize("kv_tile", [0, 8, 16, 64])
+@pytest.mark.parametrize("seq_lens", [
+    [7, 33, 0, 16], [1, 1, 1, 1], [0, 64, 0, 63], [0, 0, 0, 9]])
+def test_decode_kernel_matches_dense(seq_lens, kv_tile):
+    # kv_tile: the default, then 1, 2 and 8 pages of 8 a step of the walk
     bs, W, B = 8, 8, 4
     KV, G, hd = 2, 4, 16
     H = KV * G
@@ -61,7 +64,7 @@ def test_decode_kernel_matches_dense(seq_lens):
     got = paged_attention_decode(
         jnp.asarray(q), jnp.asarray(k_cache), jnp.asarray(v_cache),
         jnp.asarray(tables), jnp.asarray(seq_lens),
-        block_size=bs, interpret=True,
+        block_size=bs, kv_tile=kv_tile, interpret=True,
     )
     want = _reference(q, k_cache, v_cache, tables, seq_lens, bs)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)

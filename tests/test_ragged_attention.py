@@ -77,21 +77,22 @@ def _make_case(rows, *, G=2, KV=2, hd=64, bs=16, W=8, q_tile=4, seed=0,
             np.asarray([r[1] for r in rows], np.int32), bs, q_tile)
 
 
-def _run(case, max_q_len=None):
+def _run(case, max_q_len=None, kv_tile=0):
     q, k, v, tables, q_start, q_len, ctx_len, bs, q_tile = case
     if max_q_len is None:
         max_q_len = int(np.max(np.diff(q_start)))
     out = paged_attention_ragged(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables),
         jnp.asarray(q_start), jnp.asarray(q_len), jnp.asarray(ctx_len),
-        block_size=bs, max_q_len=max_q_len, q_tile=q_tile, interpret=True,
+        block_size=bs, max_q_len=max_q_len, q_tile=q_tile, kv_tile=kv_tile,
+        interpret=True,
     )
     return np.asarray(out)
 
 
-def _check(case, tol=2e-3):
+def _check(case, tol=2e-3, kv_tile=0):
     q, k, v, tables, q_start, q_len, ctx_len, bs, _ = case
-    out = _run(case)
+    out = _run(case, kv_tile=kv_tile)
     assert np.isfinite(out).all(), "kernel leaked NaN/inf"
     ref = _reference(np.nan_to_num(q),
                      np.nan_to_num(k), np.nan_to_num(v),
@@ -101,10 +102,12 @@ def _check(case, tol=2e-3):
     return out
 
 
-def test_mixed_ragged_batch():
+@pytest.mark.parametrize("pages", [0, 1, 2, 8])
+def test_mixed_ragged_batch(pages):
     # one launch over every serving shape class: a decode row, a spec
     # verify window, a fresh prefill chunk (ctx == q_len), a continuation
-    # chunk with history, and a dead seat
+    # chunk with history, and a dead seat — at the default tile (0) and at
+    # 1, 2 and 8 pages a step of the KV walk
     rows = [
         (1, 37, 1),    # decode, partial last block
         (4, 20, 1),    # spec window [k+1] with history
@@ -113,7 +116,7 @@ def test_mixed_ragged_batch():
         (6, 50, 2),    # continuation chunk, partial tile tail
     ]
     case = _make_case(rows)
-    out = _check(case)
+    out = _check(case, kv_tile=pages * 16)
     # every slot of the dead row comes back exactly zero
     q_start = case[4]
     assert np.all(out[q_start[3]:q_start[4]] == 0.0)
@@ -197,3 +200,126 @@ def test_decode_wrapper_matches_ragged():
         (lens > 0).astype(np.int32), lens, bs,
     )
     assert np.max(np.abs(out - ref)) <= 2e-3
+
+
+# ---------------------------------------------------------------------------
+# the KV walk follows the tokens attended (PR 25): its length is a run-time
+# scalar, its step several pages, and the table's width is no part of it
+
+
+def _poison_beyond_contexts(case, W):
+    """The same case under a table ``W`` columns wide whose every entry
+    past a row's context is a stale id of a NaN-filled block."""
+    q, k, v, tables, q_start, q_len, ctx_len, bs, q_tile = case
+    k, v = np.array(k), np.array(v)
+    stale = k.shape[0] - 1
+    k[stale] = np.nan
+    v[stale] = np.nan
+    wide = np.full((tables.shape[0], W), stale, np.int32)
+    for r in range(tables.shape[0]):
+        used = (int(ctx_len[r]) + bs - 1) // bs
+        wide[r, :used] = tables[r, :used]
+    return (q, k, v, wide, q_start, q_len, ctx_len, bs, q_tile)
+
+
+@pytest.mark.parametrize("pages", [1, 8])
+def test_result_does_not_depend_on_table_width(pages):
+    # the same contexts under W = 4 and W = 512: bit-identical, and right
+    rows = [(1, 37, 1), (0, 0, 1), (4, 20, 1), (1, 64, 1), (6, 50, 2)]
+    case = _make_case(rows, W=4, seed=11)
+    narrow = _poison_beyond_contexts(case, 4)
+    wide = _poison_beyond_contexts(case, 512)
+    out_n = _run(narrow, kv_tile=pages * 16)
+    out_w = _run(wide, kv_tile=pages * 16)
+    assert np.isfinite(out_w).all()
+    np.testing.assert_array_equal(out_n, out_w)
+    q, k, v, tables, q_start, q_len, ctx_len, bs, _ = wide
+    ref = _reference(q, np.nan_to_num(k), np.nan_to_num(v), tables,
+                     q_start, q_len, ctx_len, bs)
+    assert np.max(np.abs(out_w - ref)) <= 2e-3
+
+
+@pytest.mark.parametrize("pages", [1, 2, 8])
+@pytest.mark.parametrize("where", [
+    "one_token", "one_tile", "one_over_a_tile", "partial_last_page",
+    "whole_table"])
+def test_walk_ends_at_each_context(pages, where):
+    bs, W = 16, 16
+    tile = pages * bs
+    ctx = {"one_token": 1, "one_tile": tile, "one_over_a_tile": tile + 1,
+           "partial_last_page": 2 * tile + bs + 5,
+           "whole_table": W * bs}[where]
+    ctx = min(ctx, W * bs)
+    # the context under test between a short and a long neighbour, so the
+    # tile fetched ahead for the next row is started at every kind of end
+    rows = [(1, 3, 1), (1, ctx, 1), (1, 90, 1), (4, max(ctx, 4), 1)]
+    _check(_poison_beyond_contexts(
+        _make_case(rows, W=W, seed=pages), W), kv_tile=tile)
+
+
+@pytest.mark.parametrize("dead", [
+    "first", "last", "between", "runs", "all_but_one"])
+def test_dead_rows_anywhere(dead):
+    # a dead row walks nothing but still hands the next row's first tile on
+    live = [(1, 37, 1), (4, 20, 1), (1, 130, 1)]
+    d = (0, 0, 1)
+    rows = {"first": [d] + live, "last": live + [d],
+            "between": [live[0], d, live[1], d, live[2]],
+            "runs": [d, d] + live[:1] + [d, d, d] + live[1:] + [d, d],
+            "all_but_one": [d, d, d, live[2], d, d]}[dead]
+    case = _poison_beyond_contexts(_make_case(rows, W=16, seed=12), 16)
+    out = _check(case, kv_tile=32)
+    q_start, q_len = case[4], case[5]
+    for r in range(len(rows)):
+        if q_len[r] == 0:
+            assert np.all(out[q_start[r]:q_start[r + 1]] == 0.0)
+
+
+@pytest.mark.parametrize("pages", [1, 2, 8])
+@pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
+def test_quantized_scale_planes_walk(kv_dtype, pages):
+    # int8 / fp8 pages with their per-(slot, head) scale planes, NaN scales
+    # in the trash block and in every partial tail, decode rows and a
+    # prefill chunk, against the naive softmax on the dequantized caches
+    from dynamo_tpu.engine import autotune, quant
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+
+    mc = ModelConfig.tiny()
+    ec = EngineConfig(block_size=16, num_blocks=128, max_num_seqs=8,
+                      max_num_batched_tokens=256, max_model_len=256,
+                      decode_buckets=(8,), prefill_buckets=(16, 32),
+                      kv_dtype=kv_dtype)
+    for attn_class, B, T in (("decode", 5, 1), ("prefill", 3, 16)):
+        case = autotune.make_sweep_case(mc, ec, attn_class, B, T, W=12,
+                                        ctx=150, seed=pages)
+        q, kc, vc, tables, q_start, q_len, ctx_len = case["args"]
+        out = np.asarray(paged_attention_ragged(
+            *(jnp.asarray(a) for a in case["args"]),
+            block_size=16, max_q_len=T, kv_tile=pages * 16, interpret=True,
+            k_scale=jnp.asarray(case["k_scale"]),
+            v_scale=jnp.asarray(case["v_scale"]),
+        )).astype(np.float64)
+        assert np.isfinite(out).all(), "kernel leaked NaN/inf"
+        ref = autotune.reference_naive(
+            q, quant.kv_dequantize_cache_np(kc, case["k_scale"]),
+            quant.kv_dequantize_cache_np(vc, case["v_scale"]),
+            tables, q_start, q_len, ctx_len, block_size=16)
+        mask = autotune.valid_slot_mask(q_start, q_len, out.shape[0])
+        assert np.max(np.abs(out[mask] - ref[mask])) <= 2e-3
+        assert np.all(out[~mask] == 0.0)
+
+
+def test_default_tile_follows_the_shapes_the_kernel_sees():
+    from dynamo_tpu.ops.paged_attention import default_kv_tile
+
+    # Mistral / Llama widths: 8 pages of 16 = 128 keys, a lane-width score
+    # tile; the tp4 shard (2 KV heads) the same
+    assert default_kv_tile(16, 8, 128, jnp.bfloat16) == 128
+    assert default_kv_tile(16, 2, 128, jnp.bfloat16) == 128
+    assert default_kv_tile(16, 8, 64, jnp.int8) == 128
+    # a page is never split by default, a large one is one step
+    assert default_kv_tile(256, 8, 128, jnp.bfloat16) == 256
+    assert default_kv_tile(4, 2, 16, jnp.float32) == 128
+    # a step too fat for its share of scoped VMEM takes fewer pages
+    assert default_kv_tile(16, 64, 256, jnp.float32) < 128
+    assert default_kv_tile(16, 64, 256, jnp.float32) % 16 == 0
