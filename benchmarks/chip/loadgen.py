@@ -6,8 +6,10 @@ The plan holds the run's shape (``shape.build_shape``), the seed for token
 ids, the port, and ``t0``: the CLOCK_MONOTONIC instant at which the ramp
 starts.  Open loop: request i is sent at ``t0 + due``.  Closed loop: client c
 starts at ``t0 + stagger * c / clients`` and walks its plan of turns, each
-sent when the previous one ended.  At ``t0 + horizon`` every stream still
-open is cut and the records are written.
+sent when the previous one ended; a client that reaches the end of its plan
+starts it again (a *lap*: the same lengths, fresh tokens no lap before had),
+so the offered load never depends on how fast the system is.  At
+``t0 + horizon`` every stream still open is cut and the records are written.
 """
 
 from __future__ import annotations
@@ -21,13 +23,13 @@ from . import client as C
 from . import shape as S
 
 
-async def _one(session, plan, shape, r, due_t, records):
-    toks = S.request_tokens(shape, r, plan["seed"], plan["vocab"])
+async def _one(session, plan, shape, r, due_t, records, lap=0):
+    toks = S.request_tokens(shape, r, plan["seed"], plan["vocab"], lap)
     now = time.monotonic()
     if due_t > now:
         await asyncio.sleep(due_t - now)
     rec = {"idx": r["idx"], "due_t": due_t, "group": r["group"],
-           "client": r.get("client"), "turn": r.get("turn"),
+           "client": r.get("client"), "turn": r.get("turn"), "lap": lap,
            "send_t": time.monotonic()}
     records.append(rec)
     await C.stream_completion(session, plan["port"], plan["model"], toks,
@@ -38,11 +40,14 @@ async def _one(session, plan, shape, r, due_t, records):
 async def _client_loop(session, plan, shape, c, mine, t0, records):
     start = t0 + shape["stagger_s"] * c / max(1, shape["clients"])
     due = start
-    for r in mine:
-        rec = await _one(session, plan, shape, r, due, records)
-        due = time.monotonic()           # closed loop: due when free
-        if rec.get("errors") or rec.get("status") != 200:
-            await asyncio.sleep(0.2)     # do not spin on a refusing server
+    lap = 0
+    while True:                          # until the horizon cancels it
+        for r in mine:
+            rec = await _one(session, plan, shape, r, due, records, lap)
+            due = time.monotonic()       # closed loop: due when free
+            if rec.get("errors") or rec.get("status") != 200:
+                await asyncio.sleep(0.2)  # do not spin on a refusing server
+        lap += 1
 
 
 async def run(plan: dict) -> dict:
@@ -63,8 +68,6 @@ async def run(plan: dict) -> dict:
                 session, plan, shape, c, sorted(v, key=lambda r: r["turn"]),
                 t0, records)) for c, v in sorted(per.items())]
         await asyncio.sleep(max(0.0, stop_t - time.monotonic()))
-        exhausted = sum(1 for t in tasks if t.done()) \
-            if shape["loop"] == "closed" else 0
         for t in tasks:
             t.cancel()
         res = await asyncio.gather(*tasks, return_exceptions=True)
@@ -74,7 +77,8 @@ async def run(plan: dict) -> dict:
         if not rec.get("done") and not rec.get("errors") \
                 and rec.get("status") in (200, None):
             rec["cut"] = True
-    return {"records": records, "plans_exhausted": exhausted,
+    return {"records": records,
+            "laps_max": max((r["lap"] for r in records), default=0),
             "crashed": crashed, "stopped_t": time.monotonic()}
 
 

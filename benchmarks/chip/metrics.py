@@ -32,7 +32,7 @@ def request_ok(r: dict) -> bool:
 def reduce_client(records: List[dict], w0: float, w1: float, chips: int,
                   open_loop: bool) -> dict:
     """End-to-end and client-view numbers over the window [w0, w1)."""
-    ttft, tpot, gaps, lag = [], [], [], []
+    ttft, tpot, gaps, lag, times = [], [], [], [], []
     tokens_in = 0
     done_in, attempted, failed = 0, 0, 0
     for r in records:
@@ -55,6 +55,7 @@ def reduce_client(records: List[dict], w0: float, w1: float, chips: int,
         for i, (t, n) in enumerate(ev):
             if w0 <= t < w1:
                 tokens_in += n
+                times.append(t)
                 if i == 0:
                     ttft.append((t - t_ref) * 1e3)
                 else:
@@ -65,7 +66,13 @@ def reduce_client(records: List[dict], w0: float, w1: float, chips: int,
             if n_tok > 1:
                 tpot.append((ev[-1][0] - ev[0][0]) * 1e3 / (n_tok - 1))
     secs = w1 - w0
+    times.sort()
+    # the longest stretch of the window in which no stream got a token: a
+    # stall of the whole system, which no median and no p95 of gaps shows
+    silence = max((b - a for a, b in zip([w0] + times, times + [w1])),
+                  default=None)
     return {
+        "longest_silence_ms": None if silence is None else silence * 1e3,
         "attempted": attempted, "failed": failed, "completed": done_in,
         "tokens_in_window": tokens_in,
         "out_tok_s": tokens_in / secs / chips,

@@ -167,7 +167,8 @@ async def warm_up(dep, shape, eng, seed, vocab, steps: list) -> None:
     reached by a prompt whose first ``W*16 - T`` tokens are already cached
     (the head of one long base prompt sent first) plus T fresh tokens: the
     prefix cache starts the chunk where the hit ends.  A decode bucket is
-    reached by that many short requests at once."""
+    reached by that many short requests at once, each long enough to be
+    decoding still when the last of them has been admitted."""
     bs = eng["block_size"]
     progs = S.reachable_prefill_programs(shape, eng)
     buckets = S.reachable_decode_buckets(shape, eng)
@@ -200,10 +201,21 @@ async def warm_up(dep, shape, eng, seed, vocab, steps: list) -> None:
             await step(f"prefill_T{T}_W{W}", _send(session, port, toks, 2))
         lens = sorted({r["total_len"] for r in shape["requests"]})
         short = lens[0]
-        for b in buckets:
+        # the engine's control-state update is a program per power of two
+        # of the rows that change in one step: b requests that end together
+        # reach the one for b rows, so the small powers get bursts too (a
+        # window in which 2 or 4 requests ended together compiled it: PR 27)
+        small = [b for b in (2, 4) if not buckets or b < buckets[0]]
+        for b in small + buckets:
+            # the b requests must all still be decoding when the last one
+            # joins (a step admits max_batched_tokens of prompts; twice the
+            # steps that takes, and 8 more), or the bucket's program is left
+            # to compile in the ramp (on four chips the one for 64 rows took
+            # 17.7 s and ran 8 s into the window: PR 27)
+            n_tok = 2 * -(-b * short // eng["max_batched_tokens"]) + 8
             burst = [_send(session, port,
                            S.tokens_for(seed, "warm", 1000 + 100 * b + j,
-                                        short, vocab), 4)
+                                        short, vocab), n_tok)
                      for j in range(b)]
             await step(f"decode_B{b}", asyncio.gather(*burst))
         if shape["docs"]:
@@ -298,6 +310,8 @@ def launch(cell: dict, cfg: dict, args, rundir: str, setup: dict):
         }
     if rehearse:
         env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
+                            f"{int(cell['chips'])}")
     tok_path = os.path.join(rundir, "tokenizer.json")
     write_tokenizer(tok_path, vocab)
     cfg_path = os.path.join(HERE, "configs", f"{cell['config']}.json")
@@ -327,7 +341,7 @@ def launch(cell: dict, cfg: dict, args, rundir: str, setup: dict):
 
 
 def drive(dep, shape: dict, seed: int, vocab: int, rundir: str,
-          trace: int, tag: str = "") -> dict:
+          trace: int, tag: str = "", chips: int = 1) -> dict:
     """Let the load generator (its own process) run ``shape``; returns its
     records, the window's bounds on the shared monotonic clock, and the
     worker's probe at window open, close and after the tail."""
@@ -352,12 +366,15 @@ def drive(dep, shape: dict, seed: int, vocab: int, rundir: str,
             dep.check_alive()
             h0 = dep.engine_probe()
             if trace:
-                ms = int(min(3000, max(200, shape["seconds"] * 1000 / 3)))
+                # one capture's events grow with the device planes: on four
+                # chips a 3 s capture was not written out in 120 s (PR 27)
+                ms = int(min(3000, max(200, shape["seconds"] * 1000 / 3))
+                         / chips)
                 time.sleep(max(0.0, (w0 + w1) / 2 - ms / 2000.0
                                - time.monotonic()))
                 _, prof = D.http_json(dep.sys_port, "GET",
                                        f"/debug/profile?ms={ms}",
-                                       timeout=120)
+                                       timeout=240)
                 say("profile", prof)
             time.sleep(max(0.0, w1 - time.monotonic()))
             dep.check_alive()
@@ -425,33 +442,49 @@ def run_cell(args) -> int:
         setup["warm_up_s"] = round(time.monotonic() - t_w, 3)
         setup["warm_up_steps"] = steps
         setup["ramp_s"] = shape["ramp_s"]
-        got = drive(dep, shape, args.seed, vocab, rundir, args.trace)
+        got = drive(dep, shape, args.seed, vocab, rundir, args.trace,
+                    chips=chips)
     except D.DeployFailed as e:
         sys.stderr.write(f"benchmark run failed: {e}\n")
         return EXIT_FAILED
     finally:
         dep.stop_all()
     lg, w0, w1 = got["lg"], got["w0"], got["w1"]
+    with open(dep.worker.log_path, errors="replace") as f:
+        remat_lines = f.read().count("Involuntary full rematerialization")
     h0, h1, h2, prof = got["h0"], got["h1"], got["h2"], got["prof"]
     setup_s = w0 - T_START
     client = M.reduce_client(lg["records"], w0, w1, chips,
                              shape["loop"] == "open")
     window_compiles = (h1["compile"]["compiles_total"]
                       - h0["compile"]["compiles_total"])
+    # what compiled between window open and close, by the program's own
+    # label: [count, seconds]
+    by0, by1 = (h["compile"].get("compiles_by_fn", {}) for h in (h0, h1))
+    secs0, secs1 = (h["compile"].get("compile_secs_by_fn", {})
+                    for h in (h0, h1))
+    compiled = {fn: [n - by0.get(fn, 0),
+                     round(secs1.get(fn, 0.0) - secs0.get(fn, 0.0), 3)]
+                for fn, n in by1.items() if n > by0.get(fn, 0)}
     mem = [m["peak_bytes_in_use"] for m in h2["memory"]
            if m.get("peak_bytes_in_use")]
     device["memory_peak_bytes"] = max(mem) if mem else 0
     say("setup", {"setup_s": setup_s, **setup})
     untimed = sum(abs(r.get("untimed_tokens", 0)) for r in lg["records"])
     say("window", {"w0": w0, "w1": w1, "window_compiles": window_compiles,
+                   "compiled_in_window": compiled,
                    "compile_cache": h2["compile_cache"],
-                   "plans_exhausted": lg["plans_exhausted"],
+                   "laps_max": lg["laps_max"],
+                   "involuntary_remats":
+                       h2["compile"].get("involuntary_remats_total"),
+                   "remat_lines_in_worker_log": remat_lines,
                    "crashed": lg["crashed"], "untimed_tokens": untimed,
                    "served_out_tok_s_all_chips":
                        client["tokens_in_window"] / float(args.seconds),
                    **{k: client[k] for k in ("attempted", "failed",
                                              "completed", "n_ttft", "n_tpot",
-                                             "n_gaps", "gen_lag_p99_ms")}})
+                                             "n_gaps", "gen_lag_p99_ms",
+                                             "longest_silence_ms")}})
 
     values = dict(client)
     values["setup_s"] = setup_s
@@ -477,6 +510,15 @@ def run_cell(args) -> int:
         steprecs = [r for r in read_jsonl(os.path.join(rundir,
                                                        "stepstats.jsonl"))
                     if w0 <= r.get("t_dispatch", 0) < w1]
+        first_in = [r for r in lg["records"] if r.get("events")
+                    and w0 <= r["events"][0][0] < w1]
+        say("prefill_tokens", {
+            "prompt_tokens_of_first_tokens_in_window":
+                sum(r["prompt_tokens_sent"] for r in first_in),
+            "stepstats_prefill_real_tokens":
+                sum(r["real_tokens"] for r in steprecs
+                    if r.get("kind") == "prefill"),
+            "lapped_requests": sum(1 for r in first_in if r.get("lap"))})
         ctx = {"client": client, "records": lg["records"],
                "window": (w0, w1), "steps": steprecs,
                "spans": read_jsonl(os.path.join(rundir, "spans.jsonl")),

@@ -4,7 +4,8 @@
     python3 benchmarks/chip/selfcheck.py
 
 - the shape builder offers identical totals, schedules and per-client plans
-  whatever the ``--seed``, while the token ids differ;
+  whatever the ``--seed``, while the token ids differ, and a closed loop's
+  second lap repeats no fresh token of the first;
 - the percentile arithmetic and the window reduction on a hand-made sample;
 - the trace reducer on hand-made events with known busy / idle / op shares /
   exposed collective time, and on the small recorded trace in ``testdata/``.
@@ -55,6 +56,13 @@ def check_shapes():
         assert len(ta) == len(tb) and ta != tb, name
         assert ta == S.request_tokens(a, a["requests"][0], 1, 32768)
         assert min(ta) >= 256 and max(ta) < 32768
+        if a["loop"] == "closed":
+            # a lap's fresh tokens are no other lap's; its document repeats
+            r = a["requests"][0]
+            n_doc = 0 if r["group"] is None else a["docs"][r["group"]]
+            lap1 = S.request_tokens(a, r, 1, 32768, lap=1)
+            assert lap1[:n_doc] == ta[:n_doc] and lap1[n_doc:] != ta[n_doc:]
+            assert lap1 == S.request_tokens(a, r, 1, 32768, lap=1)
     # stratified lengths hit the stated mean: uniform 128..384 -> 256
     v = S.stratified({"dist": "uniform", "lo": 128, "hi": 384}, 288,
                      random.Random(0))
@@ -99,6 +107,8 @@ def check_percentiles():
     assert close(c["tpot_p50_ms"], 450.0, 1e-6)
     # gaps ending in the window: 100, 200, 300, 1200, 200 ms
     assert c["n_gaps"] == 5 and close(c["itl_p95_ms"], 1020.0, 1e-6)
+    # no token between 11.6 and the window's end at 20
+    assert close(c["longest_silence_ms"], 8400.0, 1e-6)
     c2 = M.reduce_client(recs, 10.0, 20.0, 1, open_loop=False)
     assert close(c2["ttft_p50_ms"], 400.0, 1e-6)   # 11.0 - send 10.6
 

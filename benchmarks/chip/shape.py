@@ -234,8 +234,13 @@ def tokens_for(seed: int, kind: str, idx: int, n: int, vocab: int) -> list:
     return rng.integers(256, vocab, size=n).tolist()
 
 
-def request_tokens(shape: dict, r: dict, seed: int, vocab: int) -> list:
-    fresh = tokens_for(seed, "req", r["idx"], r["prompt_len"], vocab)
+def request_tokens(shape: dict, r: dict, seed: int, vocab: int,
+                   lap: int = 0) -> list:
+    """The prompt of request ``r`` in a client's ``lap``-th walk through its
+    plan (closed loop).  A lap's fresh tokens are those of an index no other
+    lap has, so a lapped prompt repeats nothing but its document."""
+    fresh = tokens_for(seed, "req", r["idx"] + lap * len(shape["requests"]),
+                       r["prompt_len"], vocab)
     if r["group"] is None:
         return fresh
     return doc_tokens(shape, r["group"], seed, vocab) + fresh
