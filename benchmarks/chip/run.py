@@ -41,6 +41,7 @@ from benchmarks.chip import deploy as D            # noqa: E402
 from benchmarks.chip import metrics as M           # noqa: E402
 from benchmarks.chip import peaks as P             # noqa: E402
 from benchmarks.chip import shape as S             # noqa: E402
+from benchmarks.chip import worker_launch as WL    # noqa: E402
 
 EXIT_FAILED = 1
 EXIT_NO_CHIP = 3
@@ -284,6 +285,13 @@ def launch(cell: dict, cfg: dict, args, rundir: str, setup: dict):
     Returns (deployment, child environment, device, reference verdict)."""
     rehearse = args.rehearse
     vocab = cfg["rehearse"]["vocab_size"] if rehearse else cfg["vocab_size"]
+    cfg_path = os.path.join(HERE, "configs", f"{cell['config']}.json")
+    try:
+        # a field the program lacks or a reference that is not there stops
+        # the run here, before a process is started or the chip is asked for
+        WL.check_configuration(cfg, rehearse, cfg_path)
+    except WL.ConfigError as e:
+        raise D.DeployFailed(str(e))
     shutil.rmtree(rundir, ignore_errors=True)
     os.makedirs(rundir)
     cache = os.path.abspath(args.cache_dir)
@@ -314,7 +322,6 @@ def launch(cell: dict, cfg: dict, args, rundir: str, setup: dict):
                             f"{int(cell['chips'])}")
     tok_path = os.path.join(rundir, "tokenizer.json")
     write_tokenizer(tok_path, vocab)
-    cfg_path = os.path.join(HERE, "configs", f"{cell['config']}.json")
     dep = D.Deployment(rundir, env)
     setup["launch_s"] = round(time.monotonic() - T_START, 3)
     try:
@@ -543,6 +550,18 @@ def run_cell(args) -> int:
     if not args.keep:
         shutil.rmtree(os.path.join(rundir, "profile"), ignore_errors=True)
     print(json.dumps(result), flush=True)
+    # each number `correct` compared, beside its limit
+    sys.stderr.write(json.dumps({"compared": {
+        "failed": [client["failed"], 0],
+        "load_generator_crashed": [bool(lg["crashed"]), False],
+        "reference": verdict.get("reference"),
+        "reference_ok": [bool(verdict.get("ok")), True],
+        **{f"{ph}.rel": [verdict[ph]["rel"], verdict.get("rel_tol")]
+           for ph in ("prefill", "decode") if ph in verdict},
+        **{c["stat"]: [c["value"], c["limit"]]
+           for c in verdict.get("limits", [])},
+        **({"error": verdict["error"]} if "error" in verdict else {})}})
+        + "\n")
     if rehearse:
         return EXIT_NO_CHIP     # a rehearsal can never pass for a chip run
     return 0
