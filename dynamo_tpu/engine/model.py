@@ -18,6 +18,18 @@ TPU-native. Design points:
   Pallas paged kernel streaming blocks HBM→VMEM (ops/paged_attention.py),
   prefill via a gathered-context einsum. Physical block 0 is a trash block —
   padding positions scatter there, and the allocator never hands it out.
+- **The write keeps the kernel's layout** (:func:`_kv_write`). The kernel
+  reads a layer row-major, ``{3,2,1,0}``. The plain
+  ``lk.at[block, :, off].set(k)`` indexes dims 0 and 2 around the window dim
+  ``KV``; XLA's layout assignment gives such a scatter the operand layout
+  ``{3,1,2,0}`` (block, token, head, hd) and converts with whole-layer
+  ``copy`` ops: K in, K out, V in, V out per layer, 64 copies of 32 MiB a
+  16-layer step, half of every step program on a v5e (PERF.md, PR 29). So
+  the step writes through the free ``[NB*KV*bs, hd]`` view, one update per
+  (token, head): the indexed dim leads, ``hd`` is the only window dim, the
+  scatter takes the layer row-major and nothing is converted. Same shape,
+  same layout, same bits. ``tests/test_chip_compile.py`` holds the compiled
+  step programs to "no copy of a cache layer".
 - **TP via shardings, not code**: parameters and cache carry
   ``jax.sharding.NamedSharding`` annotations over a ``("dp", "tp")`` mesh
   (attention/MLP column-row sharded, KV heads sharded over tp); XLA GSPMD
@@ -133,7 +145,13 @@ def init_cache(cfg: ModelConfig, eng: EngineConfig) -> Cache:
     array) are the TPU-critical choice: each layer's buffer is donated and
     scatter-updated IN PLACE. A stacked cache threaded through ``lax.scan``
     forces XLA to slice-out + update-in the whole cache every step —
-    measured ~90 ms/step of pure copies on v5e for a 1B model."""
+    measured ~90 ms/step of pure copies on v5e for a 1B model.
+
+    In place also depends on HOW a step writes: only through
+    :func:`_kv_write`, whose scatter indexes the leading dim of the
+    ``[NB*KV*bs, hd]`` view and so accepts this row-major layout. A
+    ``.at[block, :, off].set`` on the 4-D array makes XLA copy the whole
+    layer to ``{3,1,2,0}`` and back around every write (module header)."""
     dt = _dtype(cfg)
     shape = (eng.num_blocks, cfg.num_kv_heads, eng.block_size, cfg.head_dim_)
     if quant.is_quantized(eng.kv_dtype):
@@ -602,6 +620,48 @@ def _paged_ragged_attention(
     return out.reshape(B, T, H, hd)
 
 
+def _kv_write(plane: jax.Array, blocks: jax.Array, offs: jax.Array,
+              upd: jax.Array, mesh: Optional[Mesh] = None) -> jax.Array:
+    """Write one step's rows into a cache plane; same bits as
+    ``plane.at[blocks, :, offs].set(upd)``, without the whole-plane layout
+    copies that expression costs on the TPU (module header).
+
+    ``plane`` is a payload plane ``[NB, KV, bs, hd]`` with ``upd [N, KV,
+    hd]``, or a scale plane ``[NB, KV, bs]`` with ``upd [N, KV]``;
+    ``blocks`` / ``offs`` ``[N]`` are each row's physical block and slot
+    (pads point at trash block 0, where duplicates may race).
+
+    One update per (token, head) through the free ``[NB*KV*bs, hd]`` view:
+    the indexed dim leads and ``hd`` is the only window dim, so the scatter
+    takes the plane row-major. (Two index dims over ``[NB*KV, bs, hd]``
+    compile to the same program, but XLA's rewrite of that scatter drops
+    the op's scope from the trace.) On a ``tp`` mesh the write runs under
+    ``shard_map`` with the cache's own specs, as the kernel does: under
+    GSPMD the reshape would merge the sharded ``KV`` dim into the block dim
+    and all-gather the plane."""
+    def write(plane_, blocks_, offs_, upd_):
+        NB, KV, bs = plane_.shape[:3]
+        heads = jnp.arange(KV, dtype=blocks_.dtype)[None, :]
+        rows = (blocks_[:, None] * KV + heads) * bs + offs_[:, None]
+        view = plane_.reshape((NB * KV * bs,) + plane_.shape[3:])
+        view = view.at[rows.reshape(-1)].set(
+            upd_.reshape((-1,) + upd_.shape[2:]))
+        return view.reshape(plane_.shape)
+
+    if mesh is None or mesh.shape.get(AXIS_TP, 1) == 1:
+        return write(plane, blocks, offs, upd)
+    lay = SpecLayout.for_mesh(mesh)
+    plane_spec = (lay.cache_block() if plane.ndim == 4
+                  else lay.cache_scale_block())
+    upd_spec = layout.spec(None, lay.tp, *(None,) * (upd.ndim - 2))
+    return layout.shard_map(
+        write, mesh=mesh,
+        in_specs=(plane_spec, layout.spec(None), layout.spec(None),
+                  upd_spec),
+        out_specs=plane_spec,
+    )(plane, blocks, offs, upd)
+
+
 def _layer_attention(
     eng: EngineConfig, mesh, ring_mesh, ring_lay, use_pallas: bool,
     q, k, v, lk, lv, lks, lvs, positions, block_tables,
@@ -815,10 +875,10 @@ def forward(
                 # scatters to
                 k_upd, k_sc = quant.kv_quantize(k_upd, eng.kv_dtype)
                 v_upd, v_sc = quant.kv_quantize(v_upd, eng.kv_dtype)
-                lks = lks.at[scatter_block, :, scatter_off].set(k_sc)
-                lvs = lvs.at[scatter_block, :, scatter_off].set(v_sc)
-            lk = lk.at[scatter_block, :, scatter_off].set(k_upd)
-            lv = lv.at[scatter_block, :, scatter_off].set(v_upd)
+                lks = _kv_write(lks, scatter_block, scatter_off, k_sc, mesh)
+                lvs = _kv_write(lvs, scatter_block, scatter_off, v_sc, mesh)
+            lk = _kv_write(lk, scatter_block, scatter_off, k_upd, mesh)
+            lv = _kv_write(lv, scatter_block, scatter_off, v_upd, mesh)
 
         with jax.named_scope("attention"):
             attn = _layer_attention(

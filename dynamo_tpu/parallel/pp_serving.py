@@ -105,12 +105,10 @@ def _stage_layers(cfg: ModelConfig, eng: EngineConfig, Lp: int,
         v = (x @ p["wv"]).reshape(B, T, KV, hd)
         q = model_lib._rope(q, positions, cfg.rope_theta)
         k = model_lib._rope(k, positions, cfg.rope_theta)
-        layer_k = lk[li].at[scatter_block, :, scatter_off].set(
-            k.reshape(B * T, KV, hd)
-        )
-        layer_v = lv[li].at[scatter_block, :, scatter_off].set(
-            v.reshape(B * T, KV, hd)
-        )
+        layer_k = model_lib._kv_write(
+            lk[li], scatter_block, scatter_off, k.reshape(B * T, KV, hd))
+        layer_v = model_lib._kv_write(
+            lv[li], scatter_block, scatter_off, v.reshape(B * T, KV, hd))
         k_all = jnp.take(
             layer_k, block_tables.reshape(-1), axis=0
         ).reshape(B, W, KV, bs, hd).transpose(0, 1, 3, 2, 4).reshape(

@@ -1,6 +1,8 @@
 """The chip's compiler, asked without a chip: the ragged paged-attention
 kernel at Llama-3.2-1B widths in every shape class the engine can select,
-and at the benchmark cells' own widths (Mistral-7B) in the decode class.
+and at the benchmark cells' own widths (Mistral-7B) in the decode class; and
+whole step programs at the cells' shapes (2 layers), which must hold no copy
+of a cache layer and, on four chips, no collective but the layer's two.
 
 Interpret-mode parity (every other kernel test) cannot see what Mosaic
 refuses — a block that overflows scoped VMEM, a slice off the dtype's tile.
@@ -18,15 +20,19 @@ loadfile`` one file is one worker); the persistent compilation cache is off
 around them (a program compiled for a described device cannot be read back).
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from dynamo_tpu.engine import model as M
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.ops.paged_attention import paged_attention_ragged
+from dynamo_tpu.parallel import layout
 
 pytestmark = pytest.mark.chipcompile
 
@@ -161,3 +167,143 @@ def test_mistral_decode_sweep_tiles_compile(
         one_chip, 64, 1, hd=128, kv_tile=kv_tile)
     # two slots of K and V at the largest offered tile, in bf16
     assert 2 * 2 * 256 * 8 * 128 * 2 < VMEM_LIMIT_BYTES // 8
+
+
+# ---- whole step programs at the cells' shapes: the cache keeps its layout ---
+#
+# ``forward`` writes K and V through a view whose indexed dim leads and whose
+# only window dim is ``hd`` (``model._kv_write``). The plain
+# ``.at[block, :, off].set`` made XLA's layout assignment ask for the cache
+# as {3,1,2,0} and copy every layer's K and V into that layout and back, a
+# step: half of every step program on the chip (PERF.md, PR 29). Compile
+# only: Mistral-7B widths, 2 layers, the benchmark's engine arguments.
+
+CELL_MODEL = dict(
+    vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+    num_heads=32, num_kv_heads=8, head_dim=128, rope_theta=1e6,
+    rms_norm_eps=1e-5, tie_word_embeddings=False, max_position=32768)
+CELL_LAYERS = 2
+
+
+def _cell_programs(topo, mesh_shape):
+    """(cfg, eng, mesh, params, cache, S): the cells' model at 2 layers as
+    shapes placed on the described chips; ``S(shape, dtype)`` is a
+    replicated argument."""
+    cfg = ModelConfig(num_layers=CELL_LAYERS, **CELL_MODEL)
+    eng = EngineConfig(mesh_shape=mesh_shape)   # block 16, 2048 blocks, pallas
+    n = mesh_shape[0] * mesh_shape[1]
+    mesh = layout.make_mesh(mesh_shape, devices=topo.devices[:n])
+    params = jax.eval_shape(
+        lambda: M._init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, eng))
+    if n > 1:
+        repl = layout.replicated(mesh)
+        p_sh = M.param_shardings(mesh, cfg)
+        c_sh = M.cache_shardings(mesh, cfg)
+    else:
+        repl = SingleDeviceSharding(topo.devices[0])
+        p_sh = jax.tree.map(lambda _: repl, params)
+        c_sh = jax.tree.map(lambda _: repl, cache)
+
+    def place(tree, sh):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, sh)
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=repl)
+
+    return cfg, eng, mesh, place(params, p_sh), place(cache, c_sh), S
+
+
+def _decode_window_text(topo):
+    cfg, eng, mesh, params, cache, S = _cell_programs(topo, (1, 1))
+    Wcap = eng.max_blocks_per_seq
+    ctl = jax.tree.map(lambda a: S(a.shape, a.dtype),
+                       M.init_ctl(eng, eng.max_num_seqs, Wcap))
+    window, _ = M.make_autopilot_fns(cfg, eng, 1, Wcap, mesh)
+    return window.__wrapped__.lower(
+        params, cache, ctl, S((64,), jnp.int32)).compile().as_text()
+
+
+def _prefill_text(topo, T=256, W=16):
+    cfg, eng, mesh, params, cache, S = _cell_programs(topo, (1, 1))
+    fn = M.make_packed_prefill_fn(cfg, eng, T, W, mesh)
+    return fn.__wrapped__.lower(
+        params, cache, S((eng.max_num_seqs + 1,), jnp.int32),
+        S((1, T + W + M.PP_SCALARS), jnp.int32),
+        S((2,), jnp.uint32)).compile().as_text()
+
+
+def _tp4_forward_text(topo):
+    cfg, eng, mesh, params, cache, S = _cell_programs(topo, (1, 4))
+    fwd = jax.jit(
+        lambda p, c, t, pos, bt: M.forward(cfg, eng, p, c, t, pos, bt,
+                                           mesh=mesh),
+        donate_argnums=(1,),
+        **M._io_kwargs(mesh, cfg, 3, ("cache", "repl"), eng=eng))
+    return fwd.lower(
+        params, cache, S((64, 1), jnp.int32), S((64, 1), jnp.int32),
+        S((64, eng.max_blocks_per_seq), jnp.int32)).compile().as_text()
+
+
+def _cache_sized_copies(text, kv_heads=CELL_MODEL["num_kv_heads"]):
+    """``copy`` / ``copy-start`` ops whose result holds as many elements as
+    one cache layer (on a device: ``kv_heads`` of them) — the layer or any
+    view of it."""
+    hd = CELL_MODEL["head_dim"]
+    layer = ENGINE.num_blocks * kv_heads * ENGINE.block_size * hd
+    found = []
+    for line in text.splitlines():
+        m = re.search(r" = (.*?) copy(-start)?\(", line)
+        if m is None:
+            continue
+        for dims in re.findall(r"\[([\d,]+)\]", m.group(1)):
+            dims = [int(d) for d in dims.split(",")]
+            # hd is never merged into another dim: that tells a view of the
+            # cache from a weight of as many elements
+            if math.prod(dims) == layer and dims[-1] == hd:
+                found.append(line.strip()[:120])
+                break
+    return found
+
+
+def _collectives(text):
+    return {k: len(re.findall(r" = \S+ %s(?:-start)?\(" % k, text))
+            for k in ("all-reduce", "all-gather", "all-to-all",
+                      "collective-permute", "reduce-scatter")}
+
+
+@pytest.mark.parametrize("program", ["decode_window_b64", "prefill_T256"])
+def test_cell_step_programs_keep_the_cache_layout(
+        topo, no_compile_cache, program):
+    text = (_decode_window_text(topo) if program == "decode_window_b64"
+            else _prefill_text(topo))
+    assert "bf16[2048,8,16,128]" in text          # the cache is in there
+    assert _cache_sized_copies(text) == []
+    if program == "decode_window_b64":
+        assert text.count("tpu_custom_call") >= CELL_LAYERS
+
+
+def test_the_plain_scatter_is_what_copies_the_cache(
+        topo, no_compile_cache, monkeypatch):
+    # the reader above sees the copies where they are: the old expression
+    # brings K in, K out, V in, V out per layer
+    monkeypatch.setattr(
+        M, "_kv_write",
+        lambda plane, blocks, offs, upd, mesh=None:
+            plane.at[blocks, :, offs].set(upd))
+    assert len(_cache_sized_copies(_prefill_text(topo))) >= 4 * CELL_LAYERS
+
+
+def test_tp4_decode_keeps_the_cache_layout_and_adds_no_collective(
+        topo, no_compile_cache):
+    text = _tp4_forward_text(topo)
+    assert "bf16[2048,2,16,128]" in text          # a shard of the cache
+    assert _cache_sized_copies(text, kv_heads=2) == []
+    assert _cache_sized_copies(text, kv_heads=8) == []
+    # o_proj and down_proj a layer, and the vocabulary-sharded embedding
+    assert _collectives(text) == {
+        "all-reduce": 2 * CELL_LAYERS + 1, "all-gather": 0, "all-to-all": 0,
+        "collective-permute": 0, "reduce-scatter": 0}
+    assert text.count("tpu_custom_call") >= CELL_LAYERS
