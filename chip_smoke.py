@@ -100,7 +100,8 @@ def kernel_phase(jax, device: dict, rehearse: bool, seed: int) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from dynamo_tpu.engine import autotune, quant
+    from dynamo_tpu.engine import attention_parity as parity
+    from dynamo_tpu.engine import quant
     from dynamo_tpu.engine.config import EngineConfig, ModelConfig
     from dynamo_tpu.ops.paged_attention import paged_attention_ragged
 
@@ -137,7 +138,7 @@ def kernel_phase(jax, device: dict, rehearse: bool, seed: int) -> dict:
     for name, kv_dtype, B, T, W in classes:
         eng = dataclasses.replace(base, kv_dtype=kv_dtype)
         attn_class = "decode" if T == 1 else ("spec" if T <= 5 else "prefill")
-        case = autotune.make_sweep_case(
+        case = parity.make_sweep_case(
             mcfg, eng, attn_class, B, T, W=W, seed=seed, poison=True)
         if mcfg.dtype != "bfloat16":  # tiny preset is f32
             tol = 2e-3
@@ -161,10 +162,10 @@ def kernel_phase(jax, device: dict, rehearse: bool, seed: int) -> dict:
         if ks is not None:  # anchor on the dequantized caches
             kc = quant.kv_dequantize_cache_np(kc, ks)
             vc = quant.kv_dequantize_cache_np(vc, vs)
-        ref = autotune.reference_naive(
+        ref = parity.reference_naive(
             q, kc, vc, tables, q_start, q_len, ctx_len,
             block_size=eng.block_size)
-        mask = autotune.valid_slot_mask(q_start, q_len, got.shape[0])
+        mask = parity.valid_slot_mask(q_start, q_len, got.shape[0])
         err = float(np.max(np.abs(got[mask] - ref[mask]), initial=0.0))
         finite = bool(np.isfinite(got).all())
         dead_zero = bool((got[~mask] == 0.0).all()) if T == 1 else None

@@ -4,9 +4,6 @@
 # sigma=0, OSL 1024, concurrency 64, 320 requests, streaming).
 #
 #   URL=http://127.0.0.1:8000 MODEL=llama70b ./perf-baseline.sh
-#
-# For the single-process engine bench on the same shape instead:
-#   BENCH_PROFILE=baseline BENCH_MODEL=70b BENCH_MESH=1,8 python bench.py
 set -euo pipefail
 URL="${URL:-http://127.0.0.1:8000}"
 MODEL="${MODEL:?set MODEL to the served model name}"

@@ -29,8 +29,8 @@ class AnalysisConfig:
 
     root: Path = Path(".")
     # the serving hot loop: kernels, the JAX engine, the scheduler.  Cold
-    # engine modules (weights loading, startup autotune, config) stay out so
-    # a checkpoint load is not "a host sync in the decode loop".
+    # engine modules (weights loading, the kernel's parity gate, config)
+    # stay out so a checkpoint load is not "a host sync in the decode loop".
     hot_modules: Tuple[str, ...] = (
         "dynamo_tpu/ops/",
         "dynamo_tpu/engine/engine.py",

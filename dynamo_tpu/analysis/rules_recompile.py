@@ -3,7 +3,7 @@
 A jitted function that closes over mutable state, branches in Python on a
 traced value, or is rebuilt per iteration silently retraces; on TPU that is
 seconds of XLA compile in the middle of serving.  These rules target the
-trap shapes this repo has actually hit (MULTICHIP logs, autotune probes).
+trap shapes this repo has actually hit (MULTICHIP logs, start-up probes).
 """
 
 from __future__ import annotations

@@ -112,27 +112,18 @@ class EngineConfig:
     # sharding: (dp, tp) or (dp, fsdp, tp) mesh axis sizes; (1, 1) =
     # single chip. Axis semantics live in parallel/layout.py (SpecLayout)
     mesh_shape: Tuple[int, ...] = (1, 1)
-    # decode attention implementation: "pallas" streams KV blocks HBM→VMEM
-    # with online softmax (ops/paged_attention.py); "einsum" materialises the
-    # gathered context (the XLA-fusion reference path); "auto" microprobes
-    # both at engine startup (engine/autotune.py)
+    # attention implementation of the decode step: "pallas" streams KV
+    # blocks HBM→VMEM with online softmax (ops/paged_attention.py);
+    # "einsum" materialises the gathered context (the XLA reference path,
+    # and the program the stall watchdog rebuilds a wedged window on).
+    # The kernel's tile is not a knob: ops.paged_attention.default_kv_tile
+    # derives it from the shapes a launch sees.
     attention_impl: str = "pallas"
-    # per-shape-class overrides for the ragged kernel ("" = inherit:
-    # decode follows attention_impl, spec/prefill default to einsum).
-    # attention_impl="auto" fills all three from the startup microprobe.
-    attention_impl_decode: str = ""
+    # the T>1 shape classes (spec windows, prefill chunks) run einsum
+    # unless overridden here ("" = einsum); no cell selects the kernel
+    # there yet, only tests do
     attention_impl_spec: str = ""
     attention_impl_prefill: str = ""
-    # per-shape-class (q_tile, kv_tile) for the ragged pallas kernel.
-    # (0, 0) = kernel defaults; engine/autotune.py's tile sweep fills these
-    # with the fastest byte-parity-verified candidate per class (persisted
-    # across runs via DYNTPU_AUTOTUNE_CACHE). q_tile must divide the class's
-    # query window (decode: 1); kv_tile — the key positions one step of a
-    # row's KV walk covers — is a multiple of block_size (that many whole
-    # pages a step) or a divisor of it.
-    attention_tile_decode: Tuple[int, int] = (0, 0)
-    attention_tile_spec: Tuple[int, int] = (0, 0)
-    attention_tile_prefill: Tuple[int, int] = (0, 0)
     # adaptive bucket ladders (engine/ladder.py): let the engine split hot
     # decode/prefill buckets and retire cold ones from the flight recorder's
     # live per-bucket occupancy, under ladder_compile_budget extra rungs per
@@ -236,30 +227,16 @@ class EngineConfig:
             raise ValueError("max_num_seqs exceeds largest decode bucket")
         if self.spec_mode not in ("off", "ngram"):
             raise ValueError(f"unknown spec_mode {self.spec_mode!r}")
-        if self.attention_impl not in ("pallas", "einsum", "auto"):
+        if self.attention_impl not in ("pallas", "einsum"):
             raise ValueError(
                 f"unknown attention_impl {self.attention_impl!r}"
             )
-        for cls in ("decode", "spec", "prefill"):
+        for cls in ("spec", "prefill"):
             v = getattr(self, f"attention_impl_{cls}")
             if v not in ("", "pallas", "einsum"):
                 raise ValueError(
                     f"unknown attention_impl_{cls} {v!r}"
                 )
-        for cls in ("decode", "spec", "prefill"):
-            tile = getattr(self, f"attention_tile_{cls}")
-            if (len(tile) != 2 or tile[0] < 0 or tile[1] < 0):
-                raise ValueError(
-                    f"attention_tile_{cls} must be (q_tile>=0, kv_tile>=0)"
-                )
-            if (tile[1] > 0 and self.block_size % tile[1]
-                    and tile[1] % self.block_size):
-                raise ValueError(
-                    f"attention_tile_{cls} kv_tile {tile[1]} must divide "
-                    f"block_size {self.block_size} or be a multiple of it"
-                )
-        if self.attention_tile_decode[0] > 1:
-            raise ValueError("decode q_tile must be 0 or 1 (one query/row)")
         if self.ladder_compile_budget < 0:
             raise ValueError("ladder_compile_budget must be >= 0")
         if self.prefill_chunk_tokens < 0:

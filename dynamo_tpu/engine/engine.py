@@ -1342,15 +1342,6 @@ class InferenceEngine(EngineCore):
         seed: int = 0,
         devices: Optional[list] = None,
     ):
-        # attention autotune, BEFORE any step fn is built: the impl probe
-        # (attention_impl="auto" times Pallas vs einsum on the live
-        # backend) plus per-shape-class (q_tile, kv_tile) resolution —
-        # explicit config > persisted cache (DYNTPU_AUTOTUNE_CACHE) >
-        # on-TPU sweep > kernel defaults
-        from .autotune import autotune_attention
-        engine_config, self.attention_impl_choice = autotune_attention(
-            model_config, engine_config
-        )
         # adaptive bucket ladders (engine/ladder.py); built after the
         # recorder below when enabled, {} keeps every bucketing call on
         # the static grid
@@ -1489,11 +1480,6 @@ class InferenceEngine(EngineCore):
             )
             # host mirror of per-slot device state + seat map
             self._packed_prefill_fns: Dict[Tuple[int, int], Any] = {}
-            # channel-traffic counters (surfaced by bench.py)
-            self.num_windows = 0
-            self.num_deltas = 0
-            self.num_delta_rows = 0
-            self.num_cols_uploads = 0
             self.num_prefill_dispatches = 0
             self._ap: Dict[int, Dict[str, Any]] = {}
             self._ap_cols: List[int] = []       # device slot_rows content
@@ -1508,6 +1494,10 @@ class InferenceEngine(EngineCore):
                     model_config, engine_config, self.mesh
                 )
                 self.scheduler.sp_enabled = True
+        # per shape class the impl and tile the step programs are traced
+        # with; nothing is timed to decide it
+        self.attention_impl_choice = model_lib.attention_choice(
+            model_config, engine_config, self.mesh)
         # flight recorder: per-step MFU/goodput accounting + the compile
         # watchdog. Records are stamped at dispatch/landing on arrays the
         # fetcher already syncs — no extra host round-trips.
@@ -2040,9 +2030,7 @@ class InferenceEngine(EngineCore):
             if bucket >= max(grid):
                 try:
                     import dataclasses as _dc
-                    fb_cfg = _dc.replace(
-                        cfg, attention_impl_decode="einsum"
-                    )
+                    fb_cfg = _dc.replace(cfg, attention_impl="einsum")
                     self._ap_window_fn, self._ap_delta_fn = (
                         model_lib.make_autopilot_fns(
                             self.model_config, fb_cfg, self._window_K,
@@ -2051,6 +2039,8 @@ class InferenceEngine(EngineCore):
                     )
                     self._stall_einsum_fallback = True
                     self._decode_kv_tile = 0
+                    self.attention_impl_choice = model_lib.attention_choice(
+                        self.model_config, fb_cfg, self.mesh)
                     log.warning(
                         "stall watchdog: decode:%d is the largest rung — "
                         "rebuilt the decode window on the einsum attention "
@@ -2256,8 +2246,6 @@ class InferenceEngine(EngineCore):
             df[i, 1] = d["tp"]
         if self.step_sink is not None:
             self.step_sink("ctl", {"di": di, "df": df})
-        self.num_deltas += 1
-        self.num_delta_rows += len(deltas)
         self._ctl = self._ap_delta_fn(self._ctl, di, df)
 
     @hot_path
@@ -2331,11 +2319,9 @@ class InferenceEngine(EngineCore):
             if self.step_sink is not None:
                 self.step_sink("cols", {"rows": arr})
             self._ap_cols = cols
-            self.num_cols_uploads += 1
             self._ap_rows_dev = jax.device_put(arr)
         if self.step_sink is not None:
             self.step_sink("sw" if spec else "w", {})
-        self.num_windows += 1
         if obs_out is not None:
             # realized goodput (emitted tokens; spec accept counts) is
             # stamped at landing — only padded/real shapes are known here

@@ -392,46 +392,34 @@ def attention_class(eng: EngineConfig, T: int) -> str:
 def resolve_attention_impl(eng: EngineConfig, attn_class: str) -> str:
     """Resolve the attention impl ("pallas" | "einsum") for a shape class.
 
-    Per-class overrides (``attention_impl_{decode,spec,prefill}``, set
-    explicitly or by the autotune probe) win; otherwise decode follows the
-    legacy ``attention_impl`` knob and the T>1 classes default to einsum —
-    running every CPU test's prefills through interpret-mode Pallas would
-    be pointlessly slow, and on TPU the autotuner sets the fields anyway.
+    Decode follows ``attention_impl``.  The T>1 classes run einsum unless
+    ``attention_impl_{spec,prefill}`` says otherwise — running every CPU
+    test's prefills through interpret-mode Pallas would be pointlessly
+    slow, and no deployment selects the kernel there yet.
     """
-    override = getattr(eng, f"attention_impl_{attn_class}", "")
-    if override:
-        return override
-    if attn_class == "decode" and eng.attention_impl == "pallas":
-        return "pallas"
-    return "einsum"
+    if attn_class == "decode":
+        return eng.attention_impl
+    return getattr(eng, f"attention_impl_{attn_class}") or "einsum"
 
 
-def _class_tile(eng: EngineConfig, attn_class: str, T: int) -> Tuple[int, int]:
-    """Effective ``(q_tile, kv_tile)`` for a shape class at window length T.
-
-    Tuned tiles are advisory: a q_tile that doesn't divide this trace's T
-    (a winner picked at the largest prefill bucket vs. a smaller chunk)
-    falls back to the kernel default (0) instead of failing the trace.
-    """
-    q_tile, kv_tile = getattr(eng, f"attention_tile_{attn_class}", (0, 0))
-    if q_tile > 0 and T % q_tile:
-        q_tile = 0
-    if kv_tile > 0 and eng.block_size % kv_tile and kv_tile % eng.block_size:
-        kv_tile = 0
-    return q_tile, kv_tile
-
-
-def _walk_tile(eng: EngineConfig, mesh: Optional[Mesh], kv_tile: int,
+def _walk_tile(eng: EngineConfig, mesh: Optional[Mesh],
                kv_heads: int, head_dim: int, page_dtype) -> int:
-    """``kv_tile`` with 0 resolved as the kernel resolves it for the shapes
-    a launch sees: under ``shard_map`` a shard's share of the KV heads."""
+    """Key positions one step of the kernel's KV walk covers, as the kernel
+    resolves it for the shapes a launch sees: under ``shard_map`` a shard's
+    share of the KV heads."""
     from ..ops.paged_attention import default_kv_tile
 
-    if kv_tile > 0:
-        return kv_tile
     tp = mesh.shape.get(AXIS_TP, 1) if mesh is not None else 1
     return default_kv_tile(eng.block_size, max(1, kv_heads // tp), head_dim,
                            page_dtype)
+
+
+def _config_kv_tile(cfg: ModelConfig, eng: EngineConfig,
+                    mesh: Optional[Mesh]) -> int:
+    """``_walk_tile`` of the cache this configuration builds."""
+    page_dtype = quant.storage_dtype(eng.kv_dtype) \
+        if quant.is_quantized(eng.kv_dtype) else _dtype(cfg)
+    return _walk_tile(eng, mesh, cfg.num_kv_heads, cfg.head_dim_, page_dtype)
 
 
 def decode_kv_tile(cfg: ModelConfig, eng: EngineConfig,
@@ -442,10 +430,25 @@ def decode_kv_tile(cfg: ModelConfig, eng: EngineConfig,
     host's ``StepRecord.kv_blocks_walked`` counts with it."""
     if resolve_attention_impl(eng, "decode") != "pallas":
         return 0
-    page_dtype = quant.storage_dtype(eng.kv_dtype) \
-        if quant.is_quantized(eng.kv_dtype) else _dtype(cfg)
-    return _walk_tile(eng, mesh, _class_tile(eng, "decode", 1)[1],
-                      cfg.num_kv_heads, cfg.head_dim_, page_dtype)
+    return _config_kv_tile(cfg, eng, mesh)
+
+
+def attention_choice(cfg: ModelConfig, eng: EngineConfig,
+                     mesh: Optional[Mesh]) -> Dict[str, Any]:
+    """Per shape class, the impl and the ``[q_tile, kv_tile]`` its step
+    programs are traced with, as ``ATTENTION_TRACES`` notes them: the
+    kernel's decode window is ``[1, kv_tile]``, a spec or prefill launch
+    ``[0, kv_tile]`` (the kernel's own q tile for each T), einsum
+    ``[0, 0]``.  Decided from the configuration and the mesh alone."""
+    impls = {cls: resolve_attention_impl(eng, cls)
+             for cls in ("decode", "spec", "prefill")}
+    kv_tile = _config_kv_tile(cfg, eng, mesh)
+    return {
+        "impl": impls,
+        "tiles": {cls: [int(cls == "decode"), kv_tile]
+                  if impl == "pallas" else [0, 0]
+                  for cls, impl in impls.items()},
+    }
 
 
 # What each attention shape class resolved to the last time a step program
@@ -502,8 +505,7 @@ def _paged_decode_attention(
     from ..ops.paged_attention import paged_attention_decode
 
     interpret = pallas_interpret(mesh)
-    kv_tile = _walk_tile(eng, mesh, _class_tile(eng, "decode", 1)[1],
-                         lk.shape[1], lk.shape[3], lk.dtype)
+    kv_tile = _walk_tile(eng, mesh, lk.shape[1], lk.shape[3], lk.dtype)
     _note_attention("decode", "pallas", interpret, (1, kv_tile))
     kernel = functools.partial(
         paged_attention_decode,
@@ -568,15 +570,13 @@ def _paged_ragged_attention(
     B, T, H, hd = q.shape
     interpret = pallas_interpret(mesh)
     attn_class = attention_class(eng, T)
-    q_tile, kv_tile = _class_tile(eng, attn_class, T)
-    kv_tile = _walk_tile(eng, mesh, kv_tile, lk.shape[1], lk.shape[3],
-                         lk.dtype)
-    _note_attention(attn_class, "pallas", interpret, (q_tile, kv_tile))
+    kv_tile = _walk_tile(eng, mesh, lk.shape[1], lk.shape[3], lk.dtype)
+    # q tile 0: the kernel's own for this T
+    _note_attention(attn_class, "pallas", interpret, (0, kv_tile))
     kernel = functools.partial(
         paged_attention_ragged,
         block_size=eng.block_size,
         max_q_len=T,
-        q_tile=q_tile,
         kv_tile=kv_tile,
         interpret=interpret,
     )
@@ -1172,54 +1172,6 @@ def raw_step_fn(cfg: ModelConfig, eng: EngineConfig,
         return cache, sampled
 
     return step
-
-
-def raw_multistep_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
-                     mesh: Optional[Mesh] = None):
-    """K chained decode steps per host roundtrip.
-
-    The serving host↔device boundary has real latency (dispatch + fetch of
-    the sampled tokens); fetching once per K tokens amortises it — the
-    sampled token feeds the next step entirely on device via ``lax.scan``.
-
-    Signature:
-      multistep(params, cache, tokens[B,1], positions[B,1],
-                block_tables[B,W], valid_until[B], rngs[K],
-                temperature[B], top_k[B], top_p[B], seeds[B])
-        -> (cache, sampled[K, B])
-
-    Rows whose position reaches ``valid_until`` (capacity / length limit)
-    scatter to the trash block and their sampled tokens are garbage — the
-    scheduler discards them (mid-window EOS works the same way: the extra
-    tokens are computed and thrown away, which is cheaper than a mid-window
-    host sync).
-    """
-
-    def multistep(params, cache, tokens, positions, block_tables,
-                  valid_until, rngs, temperature, top_k, top_p, seeds):
-        B = tokens.shape[0]
-
-        def body(carry, rng_t):
-            cache, tok, pos = carry
-            pos_eff = jnp.where(pos < valid_until[:, None], pos, -1)
-            cache, h = forward(
-                cfg, eng, params, cache, tok, pos_eff, block_tables,
-                mesh=mesh,
-            )
-            with jax.named_scope("lm_head"):
-                h_last = h[:, 0]
-            logits = logits_fn(cfg, params, h_last)
-            s = sample(
-                logits, rng_t, temperature, top_k, top_p, seeds, pos[:, 0]
-            )
-            return (cache, s[:, None], pos + 1), s
-
-        (cache, _, _), samples = jax.lax.scan(
-            body, (cache, tokens, positions), rngs
-        )
-        return cache, samples
-
-    return multistep
 
 
 def make_step_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Optional[Mesh]):

@@ -4,7 +4,7 @@ watchdog, on-demand TPU profiles.
 Layout:
 
 * :mod:`.flops` — the ONE analytic FLOPs/parameter model (attention term
-  included) shared with ``bench.py``
+  included)
 * :mod:`.stepstats` — per-engine-step records + windowed live gauges
 * :mod:`.compilewatch` — per-jitted-function XLA recompile counters and
   ``[SPMD]`` involuntary-remat parsing

@@ -16,7 +16,7 @@ labelled call is attributed to that function, anything else lands in the
 Steady-state discipline: after :func:`mark_warmup_done` every further
 compile increments the *steady* counters — the thing that must stay flat
 in serving. ``engine_recompiles_total{fn}`` / ``engine_involuntary_remats_
-total`` surface through the worker gauges and ``bench.py``'s
+total`` surface through the worker gauges and ``obs_snapshot()``'s
 ``recompiles_steady_state``.
 """
 

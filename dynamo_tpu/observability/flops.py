@@ -1,5 +1,5 @@
-"""The ONE analytic FLOPs/parameter model shared by the flight recorder
-(``observability/stepstats.py``) and ``bench.py``.
+"""The ONE analytic FLOPs/parameter model of the flight recorder
+(``observability/stepstats.py``).
 
 Two terms per processed token:
 
@@ -14,7 +14,7 @@ Two terms per processed token:
   dropped; at long contexts it dominates.
 
 Peak FLOP/s per chip comes from public spec sheets (dense bf16; fp32
-halves the MXU rate). The table lived in ``bench.py`` before PR 9.
+halves the MXU rate).
 """
 
 from __future__ import annotations

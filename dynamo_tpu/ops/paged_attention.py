@@ -59,8 +59,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _LANES = 128
-# keys one step of the walk aims for: one lane-width score tile
-_STEP_KEYS = 128
+# keys one step of the walk aims for: two lane-width score tiles, the
+# winner or the tie of every chip reading of 128 against 256 (PERF.md, PR 30)
+_STEP_KEYS = 256
 # most of the scoped limit one step's K and V may take: two DMA slots in
 # the page dtype plus the float32 working copies the maths makes of them
 _STEP_BYTES = VMEM_LIMIT_BYTES // 8
@@ -353,12 +354,14 @@ def paged_attention_ragged(
     that holds at least one valid query — and every slot of a dead row —
     come back as exact zeros.
 
-    ``(q_tile, kv_tile)`` are pure performance knobs (``engine.autotune``
-    sweeps them per shape class): ``q_tile`` sets the output tile height,
-    ``kv_tile`` the key positions one step of a row's KV walk covers.  A
-    multiple of ``block_size`` moves that many whole pages a step (one DMA
-    per table entry, paged tables being non-contiguous); a divisor of it
-    walks each page in ``block_size // kv_tile`` slices.  ``0`` means the
+    ``(q_tile, kv_tile)`` change the accumulation order, never the
+    contract (``engine.attention_parity`` holds each to an order-exact
+    reference; the engine runs the defaults): ``q_tile`` sets the output
+    tile height, ``kv_tile`` the key positions one step of a row's KV walk
+    covers.  A multiple of ``block_size`` moves that many whole pages a
+    step (one DMA per table entry, paged tables being non-contiguous); a
+    divisor of it walks each page in ``block_size // kv_tile`` slices.
+    ``0`` means the
     default: ``min(max_q_len, 128)`` / ``default_kv_tile`` of the shapes
     this launch sees.  The walk's length is ``cdiv(frontier, kv_tile)``
     read at run time, so neither a context nor the table's width ``W`` is

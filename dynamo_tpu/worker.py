@@ -75,10 +75,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                    help="max draft tokens verified per window "
                         "(default: DYNTPU_SPEC_K, 4)")
     p.add_argument("--attention-impl", default="pallas",
-                   choices=["pallas", "einsum", "auto"],
-                   help="decode attention path; 'auto' probes both on the "
-                        "live backend at startup and picks per-shape-class "
-                        "winners (decode / spec window / prefill chunk)")
+                   choices=["pallas", "einsum"],
+                   help="decode attention path: the Pallas paged kernel or "
+                        "the XLA gathered-einsum reference")
     p.add_argument("--weight-dtype", default=None,
                    choices=["bf16", "int8", "fp8"],
                    help="weight storage dtype: int8/fp8 quantize at load "

@@ -23,9 +23,11 @@
 #                                 # suite: epoch guard, wire integrity,
 #                                 # chaos storms; echoes the repro seed
 #                                 # (DYNTPU_CHAOS_SEED=<n>) on failure
-#   scripts/verify.sh tune        # kernel tile autotune (CPU bitwise
-#                                 # parity sweep in a fusion-disabled
-#                                 # subprocess) + adaptive bucket ladders
+#   scripts/verify.sh tune        # the attention kernel's parity gate
+#                                 # (engine/attention_parity.py: every
+#                                 # tile of its grid bit-exact on CPU, in
+#                                 # a fusion-disabled subprocess)
+#                                 # + adaptive bucket ladders
 #   scripts/verify.sh mesh        # SpecLayout sharding parity: 1x8 / 2x4 /
 #                                 # 2x2x2 CPU meshes byte-identical to
 #                                 # single-device across decode, chunked

@@ -158,34 +158,3 @@ def test_router_bench_end_to_end():
     assert rr["completed"] == 24 and kv["completed"] == 24
     assert rr["errors"] == 0 and kv["errors"] == 0
     assert "kv_ttft_speedup" in report
-
-
-# --------------------------- bench.py paths ---------------------------
-
-
-@pytest.mark.anyio
-async def test_bench_baseline_profile_mechanics(monkeypatch):
-    """The BENCH_PROFILE=baseline branch (reference recipe shape) builds a
-    valid engine config and completes a run — exercised with tiny model
-    shapes substituted so CPU can execute it (the real profile is the TPU
-    path)."""
-    import bench
-    from dynamo_tpu.engine.config import ModelConfig
-
-    monkeypatch.setenv("BENCH_PROFILE", "baseline")
-    monkeypatch.setenv("BENCH_MODEL", "1b")
-    monkeypatch.setenv("BENCH_ISL", "32")
-    monkeypatch.setenv("BENCH_OSL", "4")
-    monkeypatch.setenv("BENCH_CONCURRENCY", "2")
-    monkeypatch.setenv("BENCH_REQUESTS", "2")
-    monkeypatch.setenv("BENCH_MESH", "1,1")
-    monkeypatch.setattr(ModelConfig, "llama3_1b",
-                        staticmethod(ModelConfig.tiny))
-    result = await bench.run_bench()
-    assert result["value"] > 0
-    assert "llama-1b" in result["metric"]
-    assert "chips=1" in result["metric"]
-    assert result["requests"] == 2
-    # per-model parity bar applied
-    assert result["vs_baseline"] == round(
-        result["value"] / bench.GPU_PARITY_TOKS["1b"], 4)

@@ -136,8 +136,8 @@ def test_tp4_shard_of_the_kernel_compiles(one_chip, no_compile_cache):
 
 
 @pytest.mark.parametrize("q_tile", [1, 8, 64])
-def test_prefill_sweep_tiles_compile(one_chip, no_compile_cache, q_tile):
-    # engine.autotune sweeps these at T 256 and raises on a refusal
+def test_prefill_grid_tiles_compile(one_chip, no_compile_cache, q_tile):
+    # q tiles of the parity gate's grid at T 256
     assert "tpu_custom_call" in _compile(one_chip, 4, 256, q_tile=q_tile)
 
 
@@ -155,18 +155,18 @@ def test_mistral_decode_compiles_at_the_cells_shapes(
     assert text.count("tpu_custom_call") == 1
 
 
-@pytest.mark.parametrize("kv_tile", [64, 256])
-def test_mistral_decode_sweep_tiles_compile(
+@pytest.mark.parametrize("kv_tile", [128, 512])
+def test_mistral_decode_grid_tiles_compile(
         one_chip, no_compile_cache, kv_tile):
-    # engine.autotune offers half and twice the default's 8 pages a step
+    # the parity gate's grid: half and twice the default's 16 pages a step
     from dynamo_tpu.ops.paged_attention import (
         VMEM_LIMIT_BYTES, default_kv_tile,
     )
-    assert default_kv_tile(16, 8, 128, jnp.bfloat16) == 128
+    assert default_kv_tile(16, 8, 128, jnp.bfloat16) == 256
     assert "tpu_custom_call" in _compile(
         one_chip, 64, 1, hd=128, kv_tile=kv_tile)
-    # two slots of K and V at the largest offered tile, in bf16
-    assert 2 * 2 * 256 * 8 * 128 * 2 < VMEM_LIMIT_BYTES // 8
+    # two slots of K and V at the largest tile of the grid, in bf16
+    assert 2 * 2 * 512 * 8 * 128 * 2 < VMEM_LIMIT_BYTES // 8
 
 
 # ---- whole step programs at the cells' shapes: the cache keeps its layout ---

@@ -42,9 +42,9 @@ def test_rehearsal_serves_every_request_then_refuses_ok(tmp_path):
     for who in ("store", "frontend"):
         assert not serve["accelerator_holders"][who]["jaxlib_mapped"]
     # ... the kernel was interpreted, and said so, with the tile it was
-    # traced with (the default resolved: 128 keys a step of the KV walk) ...
+    # traced with (the default resolved: 256 keys a step of the KV walk) ...
     assert serve["attention_traced"]["decode"] == {
-        "impl": "pallas", "interpret": True, "tile": [1, 128]}
+        "impl": "pallas", "interpret": True, "tile": [1, 256]}
     # ... and for exactly that reason — the platform — there is no ok
     assert out.returncode == 3, out.stderr[-2000:]
     assert "not a chip run" in out.stderr

@@ -115,9 +115,9 @@ def test_traced_attention_is_exposed(cpu_devices):
             cfg, eng, p, c, t, ps, tb, mesh=mesh)[1]).lower(
                 params, cache, tok, pos, tables)
     # the tile as traced: the kernel's default resolved for these shapes
-    # (block 16: 8 pages a step of the KV walk)
+    # (block 16: 16 pages a step of the KV walk)
     assert M.ATTENTION_TRACES["decode"] == {
-        "impl": "pallas", "interpret": True, "tile": [1, 128]}
+        "impl": "pallas", "interpret": True, "tile": [1, 256]}
     assert M.ATTENTION_TRACES["prefill"]["impl"] == "einsum"
 
 

@@ -271,7 +271,7 @@ async def test_epd_over_processes(tmp_path_factory):
 
     tok = tmp_path_factory.mktemp("tok") / "tokenizer.json"
     tok.write_text(byte_tokenizer().to_json_str())
-    store_port, http_port = free_port(), free_port()
+    store_port = free_port()
     procs = []
     try:
         store = ManagedProcess(
@@ -292,13 +292,18 @@ async def test_epd_over_processes(tmp_path_factory):
         )
         procs.append(worker)
         worker.wait_ready(90)
+        # the frontend binds a port of its own choosing and says which: a
+        # port picked before the store and the worker started (20-30 s
+        # earlier) can be another process's by now
         frontend = ManagedProcess(
             ["-m", "dynamo_tpu.frontend", "--host", "127.0.0.1",
-             "--port", str(http_port)],
+             "--port", "0"],
             name="frontend", env=env, ready_pattern=r"frontend ready",
         )
         procs.append(frontend)
         frontend.wait_ready(30)
+        http_port = int(frontend.wait_log(
+            r"frontend ready on \S+:(\d+)").group(1))
 
         async def ask(img):
             body = {

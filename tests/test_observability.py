@@ -323,7 +323,6 @@ async def test_engine_steady_state_recompiles_flat_then_seeded_shape(watch):
         snap = engine.obs_snapshot()
         assert snap["recompiles_steady_state"] == 0
         assert snap["recompiles_by_fn"] == {}
-        # the five live fields bench.py reports come from this snapshot
         assert snap["total_steps"] > 0
         assert snap["goodput_tok_s"] > 0.0
         # CPU has no published peak: MFU is absent, not nominal
@@ -826,9 +825,9 @@ async def test_decode_records_carry_kv_blocks_walked(
         tile = engine._decode_kv_tile
         if impl == "pallas":
             # what the window was traced with: the kernel's default for the
-            # tiny model's shapes, 32 pages of 4
+            # tiny model's shapes, 64 pages of 4
             assert model_lib.ATTENTION_TRACES["decode"]["tile"] == [1, tile]
-            assert tile == default_kv_tile(4, 8, 8, jnp.float32) == 128
+            assert tile == default_kv_tile(4, 8, 8, jnp.float32) == 256
         else:
             assert tile == 0
         for r in decode:

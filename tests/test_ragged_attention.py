@@ -281,7 +281,8 @@ def test_quantized_scale_planes_walk(kv_dtype, pages):
     # int8 / fp8 pages with their per-(slot, head) scale planes, NaN scales
     # in the trash block and in every partial tail, decode rows and a
     # prefill chunk, against the naive softmax on the dequantized caches
-    from dynamo_tpu.engine import autotune, quant
+    from dynamo_tpu.engine import attention_parity as parity
+    from dynamo_tpu.engine import quant
     from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 
     mc = ModelConfig.tiny()
@@ -290,8 +291,8 @@ def test_quantized_scale_planes_walk(kv_dtype, pages):
                       decode_buckets=(8,), prefill_buckets=(16, 32),
                       kv_dtype=kv_dtype)
     for attn_class, B, T in (("decode", 5, 1), ("prefill", 3, 16)):
-        case = autotune.make_sweep_case(mc, ec, attn_class, B, T, W=12,
-                                        ctx=150, seed=pages)
+        case = parity.make_sweep_case(mc, ec, attn_class, B, T, W=12,
+                                      ctx=150, seed=pages)
         q, kc, vc, tables, q_start, q_len, ctx_len = case["args"]
         out = np.asarray(paged_attention_ragged(
             *(jnp.asarray(a) for a in case["args"]),
@@ -300,26 +301,33 @@ def test_quantized_scale_planes_walk(kv_dtype, pages):
             v_scale=jnp.asarray(case["v_scale"]),
         )).astype(np.float64)
         assert np.isfinite(out).all(), "kernel leaked NaN/inf"
-        ref = autotune.reference_naive(
+        ref = parity.reference_naive(
             q, quant.kv_dequantize_cache_np(kc, case["k_scale"]),
             quant.kv_dequantize_cache_np(vc, case["v_scale"]),
             tables, q_start, q_len, ctx_len, block_size=16)
-        mask = autotune.valid_slot_mask(q_start, q_len, out.shape[0])
+        mask = parity.valid_slot_mask(q_start, q_len, out.shape[0])
         assert np.max(np.abs(out[mask] - ref[mask])) <= 2e-3
         assert np.all(out[~mask] == 0.0)
 
 
-def test_default_tile_follows_the_shapes_the_kernel_sees():
+@pytest.mark.parametrize("bs,kv_heads,hd,dtype,want", [
+    # the benchmark's shapes: 16 pages of 16 = 256 keys, two lane-width
+    # score tiles, on one chip (8 KV heads) and on the tp4 shard (2)
+    (16, 8, 128, jnp.bfloat16, 256),
+    (16, 2, 128, jnp.bfloat16, 256),
+    # int8 pages: narrower slots, the same float32 working copies
+    (16, 8, 64, jnp.int8, 256),
+    # a head narrower than the lanes is budgeted as a whole lane tile
+    (4, 2, 16, jnp.float32, 256),
+    # a step too fat for its share of scoped VMEM takes half the pages ...
+    (16, 16, 128, jnp.bfloat16, 128),
+    # ... and never less than one: a page is not split by default, however
+    # fat, and a page larger than the aim is one step
+    (16, 64, 256, jnp.float32, 16),
+    (512, 8, 128, jnp.bfloat16, 512),
+])
+def test_default_tile_follows_the_shapes_the_kernel_sees(
+        bs, kv_heads, hd, dtype, want):
     from dynamo_tpu.ops.paged_attention import default_kv_tile
 
-    # Mistral / Llama widths: 8 pages of 16 = 128 keys, a lane-width score
-    # tile; the tp4 shard (2 KV heads) the same
-    assert default_kv_tile(16, 8, 128, jnp.bfloat16) == 128
-    assert default_kv_tile(16, 2, 128, jnp.bfloat16) == 128
-    assert default_kv_tile(16, 8, 64, jnp.int8) == 128
-    # a page is never split by default, a large one is one step
-    assert default_kv_tile(256, 8, 128, jnp.bfloat16) == 256
-    assert default_kv_tile(4, 2, 16, jnp.float32) == 128
-    # a step too fat for its share of scoped VMEM takes fewer pages
-    assert default_kv_tile(16, 64, 256, jnp.float32) < 128
-    assert default_kv_tile(16, 64, 256, jnp.float32) % 16 == 0
+    assert default_kv_tile(bs, kv_heads, hd, dtype) == want
