@@ -8,10 +8,12 @@ TPU-native. Design points:
   ``tokens [B, T]`` with per-sequence block tables. Prefill runs ``B=1`` with a
   bucketed ``T``; decode runs ``T=1`` with a bucketed ``B``. XLA compiles one
   program per (B, T, W) bucket combination.
-- **Layers are unrolled** over stacked parameters (a static per-layer slice
-  is a read, not a copy). The paged KV cache is per-layer arrays so each
-  buffer is donated and scatter-updated IN PLACE — threading a stacked
-  cache through ``lax.scan`` costs whole-cache copies every step.
+- **Layers are unrolled** over stacked parameters. A static per-layer slice
+  is a read inside the matmul's fusion as long as the product stays the 2-D
+  matmul it is written as; :func:`_qkv_proj` says what happens when it does
+  not. The paged KV cache is per-layer arrays so each buffer is donated and
+  scatter-updated IN PLACE — threading a stacked cache through ``lax.scan``
+  costs whole-cache copies every step.
 - **Paged KV**: the cache is ``[L, num_blocks, KV, block_size, hd]``
   (block-major, head-contiguous); the step scatters the chunk's K/V into
   (block, offset) slots from the block table, then attends — decode via the
@@ -87,7 +89,8 @@ def _dtype(cfg: ModelConfig):
 
 
 def _init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
-    """Random-init parameters (stacked per-layer leaves for lax.scan)."""
+    """Random-init parameters; per-layer leaves are stacked ``[L, ...]``
+    and read by a static slice in :func:`forward`'s unrolled loop."""
     dt = _dtype(cfg)
     hd = cfg.head_dim_
     D, H, KV, F, L, V = (
@@ -313,9 +316,12 @@ def _mm(x: jax.Array, w: Any) -> jax.Array:
 
 
 class _LayerSlice:
-    """Static per-layer slice of the stacked param tree (a read, not a
-    copy); quantized ``{"q", "s"}`` leaves slice both members. The slice is
-    taken where the weight is read, so it carries that stage's scope."""
+    """Static per-layer slice of the stacked param tree; quantized
+    ``{"q", "s"}`` leaves slice both members. The slice is taken where the
+    weight is read, so it carries that stage's scope. XLA fuses it into the
+    matmul that reads it (a read, not a copy) unless it re-lays the weight
+    for that matmul, which is what :func:`_qkv_proj` keeps from
+    happening."""
 
     def __init__(self, stacked: Dict[str, Any], li: int):
         self._stacked, self._li = stacked, li
@@ -325,6 +331,34 @@ class _LayerSlice:
         if isinstance(w, dict):
             return {k: v[li] for k, v in w.items()}
         return w[li]
+
+
+def _qkv_proj(x: jax.Array, p: Any, H: int, KV: int, hd: int
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``x [B, T, D]`` times a layer's ``wq`` / ``wk`` / ``wv``, split into
+    heads: ``q [B, T, H, hd]``, ``k`` and ``v`` ``[B, T, KV, hd]``.
+
+    The barrier stands between the matmuls and the reshape. Without it
+    XLA's TPU pipeline folds the head reshape of the two products that feed
+    :func:`_rope` into their dot: a convolution over ``H`` whose result is
+    head-major ``[H, B, hd]``. For that it wants the weight as ``[H, hd,
+    D]`` with ``D`` minor; the parameter arrives ``[D, H*hd]``, a
+    parameter's layout is fixed, so every step program slices and
+    physically transposes every ``wq[l]`` and ``wk[l]`` (and on a
+    ``--mesh 1,4`` shard ``wv[l]``) before it multiplies by them:
+    ``slice_bitcast_fusion`` + ``copy``, 3.4 of a 16.5 ms decode step at
+    Mistral-7B widths on a v5e (PERF.md, PR 31). Behind the barrier the
+    three stay the plain ``[B*T, D] x [D, N]`` matmuls that ``wo`` and the
+    MLP are, read their weight slice inside the fusion at the HBM's rate,
+    and the compiler asks for no other layout. The price is the products'
+    own round trip through HBM, 0.6 MB a decode step. Same arithmetic,
+    same bits. ``tests/test_chip_compile.py`` holds the compiled step
+    programs to "no relayout of a weight"."""
+    B, T = x.shape[:2]
+    q, k, v = jax.lax.optimization_barrier(
+        (_mm(x, p["wq"]), _mm(x, p["wk"]), _mm(x, p["wv"])))
+    return (q.reshape(B, T, H, hd), k.reshape(B, T, KV, hd),
+            v.reshape(B, T, KV, hd))
 
 
 def _dequant_leaf(w: Any, dtype) -> jax.Array:
@@ -818,7 +852,8 @@ def forward(
     # donated and scatter-updated in place; a scanned stacked cache is
     # copied out of xs and back into ys wholesale every step (profiled at
     # ~90 ms/step of pure copies for a 1B model on v5e). Weights stay
-    # stacked [L, …]; the static per-layer slice is a read, not a copy.
+    # stacked [L, …]; the static per-layer slice is fused into the matmul
+    # that reads it (for wq / wk because _qkv_proj keeps them matmuls).
     kv_quant = quant.is_quantized(eng.kv_dtype)
     new_k: list = []
     new_v: list = []
@@ -833,9 +868,7 @@ def forward(
 
         with jax.named_scope("qkv_proj"):
             x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
-            q = _mm(x, p["wq"]).reshape(B, T, H, hd)
-            k = _mm(x, p["wk"]).reshape(B, T, KV, hd)
-            v = _mm(x, p["wv"]).reshape(B, T, KV, hd)
+            q, k, v = _qkv_proj(x, p, H, KV, hd)
         with jax.named_scope("rope"):
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
@@ -982,9 +1015,7 @@ def encode_forward(
     for li in range(cfg.num_layers):
         p = _LayerSlice(stacked, li)
         x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
-        q = _mm(x, p["wq"]).reshape(B, T, H, hd)
-        k = _mm(x, p["wk"]).reshape(B, T, KV, hd)
-        v = _mm(x, p["wv"]).reshape(B, T, KV, hd)
+        q, k, v = _qkv_proj(x, p, H, KV, hd)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
         attn = _attention(q, k, v, positions)

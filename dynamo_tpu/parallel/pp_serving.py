@@ -100,9 +100,7 @@ def _stage_layers(cfg: ModelConfig, eng: EngineConfig, Lp: int,
     for li in range(Lp):
         p = {name: w[li] for name, w in stage_params.items()}
         x = model_lib._rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
-        q = (x @ p["wq"]).reshape(B, T, H, hd)
-        k = (x @ p["wk"]).reshape(B, T, KV, hd)
-        v = (x @ p["wv"]).reshape(B, T, KV, hd)
+        q, k, v = model_lib._qkv_proj(x, p, H, KV, hd)
         q = model_lib._rope(q, positions, cfg.rope_theta)
         k = model_lib._rope(k, positions, cfg.rope_theta)
         layer_k = model_lib._kv_write(

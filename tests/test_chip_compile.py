@@ -2,7 +2,8 @@
 kernel at Llama-3.2-1B widths in every shape class the engine can select,
 and at the benchmark cells' own widths (Mistral-7B) in the decode class; and
 whole step programs at the cells' shapes (2 layers), which must hold no copy
-of a cache layer and, on four chips, no collective but the layer's two.
+of a cache layer, no relayout of a weight and, on four chips, no collective
+but the layer's two.
 
 Interpret-mode parity (every other kernel test) cannot see what Mosaic
 refuses — a block that overflows scoped VMEM, a slice off the dtype's tile.
@@ -27,6 +28,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.layout import Format, Layout
 from jax.sharding import SingleDeviceSharding
 
 from dynamo_tpu.engine import model as M
@@ -307,3 +309,104 @@ def test_tp4_decode_keeps_the_cache_layout_and_adds_no_collective(
         "all-reduce": 2 * CELL_LAYERS + 1, "all-gather": 0, "all-to-all": 0,
         "collective-permute": 0, "reduce-scatter": 0}
     assert text.count("tpu_custom_call") >= CELL_LAYERS
+
+
+# ---- no step program moves a weight before it multiplies by it --------------
+#
+# A parameter's layout is fixed before the program is compiled. Where XLA
+# folds the head reshape into the q and k projections it wants ``wq`` / ``wk``
+# with ``D`` minor and transposes every layer's slice of both inside the step
+# (a ``slice_bitcast_fusion`` that writes the slices, a ``copy`` a layer that
+# re-lays them: 3.4 of a 16.5 ms decode step, PERF.md PR 31). The barrier in
+# ``model._qkv_proj`` keeps the products plain matmuls; the compiler then
+# asks for no layout but the default one.
+
+_PROGRAMS = {"decode_window_b64": (_decode_window_text, (1, 1)),
+             "prefill_T256": (_prefill_text, (1, 1)),
+             "tp4_forward": (_tp4_forward_text, (1, 4))}
+
+
+def _weight_relayouts(text, tp):
+    """``copy`` ops and ``slice_bitcast_fusion`` loop fusions whose result
+    holds an array of 2 MB or more with the last two dims of a layer weight
+    (on a device: of its shard).  Asynchronous ``copy-start`` prefetches,
+    which overlap the matmuls, are not among them."""
+    D, F = CELL_MODEL["hidden_size"], CELL_MODEL["intermediate_size"]
+    hd = CELL_MODEL["head_dim"]
+    outs = {CELL_MODEL["num_heads"] * hd // tp,
+            CELL_MODEL["num_kv_heads"] * hd // tp, F // tp}
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"^\s*(?:ROOT )?%?(\S+) = (.*?) (copy|fusion)\(", line)
+        if m is None or (m.group(3) == "fusion"
+                         and "slice_bitcast_fusion" not in m.group(1)):
+            continue
+        for dims in re.findall(r"bf16\[([\d,]+)\]", m.group(2)):
+            dims = [int(d) for d in dims.split(",")]
+            if (len(dims) >= 2 and 2 * math.prod(dims) >= 2 << 20
+                    and D in dims[-2:] and set(dims[-2:]) - {D} <= outs):
+                found.append(line.strip()[:120])
+                break
+    return found
+
+
+def _asked_layouts(topo, mesh_shape):
+    """What the compiled decode window would have each stacked matrix lie
+    as, left free to choose (``Layout.AUTO``): leaf -> ``major_to_minor``
+    where that is not the default order."""
+    cfg, eng, mesh, params, cache, S = _cell_programs(topo, mesh_shape)
+    ask = jax.tree.map(
+        lambda a: Format(Layout.AUTO, a.sharding) if a.ndim == 3
+        else a.sharding, params)
+    bare = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        params)
+    Wcap = eng.max_blocks_per_seq
+    ctl = jax.tree.map(lambda a: S(a.shape, a.dtype),
+                       M.init_ctl(eng, eng.max_num_seqs, Wcap))
+    kw = M._io_kwargs(mesh, cfg, 2, ("cache", "repl", "repl"), eng=eng)
+    rest = kw.pop("in_shardings", (None,) * 4)[1:]
+    compiled = jax.jit(
+        M.raw_autopilot_window_fn(cfg, eng, 1, mesh), donate_argnums=(1, 2),
+        in_shardings=(ask,) + tuple(rest), **kw,
+    ).lower(bare, cache, ctl, S((64,), jnp.int32)).compile()
+    return {name: tuple(fmt.layout.major_to_minor)
+            for name, fmt in compiled.input_formats[0][0]["layers"].items()
+            if tuple(fmt.layout.major_to_minor)
+            != tuple(range(len(fmt.layout.major_to_minor)))}
+
+
+def _without_the_barrier(monkeypatch):
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+
+
+@pytest.mark.parametrize("program", sorted(_PROGRAMS))
+def test_cell_step_programs_move_no_weight(topo, no_compile_cache, program):
+    text_of, mesh_shape = _PROGRAMS[program]
+    text = text_of(topo)
+    assert "bf16[%d,4096,%d]" % (CELL_LAYERS, 4096 // mesh_shape[1]) in text
+    assert _weight_relayouts(text, mesh_shape[1]) == []
+
+
+@pytest.mark.parametrize("program", sorted(_PROGRAMS))
+def test_the_folded_reshape_is_what_moves_the_weights(
+        topo, no_compile_cache, monkeypatch, program):
+    # the reader above sees the relayouts where they are: without the
+    # barrier wq and wk (on four chips a shard of wv too) are sliced by a
+    # fusion each and transposed once a layer
+    _without_the_barrier(monkeypatch)
+    text_of, mesh_shape = _PROGRAMS[program]
+    assert len(_weight_relayouts(text_of(topo), mesh_shape[1])) \
+        >= 2 * CELL_LAYERS
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 4)])
+def test_the_compiler_asks_for_no_layout_but_the_default(
+        topo, no_compile_cache, monkeypatch, mesh_shape):
+    # left free to choose, the decode window takes every stacked matrix as
+    # it is stored; without the barrier it would have the projections that
+    # feed rope with D minor, per leaf and per mesh
+    assert _asked_layouts(topo, mesh_shape) == {}
+    _without_the_barrier(monkeypatch)
+    asked = _asked_layouts(topo, mesh_shape)
+    assert asked["wq"] == asked["wk"] == (0, 2, 1)
+    assert set(asked) <= {"wq", "wk", "wv"}
