@@ -10,7 +10,37 @@ ModelRuntimeConfig (ref: lib/llm/src/local_model/runtime_config.rs:9 —
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+
+def _freeze(v: Any) -> Any:
+    """A JSON value as something hashable: a ``ModelConfig`` is a static
+    argument of ``jax.jit``, and a configuration's file hands nested lists
+    and objects through as they are."""
+    if isinstance(v, dict):
+        return tuple(sorted((k, _freeze(x)) for k, x in v.items()))
+    if isinstance(v, (list, tuple)):
+        return tuple(_freeze(x) for x in v)
+    return v
+
+
+class AttnKind(NamedTuple):
+    """One kind of attention layer of a table: its stack of ``wq`` / ``wo``
+    / gate, its mask and its rope."""
+
+    name: str          # the ``layer_types`` value, and the rope's key
+    num_heads: int
+    window: int        # keys i - j >= window are masked; 0 = none
+    layers: Tuple[int, ...]   # the model's layers of this kind, in order
+
+
+class LayerEntry(NamedTuple):
+    """One row of the table: which stacks layer ``l`` reads, and where."""
+
+    attn: int          # index into ``ModelConfig.attn_kinds``
+    attn_at: int       # row of that kind's stacks
+    ffn: str           # "dense" | "sparse"
+    ffn_at: int        # row of that FFN kind's stacks
 
 
 @dataclass(frozen=True)
@@ -33,14 +63,146 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_token: int = 0
     moe_capacity_factor: float = 2.0
+    # A table of layer kinds, under the published keys of a ``config.json``
+    # that has one.  Empty = every layer is the one Llama-class block above
+    # and none of the fields below is read.  With a table: per layer the
+    # attention kind, the FFN kind and the query heads; per attention kind
+    # the window (``sliding_attention`` layers take ``sliding_window``) and
+    # the rope (``rope_parameters[kind]``: ``rope_theta``,
+    # ``partial_rotary_factor`` and, for ``rope_type`` "yarn", ``factor``,
+    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    # ``attention_factor``).  ``attn_gate`` "per-head" multiplies each
+    # query head's output by ``sigmoid(x Wg)``.  A "sparse" layer routes
+    # over ``num_routed_experts`` by softmax, keeps every token's
+    # ``num_experts_per_token`` choices, and computes those of the
+    # ``num_experts`` it holds: shard ``expert_shard["index"]`` of
+    # ``expert_shard["of"]`` equal ranges (parallel/moe.py: routed_ffn);
+    # beside them one shared expert on every token.  "dense" layers are the
+    # SwiGLU of ``intermediate_size``.
+    layer_types: Tuple[str, ...] = ()
+    mlp_layer_types: Tuple[str, ...] = ()
+    num_heads_per_layer: Tuple[int, ...] = ()
+    sliding_window: int = 0
+    rope_parameters: Any = None
+    attn_gate: str = ""
+    num_routed_experts: int = 0
+    expert_shard: Any = None
+    moe_intermediate_size: int = 0
+    shared_expert_intermediate_size: int = 0
+    norm_topk_prob: bool = True
+    moe_routed_scaling_factor: float = 1.0
+
+    def __post_init__(self):
+        for name in ("layer_types", "mlp_layer_types", "num_heads_per_layer",
+                     "rope_parameters", "expert_shard"):
+            object.__setattr__(self, name, _freeze(getattr(self, name)))
+        if not self.has_table:
+            return
+        L = self.num_layers
+        for name in ("layer_types", "mlp_layer_types", "num_heads_per_layer"):
+            if len(getattr(self, name)) != L:
+                raise ValueError(
+                    f"{name} has {len(getattr(self, name))} entries for "
+                    f"{L} layers")
+        if self.attn_gate not in ("", "per-head"):
+            raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
+        ropes = dict(self.rope_parameters or ())
+        for kind in self.attn_kinds:
+            if kind.name not in ("full_attention", "sliding_attention"):
+                raise ValueError(f"unknown layer type {kind.name!r}")
+            if kind.name not in ropes:
+                raise ValueError(f"rope_parameters has no {kind.name!r}")
+            if kind.num_heads % self.num_kv_heads:
+                raise ValueError(
+                    f"{kind.num_heads} query heads over "
+                    f"{self.num_kv_heads} KV heads")
+            if kind.window < 0 or (kind.name == "sliding_attention"
+                                   and kind.window == 0):
+                raise ValueError("sliding_attention needs sliding_window")
+        if set(self.mlp_layer_types) - {"dense", "sparse"}:
+            raise ValueError(f"unknown mlp layer type in "
+                             f"{self.mlp_layer_types}")
+        if "sparse" in self.mlp_layer_types:
+            start, held = self.experts_held
+            if held != self.num_experts or held < 1:
+                raise ValueError(
+                    f"num_experts {self.num_experts} is not shard "
+                    f"{dict(self.expert_shard or ())} of "
+                    f"{self.num_routed_experts} routed experts")
+            if not 0 < self.num_experts_per_token <= self.num_routed_experts:
+                raise ValueError("num_experts_per_token out of range")
+            if self.moe_intermediate_size < 1 \
+                    or self.shared_expert_intermediate_size < 1:
+                raise ValueError("a sparse layer needs its expert widths")
 
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
     @property
+    def has_table(self) -> bool:
+        return bool(self.layer_types)
+
+    @property
     def is_moe(self) -> bool:
-        return self.num_experts > 0
+        """The capacity-dispatch expert FFN in every layer (moe_ffn); a
+        table's sparse layers are ``has_routed_experts``."""
+        return self.num_experts > 0 and not self.has_table
+
+    @property
+    def has_routed_experts(self) -> bool:
+        return "sparse" in self.mlp_layer_types
+
+    @property
+    def attn_kinds(self) -> Tuple[AttnKind, ...]:
+        """The table's attention kinds in order of first appearance (one
+        nameless kind of ``num_heads`` without a table)."""
+        if not self.has_table:
+            return (AttnKind("", self.num_heads, 0,
+                             tuple(range(self.num_layers))),)
+        kinds: Dict[str, AttnKind] = {}
+        for li, (name, heads) in enumerate(
+                zip(self.layer_types, self.num_heads_per_layer)):
+            k = kinds.get(name)
+            if k is None:
+                window = (self.sliding_window
+                          if name == "sliding_attention" else 0)
+                kinds[name] = AttnKind(name, heads, window, (li,))
+            elif k.num_heads != heads:
+                raise ValueError(
+                    f"layer {li}: {heads} query heads in a {name} layer, "
+                    f"{k.num_heads} in an earlier one (one stack a kind)")
+            else:
+                kinds[name] = k._replace(layers=k.layers + (li,))
+        return tuple(kinds.values())
+
+    @property
+    def layer_table(self) -> Tuple[LayerEntry, ...]:
+        kinds = self.attn_kinds
+        if not self.has_table:
+            return tuple(LayerEntry(0, li, "dense", li)
+                         for li in range(self.num_layers))
+        of = {k.name: i for i, k in enumerate(kinds)}
+        seen = {"dense": 0, "sparse": 0}
+        rows = []
+        for li, (a, f) in enumerate(
+                zip(self.layer_types, self.mlp_layer_types)):
+            rows.append(LayerEntry(of[a], kinds[of[a]].layers.index(li),
+                                   f, seen[f]))
+            seen[f] += 1
+        return tuple(rows)
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this shard holds."""
+        shard = dict(self.expert_shard or (("index", 0), ("of", 1)))
+        count = self.num_routed_experts // shard["of"]
+        return shard["index"] * count, count
+
+    def rope_of(self, kind: AttnKind) -> Dict[str, Any]:
+        """The rope of a table's attention kind, as ``model._rope_kind``
+        takes it."""
+        return dict(dict(self.rope_parameters)[kind.name])
 
     # -- canned configs ---------------------------------------------------
 

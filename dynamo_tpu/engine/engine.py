@@ -30,6 +30,7 @@ from .. import tracing
 from ..observability import compilewatch, profiling
 from ..observability import flops as obs_flops
 from ..parallel import layout
+from ..parallel.moe import MOE_STATS
 from ..observability.flops import FlopsModel
 from ..observability.stepstats import (
     DECODE, PREFILL, SPEC_VERIFY, StepRecord, StepStats, kv_blocks_walked,
@@ -1377,6 +1378,10 @@ class InferenceEngine(EngineCore):
             )
         self._sp_prefill_fn = None
         self._mm_prefill_fn = None  # built lazily on the first mm request
+        # a table's window kind (0: none), for the window counters, and
+        # whether its decode windows carry the routing counters' row
+        self._attn_window = max(k.window for k in model_config.attn_kinds)
+        self._moe_stats = model_config.has_routed_experts
         self.num_sp_prefills = 0
         self.num_mm_prefills = 0
         if self.pp > 1:
@@ -1888,6 +1893,7 @@ class InferenceEngine(EngineCore):
             if chunk.final and chunk.seq.t_first_token is None:
                 chunk.seq.t_landed = t_got
         decode_samples: List[List[int]] = []
+        moe_stats = None
         if decode_handle is not None:
             col_of = {}
             for col, slot in enumerate(decode_handle[1]):
@@ -1896,6 +1902,9 @@ class InferenceEngine(EngineCore):
             if len(decode_handle) > 2 and decode_handle[2]:
                 decode_samples = self._unpack_spec(batch, out, col_of)
             else:
+                if self._moe_stats:
+                    # the routing counters' row behind the K sample rows
+                    out, moe_stats = out[:-1], out[-1]
                 for row in batch.decode_rows:
                     col = col_of[row.slot]
                     decode_samples.append([
@@ -1903,11 +1912,12 @@ class InferenceEngine(EngineCore):
                         for k in range(min(row.accepted, out.shape[0]))
                     ])
         if self.obs is not None:
-            self._obs_on_land(batch, decode_samples, t_got)
+            self._obs_on_land(batch, decode_samples, t_got, moe_stats)
         return prefill_samples, decode_samples
 
     @hot_path
-    def _obs_on_land(self, batch, decode_samples, t_got: float) -> None:
+    def _obs_on_land(self, batch, decode_samples, t_got: float,
+                     moe_stats=None) -> None:
         """Stamp landing time + realized goodput on this window's records
         and commit them to the flight recorder. Runs right after the
         window's one designed device_get, on already-fetched host ints —
@@ -1922,6 +1932,9 @@ class InferenceEngine(EngineCore):
         owner = next((r for r in recs if r.kind != PREFILL), recs[-1])
         owner.host_s = batch.host_s
         owner.unpack_s = t_land - t_got
+        if moe_stats is not None and owner.kind == DECODE:
+            for name, v in zip(MOE_STATS, moe_stats):
+                setattr(owner, name, int(v))
         for rec in recs:
             rec.t_land = t_land
             if rec.kind != PREFILL:
@@ -2335,6 +2348,18 @@ class InferenceEngine(EngineCore):
                 ) for k in range(K))
             elif not spec:
                 walked = B * self._ap_Wcap * K
+            walked_w = ctx_w = 0
+            if self._attn_window and not spec:
+                win = self._attn_window
+                ctx_w = sum(min(r.base + k + 1, win)
+                            for r in rows for k in range(K))
+                walked_w = walked   # the einsum path gathers every column
+                if self._decode_kv_tile:
+                    walked_w = sum(kv_blocks_walked(
+                        [r.base + k + 1 for r in rows],
+                        kv_tile=self._decode_kv_tile, block_size=bs,
+                        window=win,
+                    ) for k in range(K))
             obs_out.append(StepRecord(
                 kind=SPEC_VERIFY if spec else DECODE,
                 t_dispatch=time.monotonic(),
@@ -2342,6 +2367,8 @@ class InferenceEngine(EngineCore):
                 rows=B, live_rows=len(rows),
                 padded_tokens=B * K, real_tokens=len(rows) * K,
                 context_sum=ctx, kv_blocks_walked=walked,
+                kv_blocks_walked_window=walked_w,
+                context_sum_window=ctx_w,
             ))
         fn = self._spec_window_fn if spec else self._ap_window_fn
         self.cache, self._ctl, samples = fn(

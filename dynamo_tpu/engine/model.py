@@ -79,6 +79,17 @@ SCOPES = (
     "sample",
     "ctl",         # token ring / autopilot control-state reads and deltas
 )
+# the stages only a table's layers have (ModelConfig.layer_types; PR 32),
+# added behind the others: none of those was renamed
+TABLE_SCOPES = (
+    "attention_window",  # "attention" of a layer with a window: its walk
+                         # starts at the window, so the trace parts the two
+    "attn_gate",   # per-head sigmoid gate on the attention output
+    "moe_router",  # mlp norm, router matmul, softmax, top-k, sort
+    "moe_experts",  # dispatch, grouped matmuls over the experts held, combine
+    "moe_shared",  # the shared expert + residual
+)
+SCOPES += TABLE_SCOPES
 
 
 # ------------------------------ init ------------------------------------
@@ -86,6 +97,98 @@ SCOPES = (
 
 def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
+
+
+def _normal(key, shape, fan_in, dt):
+    return (jax.random.normal(key, shape, jnp.float32)
+            / np.sqrt(fan_in)).astype(dt)
+
+
+# one routed-expert leaf of one layer, a program of its own: its float32
+# draw (1.6 GB at 128 x 3072 x 1024) is dead before the next one starts
+_expert_leaf = jax.jit(_normal, static_argnums=(1, 2, 3))
+
+# the expert leaves of a table: a list of per-layer ``[Eh, in, out]`` arrays
+# (not one stack: a grouped matmul is a custom call, and a static slice of a
+# stack as its operand is a 1.6 GB copy a leaf a layer a step)
+EXPERT_LEAVES = ("expert_gate", "expert_up", "expert_down")
+
+
+def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
+    """Everything of a table's parameters but the routed experts: leaves
+    that every layer has alike are one ``[L, ...]`` stack, ``wq`` / ``wo``
+    / the gate one stack an attention kind, the dense FFN and the sparse
+    layers' router and shared expert one stack an FFN kind."""
+    dt = _dtype(cfg)
+    hd, D, KV, L, V = (cfg.head_dim_, cfg.hidden_size, cfg.num_kv_heads,
+                       cfg.num_layers, cfg.vocab_size)
+    key = iter(jax.random.split(rng, 64))
+    layers: Dict[str, Any] = {
+        "attn_norm": jnp.ones((L, D), dt),
+        "mlp_norm": jnp.ones((L, D), dt),
+        "wk": _normal(next(key), (L, D, KV * hd), D, dt),
+        "wv": _normal(next(key), (L, D, KV * hd), D, dt),
+        "wq": {}, "wo": {},
+    }
+    if cfg.attn_gate:
+        layers["w_attn_gate"] = {}
+    for kind in cfg.attn_kinds:
+        n, H = len(kind.layers), kind.num_heads
+        layers["wq"][kind.name] = _normal(next(key), (n, D, H * hd), D, dt)
+        layers["wo"][kind.name] = _normal(
+            next(key), (n, H * hd, D), H * hd, dt)
+        if cfg.attn_gate:
+            layers["w_attn_gate"][kind.name] = _normal(
+                next(key), (n, D, H), D, dt)
+    n_dense = cfg.mlp_layer_types.count("dense")
+    n_sparse = L - n_dense
+    if n_dense:
+        F = cfg.intermediate_size
+        layers["w_gate"] = _normal(next(key), (n_dense, D, F), D, dt)
+        layers["w_up"] = _normal(next(key), (n_dense, D, F), D, dt)
+        layers["w_down"] = _normal(next(key), (n_dense, F, D), F, dt)
+    if n_sparse:
+        Fs = cfg.shared_expert_intermediate_size
+        layers["w_router"] = _normal(
+            next(key), (n_sparse, D, cfg.num_routed_experts), D, dt)
+        layers["shared_gate"] = _normal(next(key), (n_sparse, D, Fs), D, dt)
+        layers["shared_up"] = _normal(next(key), (n_sparse, D, Fs), D, dt)
+        layers["shared_down"] = _normal(next(key), (n_sparse, Fs, D), Fs, dt)
+    params: Params = {
+        "embed": _normal(next(key), (V, D), D, dt),
+        "layers": layers,
+        "final_norm": jnp.ones((D,), dt),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = _normal(next(key), (D, V), D, dt)
+    return params
+
+
+_init_table_small_jit = jax.jit(_init_table_small, static_argnums=(1,))
+
+
+def _init_table_params(rng: jax.Array, cfg: ModelConfig) -> Params:
+    """A table's parameters from ``rng``: the small leaves in one program,
+    then each routed-expert leaf of each sparse layer in one of its own, in
+    turn, so that no two float32 draws of that size are alive at once."""
+    small_key, expert_key = jax.random.split(rng)
+    params = _init_table_small_jit(small_key, cfg)
+    n_sparse = cfg.mlp_layer_types.count("sparse")
+    if n_sparse:
+        dt = _dtype(cfg)
+        D, F, Eh = (cfg.hidden_size, cfg.moe_intermediate_size,
+                    cfg.num_experts)
+        shapes = dict(zip(EXPERT_LEAVES, (((Eh, D, F), D), ((Eh, D, F), D),
+                                          ((Eh, F, D), F))))
+        keys = jax.random.split(expert_key, n_sparse * len(shapes))
+        for j, (name, (shape, fan_in)) in enumerate(shapes.items()):
+            params["layers"][name] = [
+                # at load, not in a step: the next draw starts when this
+                # one's float32 copy is gone
+                jax.block_until_ready(  # dynalint: disable=DT102
+                    _expert_leaf(keys[j * n_sparse + i], shape, fan_in, dt))
+                for i in range(n_sparse)]
+    return params
 
 
 def _init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
@@ -135,7 +238,13 @@ def _init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
 # the sampler's own constants, so an op-by-op run differs from a compiled
 # one in the last bit — every caller, :func:`init_params_sharded` included,
 # gets the compiled values and therefore the same ones for the same key.
-init_params = jax.jit(_init_params, static_argnums=(1,))
+_init_params_jit = jax.jit(_init_params, static_argnums=(1,))
+
+
+def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
+    if cfg.has_table:
+        return _init_table_params(rng, cfg)
+    return _init_params_jit(rng, cfg)
 
 
 def init_cache(cfg: ModelConfig, eng: EngineConfig) -> Cache:
@@ -195,6 +304,31 @@ def cache_shardings(mesh: Mesh, cfg: ModelConfig,
     return SpecLayout.for_mesh(mesh).cache_shardings(mesh, cfg, kv_dtype)
 
 
+def refuse_table(cfg: ModelConfig, *, what: str = "",
+                 mesh: Optional[Mesh] = None,
+                 weight_dtype: str = "bf16") -> None:
+    """A table of layer kinds (``ModelConfig.layer_types``) runs on one
+    device in the model's own dtype through :func:`forward` and
+    :func:`encode_forward`.  Whatever else is asked to run one says so when
+    it is built, not at its first step: ``SpecLayout`` has no specs for a
+    stack a kind, the pipeline stages slice one ``[L, ...]`` stack a leaf,
+    and ``quant.quantize_params`` knows the one-kind tree."""
+    if not cfg.has_table:
+        return
+    if what:
+        raise ValueError(
+            f"{what} has no path for a table of layer kinds "
+            f"(layer_types {sorted(set(cfg.layer_types))})")
+    if _multi(mesh):
+        raise ValueError(
+            f"a table of layer kinds runs on --mesh 1,1; mesh "
+            f"{dict(mesh.shape)} has no sharding specs for it")
+    if weight_dtype != "bf16":
+        raise ValueError(
+            f"a table of layer kinds is served in the model's dtype; "
+            f"--weight-dtype {weight_dtype} has no quantiser for it")
+
+
 def _multi(mesh: Optional[Mesh]) -> bool:
     """Explicit in/out shardings only pay off (and only typecheck against
     axis names) on a real multi-device mesh."""
@@ -243,7 +377,12 @@ def init_params_sharded(rng: jax.Array, cfg: ModelConfig, mesh: Mesh,
     only ever materialises its own shards.  Building the tree on the
     default device first and spreading it afterwards puts the whole model
     on chip 0 — 16 GB of bf16 for the 8B preset, all of a v5e chip's HBM.
-    Same values as :func:`init_params` for the same key."""
+    Same values as :func:`init_params` for the same key.  A table is one
+    device's (``refuse_table`` holds the meshes off) and is drawn by the
+    same programs as :func:`init_params`."""
+    if cfg.has_table:
+        refuse_table(cfg, mesh=mesh, weight_dtype=weight_dtype)
+        return _init_table_params(rng, cfg)
     fn = jax.jit(
         lambda key: quant.quantize_params(_init_params(key, cfg),
                                           weight_dtype),
@@ -265,6 +404,9 @@ def init_cache_sharded(cfg: ModelConfig, eng: EngineConfig,
 
 def shard_params(params: Params, mesh: Mesh, cfg: ModelConfig,
                  weight_dtype: str = "bf16") -> Params:
+    if cfg.has_table:
+        refuse_table(cfg, mesh=mesh, weight_dtype=weight_dtype)
+        return jax.device_put(params, mesh.devices.flat[0])
     return jax.device_put(params, param_shardings(mesh, cfg, weight_dtype))
 
 
@@ -283,21 +425,79 @@ def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (x * w.astype(jnp.float32)).astype(dt)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """HF-convention rotary embedding (rotate-half). x: [B, T, Hx, hd]."""
+def rope_frequencies(rope: Dict[str, Any], head_dim: int
+                     ) -> Tuple[np.ndarray, float]:
+    """Inverse frequencies ``[rotary // 2]`` (float32, computed once at
+    trace time) and the factor on ``cos`` and ``sin`` of a rope given by
+    the published keys: ``rope_theta``; ``partial_rotary_factor`` (the
+    leading share of each head that turns; the rest passes through); and
+    for ``rope_type`` "yarn" the blend of ``1 / theta^(2i/d)`` with the same
+    over ``factor``, by the linear ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow`` turns in
+    ``original_max_position_embeddings`` positions, with
+    ``attention_factor`` on ``cos`` and ``sin``."""
+    theta = float(rope["rope_theta"])
+    rotary = int(head_dim * float(rope.get("partial_rotary_factor", 1)))
+    half = rotary // 2
+    exponent = np.arange(half, dtype=np.float64) / half
+    inv = 1.0 / theta ** exponent
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return inv.astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"unknown rope_type {kind!r}")
+    factor = float(rope["factor"])
+    orig = float(rope["original_max_position_embeddings"])
+
+    def correction_dim(turns: float) -> float:
+        return (rotary * np.log(orig / (turns * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(correction_dim(float(rope["beta_fast"]))), 0)
+    high = min(np.ceil(correction_dim(float(rope["beta_slow"]))), rotary - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0, 1)
+    inv = inv / factor * ramp + inv * (1 - ramp)
+    scale = rope.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * np.log(factor) + 1.0
+    return inv.astype(np.float32), float(scale)
+
+
+def _rotate(x: jax.Array, positions: jax.Array, freqs, scale: float = 1.0
+            ) -> jax.Array:
+    """Rotate-half on the leading ``2 * len(freqs)`` dims of each head of
+    ``x [B, T, Hx, hd]``; the rest passes through."""
     hd = x.shape[-1]
-    half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    half = freqs.shape[0]
     pos = jnp.maximum(positions, 0).astype(jnp.float32)  # [B, T]
     angles = pos[..., None] * freqs  # [B, T, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    x1, x2 = x[..., :half], x[..., half:2 * half]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
-    )
-    return out.astype(x.dtype)
+    ).astype(x.dtype)
+    if 2 * half < hd:
+        return jnp.concatenate([out, x[..., 2 * half:]], axis=-1)
+    return out
+
+
+def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """HF-convention rotary embedding (rotate-half). x: [B, T, Hx, hd]."""
+    half = x.shape[-1] // 2
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    return _rotate(x, positions, freqs)
+
+
+def _rope_kind(x: jax.Array, positions: jax.Array,
+               rope: Dict[str, Any]) -> jax.Array:
+    """The rope of a table's attention kind (:func:`rope_frequencies`)."""
+    freqs, scale = rope_frequencies(rope, x.shape[-1])
+    return _rotate(x, positions, freqs, scale)
 
 
 def _mm(x: jax.Array, w: Any) -> jax.Array:
@@ -331,6 +531,36 @@ class _LayerSlice:
         if isinstance(w, dict):
             return {k: v[li] for k, v in w.items()}
         return w[li]
+
+
+class _TableSlice:
+    """:class:`_LayerSlice` of a table's tree (``_init_table_params``):
+    layer ``li``'s row of whichever stack holds ``name`` for it."""
+
+    _FFN = {"dense": ("w_gate", "w_up", "w_down"),
+            "sparse": ("w_router", "shared_gate", "shared_up", "shared_down")
+            + EXPERT_LEAVES}
+
+    def __init__(self, stacked: Dict[str, Any], li: int, entry, kind):
+        self._stacked, self._li = stacked, li
+        self._entry, self._kind = entry, kind
+
+    def __getitem__(self, name: str) -> Any:
+        w = self._stacked[name]
+        if name in ("wq", "wo", "w_attn_gate"):
+            return w[self._kind.name][self._entry.attn_at]
+        if name in self._FFN[self._entry.ffn]:
+            return w[self._entry.ffn_at]
+        return w[self._li]
+
+
+def layer_params(cfg: ModelConfig, stacked: Dict[str, Any], li: int):
+    """(slice, table row, attention kind) of layer ``li``."""
+    entry = cfg.layer_table[li]
+    kind = cfg.attn_kinds[entry.attn]
+    if cfg.has_table:
+        return _TableSlice(stacked, li, entry, kind), entry, kind
+    return _LayerSlice(stacked, li), entry, kind
 
 
 def _qkv_proj(x: jax.Array, p: Any, H: int, KV: int, hd: int
@@ -380,12 +610,13 @@ def _attention(
     k_all: jax.Array,    # [B, S, KV, hd]  gathered sequence KV
     v_all: jax.Array,    # [B, S, KV, hd]
     positions: jax.Array,  # [B, T] absolute positions (-1 = pad)
+    window: int = 0,       # > 0: a query sees only the last ``window`` keys
 ) -> jax.Array:
     T = q.shape[1]
     if T > _Q_BLOCK:
         outs = [
             _attention(q[:, t0:t0 + _Q_BLOCK], k_all, v_all,
-                       positions[:, t0:t0 + _Q_BLOCK])
+                       positions[:, t0:t0 + _Q_BLOCK], window)
             for t0 in range(0, T, _Q_BLOCK)
         ]
         return jnp.concatenate(outs, axis=1)
@@ -401,6 +632,8 @@ def _attention(
     # causal paged mask: key slot s corresponds to absolute position s
     kpos = jnp.arange(S)[None, None, :]                  # [1, 1, S]
     valid = kpos <= positions[:, :, None]                # [B, T, S]
+    if window:
+        valid = valid & (kpos > positions[:, :, None] - window)
     scores = jnp.where(valid[:, :, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(
@@ -528,6 +761,7 @@ def _paged_decode_attention(
     seq_lens: jax.Array,      # [B] valid context incl. current token
     lks: Optional[jax.Array] = None,  # [NB, KV, bs] f32 scales (quant kv)
     lvs: Optional[jax.Array] = None,
+    window: int = 0,          # the layer's window: the walk's lower bound
 ) -> jax.Array:
     """Decode-path attention via the Pallas paged kernel ([B, 1, H, hd]).
 
@@ -546,6 +780,7 @@ def _paged_decode_attention(
         block_size=eng.block_size,
         kv_tile=kv_tile,
         interpret=interpret,
+        window=window,
     )
     q3 = q[:, 0]  # [B, H, hd]
     if mesh is not None and mesh.shape.get(AXIS_TP, 1) > 1:
@@ -591,6 +826,7 @@ def _paged_ragged_attention(
     ctx_len: jax.Array,       # [B] context incl. the row's own tokens
     lks: Optional[jax.Array] = None,  # [NB, KV, bs] f32 scales (quant kv)
     lvs: Optional[jax.Array] = None,
+    window: int = 0,
 ) -> jax.Array:
     """T>1 attention (spec windows, prefill chunks) via the ragged kernel.
 
@@ -613,6 +849,7 @@ def _paged_ragged_attention(
         max_q_len=T,
         kv_tile=kv_tile,
         interpret=interpret,
+        window=window,
     )
     q_flat = q.reshape(B * T, H, hd)
     q_start = jnp.arange(B + 1, dtype=jnp.int32) * T
@@ -699,11 +936,13 @@ def _kv_write(plane: jax.Array, blocks: jax.Array, offs: jax.Array,
 def _layer_attention(
     eng: EngineConfig, mesh, ring_mesh, ring_lay, use_pallas: bool,
     q, k, v, lk, lv, lks, lvs, positions, block_tables,
-    seq_lens, q_len, ctx_len,
+    seq_lens, q_len, ctx_len, window: int = 0,
 ) -> jax.Array:
     """One layer's attention over the just-updated paged cache: the ring
     (full fresh prompt, T-sharded), the Pallas decode / ragged kernel, or
-    the gathered-context einsum. Returns ``[B, T, H, hd]``."""
+    the gathered-context einsum. Returns ``[B, T, H, hd]``.  ``window`` is
+    the layer's (0 = none): a mask on the einsum path, a mask and the
+    walk's lower bound in the kernel; the ring has neither."""
     B, T = q.shape[:2]
     KV, bs, hd = lk.shape[1], lk.shape[2], lk.shape[3]
     W = block_tables.shape[1]
@@ -726,12 +965,12 @@ def _layer_attention(
     if use_pallas and T == 1:
         return _paged_decode_attention(
             eng, mesh, q, lk, lv, block_tables, seq_lens,
-            lks=lks, lvs=lvs,
+            lks=lks, lvs=lvs, window=window,
         )
     if use_pallas:
         return _paged_ragged_attention(
             eng, mesh, q, lk, lv, block_tables, q_len, ctx_len,
-            lks=lks, lvs=lvs,
+            lks=lks, lvs=lvs, window=window,
         )
     # gather the full context for attention: [B, W*bs, KV, hd] with
     # gathered position = w*bs + offset = absolute position
@@ -758,7 +997,104 @@ def _layer_attention(
         )
         k_all = quant.kv_dequantize(k_all, ks_all, q.dtype)
         v_all = quant.kv_dequantize(v_all, vs_all, q.dtype)
-    return _attention(q, k_all, v_all, positions)
+    return _attention(q, k_all, v_all, positions, window)
+
+
+# The layer body, in three parts around the attention itself (which differs
+# by caller: the paged cache here, the chunk's own K and V in
+# encode_forward, a stage's stacked cache in parallel/pp_serving.py).  Every
+# caller runs these, so a layer kind is written once.
+
+
+def attn_inputs(cfg: ModelConfig, kind, p: Any, h: jax.Array,
+                positions: jax.Array):
+    """Normed input ``x`` and the roped ``q [B, T, H, hd]``, ``k``, ``v
+    [B, T, KV, hd]`` of one layer, ``H`` and the rope its kind's."""
+    with jax.named_scope("qkv_proj"):
+        x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
+        q, k, v = _qkv_proj(x, p, kind.num_heads, cfg.num_kv_heads,
+                            cfg.head_dim_)
+    with jax.named_scope("rope"):
+        if cfg.has_table:
+            rope = cfg.rope_of(kind)
+            q = _rope_kind(q, positions, rope)
+            k = _rope_kind(k, positions, rope)
+        else:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+    return x, q, k, v
+
+
+def attn_output(cfg: ModelConfig, p: Any, h: jax.Array, x: jax.Array,
+                attn: jax.Array) -> jax.Array:
+    """``h`` plus the output projection of ``attn [B, T, H, hd]``, each
+    head first times its gate ``sigmoid(x Wg)`` where the model has one."""
+    B, T, H, hd = attn.shape
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            g = jax.nn.sigmoid(_mm(x, p["w_attn_gate"]).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * g[..., None]).astype(
+                attn.dtype)
+    with jax.named_scope("o_proj"):
+        return h + _mm(attn.reshape(B, T, H * hd), p["wo"])
+
+
+def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
+        live: Optional[jax.Array] = None, interpret: bool = False,
+        ff_pin=None, stats_out: Optional[list] = None,
+        choices_out: Optional[list] = None) -> jax.Array:
+    """``h`` plus the layer's FFN on its norm: the dense SwiGLU, the
+    capacity experts of a model without a table (``cfg.is_moe``), or a
+    table's sparse layer: the routed experts held here (``live [B, T]``
+    rows only; their counters appended to ``stats_out``, every token's
+    chosen experts ``[B, T, k]`` to ``choices_out``) plus the shared
+    expert.  ``ff_pin`` pins the dense intermediates (the ring path)."""
+    B, T, D = h.shape
+    if entry.ffn == "sparse":
+        from ..parallel.moe import routed_ffn
+
+        with jax.named_scope("moe_router"):
+            x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
+        routed, stats, chosen = routed_ffn(
+            x.reshape(B * T, D), p["w_router"], p["expert_gate"],
+            p["expert_up"], p["expert_down"],
+            top_k=cfg.num_experts_per_token,
+            held_start=cfg.experts_held[0],
+            renormalise=cfg.norm_topk_prob,
+            scale=cfg.moe_routed_scaling_factor,
+            live=None if live is None else live.reshape(B * T),
+            interpret=interpret,
+        )
+        if stats_out is not None:
+            stats_out.append(stats)
+        if choices_out is not None:
+            choices_out.append(chosen.reshape(B, T, -1))
+        with jax.named_scope("moe_shared"):
+            gate = jax.nn.silu(_mm(x, p["shared_gate"]).astype(jnp.float32))
+            up = _mm(x, p["shared_up"]).astype(jnp.float32)
+            shared = _mm((gate * up).astype(h.dtype), p["shared_down"])
+            return h + routed.reshape(B, T, D) + shared
+    with jax.named_scope("mlp"):
+        x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
+        if cfg.is_moe:
+            from ..parallel.moe import moe_ffn
+
+            out = moe_ffn(
+                x.reshape(B * T, D),
+                p["w_router"],
+                _dequant_leaf(p["w_gate"], x.dtype),
+                _dequant_leaf(p["w_up"], x.dtype),
+                _dequant_leaf(p["w_down"], x.dtype),
+                top_k=cfg.num_experts_per_token,
+                capacity_factor=cfg.moe_capacity_factor,
+            )
+            return h + out.reshape(B, T, D)
+        gate = jax.nn.silu(_mm(x, p["w_gate"]).astype(jnp.float32))
+        up = _mm(x, p["w_up"]).astype(jnp.float32)
+        if ff_pin is not None:
+            gate = jax.lax.with_sharding_constraint(gate, ff_pin)
+            up = jax.lax.with_sharding_constraint(up, ff_pin)
+        return h + _mm((gate * up).astype(h.dtype), p["w_down"])
 
 
 def forward(
@@ -773,8 +1109,15 @@ def forward(
     ring_mesh: Optional[Mesh] = None,
     mm_embeds: Optional[jax.Array] = None,  # [B, T, D] vision embeddings
     mm_mask: Optional[jax.Array] = None,    # [B, T] True = use mm_embeds
+    moe_stats: Optional[list] = None,
+    moe_choices: Optional[list] = None,
 ) -> Tuple[Cache, jax.Array]:
     """Run the transformer over a token chunk, updating the paged cache.
+
+    ``moe_stats``, where a list is given, gains one int32 ``[4]`` a sparse
+    layer of a table (``parallel.moe.MOE_STATS``), and ``moe_choices`` the
+    layer's chosen experts ``[B, T, k]``: traced values of the caller's own
+    trace.
 
     With ``ring_mesh`` set (an "sp" mesh over the same devices), the chunk
     MUST be a full fresh prompt (start position 0): its T axis is sharded
@@ -790,9 +1133,11 @@ def forward(
     W = block_tables.shape[1]
     bs = eng.block_size
     hd = cfg.head_dim_
-    H, KV = cfg.num_heads, cfg.num_kv_heads
+    KV = cfg.num_kv_heads
 
     use_ring = ring_mesh is not None and T > 1
+    if use_ring:
+        refuse_table(cfg, what="the sequence-parallel ring prefill")
     ring_lay = SpecLayout.for_mesh(ring_mesh) if use_ring else None
     if use_ring and ring_lay.seq_axes() is None:
         use_ring = ring_lay = None  # single-device "ring" is dense attention
@@ -860,18 +1205,18 @@ def forward(
     new_ks: list = []
     new_vs: list = []
     stacked = params["layers"]
+    interpret = pallas_interpret(mesh) if cfg.has_routed_experts else False
+    live = positions >= 0 if cfg.has_routed_experts else None
+    ff_pin = (NamedSharding(ring_mesh,
+                            layout.spec(None, ring_lay.seq_axes(), None))
+              if use_ring else None)
     for li in range(cfg.num_layers):
-        p = _LayerSlice(stacked, li)
+        p, entry, kind = layer_params(cfg, stacked, li)
         lk, lv = cache["k"][li], cache["v"][li]   # [NB, KV, bs, hd]
         lks = cache["ks"][li] if kv_quant else None  # [NB, KV, bs] f32
         lvs = cache["vs"][li] if kv_quant else None
 
-        with jax.named_scope("qkv_proj"):
-            x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _qkv_proj(x, p, H, KV, hd)
-        with jax.named_scope("rope"):
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+        x, q, k, v = attn_inputs(cfg, kind, p, h, positions)
         if use_ring:
             # projections of the T-sharded chunk stay T-sharded — without
             # the pin the column-sharded wq/wk/wv propagate a head
@@ -913,47 +1258,23 @@ def forward(
             lk = _kv_write(lk, scatter_block, scatter_off, k_upd, mesh)
             lv = _kv_write(lv, scatter_block, scatter_off, v_upd, mesh)
 
-        with jax.named_scope("attention"):
+        # the trace tells a window layer's walk from a full one's
+        with jax.named_scope(
+                "attention_window" if kind.window else "attention"):
             attn = _layer_attention(
                 eng, mesh, ring_mesh, ring_lay, use_pallas, q, k, v,
                 lk, lv, lks, lvs, positions, block_tables,
-                seq_lens, q_len, ctx_len,
+                seq_lens, q_len, ctx_len, kind.window,
             )
-        with jax.named_scope("o_proj"):
-            h = h + _mm(attn.reshape(B, T, H * hd), p["wo"])
-
-        with jax.named_scope("mlp"):
-            x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-            if cfg.is_moe:
-                from ..parallel.moe import moe_ffn
-
-                D = x.shape[-1]
-                out = moe_ffn(
-                    x.reshape(B * T, D),
-                    p["w_router"],
-                    _dequant_leaf(p["w_gate"], x.dtype),
-                    _dequant_leaf(p["w_up"], x.dtype),
-                    _dequant_leaf(p["w_down"], x.dtype),
-                    top_k=cfg.num_experts_per_token,
-                    capacity_factor=cfg.moe_capacity_factor,
-                )
-                h = h + out.reshape(B, T, D)
-            else:
-                gate = jax.nn.silu(_mm(x, p["w_gate"]).astype(jnp.float32))
-                up = _mm(x, p["w_up"]).astype(jnp.float32)
-                if use_ring:
-                    # ring chunks run the MLP sequence-parallel:
-                    # activations stay T-sharded, the (small) weights
-                    # all-gather — pin the intermediates so w_down's row
-                    # sharding can't pull a head-style spec onto them
-                    ff_pin = NamedSharding(
-                        ring_mesh,
-                        layout.spec(None, ring_lay.seq_axes(), None),
-                    )
-                    gate = jax.lax.with_sharding_constraint(gate, ff_pin)
-                    up = jax.lax.with_sharding_constraint(up, ff_pin)
-                h = h + _mm((gate * up).astype(h.dtype), p["w_down"])
-            if h_pin is not None:
+        h = attn_output(cfg, p, h, x, attn)
+        # ring chunks run the MLP sequence-parallel: activations stay
+        # T-sharded, the (small) weights all-gather — ff_pin pins the
+        # intermediates so w_down's row sharding can't pull a head-style
+        # spec onto them
+        h = ffn(cfg, entry, p, h, live=live, interpret=interpret,
+                ff_pin=ff_pin, stats_out=moe_stats, choices_out=moe_choices)
+        if h_pin is not None:
+            with jax.named_scope("mlp"):
                 h = jax.lax.with_sharding_constraint(h, h_pin)
         new_k.append(lk)
         new_v.append(lv)
@@ -1006,39 +1327,16 @@ def encode_forward(
     Returns L2-normalised mean-pooled final hidden states ``[B, D]`` (mean
     over non-pad positions — the standard decoder-as-encoder pooling).
     """
-    B, T = tokens.shape
-    hd = cfg.head_dim_
-    H, KV = cfg.num_heads, cfg.num_kv_heads
-
     h = jnp.take(params["embed"], tokens, axis=0)  # [B, T, D]
     stacked = params["layers"]
+    interpret = pallas_interpret(None) if cfg.has_routed_experts else False
+    live = positions >= 0 if cfg.has_routed_experts else None
     for li in range(cfg.num_layers):
-        p = _LayerSlice(stacked, li)
-        x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv_proj(x, p, H, KV, hd)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        attn = _attention(q, k, v, positions)
-        h = h + _mm(attn.reshape(B, T, H * hd), p["wo"])
-        x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        if cfg.is_moe:
-            from ..parallel.moe import moe_ffn
-
-            D = x.shape[-1]
-            out = moe_ffn(
-                x.reshape(B * T, D),
-                p["w_router"],
-                _dequant_leaf(p["w_gate"], x.dtype),
-                _dequant_leaf(p["w_up"], x.dtype),
-                _dequant_leaf(p["w_down"], x.dtype),
-                top_k=cfg.num_experts_per_token,
-                capacity_factor=cfg.moe_capacity_factor,
-            )
-            h = h + out.reshape(B, T, D)
-        else:
-            gate = jax.nn.silu(_mm(x, p["w_gate"]).astype(jnp.float32))
-            up = _mm(x, p["w_up"]).astype(jnp.float32)
-            h = h + _mm((gate * up).astype(h.dtype), p["w_down"])
+        p, entry, kind = layer_params(cfg, stacked, li)
+        x, q, k, v = attn_inputs(cfg, kind, p, h, positions)
+        attn = _attention(q, k, v, positions, kind.window)
+        h = attn_output(cfg, p, h, x, attn)
+        h = ffn(cfg, entry, p, h, live=live, interpret=interpret)
     h = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
 
     valid = (positions >= 0).astype(jnp.float32)[:, :, None]  # [B, T, 1]
@@ -1417,9 +1715,14 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
     and advance nothing; their sample columns are discarded by the host.
     Step rngs derive from the carried key + counter, so a window dispatch
     carries zero fresh host arrays.
+
+    A table with routed experts adds one row to ``samples`` (``[K + 1,
+    B]``, :func:`moe_stats_row`): the window's routing counters ride the
+    one fetch the sampled tokens already make.
     """
 
     def window(params, cache, ctl, slot_rows):
+        moe_stats = [] if cfg.has_routed_experts else None
         rows = slot_rows
         with jax.named_scope("ctl"):
             tok = ctl["last_tok"][rows][:, None]
@@ -1438,6 +1741,7 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
                 pos_eff = jnp.where(pos < vu[:, None], pos, -1)
             cache, h = forward(
                 cfg, eng, params, cache, tok, pos_eff, tables, mesh=mesh,
+                moe_stats=moe_stats,
             )
             with jax.named_scope("lm_head"):
                 h_last = h[:, 0]
@@ -1459,9 +1763,28 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
             # duplicate trash rows accumulate zero (acc there is 0)
             ctl["pos"] = ctl["pos"].at[rows].add(acc)
             ctl["ctr"] = ctl["ctr"] + 1
+            if moe_stats:
+                samples = jnp.concatenate(
+                    [samples, moe_stats_row(moe_stats, samples.shape[1])])
         return cache, ctl, samples
 
     return window
+
+
+def moe_stats_row(stats: list, width: int) -> jax.Array:
+    """The routing counters of a window's sparse layers and steps (each
+    int32 ``[4]``, ``parallel.moe.MOE_STATS``) as one ``[1, width]`` int32
+    row: sums of pairs, pairs held and experts touched, the largest load;
+    zeros behind them."""
+    from ..parallel.moe import MOE_STATS
+
+    if width < len(MOE_STATS):
+        raise ValueError(f"a decode bucket of {width} rows cannot carry "
+                         f"the {len(MOE_STATS)} routing counters")
+    per = jnp.stack(stats)                                 # [n, 4]
+    row = jnp.concatenate([jnp.sum(per[:, :3], axis=0),
+                           jnp.max(per[:, 3:], axis=0)])
+    return jnp.pad(row, (0, width - row.shape[0]))[None, :]
 
 
 def make_autopilot_fns(cfg: ModelConfig, eng: EngineConfig, K: int,

@@ -78,6 +78,8 @@ def peak_flops(device_kind: str, platform: str,
 def param_count(cfg) -> int:
     """Exact parameter count of ``engine.model.init_params`` for a
     ModelConfig (checked against the real tree in test_observability)."""
+    if cfg.has_table:
+        return _table_param_count(cfg)
     hd = cfg.head_dim_
     D, H, KV, F, L, V = (
         cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
@@ -95,10 +97,49 @@ def param_count(cfg) -> int:
     return total
 
 
+def _table_param_count(cfg, active: bool = False) -> int:
+    """``param_count`` of a table of layer kinds (``ModelConfig.
+    layer_types``), layer by layer: the attention of the layer's kind (its
+    own query heads, the per-head gate), then the dense SwiGLU or the
+    router over every routed expert, the routed experts held here and the
+    shared expert.  ``active``: what multiplies one token instead — of the
+    routed experts the ``num_experts_per_token`` a token chooses, times the
+    share of the routed experts that is held (the rest of its choices are
+    computed on the shards that hold them), and the head but not the
+    embedding's gather."""
+    hd, D, KV, V = (cfg.head_dim_, cfg.hidden_size, cfg.num_kv_heads,
+                    cfg.vocab_size)
+    kinds = cfg.attn_kinds
+    total = D                                            # final_norm
+    if not active:
+        total += V * D                                   # embed
+    if active or not cfg.tie_word_embeddings:
+        total += D * V                                   # head
+    for entry in cfg.layer_table:
+        H = kinds[entry.attn].num_heads
+        total += 2 * D                                   # the two norms
+        total += 2 * D * H * hd + 2 * D * KV * hd        # wq, wo, wk, wv
+        if cfg.attn_gate:
+            total += D * H
+        if entry.ffn == "dense":
+            total += 3 * D * cfg.intermediate_size
+            continue
+        experts = cfg.num_experts
+        if active:
+            experts = (cfg.num_experts_per_token * cfg.num_experts
+                       / cfg.num_routed_experts)
+        total += D * cfg.num_routed_experts              # router
+        total += int(experts * 3 * D * cfg.moe_intermediate_size)
+        total += 3 * D * cfg.shared_expert_intermediate_size
+    return total
+
+
 def active_param_count(cfg) -> int:
     """Parameters doing matmul work per token: the full count minus the
     embedding table (gather, not matmul), with MoE expert weights scaled
     to the ``num_experts_per_token`` actually routed."""
+    if cfg.has_table:
+        return _table_param_count(cfg, active=True)
     D, F, L, V = (cfg.hidden_size, cfg.intermediate_size,
                   cfg.num_layers, cfg.vocab_size)
     active = param_count(cfg) - V * D
@@ -124,8 +165,12 @@ class FlopsModel:
         self.n_active_params = active_param_count(model_cfg)
         self.matmul_per_token = 2.0 * self.n_active_params
         # QK^T + PV: 2 matmuls of (num_heads*head_dim x context) per token
-        self.attn_coef = (4.0 * model_cfg.num_layers * model_cfg.num_heads
-                          * model_cfg.head_dim_)
+        # (a table: each layer its kind's heads; a window's layers are
+        # counted at the whole context, which is all a record carries for a
+        # prefill: an upper bound there)
+        self.attn_coef = 4.0 * model_cfg.head_dim_ * sum(
+            model_cfg.attn_kinds[e.attn].num_heads
+            for e in model_cfg.layer_table)
 
     def step_flops(self, tokens: float, context_sum: float) -> float:
         return self.matmul_per_token * tokens + self.attn_coef * context_sum

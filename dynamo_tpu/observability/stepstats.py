@@ -44,15 +44,20 @@ DECODE = "decode"
 SPEC_VERIFY = "spec_verify"
 
 
-def kv_blocks_walked(contexts, *, kv_tile: int, block_size: int) -> int:
+def kv_blocks_walked(contexts, *, kv_tile: int, block_size: int,
+                     window: int = 0) -> int:
     """KV pages one decode launch of the paged-attention kernel visits in a
     layer, from host integers (``ops.paged_attention``: a row of context
     ``c`` walks ``cdiv(c, kv_tile)`` tiles and fetches every page of each;
     ``c == 0`` is a dead row and walks nothing).  A ``kv_tile`` below
     ``block_size`` fetches a slice of one page per tile and counts it as
-    that page again.  The table's width does not appear."""
+    that page again.  The table's width does not appear.  In a layer with a
+    ``window`` the walk begins at the tile of key ``max(0, c - window)``."""
     pages = max(1, kv_tile // block_size)
-    return sum(-(-int(c) // kv_tile) * pages for c in contexts)
+    return sum(
+        (-(-int(c) // kv_tile)
+         - (max(0, int(c) - window) // kv_tile if window else 0)) * pages
+        for c in contexts)
 
 
 @dataclass
@@ -75,6 +80,20 @@ class StepRecord:
     # gathers every column of the table: rows x width). Times block_size
     # over context_sum = how far the walk is from the tokens attended.
     kv_blocks_walked: int = 0
+    # the same two for ONE layer with a window (a table's sliding kind; 0
+    # where the model has none): pages walked from the window's tile on,
+    # and min(context, window) summed over the same rows
+    kv_blocks_walked_window: int = 0
+    context_sum_window: int = 0
+    # decode records of a table with routed experts, summed over the
+    # window's steps and sparse layers, read from the window's own fetch
+    # (model.moe_stats_row): (token, expert) pairs of live rows, those of
+    # them whose expert is held here, held experts with at least one token,
+    # and the largest token count on one held expert in a layer
+    moe_pairs: int = 0
+    moe_pairs_held: int = 0
+    moe_experts_touched: int = 0
+    moe_load_max: int = 0
     spec_drafted: int = 0
     spec_accepted: int = 0
     # host seconds (``time.monotonic()`` differences, no device access).

@@ -127,6 +127,7 @@ def _ragged_kernel(
     q_tile: int,
     scale: float,
     quantized: bool = False,
+    window: int = 0,
 ):
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
@@ -147,7 +148,10 @@ def _ragged_kernel(
     piece = min(kv_tile, bs)
 
     def walk(r_, t_):
-        """(tiles this grid step walks, its q tile, q_len, ctx_len)."""
+        """(tiles this grid step walks, the first of them, its q tile,
+        q_len, ctx_len).  The walk is tiles ``[lo, lo + n)``: ``lo`` is 0
+        without a window, else the tile of the lowest key the tile's first
+        query may see."""
         q_len = q_len_ref[r_]
         ctx_len = ctx_len_ref[r_]
         alloc, t_eff = _row_tile(t_, q_start_ref, r_, q_tile)
@@ -157,7 +161,11 @@ def _ragged_kernel(
         last_q = jnp.minimum((t_eff + 1) * q_tile, q_len) - 1
         frontier = ctx_len - q_len + last_q + 1
         n = jnp.where(live, (frontier + kv_tile - 1) // kv_tile, 0)
-        return jnp.maximum(n, 0), alloc, t_eff, q_len, ctx_len
+        if not window:
+            return jnp.maximum(n, 0), 0, alloc, t_eff, q_len, ctx_len
+        first_key = ctx_len - q_len + t_eff * q_tile - (window - 1)
+        lo = jnp.where(live, jnp.maximum(first_key, 0) // kv_tile, 0)
+        return jnp.maximum(n - lo, 0), lo, alloc, t_eff, q_len, ctx_len
 
     def fetch(r_, i, slot, *, wait=False):
         """Start (or wait for) the DMAs of tile ``i`` of row ``r_`` into
@@ -190,7 +198,8 @@ def _ragged_kernel(
 
         jax.lax.fori_loop(0, pieces, piece_copies, 0)
 
-    n, alloc, t_eff, q_len, ctx_len = walk(r, t)
+    n, lo, alloc, t_eff, q_len, ctx_len = walk(r, t)
+    end = lo + n if window else n    # one past the walk's last tile
     first = (r == 0) & (t == 0)
     # the slot this step's first tile is (being) copied into
     slot0 = jnp.where(first, 0, slot_ref[0])
@@ -200,29 +209,31 @@ def _ragged_kernel(
     t_next = jnp.where(last_t, 0, t + 1)
     has_next = r_next < num_r
     r_next = jnp.minimum(r_next, num_r - 1)
-    n_next = jnp.where(has_next, walk(r_next, t_next)[0], 0)
+    n_next, lo_next = walk(r_next, t_next)[:2]
+    n_next = jnp.where(has_next, n_next, 0)
 
     @pl.when(first & (n > 0))
     def _start_own():
         # only the launch's first step fetches its own first tile; every
         # later step finds it started by the step before
-        fetch(r, 0, slot0)
+        fetch(r, lo, slot0)
 
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def tile(i, carry):
-        slot = (slot0 + i) % 2
+        # ``i`` is the tile's place in the context: tiles lo .. lo + n - 1
+        slot = (slot0 + i - lo) % 2 if window else (slot0 + i) % 2
 
         # what follows this tile: the row's next one, or — from a step's
         # last iteration — the first tile of the next grid step
-        more = i + 1 < n
+        more = i + 1 < end
 
         @pl.when(more | (n_next > 0))
         def _start_following():
-            fetch(jnp.where(more, r, r_next), jnp.where(more, i + 1, 0),
-                  1 - slot)
+            fetch(jnp.where(more, r, r_next),
+                  jnp.where(more, i + 1, lo_next), 1 - slot)
 
         fetch(r, i, slot, wait=True)
 
@@ -282,6 +293,8 @@ def _ragged_kernel(
             jnp.int32, s.shape, dimension=2
         )
         valid = (qi < q_len) & (spos <= ctx_len - q_len + qi)
+        if window:
+            valid = valid & (spos > ctx_len - q_len + qi - window)
         s = jnp.where(valid, s, -jnp.inf)
 
         m_prev = m_ref[...]                              # [KV, TQ*G, 1]
@@ -301,12 +314,12 @@ def _ragged_kernel(
         )                                                # [KV, TQ*G, hd]
         return carry
 
-    jax.lax.fori_loop(0, n, tile, 0)
+    jax.lax.fori_loop(lo, end, tile, 0)
 
     @pl.when((n == 0) & (n_next > 0))
     def _hand_on():
         # a step that walks nothing still starts its successor's first tile
-        fetch(r_next, 0, slot0)
+        fetch(r_next, lo_next, slot0)
 
     slot_ref[0] = (slot0 + n) % 2
 
@@ -323,7 +336,7 @@ def _ragged_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("block_size", "q_tile", "kv_tile", "max_q_len",
-                     "interpret"),
+                     "interpret", "window"),
 )
 def paged_attention_ragged(
     q: jax.Array,             # [Tq, H, hd] flat packed queries
@@ -341,6 +354,7 @@ def paged_attention_ragged(
     interpret: bool = False,
     k_scale: jax.Array | None = None,  # [num_blocks, KV, bs] f32
     v_scale: jax.Array | None = None,  # [num_blocks, KV, bs] f32
+    window: int = 0,
 ) -> jax.Array:
     """Ragged paged attention over heterogeneous-length query rows.
 
@@ -375,6 +389,12 @@ def paged_attention_ragged(
     into slots of their own (pages past a context bring arbitrary scales;
     the in-kernel zeroing wipes them along with the payload).  ``None``
     (the default) traces the exact unquantized kernel.
+
+    ``window > 0`` (a sliding-window layer): query ``i`` sees keys ``j``
+    with ``0 <= i - j < window`` only, and a q tile's walk begins at the
+    KV tile that holds the lowest key its first query sees instead of at
+    tile 0: pages wholly behind the window are never fetched.  ``0`` traces
+    the kernel without any of it.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
@@ -453,7 +473,7 @@ def paged_attention_ragged(
 
     kernel = functools.partial(
         _ragged_kernel, block_size=bs, kv_tile=kv_tile, q_tile=q_tile,
-        scale=1.0 / (hd_model ** 0.5), quantized=quantized,
+        scale=1.0 / (hd_model ** 0.5), quantized=quantized, window=window,
     )
     out = pl.pallas_call(
         kernel,
@@ -468,7 +488,7 @@ def paged_attention_ragged(
     )(q_start, q_len, ctx_len, block_tables, *operands)
     return out.transpose(1, 0, 2, 3).reshape(Tq, H, hd)[..., :hd_model]
 @functools.partial(
-    jax.jit, static_argnames=("block_size", "kv_tile", "interpret")
+    jax.jit, static_argnames=("block_size", "kv_tile", "interpret", "window")
 )
 def paged_attention_decode(
     q: jax.Array,          # [B, H, hd]
@@ -482,6 +502,7 @@ def paged_attention_decode(
     interpret: bool = False,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    window: int = 0,
 ) -> jax.Array:
     """Single-token-per-sequence paged attention.  Returns ``[B, H, hd]``.
 
@@ -489,7 +510,9 @@ def paged_attention_decode(
     (``q_tile == 1``).  ``seq_lens[b]`` counts the valid context slots for
     row ``b`` *including* the token being decoded; ``seq_lens[b] == 0``
     rows emit exact zeros.  ``k_scale``/``v_scale`` carry quantized-KV
-    dequant scales exactly as in :func:`paged_attention_ragged`.
+    dequant scales exactly as in :func:`paged_attention_ragged`, and
+    ``window`` its lower bound: a row of context ``c`` walks from the tile
+    of key ``max(0, c - window)``.
     """
     B = q.shape[0]
     q_start = jnp.arange(B + 1, dtype=jnp.int32)
@@ -498,4 +521,5 @@ def paged_attention_decode(
         q, k_cache, v_cache, block_tables, q_start, q_len, seq_lens,
         block_size=block_size, max_q_len=1, q_tile=1, kv_tile=kv_tile,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,
+        window=window,
     )

@@ -15,6 +15,7 @@ E — each device computes only its experts.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict
 
@@ -85,3 +86,115 @@ def moe_ffn(
     return jnp.einsum(
         "nec,ecd->nd", combine, out.astype(jnp.float32)
     ).astype(dt)
+
+
+# ---------------------------------------------------------------------------
+# Routing without drops over the experts this shard holds (a table's sparse
+# layers).  moe_ffn above stays for a model without a table; that there are
+# two is debt (ROADMAP.md, Queue 3).
+
+# the grouped matmul's (m, k, n) tile for x @ [D, F] and h @ [F, D]; the
+# sorted pairs are padded to a multiple of m.  Read on the chip at D 3072,
+# F 1024, 8 rows, 38 experts touched (PERF.md, PR 32): 1.01 ms = 710 GB/s of
+# expert weights, against 1.02-1.10 for ten other tilings with k, n >= 512
+# and 1.18 for three ``jax.lax.ragged_dot``
+GMM_TILE = (128, 1024, 1024)
+
+MOE_STATS = ("moe_pairs", "moe_pairs_held", "moe_experts_touched",
+             "moe_load_max")
+
+
+def _tile(k: int, n: int):
+    """``GMM_TILE`` with its k and n tiles no larger than the matrix."""
+    return (GMM_TILE[0], min(GMM_TILE[1], k), min(GMM_TILE[2], n))
+
+
+def route(x: jax.Array, w_router: jax.Array, *, top_k: int,
+          renormalise: bool, scale: float):
+    """Softmax router in float32 over ALL routed experts: the ``top_k``
+    experts of each token ``[N, k]`` and their weights on the experts'
+    outputs, ``scale * s_e / sum_top s`` (the sum over the chosen of all
+    routed experts, never over the ones held)."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_vals, top_idx = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    return top_idx, top_vals * scale
+
+
+def routed_ffn(
+    x: jax.Array,          # [N, D] tokens (flattened batch)
+    w_router: jax.Array,   # [D, E] over every routed expert
+    w_gate: jax.Array,     # [Eh, D, F] the experts held here
+    w_up: jax.Array,       # [Eh, D, F]
+    w_down: jax.Array,     # [Eh, F, D]
+    *,
+    top_k: int,
+    held_start: int,       # w_gate[0] is routed expert ``held_start``
+    renormalise: bool = True,
+    scale: float = 1.0,
+    live: jax.Array | None = None,   # [N] bool; dead rows route nowhere
+    interpret: bool = False,
+):
+    """What the experts held here add for the tokens routed to them:
+    ``sum_{e in top_k(x) and held} w_e * swiglu_e(x)``, ``[N, D]``; the
+    four ``MOE_STATS`` as int32; and every token's ``top_k`` choices among
+    all routed experts, ``[N, top_k]`` int32 (what a reference check reads:
+    a top-k is a discrete choice).
+
+    Every token keeps all its experts: there is no capacity.  The
+    ``N * top_k`` (token, expert) pairs are sorted by expert; pairs of
+    experts not held, and of dead rows, sort behind every held group into
+    rows no group owns, which the grouped matmuls neither read weights for
+    nor compute.  The grouped matmul (megablox ``gmm``) visits only the
+    (group, row tile) pairs that hold a row, so an expert no token chose is
+    not read.  Shapes are static at the worst case of ``N * top_k`` rows.
+    One device: an ``ep`` mesh would exchange tokens before and after, and
+    this layer has no such exchange (PERF.md, Open questions)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    N, D = x.shape
+    E = w_router.shape[1]
+    Eh, _, F = w_gate.shape
+    dt = x.dtype
+    pairs = N * top_k
+    rows = -(-pairs // GMM_TILE[0]) * GMM_TILE[0]
+
+    with jax.named_scope("moe_router"):
+        top_idx, top_w = route(x, w_router, top_k=top_k,
+                               renormalise=renormalise, scale=scale)
+        expert = top_idx.reshape(pairs)
+        alive = (jnp.ones((pairs,), bool) if live is None
+                 else jnp.repeat(live, top_k))
+        held = alive & (expert >= held_start) & (expert < held_start + Eh)
+        # held pairs by expert, the rest behind them under the key E
+        key = jnp.where(held, expert, E)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((E,), jnp.int32).at[key].add(1, mode="drop")
+        token = order // top_k
+        held_sorted = jnp.take(held, order)
+        here = sizes[held_start:held_start + Eh]
+        stats = jnp.stack([
+            jnp.sum(alive), jnp.sum(held), jnp.sum(here > 0), jnp.max(here),
+        ]).astype(jnp.int32)
+
+    with jax.named_scope("moe_experts"):
+        xs = jnp.take(x, token, axis=0)
+        xs = jnp.pad(xs, ((0, rows - pairs), (0, 0)))
+        start = jnp.asarray(held_start, jnp.int32)
+        mm = functools.partial(gmm, group_sizes=sizes,
+                               preferred_element_type=jnp.float32,
+                               group_offset=start, interpret=interpret)
+        gate = jax.nn.silu(mm(xs, w_gate, tiling=_tile(D, F)))
+        up = mm(xs, w_up, tiling=_tile(D, F))
+        y = mm((gate * up).astype(dt), w_down,
+               tiling=_tile(F, D))[:pairs]                   # [pairs, D] f32
+        # rows of no group come back as they were left: select, not multiply
+        w_sorted = jnp.take(top_w.reshape(pairs), order)
+        y = jnp.where(held_sorted[:, None], y * w_sorted[:, None], 0.0)
+        # back to (token, slot) order, then the sum over a token's slots
+        y = jnp.take(y, jnp.argsort(order), axis=0)
+        out = jnp.sum(y.reshape(N, top_k, D), axis=1).astype(dt)
+    return out, stats, top_idx

@@ -94,15 +94,15 @@ def _stage_layers(cfg: ModelConfig, eng: EngineConfig, Lp: int,
     B, T = h.shape[0], h.shape[1]
     bs = eng.block_size
     hd = cfg.head_dim_
-    H, KV = cfg.num_heads, cfg.num_kv_heads
+    KV = cfg.num_kv_heads
     W = block_tables.shape[1]
+    # one kind of layer (raw_pp_step_fn refuses a table): every stage row
+    # is the model's one attention kind and its one FFN
+    entry, kind = cfg.layer_table[0], cfg.attn_kinds[0]
 
     for li in range(Lp):
         p = {name: w[li] for name, w in stage_params.items()}
-        x = model_lib._rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = model_lib._qkv_proj(x, p, H, KV, hd)
-        q = model_lib._rope(q, positions, cfg.rope_theta)
-        k = model_lib._rope(k, positions, cfg.rope_theta)
+        x, q, k, v = model_lib.attn_inputs(cfg, kind, p, h, positions)
         layer_k = model_lib._kv_write(
             lk[li], scatter_block, scatter_off, k.reshape(B * T, KV, hd))
         layer_v = model_lib._kv_write(
@@ -116,23 +116,8 @@ def _stage_layers(cfg: ModelConfig, eng: EngineConfig, Lp: int,
         ).reshape(B, W, KV, bs, hd).transpose(0, 1, 3, 2, 4).reshape(
             B, W * bs, KV, hd)
         attn = model_lib._attention(q, k_all, v_all, positions)
-        h = h + attn.reshape(B, T, H * hd) @ p["wo"]
-        x = model_lib._rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
-        if cfg.is_moe:
-            from .moe import moe_ffn
-
-            D = x.shape[-1]
-            out = moe_ffn(
-                x.reshape(B * T, D),
-                p["w_router"], p["w_gate"], p["w_up"], p["w_down"],
-                top_k=cfg.num_experts_per_token,
-                capacity_factor=cfg.moe_capacity_factor,
-            )
-            h = h + out.reshape(B, T, D)
-        else:
-            gate = jax.nn.silu((x @ p["w_gate"]).astype(jnp.float32))
-            up = (x @ p["w_up"]).astype(jnp.float32)
-            h = h + ((gate * up).astype(h.dtype) @ p["w_down"])
+        h = model_lib.attn_output(cfg, p, h, x, attn)
+        h = model_lib.ffn(cfg, entry, p, h)
         lk = lk.at[li].set(layer_k)
         lv = lv.at[li].set(layer_v)
     return h, lk, lv
@@ -141,6 +126,7 @@ def _stage_layers(cfg: ModelConfig, eng: EngineConfig, Lp: int,
 def raw_pp_step_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Mesh,
                    num_microbatches: int = 4):
     """The pipelined unified step (same signature as raw_step_fn)."""
+    model_lib.refuse_table(cfg, what="the pipeline-parallel step (pp_stages)")
     S = mesh.shape[AXIS_PP]
     if cfg.num_layers % S != 0:
         raise ValueError(

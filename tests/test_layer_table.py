@@ -1,0 +1,567 @@
+"""A table of layer kinds (``ModelConfig.layer_types``): two attention kinds
+with their own heads, window, rope and gate, a dense layer 0 and routed
+experts without drops on the share of them held here — the tiny
+configuration of ``benchmarks/chip/configs/laguna-s-2.1-ep2.json``
+(``rehearse.model``: window 16, 4 / 6 query heads over 2 KV heads, 16 routed
+experts of which 8 held, 4 a token) against ``references/laguna.py`` and
+against plain numpy.  CPU, float32; Pallas kernels interpreted."""
+
+import dataclasses
+import hashlib
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine import model as M
+from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.engine import InferenceEngine, Request
+from dynamo_tpu.observability import flops as F
+from dynamo_tpu.observability.stepstats import DECODE, kv_blocks_walked
+from dynamo_tpu.ops.paged_attention import (
+    paged_attention_decode, paged_attention_ragged,
+)
+from dynamo_tpu.parallel import moe
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = os.path.join(ROOT, "benchmarks", "chip", "configs",
+                      "laguna-s-2.1-ep2.json")
+SEED = 2500000417
+
+
+def _file() -> dict:
+    with open(CONFIG) as f:
+        return json.load(f)
+
+
+def _model(rehearse: bool = True, **replace) -> ModelConfig:
+    from benchmarks.chip import worker_launch as WL
+
+    cfg = WL.model_config_from(_file(), rehearse)
+    return dataclasses.replace(cfg, **replace) if replace else cfg
+
+
+def _reference():
+    path = os.path.join(ROOT, "benchmarks", "chip", "references", "laguna.py")
+    spec = importlib.util.spec_from_file_location("laguna_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _engine_config(**kw) -> EngineConfig:
+    base = dict(num_blocks=96, max_model_len=256, max_num_batched_tokens=64,
+                prefill_buckets=(16, 32, 64), decode_buckets=(8,),
+                max_num_seqs=8, decode_steps=1, pipeline_depth=1,
+                attention_impl="pallas")
+    base.update(kw)
+    return EngineConfig(**base)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return InferenceEngine(_model(), _engine_config(), seed=SEED)
+
+
+# ------------------------- the table itself ---------------------------------
+
+
+def test_table_reads_the_published_keys():
+    cfg = _model(rehearse=False)
+    assert [(k.name, k.num_heads, k.window, k.layers)
+            for k in cfg.attn_kinds] == [
+        ("full_attention", 48, 0, (0, 4)),
+        ("sliding_attention", 72, 512, (1, 2, 3))]
+    assert [(e.attn, e.attn_at, e.ffn, e.ffn_at) for e in cfg.layer_table] \
+        == [(0, 0, "dense", 0), (1, 0, "sparse", 0), (1, 1, "sparse", 1),
+            (1, 2, "sparse", 2), (0, 1, "sparse", 3)]
+    assert cfg.experts_held == (0, 128) and cfg.num_routed_experts == 256
+    assert cfg.has_routed_experts and not cfg.is_moe
+    assert hash(cfg) == hash(_model(rehearse=False))   # a jit static argument
+    second = dataclasses.replace(cfg, expert_shard={"index": 1, "of": 2})
+    assert second.experts_held == (128, 128)
+
+
+@pytest.mark.parametrize("change,names", [
+    ({"layer_types": ("full_attention",) * 4}, ["layer_types", "4", "5"]),
+    ({"num_heads_per_layer": (4, 6, 6, 5, 4)}, ["layer 3", "5", "6"]),
+    ({"num_heads_per_layer": (4, 5, 5, 5, 4)}, ["5 query heads", "2 KV"]),
+    ({"sliding_window": 0}, ["sliding_window"]),
+    ({"attn_gate": "per-channel"}, ["per-channel"]),
+    ({"num_experts": 16}, ["num_experts 16", "16 routed"]),
+    ({"num_experts_per_token": 17}, ["num_experts_per_token"]),
+    ({"moe_intermediate_size": 0}, ["expert widths"]),
+    ({"mlp_layer_types": ("dense", "sparse", "sparse", "sparse", "mixed")},
+     ["mixed"]),
+    ({"rope_parameters": {"full_attention": {"rope_theta": 1e4}}},
+     ["rope_parameters", "sliding_attention"]),
+])
+def test_a_table_that_contradicts_itself_is_refused_when_built(change, names):
+    with pytest.raises(ValueError) as e:
+        _model(**change)
+    for n in names:
+        assert n in str(e.value), (n, str(e.value))
+
+
+def test_parameters_are_one_stack_a_kind():
+    cfg = _model()
+    params = M.init_params(jax.random.PRNGKey(1), cfg)
+    lay = params["layers"]
+    D, hd = cfg.hidden_size, cfg.head_dim_
+    assert lay["wq"]["full_attention"].shape == (2, D, 4 * hd)
+    assert lay["wq"]["sliding_attention"].shape == (3, D, 6 * hd)
+    assert lay["wo"]["sliding_attention"].shape == (3, 6 * hd, D)
+    assert lay["w_attn_gate"]["full_attention"].shape == (2, D, 4)
+    assert lay["wk"].shape == (5, D, 2 * hd)
+    assert lay["w_gate"].shape == (1, D, cfg.intermediate_size)
+    assert lay["w_router"].shape == (4, D, 16)
+    assert lay["shared_down"].shape == (4, 32, D)
+    assert [a.shape for a in lay["expert_gate"]] == [(8, D, 32)] * 4
+    assert [a.shape for a in lay["expert_down"]] == [(8, 32, D)] * 4
+    n = sum(a.size for a in jax.tree.leaves(params))
+    assert n == F.param_count(cfg)
+    # the same key draws the same weights, and the sharded entry point (one
+    # device) the same again
+    again = M.init_params_sharded(jax.random.PRNGKey(1), cfg, None)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(again)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # the cache stays one [NB, KV, bs, hd] pair a layer
+    cache = M.init_cache(cfg, _engine_config())
+    assert len(cache["k"]) == 5 and cache["k"][0].shape == (96, 2, 16, hd)
+
+
+# ------------------------- against the reference ----------------------------
+
+
+def test_chunked_prefill_and_kernel_decode_match_the_reference(engine):
+    """``forward`` in chunks of 32 into a paged cache, then the Pallas decode
+    kernel (interpreted), on sequences 4.5 windows long: logits against the
+    plain float32 forward."""
+    ref = _reference()
+    v = ref.compare(engine, SEED, T=72, chunk=32, n_decode=6)
+    assert v["ok"], v
+    assert v["decode_attention"]["impl"] == "pallas"
+    assert v["decode_attention"]["interpret"] is True
+    assert max(v["prefill"]["rel"], v["decode"]["rel"]) < 1e-4, v
+    assert v["both"]["rms_rel"] < 1e-4
+    # float32 against float32: the served path chose the reference's experts
+    assert v["routing"]["token_layers"] == 2 * (72 + 6) * 4
+    assert v["routing"]["flipped"] == 0 and v["routing"]["short_max"] == 0
+
+
+def test_the_reference_follows_the_served_choices_and_counts_the_flips(
+        engine, monkeypatch):
+    """A served path that takes the 5th score where the 4th is due: the
+    reference computes with the experts served (logits agree), counts every
+    (token, layer) as flipped and says how far under the 4th the 5th lies."""
+    def route_next(x, w_router, *, top_k, renormalise, scale):
+        probs = jax.nn.softmax(jnp.dot(x, w_router), axis=-1)
+        vals, idx = jax.lax.top_k(probs, top_k + 1)
+        vals = jnp.concatenate([vals[:, :top_k - 1], vals[:, top_k:]], -1)
+        idx = jnp.concatenate([idx[:, :top_k - 1], idx[:, top_k:]], -1)
+        return idx, scale * vals / jnp.sum(vals, -1, keepdims=True)
+
+    monkeypatch.setattr(moe, "route", route_next)
+    v = _reference().compare(engine, SEED, T=72, chunk=32, n_decode=2)
+    r = v["routing"]
+    assert v["both"]["rms_rel"] < 1e-4, v
+    assert r["flipped"] == r["token_layers"] == 2 * (72 + 2) * 4
+    assert 0 < r["short_max"] < 1
+    assert v["ok"] == (r["short_max"] <= v["short_tol"])
+
+
+@pytest.mark.parametrize("variant", ["no_scale", "renorm_held"])
+def test_the_reference_refuses_a_broken_routing_weight(engine, variant):
+    v = _reference().compare(engine, SEED, T=72, chunk=32, n_decode=2,
+                             variant=variant)
+    assert not v["ok"] or v["both"]["rms_rel"] > 0.02, v
+    assert v["both"]["rms_rel"] > 0.02
+
+
+def test_a_window_ignored_in_decode_is_seen(engine, monkeypatch):
+    real = M._paged_decode_attention
+    monkeypatch.setattr(
+        M, "_paged_decode_attention",
+        lambda *a, window=0, **k: real(*a, window=0, **k))
+    v = _reference().compare(engine, SEED, T=72, chunk=32, n_decode=2)
+    assert v["prefill"]["rel"] < 1e-4 < v["decode"]["rel"], v
+
+
+@pytest.mark.anyio
+async def test_served_tokens_are_the_references_and_the_counters_add_up():
+    """The same model through scheduler, chunked prefill and the autopilot
+    decode window: greedy tokens equal the reference's on the served prefix,
+    and every decode record carries the routing and window counters."""
+    import asyncio
+
+    cfg = _model()
+    eng = InferenceEngine(cfg, _engine_config(prefill_chunk_tokens=16),
+                          seed=SEED)
+    rng = np.random.default_rng(5)
+    prompts = [[int(t) for t in rng.integers(256, 512, size=n)]
+               for n in (56, 49, 61)]
+
+    async def one(i, p):
+        out = []
+        async for o in eng.submit(Request(
+                request_id=f"r{i}", token_ids=p, max_tokens=6,
+                ignore_eos=True)):
+            out.append(o.token_id)
+        return out
+
+    try:
+        got = await asyncio.gather(*(one(i, p) for i, p in enumerate(prompts)))
+        records = list(eng.obs._records)
+    finally:
+        await eng.stop()
+    ref = _reference()
+    for p, toks in zip(prompts, got):
+        full = np.asarray(p + toks[:-1], np.int32)
+        hidden, _ = ref.reference_hidden(cfg, eng.params, full)   # own top-k
+        logits = np.asarray(ref.head_logits(cfg, eng.params,
+                                            hidden[len(p) - 1:]))
+        assert toks == [int(t) for t in logits.argmax(-1)]
+    decode = [r for r in records if r.kind == DECODE]
+    assert decode
+    n_sparse, k = 4, cfg.num_experts_per_token
+    for r in decode:
+        assert r.moe_pairs == r.live_rows * k * n_sparse      # no drop
+        assert 0 < r.moe_pairs_held < r.moe_pairs             # routed over 16
+        assert 0 < r.moe_experts_touched <= 8 * n_sparse
+        assert 1 <= r.moe_load_max <= r.live_rows
+        assert 0 < r.context_sum_window <= r.live_rows * 16
+        assert r.context_sum_window < r.context_sum
+        assert 0 < r.kv_blocks_walked_window <= r.kv_blocks_walked
+
+
+# ------------------------- the expert layer ---------------------------------
+
+
+def _experts(E=16, D=24, Fe=16, seed=0):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((D, E)), jnp.float32),
+            jnp.asarray(rng.standard_normal((E, D, Fe)) / np.sqrt(D),
+                        jnp.float32),
+            jnp.asarray(rng.standard_normal((E, D, Fe)) / np.sqrt(D),
+                        jnp.float32),
+            jnp.asarray(rng.standard_normal((E, Fe, D)) / np.sqrt(Fe),
+                        jnp.float32))
+
+
+def _routed_numpy(x, wr, wg, wu, wd, top_k, scale, held):
+    """Per-token loop in float64: softmax over all experts, the top_k,
+    weights over the chosen, only experts in ``held`` computed."""
+    x, wr, wg, wu, wd = (np.asarray(a, np.float64)
+                         for a in (x, wr, wg, wu, wd))
+    out = np.zeros_like(x)
+    for n in range(x.shape[0]):
+        s = np.exp(x[n] @ wr - (x[n] @ wr).max())
+        s /= s.sum()
+        top = np.argsort(-s, kind="stable")[:top_k]
+        for e in top:
+            if e in held:
+                g = x[n] @ wg[e]
+                out[n] += (scale * s[e] / s[top].sum()
+                           * ((g / (1 + np.exp(-g)) * (x[n] @ wu[e])) @ wd[e]))
+    return out
+
+
+def _routed(x, w, lo, hi, **kw):
+    wr, wg, wu, wd = w
+    return moe.routed_ffn(x, wr, wg[lo:hi], wu[lo:hi], wd[lo:hi], top_k=4,
+                          held_start=lo, scale=2.5, interpret=True, **kw)
+
+
+def test_two_shares_add_up_to_the_uncut_layer():
+    """The model-configs guide's share test: the routed parts of the two
+    shares (experts 0-7 and 8-15) equal the uncut layer's routed part; the
+    shared expert is counted once, outside."""
+    w = _experts()
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((37, 24)),
+                    jnp.float32)
+    whole, s_all, c_all = _routed(x, w, 0, 16)
+    first, s0, c0 = _routed(x, w, 0, 8)
+    second, s1, c1 = _routed(x, w, 8, 16)
+    # each share routes over all 16 and makes the same choices
+    assert np.array_equal(np.asarray(c0), np.asarray(c_all))
+    assert np.array_equal(np.asarray(c1), np.asarray(c_all))
+    assert c_all.shape == (37, 4) and int(np.asarray(c_all).max()) > 7
+    np.testing.assert_allclose(np.asarray(first + second), np.asarray(whole),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(whole), _routed_numpy(x, *w, 4, 2.5, range(16)),
+        rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(first), _routed_numpy(x, *w, 4, 2.5, range(8)),
+        rtol=1e-4, atol=1e-4)
+    s_all, s0, s1 = (np.asarray(s) for s in (s_all, s0, s1))
+    assert s_all[0] == s0[0] == s1[0] == 37 * 4          # every pair kept
+    assert s_all[1] == 37 * 4 and s0[1] + s1[1] == 37 * 4
+    assert 0 < s0[1] < 37 * 4
+
+
+def test_no_token_is_dropped_when_all_choose_the_same_expert():
+    wr, wg, wu, wd = _experts()
+    # expert 3 far ahead for every token, expert 12 (not held) second
+    wr = jnp.zeros_like(wr).at[0, 3].set(40.0).at[0, 12].set(20.0)
+    x = jnp.asarray(np.random.default_rng(2).standard_normal((40, 24)),
+                    jnp.float32).at[:, 0].set(1.0)
+    got, stats, chosen = moe.routed_ffn(
+        x, wr, wg[:8], wu[:8], wd[:8], top_k=2, held_start=0, scale=2.5,
+        interpret=True)
+    assert np.all(np.asarray(chosen) == [3, 12])
+    want = _routed_numpy(x, wr, wg, wu, wd, 2, 2.5, range(8))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+    assert np.abs(want).min(axis=1).max() > 0 and np.all(
+        np.abs(np.asarray(got)).sum(axis=1) > 0)       # every token served
+    pairs, held, touched, load = (int(v) for v in np.asarray(stats))
+    assert (pairs, held, touched, load) == (80, 40, 1, 40)
+
+
+def test_dead_rows_route_nowhere():
+    w = _experts()
+    x = jnp.asarray(np.random.default_rng(3).standard_normal((8, 24)),
+                    jnp.float32)
+    live = jnp.asarray([True, False, True, True, False, False, True, False])
+    got, stats, _ = _routed(x, w, 0, 8, live=live)
+    want = _routed_numpy(x, *w, 4, 2.5, range(8))
+    idx = np.flatnonzero(np.asarray(live))
+    np.testing.assert_allclose(np.asarray(got)[idx], want[idx],
+                               rtol=1e-4, atol=1e-4)
+    assert np.all(np.asarray(got)[~np.asarray(live)] == 0)
+    assert int(stats[0]) == 4 * 4
+
+
+def test_the_new_layer_has_no_capacity():
+    import inspect
+
+    src = inspect.getsource(moe.routed_ffn) + inspect.getsource(moe.route)
+    assert "capacity" not in src.replace("no capacity", "")
+    assert "one_hot" not in src
+
+
+# ------------------------- the window in the kernels ------------------------
+
+
+def _paged(B, W, KV, bs, hd, seed=0):
+    rng = np.random.default_rng(seed)
+    nb = 1 + B * W
+    k = rng.standard_normal((nb, KV, bs, hd), dtype=np.float32)
+    v = rng.standard_normal((nb, KV, bs, hd), dtype=np.float32)
+    tables = np.zeros((B, W), np.int32)
+    for b in range(B):
+        tables[b] = 1 + b * W + np.arange(W)
+    return k, v, tables
+
+
+def _gathered(k, tables, bs):
+    B, W = tables.shape
+    return np.asarray(k)[tables].transpose(0, 1, 3, 2, 4).reshape(
+        B, W * bs, k.shape[1], k.shape[3])
+
+
+@pytest.mark.parametrize("G", [2, 3])
+@pytest.mark.parametrize("kv_tile", [16, 32])
+def test_decode_walk_with_a_lower_bound_matches_the_masked_einsum(kv_tile, G):
+    """Contexts below, at and above the window (16), across a tile edge and
+    many windows long; groups of 2 and 3 query heads a KV head."""
+    bs, W, KV, hd, window = 16, 8, 2, 16, 16
+    ctxs = np.asarray([5, 16, 17, 31, 32, 33, 48, 49, 97, 128, 0, 1],
+                      np.int32)
+    B, H = len(ctxs), KV * G
+    k, v, tables = _paged(B, W, KV, bs, hd)
+    q = np.random.default_rng(9).standard_normal((B, H, hd),
+                                                 dtype=np.float32)
+    got = paged_attention_decode(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables),
+        jnp.asarray(ctxs), block_size=bs, kv_tile=kv_tile, interpret=True,
+        window=window)
+    want = M._attention(
+        jnp.asarray(q)[:, None], jnp.asarray(_gathered(k, tables, bs)),
+        jnp.asarray(_gathered(v, tables, bs)),
+        jnp.asarray(ctxs - 1)[:, None], window)[:, 0]
+    live = ctxs > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-5)
+    assert np.all(np.asarray(got)[~live] == 0)
+    # and it is the window that is compared: the unmasked result differs
+    full = paged_attention_decode(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables),
+        jnp.asarray(ctxs), block_size=bs, kv_tile=kv_tile, interpret=True)
+    long = ctxs > window
+    assert np.abs(np.asarray(full) - np.asarray(got))[long].max() > 1e-3
+    np.testing.assert_allclose(np.asarray(full)[~long],
+                               np.asarray(got)[~long], rtol=2e-5, atol=2e-5)
+
+
+def test_ragged_walk_with_a_lower_bound_matches_the_masked_einsum():
+    """A chunk of 8 queries a row whose tile's first query sets the bound."""
+    bs, W, KV, G, hd, window, T = 16, 8, 2, 3, 16, 16, 8
+    ctxs = np.asarray([8, 20, 40, 41, 100, 128], np.int32)
+    B, H = len(ctxs), KV * G
+    k, v, tables = _paged(B, W, KV, bs, hd, seed=4)
+    q = np.random.default_rng(8).standard_normal((B, T, H, hd),
+                                                 dtype=np.float32)
+    pos = (ctxs[:, None] - T + np.arange(T)[None, :]).astype(np.int32)
+    got = paged_attention_ragged(
+        jnp.asarray(q.reshape(B * T, H, hd)), jnp.asarray(k), jnp.asarray(v),
+        jnp.asarray(tables), jnp.arange(B + 1, dtype=jnp.int32) * T,
+        jnp.full((B,), T, jnp.int32), jnp.asarray(ctxs), block_size=bs,
+        max_q_len=T, kv_tile=16, interpret=True, window=window)
+    want = M._attention(
+        jnp.asarray(q), jnp.asarray(_gathered(k, tables, bs)),
+        jnp.asarray(_gathered(v, tables, bs)), jnp.asarray(pos), window)
+    np.testing.assert_allclose(np.asarray(got).reshape(B, T, H, hd),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kv_tile,window,contexts,want", [
+    (256, 512, [100], 1 * 16),            # below the window: from page 0
+    (256, 512, [512], 2 * 16),
+    (256, 512, [513], 3 * 16),            # key 1 is in tile 0 still
+    (256, 512, [2050], (9 - 6) * 16),     # tiles 6, 7, 8 of 9
+    (256, 0, [2050], 9 * 16),
+    (16, 16, [5, 16, 17, 33, 0], 1 + 1 + 2 + 2 + 0),
+])
+def test_kv_blocks_walked_starts_at_the_windows_tile(kv_tile, window,
+                                                     contexts, want):
+    assert kv_blocks_walked(contexts, kv_tile=kv_tile, block_size=16,
+                            window=window) == want
+
+
+# ------------------------- rope ---------------------------------------------
+
+
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+@pytest.mark.parametrize("rehearse", [True, False])
+def test_rope_of_a_kind_is_the_references(kind, rehearse):
+    """YaRN on half of each head and plain rope on all of it, against the
+    reference's own tables, at the tiny and the published parameters."""
+    cfg = _model(rehearse)
+    rope = cfg.rope_of(next(k for k in cfg.attn_kinds if k.name == kind))
+    hd = cfg.head_dim_
+    pos = np.asarray([[0, 1, 7, 500, 4095, 70000]], np.int32)
+    x = np.random.default_rng(3).standard_normal(
+        (1, pos.shape[1], 3, hd)).astype(np.float32)
+    got = np.asarray(M._rope_kind(jnp.asarray(x), jnp.asarray(pos), rope))
+    cos, sin, rot = _reference().rope_tables(rope, hd, pos[0])
+    cos, sin = np.asarray(cos)[:, None], np.asarray(sin)[:, None]
+    a, b, rest = x[0, ..., :rot // 2], x[0, ..., rot // 2:rot], x[0, ..., rot:]
+    want = np.concatenate([a * cos - b * sin, b * cos + a * sin, rest], -1)
+    assert rot == (hd // 2 if kind == "full_attention" else hd)
+    np.testing.assert_allclose(got[0], want, rtol=1e-5, atol=1e-5)
+    if kind == "full_attention":
+        assert np.array_equal(got[..., rot:], x[..., rot:])   # passes through
+        inv, scale = M.rope_frequencies(rope, hd)
+        plain = 1.0 / float(rope["rope_theta"]) ** (
+            np.arange(rot // 2) / (rot // 2))
+        # the fastest dims keep their frequency, the slowest are divided
+        assert inv[0] == pytest.approx(plain[0])
+        assert inv[-1] == pytest.approx(plain[-1] / rope["factor"], rel=1e-5)
+        assert scale == pytest.approx(
+            rope.get("attention_factor", 0.1 * np.log(rope["factor"]) + 1))
+
+
+def test_plain_rope_of_a_kind_is_the_one_kind_models():
+    # (the kind's frequencies are made in float64 at trace time, the
+    # one-kind model's in float32 on the device: equal to rounding)
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 5, 4, 16)),
+                    jnp.float32)
+    pos = jnp.asarray([[0, 1, 2, 3, 900], [5, 6, -1, -1, -1]], jnp.int32)
+    a = M._rope(x, pos, 10000.0)
+    b = M._rope_kind(x, pos, {"rope_type": "default", "rope_theta": 10000})
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                               atol=1e-4)
+
+
+# ------------------------- a model without a table --------------------------
+
+# sha256 (16 hex) of the parameters' bytes at PRNGKey(7) and of the lowered
+# StableHLO of one ``forward`` (2 rows of 6 tokens, 2 pages a row), taken on
+# the commit before the table (a2f7c1f): the one-kind model builds the same
+# parameters and the same program.
+BEFORE = {
+    "tiny": ("2df05571f5908433", "977b58bc4b45bc16"),
+    "tiny_moe": ("2370a9c04f43bbd6", "5ea2ed3b39a0df13"),
+}
+
+
+@pytest.mark.parametrize("preset", sorted(BEFORE))
+def test_a_model_without_a_table_is_what_it_was(preset):
+    cfg = getattr(ModelConfig, preset)()
+    assert not cfg.has_table and len(cfg.attn_kinds) == 1
+    eng = EngineConfig(num_blocks=16, attention_impl="einsum")
+    params = M.init_params(jax.random.PRNGKey(7), cfg)
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(params):
+        h.update(np.asarray(leaf).tobytes())
+    cache = M.init_cache(cfg, eng)
+    tok = jnp.arange(12, dtype=jnp.int32).reshape(2, 6) + 300
+    pos = jnp.tile(jnp.arange(6, dtype=jnp.int32), (2, 1))
+    bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    f = jax.jit(lambda p, c, t, po, b: M.forward(cfg, eng, p, c, t, po, b))
+    text = f.lower(params, cache, tok, pos, bt).as_text()
+    assert (h.hexdigest()[:16],
+            hashlib.sha256(text.encode()).hexdigest()[:16]) == BEFORE[preset]
+
+
+# ------------------------- what refuses a table ------------------------------
+
+
+def test_what_cannot_run_a_table_says_so_when_it_is_built(cpu_devices):
+    from dynamo_tpu.parallel import layout, pp_serving
+
+    cfg = _model()
+    mesh = layout.make_mesh((1, 2), devices=cpu_devices[:2])
+    with pytest.raises(ValueError, match="--mesh 1,1"):
+        M.init_params_sharded(jax.random.PRNGKey(0), cfg, mesh)
+    with pytest.raises(ValueError, match="weight-dtype int8"):
+        M.init_params_sharded(jax.random.PRNGKey(0), cfg, None, "int8")
+    with pytest.raises(ValueError, match="pipeline-parallel"):
+        pp_serving.raw_pp_step_fn(cfg, _engine_config(), mesh)
+    with pytest.raises(ValueError, match="--mesh 1,1"):
+        InferenceEngine(cfg, _engine_config(mesh_shape=(1, 2)), seed=0)
+
+
+def test_the_encoder_runs_a_table(engine):
+    """``encode_forward`` calls the same layer body: its pooled states are
+    those of the reference's hidden states."""
+    cfg = engine.model_config
+    toks = np.random.default_rng(2).integers(256, 512, size=(1, 40))
+    pos = np.arange(40, dtype=np.int32)[None]
+    got = M.encode_forward(cfg, engine.params, jnp.asarray(toks, jnp.int32),
+                           jnp.asarray(pos))
+    hidden, _ = _reference().reference_hidden(cfg, engine.params, toks[0])
+    want = np.asarray(hidden).mean(0)
+    want /= np.linalg.norm(want)
+    np.testing.assert_allclose(np.asarray(got)[0], want, rtol=1e-4, atol=1e-4)
+
+
+# ------------------------- FLOPs ---------------------------------------------
+
+
+def test_flops_count_a_tables_layers_by_kind():
+    cfg = _model(rehearse=False)
+    D, hd, KV = 3072, 128, 8
+    attn = lambda H: 2 * D * H * hd + 2 * D * KV * hd + D * H   # noqa: E731
+    small = 2 * attn(48) + 3 * attn(72) + 5 * 2 * D + D
+    dense = 3 * D * 12288
+    sparse = D * 256 + 3 * D * 1024                 # router + shared expert
+    expert = 3 * D * 1024
+    embed = 50176 * D
+    assert F.param_count(cfg) == (small + dense + 4 * sparse
+                                  + 4 * 128 * expert + 2 * embed)
+    assert F.param_count(cfg) == 5572076544          # ISSUE.md's 5.572 B
+    # a token multiplies by 10 experts, half of them held here on average
+    assert F.active_param_count(cfg) == (small + dense + 4 * sparse
+                                         + 4 * 5 * expert + embed)
+    fm = F.FlopsModel(cfg)
+    assert fm.attn_coef == 4.0 * hd * (2 * 48 + 3 * 72)
+    assert fm.step_flops(1, 0) == 2.0 * F.active_param_count(cfg)
+    tiny = ModelConfig.tiny()
+    assert F.FlopsModel(tiny).attn_coef == (
+        4.0 * tiny.num_layers * tiny.num_heads * tiny.head_dim_)

@@ -602,7 +602,9 @@ def test_selfcheck_scopes_and_vocabulary():
     spec = importlib.util.spec_from_file_location("selfcheck_scopes", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    assert mod.VOCABULARY == model_lib.SCOPES
+    # (the stages of a table's layers came behind it in PR 32; the file is
+    # the benchmark's and spells out the stages its recorded trace has)
+    assert mod.VOCABULARY + model_lib.TABLE_SCOPES == model_lib.SCOPES
     mod.check_hand_made()
     assert mod.check_recorded()
 
@@ -628,8 +630,21 @@ def _tiny_engine_config(**kw):
                         decode_buckets=(8,), prefill_buckets=(16,), **kw)
 
 
-def _lowered_step_program(which):
-    cfg, eng = ModelConfig.tiny(), _tiny_engine_config()
+def _table_model():
+    """The benchmark's tiny table of layer kinds (its rehearsal model)."""
+    import os
+
+    from benchmarks.chip import worker_launch
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip", "configs",
+        "laguna-s-2.1-ep2.json")
+    with open(path) as f:
+        return worker_launch.model_config_from(json.load(f), True)
+
+
+def _lowered_step_program(which, cfg=None):
+    cfg, eng = cfg or ModelConfig.tiny(), _tiny_engine_config()
     params = jax.eval_shape(
         lambda: model_lib.init_params(jax.random.PRNGKey(0), cfg))
     cache = jax.eval_shape(lambda: model_lib.init_cache(cfg, eng))
@@ -652,19 +667,28 @@ def _lowered_step_program(which):
     return fn.__wrapped__.lower(*args)
 
 
+@pytest.mark.parametrize("model", ["one_kind", "table"])
 @pytest.mark.parametrize("which", ["decode_window", "packed_prefill"])
-def test_step_programs_carry_every_scope(which):
+def test_step_programs_carry_every_scope(which, model):
     """Every name of model.SCOPES is a component of some op's ``op_name``
     in the lowered program — a refactor that drops a stage's
-    ``jax.named_scope`` fails here, before a trace reads 0 for it."""
+    ``jax.named_scope`` fails here, before a trace reads 0 for it.  A table
+    of layer kinds carries all of them; a one-kind model all but the
+    table's own, and none of those."""
     import re
 
-    text = _lowered_step_program(which).as_text(debug_info=True)
+    table = model == "table"
+    text = _lowered_step_program(
+        which, _table_model() if table else None).as_text(debug_info=True)
     seen = set()
     for op_name in re.findall(r'loc\("(jit\([^"]*)"', text):
         seen.update(op_name.split("/"))
-    missing = [s for s in model_lib.SCOPES if s not in seen]
+    want = [s for s in model_lib.SCOPES
+            if table or s not in model_lib.TABLE_SCOPES]
+    missing = [s for s in want if s not in seen]
     assert not missing, f"{which}: no op carries scope(s) {missing}"
+    if not table:
+        assert not seen & set(model_lib.TABLE_SCOPES)
 
 
 def _host_event_names(trace_dir):
