@@ -34,6 +34,7 @@ from ..parallel.moe import MOE_STATS
 from ..observability.flops import FlopsModel
 from ..observability.stepstats import (
     DECODE, PREFILL, SPEC_VERIFY, StepRecord, StepStats, kv_blocks_walked,
+    kv_pages_written,
 )
 from ..runtime import faults
 from ..runtime.context import Context
@@ -2109,6 +2110,7 @@ class InferenceEngine(EngineCore):
         positions[0, :chunk.length] = np.arange(
             chunk.start, chunk.start + chunk.length
         )
+        model_lib.check_feed_positions(positions)
         tables = np.zeros((1, W), np.int32)
         tables[0, :nb] = seq.block_table[:nb]
         return {
@@ -2158,6 +2160,8 @@ class InferenceEngine(EngineCore):
                 padded_tokens=a["tokens"].shape[1], real_tokens=L,
                 goodput_tokens=L,
                 context_sum=L * S + L * (L + 1) // 2,
+                kv_pages_written=kv_pages_written(
+                    [(S, L)], block_size=cfg.block_size),
             ))
         slot = np.array(
             [seq.slot if seq.slot >= 0 else cfg.max_num_seqs], np.int32
@@ -2369,6 +2373,11 @@ class InferenceEngine(EngineCore):
                 context_sum=ctx, kv_blocks_walked=walked,
                 kv_blocks_walked_window=walked_w,
                 context_sum_window=ctx_w,
+                # a spec window writes a row's K fed positions at once, a
+                # decode window one page a row in each of its K steps
+                kv_pages_written=kv_pages_written(
+                    [(r.base, K) for r in rows], block_size=bs,
+                ) if spec else len(rows) * K,
             ))
         fn = self._spec_window_fn if spec else self._ap_window_fn
         self.cache, self._ctl, samples = fn(

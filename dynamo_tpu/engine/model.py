@@ -15,23 +15,37 @@ TPU-native. Design points:
   scatter-updated IN PLACE — threading a stacked cache through ``lax.scan``
   costs whole-cache copies every step.
 - **Paged KV**: the cache is ``[L, num_blocks, KV, block_size, hd]``
-  (block-major, head-contiguous); the step scatters the chunk's K/V into
-  (block, offset) slots from the block table, then attends — decode via the
+  (block-major, head-contiguous); the step writes the chunk's K/V into the
+  pages its block table names, then attends — decode via the
   Pallas paged kernel streaming blocks HBM→VMEM (ops/paged_attention.py),
-  prefill via a gathered-context einsum. Physical block 0 is a trash block —
-  padding positions scatter there, and the allocator never hands it out.
+  prefill via a gathered-context einsum. Physical block 0 is the null block:
+  the allocator never hands it out, pads and dead rows point at it, and no
+  step program modifies it — under serving alone it holds what
+  :func:`init_cache` put there (zeros, and zero scales) for the life of the
+  engine. (The block movers of :func:`make_kv_ops` still send the pads of a
+  padded id list there, so nothing may READ it for meaning: the kernel's
+  masks hold whatever its bytes are.)
 - **The write keeps the kernel's layout** (:func:`_kv_write`). The kernel
   reads a layer row-major, ``{3,2,1,0}``. The plain
   ``lk.at[block, :, off].set(k)`` indexes dims 0 and 2 around the window dim
   ``KV``; XLA's layout assignment gives such a scatter the operand layout
   ``{3,1,2,0}`` (block, token, head, hd) and converts with whole-layer
   ``copy`` ops: K in, K out, V in, V out per layer, 64 copies of 32 MiB a
-  16-layer step, half of every step program on a v5e (PERF.md, PR 29). So
-  the step writes through the free ``[NB*KV*bs, hd]`` view, one update per
-  (token, head): the indexed dim leads, ``hd`` is the only window dim, the
-  scatter takes the layer row-major and nothing is converted. Same shape,
-  same layout, same bits. ``tests/test_chip_compile.py`` holds the compiled
-  step programs to "no copy of a cache layer".
+  16-layer step, half of every step program on a v5e (PERF.md, PR 29). A
+  scatter whose indexed dim leads takes the layer row-major and converts
+  nothing; PR 29's wrote one ``hd`` row per (token, head) through the
+  ``[NB*KV*bs, hd]`` view, and XLA's TPU scatter walks its updates one by
+  one at ~70 ns each whatever their size: 4.4 ms of a 16.3 ms T=256 chunk.
+  So the step writes WHOLE PAGES, as many updates as pages touched
+  (PERF.md, PR 33): a row of the ``[B, T]`` chunk holds contiguous
+  positions, so it touches at most ``(T + bs - 2) // bs + 1`` pages; read
+  them (a gather on dim 0), lay the new rows over them with a ``where``,
+  and scatter the merged pages back on dim 0 alone. Real blocks come out
+  with the same bits; an entry no valid token falls on is redirected to
+  block 0 and writes block 0's own bytes back, so duplicates cannot race
+  and block 0 never changes. ``tests/test_chip_compile.py`` holds the
+  compiled step programs to "no copy of a cache layer" and "no scatter
+  with more updates than pages".
 - **TP via shardings, not code**: parameters and cache carry
   ``jax.sharding.NamedSharding`` annotations over a ``("dp", "tp")`` mesh
   (attention/MLP column-row sharded, KV heads sharded over tp); XLA GSPMD
@@ -260,15 +274,21 @@ def init_cache(cfg: ModelConfig, eng: EngineConfig) -> Cache:
     measured ~90 ms/step of pure copies on v5e for a 1B model.
 
     In place also depends on HOW a step writes: only through
-    :func:`_kv_write`, whose scatter indexes the leading dim of the
-    ``[NB*KV*bs, hd]`` view and so accepts this row-major layout. A
+    :func:`_kv_write`, whose scatter indexes the leading dim alone (whole
+    pages) and so accepts this row-major layout. A
     ``.at[block, :, off].set`` on the 4-D array makes XLA copy the whole
-    layer to ``{3,1,2,0}`` and back around every write (module header)."""
+    layer to ``{3,1,2,0}`` and back around every write (module header).
+
+    Block 0 is the null block and is made here: zeros, and zero scales
+    for int8 / fp8, which dequantize to exact zeros. No step program
+    modifies it — a write that points at it (a pad, a dead row) puts its
+    own bytes back (:func:`_kv_write`); only a padded ``inject`` of
+    :func:`make_kv_ops` can still land there."""
     dt = _dtype(cfg)
     shape = (eng.num_blocks, cfg.num_kv_heads, eng.block_size, cfg.head_dim_)
     if quant.is_quantized(eng.kv_dtype):
         # quantized pages (1 byte/elem) plus per-(slot, head) f32 scale
-        # planes; the trash block's zero scales dequantize to exact zeros
+        # planes; block 0's zero scales dequantize to exact zeros
         dt = quant.storage_dtype(eng.kv_dtype)
         sshape = shape[:-1]
         return {
@@ -891,46 +911,104 @@ def _paged_ragged_attention(
     return out.reshape(B, T, H, hd)
 
 
-def _kv_write(plane: jax.Array, blocks: jax.Array, offs: jax.Array,
-              upd: jax.Array, mesh: Optional[Mesh] = None) -> jax.Array:
-    """Write one step's rows into a cache plane; same bits as
-    ``plane.at[blocks, :, offs].set(upd)``, without the whole-plane layout
-    copies that expression costs on the TPU (module header).
+def check_feed_positions(positions: np.ndarray) -> None:
+    """Hold a host-built ``[B, T]`` feed to ``forward``'s contract: each
+    row's valid positions are a prefix, contiguous from its first. Host
+    integers only."""
+    valid = positions >= 0
+    n = valid.sum(axis=1, keepdims=True)
+    idx = np.arange(positions.shape[1])[None, :]
+    want = np.where(idx < n, positions[:, :1] + idx, -1)
+    assert np.array_equal(positions, want), (
+        "feed rows must hold contiguous positions as a prefix")
 
-    ``plane`` is a payload plane ``[NB, KV, bs, hd]`` with ``upd [N, KV,
-    hd]``, or a scale plane ``[NB, KV, bs]`` with ``upd [N, KV]``;
-    ``blocks`` / ``offs`` ``[N]`` are each row's physical block and slot
-    (pads point at trash block 0, where duplicates may race).
 
-    One update per (token, head) through the free ``[NB*KV*bs, hd]`` view:
-    the indexed dim leads and ``hd`` is the only window dim, so the scatter
-    takes the plane row-major. (Two index dims over ``[NB*KV, bs, hd]``
-    compile to the same program, but XLA's rewrite of that scatter drops
-    the op's scope from the trace.) On a ``tp`` mesh the write runs under
-    ``shard_map`` with the cache's own specs, as the kernel does: under
-    GSPMD the reshape would merge the sharded ``KV`` dim into the block dim
-    and all-gather the plane."""
-    def write(plane_, blocks_, offs_, upd_):
-        NB, KV, bs = plane_.shape[:3]
-        heads = jnp.arange(KV, dtype=blocks_.dtype)[None, :]
-        rows = (blocks_[:, None] * KV + heads) * bs + offs_[:, None]
-        view = plane_.reshape((NB * KV * bs,) + plane_.shape[3:])
-        view = view.at[rows.reshape(-1)].set(
-            upd_.reshape((-1,) + upd_.shape[2:]))
-        return view.reshape(plane_.shape)
+def _kv_pages(positions: jax.Array, block_tables: jax.Array,
+              bs: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Where a step's ``[B, T]`` chunk lands in the paged cache, page by
+    page: ``(pages [B, P], shift [B], mask [B, P*bs])``, shared by every
+    layer and plane of the step (:func:`_kv_write`).
+
+    A row's valid tokens are a prefix at contiguous positions ``start + t``
+    (``forward``'s contract), so they lie in at most ``P = (T + bs - 2) //
+    bs + 1`` logical blocks from ``start // bs`` on: 1 at T=1, 17 at T=256.
+    ``pages`` are those blocks' physical ids; an entry no valid token falls
+    on (a dead row, the pad tail, past the row's last page) is redirected
+    to block 0. ``shift = start % bs`` is the slot of the row's first token
+    in its first page, ``mask`` the slots of the ``P`` pages that take a
+    token."""
+    T = positions.shape[1]
+    W = block_tables.shape[1]
+    P = (T + bs - 2) // bs + 1
+    n = jnp.sum(positions >= 0, axis=1).astype(jnp.int32)       # [B]
+    start = jnp.maximum(positions[:, 0], 0)
+    shift = start % bs
+    end = (shift + n)[:, None]
+    page = jnp.arange(P, dtype=jnp.int32)[None, :]
+    slot = jnp.arange(P * bs, dtype=jnp.int32)[None, :]
+    phys = jnp.take_along_axis(
+        block_tables, jnp.minimum(start[:, None] // bs + page, W - 1), axis=1)
+    pages = jnp.where((page * bs < end) & (n[:, None] > 0), phys, 0)
+    mask = (slot >= shift[:, None]) & (slot < end)
+    return pages, shift, mask
+
+
+def _kv_write(plane: jax.Array, pages: jax.Array, shift: jax.Array,
+              mask: jax.Array, upd: jax.Array,
+              mesh: Optional[Mesh] = None) -> jax.Array:
+    """Write one step's rows into a cache plane, whole pages at a time; on
+    every real block the same bits as ``plane.at[blocks, :, offs]
+    .set(upd)``, without the whole-plane layout copies that expression
+    costs on the TPU (module header).
+
+    ``plane`` is a payload plane ``[NB, KV, bs, hd]`` with ``upd [B, T, KV,
+    hd]``, or a scale plane ``[NB, KV, bs]`` with ``upd [B, T, KV]``;
+    ``pages`` / ``shift`` / ``mask`` are :func:`_kv_pages` of the step.
+
+    Read the ``B*P`` pages (a gather on dim 0, the page its window), lay
+    each row's updates into page shape (a pad and one ``dynamic_slice`` a
+    row shift them by ``shift``; at T=1 the one update is every slot's and
+    the mask picks its own: 0.53 against the slice's 0.89 ms at 64 rows,
+    PERF.md, PR 33), ``where(mask, new, old)``, and scatter the
+    merged pages back on dim 0 alone: as many updates as pages touched,
+    where one per (token, head) cost ~70 ns each whatever its size
+    (PERF.md, PR 33). The indexed dim leads, so the scatter takes the plane
+    row-major and nothing is converted. A redirected entry writes block
+    0's own bytes back: block 0 is never modified. On a ``tp`` mesh the
+    write runs under ``shard_map`` with the cache's own specs, as the
+    kernel does (a device's page is its ``KV / tp`` heads)."""
+    def write(plane_, pages_, shift_, mask_, upd_):
+        KV, bs = plane_.shape[1:3]
+        tail = plane_.shape[3:]
+        B, T = upd_.shape[:2]
+        P = pages_.shape[1]
+        if T == 1:
+            rows = jnp.broadcast_to(upd_, (B, bs) + upd_.shape[2:])
+        else:
+            padded = jnp.pad(upd_, ((0, 0), (bs - 1, P * bs - T))
+                             + ((0, 0),) * (upd_.ndim - 2))
+            rows = jax.vmap(
+                lambda r, s: jax.lax.dynamic_slice_in_dim(
+                    r, bs - 1 - s, P * bs))(padded, shift_)
+        # [B, P*bs, KV, ...] -> [B*P, KV, bs, ...]
+        new = jnp.swapaxes(rows.reshape((B * P, bs, KV) + tail), 1, 2)
+        take = mask_.reshape((B * P, 1, bs) + (1,) * len(tail))
+        ids = pages_.reshape(-1)
+        old = jnp.take(plane_, ids, axis=0)
+        return plane_.at[ids].set(jnp.where(take, new, old))
 
     if mesh is None or mesh.shape.get(AXIS_TP, 1) == 1:
-        return write(plane, blocks, offs, upd)
+        return write(plane, pages, shift, mask, upd)
     lay = SpecLayout.for_mesh(mesh)
     plane_spec = (lay.cache_block() if plane.ndim == 4
                   else lay.cache_scale_block())
-    upd_spec = layout.spec(None, lay.tp, *(None,) * (upd.ndim - 2))
+    upd_spec = layout.spec(None, None, lay.tp, *(None,) * (upd.ndim - 3))
     return layout.shard_map(
         write, mesh=mesh,
-        in_specs=(plane_spec, layout.spec(None), layout.spec(None),
-                  upd_spec),
+        in_specs=(plane_spec, layout.spec(None, None), layout.spec(None),
+                  layout.spec(None, None), upd_spec),
         out_specs=plane_spec,
-    )(plane, blocks, offs, upd)
+    )(plane, pages, shift, mask, upd)
 
 
 def _layer_attention(
@@ -1104,7 +1182,7 @@ def forward(
     cache: Cache,
     tokens: jax.Array,        # [B, T] int32 (0 = pad)
     positions: jax.Array,     # [B, T] int32 absolute, -1 = pad
-    block_tables: jax.Array,  # [B, W] int32 physical block ids (0 = trash)
+    block_tables: jax.Array,  # [B, W] int32 physical block ids (0 = null)
     mesh: Optional[Mesh] = None,
     ring_mesh: Optional[Mesh] = None,
     mm_embeds: Optional[jax.Array] = None,  # [B, T, D] vision embeddings
@@ -1129,11 +1207,8 @@ def forward(
 
     Returns (updated cache, hidden states [B, T, D]).
     """
-    B, T = tokens.shape
-    W = block_tables.shape[1]
+    T = tokens.shape[1]
     bs = eng.block_size
-    hd = cfg.head_dim_
-    KV = cfg.num_kv_heads
 
     use_ring = ring_mesh is not None and T > 1
     if use_ring:
@@ -1164,17 +1239,14 @@ def forward(
         if h_pin is not None:
             h = jax.lax.with_sharding_constraint(h, h_pin)
 
-    # physical (block, offset) per (b, t); pads go to the trash block 0
+    # THE FEED CONTRACT: a row's valid tokens (position >= 0) are a prefix
+    # of the row, at contiguous positions start + t. The packed prefill
+    # builds them as start + iota, a decode window has T == 1, spec verify
+    # feeds pos0 + steps under a prefix mask, and the host's own feeds are
+    # held to it by check_feed_positions. The write's page plan and the
+    # ragged kernel's q_len / ctx_len below rest on it.
     with jax.named_scope("kv_write"):
-        pos_safe = jnp.maximum(positions, 0)
-        logical_block = pos_safe // bs                      # [B, T]
-        phys_block = jnp.take_along_axis(
-            block_tables, jnp.minimum(logical_block, W - 1), axis=1
-        )                                                   # [B, T]
-        scatter_block = jnp.where(
-            positions >= 0, phys_block, 0).reshape(-1)
-        scatter_off = jnp.where(
-            positions >= 0, pos_safe % bs, 0).reshape(-1)
+        kv_pages = _kv_pages(positions, block_tables, bs)
 
     attn_class = attention_class(eng, T)
     use_pallas = (not use_ring
@@ -1226,10 +1298,9 @@ def forward(
             k = jax.lax.with_sharding_constraint(k, qkv_pin)
             v = jax.lax.with_sharding_constraint(v, qkv_pin)
 
-        # scatter this chunk's K/V into the paged cache
+        # write this chunk's K/V into the paged cache, page by page
         with jax.named_scope("kv_write"):
-            k_upd = k.reshape(B * T, KV, hd)
-            v_upd = v.reshape(B * T, KV, hd)
+            k_upd, v_upd = k, v                     # [B, T, KV, hd]
             if use_ring and lay is not None:
                 # the one real layout change on the ring path: T-sharded
                 # K/V re-lands on the cache's head sharding. GSPMD cannot
@@ -1238,9 +1309,9 @@ def forward(
                 # explicitly: a planned all-gather over the sequence axes,
                 # then a local slice onto the cache's tp sharding
                 repl_pin = NamedSharding(
-                    mesh, layout.spec(None, None, None))
+                    mesh, layout.spec(None, None, None, None))
                 upd_pin = NamedSharding(
-                    mesh, layout.spec(None, lay.tp, None))
+                    mesh, layout.spec(None, None, lay.tp, None))
                 k_upd = jax.lax.with_sharding_constraint(k_upd, repl_pin)
                 v_upd = jax.lax.with_sharding_constraint(v_upd, repl_pin)
                 k_upd = jax.lax.with_sharding_constraint(k_upd, upd_pin)
@@ -1253,10 +1324,10 @@ def forward(
                 # scatters to
                 k_upd, k_sc = quant.kv_quantize(k_upd, eng.kv_dtype)
                 v_upd, v_sc = quant.kv_quantize(v_upd, eng.kv_dtype)
-                lks = _kv_write(lks, scatter_block, scatter_off, k_sc, mesh)
-                lvs = _kv_write(lvs, scatter_block, scatter_off, v_sc, mesh)
-            lk = _kv_write(lk, scatter_block, scatter_off, k_upd, mesh)
-            lv = _kv_write(lv, scatter_block, scatter_off, v_upd, mesh)
+                lks = _kv_write(lks, *kv_pages, k_sc, mesh)
+                lvs = _kv_write(lvs, *kv_pages, v_sc, mesh)
+            lk = _kv_write(lk, *kv_pages, k_upd, mesh)
+            lv = _kv_write(lv, *kv_pages, v_upd, mesh)
 
         # the trace tells a window layer's walk from a full one's
         with jax.named_scope(
@@ -1557,7 +1628,8 @@ def raw_decode_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
     Input token per row: ``last_tok[slot]`` when ``tok_src > 0`` (the
     previous window / prefill wrote it there — the host may not know it
     yet), else ``tok_host`` (resumed / injected sequences). Rows whose
-    position reaches ``valid_until`` scatter to the trash block; their
+    position reaches ``valid_until`` write nothing (their page is block 0,
+    which gets its own bytes back); their
     garbage tokens are discarded by the scheduler. After the window, each
     row's LAST VALID sample is written back to its slot so the next window
     can chain without the host ever seeing a token.
@@ -1638,7 +1710,7 @@ def make_decode_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
 # resumed sequence injects a host-known token, and re-uploads
 # ``slot_rows`` only on membership changes. Slot S is the trash slot:
 # delta pad rows target it, and dead seats (valid_until 0) advance
-# nothing and scatter to the trash block.
+# nothing and write nothing to the cache.
 #
 # This is the TPU-first redesign of the reference's per-step engine loop
 # (vLLM reads sampled ids back every step — affordable at ~10 µs GPU
@@ -1711,8 +1783,9 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
     Signature: window(params, cache, ctl, slot_rows[B]) ->
     (cache, ctl, samples[K, B]).
 
-    Dead seats (valid_until <= pos) compute garbage into the trash block
-    and advance nothing; their sample columns are discarded by the host.
+    Dead seats (valid_until <= pos) compute garbage, write none of it to
+    the cache and advance nothing; their sample columns are discarded by
+    the host.
     Step rngs derive from the carried key + counter, so a window dispatch
     carries zero fresh host arrays.
 

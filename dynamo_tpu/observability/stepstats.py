@@ -60,6 +60,16 @@ def kv_blocks_walked(contexts, *, kv_tile: int, block_size: int,
         for c in contexts)
 
 
+def kv_pages_written(rows, *, block_size: int) -> int:
+    """Cache pages one write of a step program touches in ONE layer and
+    plane, from host integers: ``rows`` are ``(start, count)`` of each
+    row's contiguous new positions; a row of ``count`` 0 writes nothing.
+    It is the number of scatter updates the write carries
+    (``engine.model._kv_write``), so what its cost should follow."""
+    return sum((int(s) + int(n) - 1) // block_size - int(s) // block_size + 1
+               for s, n in rows if n > 0)
+
+
 @dataclass
 class StepRecord:
     """One dispatched unit of device work, host-side metadata only."""
@@ -80,6 +90,12 @@ class StepRecord:
     # gathers every column of the table: rows x width). Times block_size
     # over context_sum = how far the walk is from the tokens attended.
     kv_blocks_walked: int = 0
+    # every record: cache pages the step's K/V write touches in ONE layer
+    # (kv_pages_written above over each row's start and count; a decode
+    # window's K steps each write one page a live row, a spec window all
+    # k + 1 fed positions of a row at once, as if every draft were fed).
+    # Beside rows and real_tokens it says what the write's cost follows.
+    kv_pages_written: int = 0
     # the same two for ONE layer with a window (a table's sliding kind; 0
     # where the model has none): pages walked from the window's tile on,
     # and min(context, window) summed over the same rows

@@ -20,7 +20,8 @@ well; a single-sequence prefill chunk runs M=1 (full bubble) — prefill
 overlap comes from the engine interleaving chunked prefills with decode
 batches, the same interleaving it already does.
 
-Correctness notes: bubble ticks scatter to the trash block (index 0) so
+Correctness notes: bubble ticks write no page (their entries point at
+block 0, which gets its own bytes back) so
 they can never touch live cache; the causal order within a sequence holds
 because each stage processes microbatches in order (the skew only offsets
 WHICH tick a microbatch is processed at, never reorders them).
@@ -85,13 +86,13 @@ def pp_param_shardings(mesh: Mesh, cfg: ModelConfig):
 
 def _stage_layers(cfg: ModelConfig, eng: EngineConfig, Lp: int,
                   stage_params, lk, lv, h, positions, block_tables,
-                  scatter_block, scatter_off):
+                  kv_pages):
     """Apply this stage's Lp layers over one microbatch chunk.
 
     h [mb, T, D]; lk/lv [Lp, NB, KV, bs, hd] (functionally updated).
     The attention path is the gathered-context einsum — inside shard_map
     every stage attends over its own layers' full context."""
-    B, T = h.shape[0], h.shape[1]
+    B = h.shape[0]
     bs = eng.block_size
     hd = cfg.head_dim_
     KV = cfg.num_kv_heads
@@ -103,10 +104,8 @@ def _stage_layers(cfg: ModelConfig, eng: EngineConfig, Lp: int,
     for li in range(Lp):
         p = {name: w[li] for name, w in stage_params.items()}
         x, q, k, v = model_lib.attn_inputs(cfg, kind, p, h, positions)
-        layer_k = model_lib._kv_write(
-            lk[li], scatter_block, scatter_off, k.reshape(B * T, KV, hd))
-        layer_v = model_lib._kv_write(
-            lv[li], scatter_block, scatter_off, v.reshape(B * T, KV, hd))
+        layer_k = model_lib._kv_write(lk[li], *kv_pages, k)
+        layer_v = model_lib._kv_write(lv[li], *kv_pages, v)
         k_all = jnp.take(
             layer_k, block_tables.reshape(-1), axis=0
         ).reshape(B, W, KV, bs, hd).transpose(0, 1, 3, 2, 4).reshape(
@@ -164,18 +163,12 @@ def raw_pp_step_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Mesh,
                 pos = jnp.take(pos_all, mb_idx, axis=0)     # [mb, T]
                 tbl = jnp.take(tbl_all, mb_idx, axis=0)     # [mb, W]
                 # bubble ticks must not touch live cache: only valid
-                # in-window microbatches with real positions scatter
-                pos_safe = jnp.maximum(pos, 0)
-                logical = pos_safe // bs
-                phys = jnp.take_along_axis(
-                    tbl, jnp.minimum(logical, W - 1), axis=1
-                )
-                live = valid & (pos >= 0)
-                scatter_block = jnp.where(live, phys, 0).reshape(-1)
-                scatter_off = jnp.where(live, pos_safe % bs, 0).reshape(-1)
+                # in-window microbatches with real positions write a page
+                kv_pages = model_lib._kv_pages(
+                    jnp.where(valid, pos, -1), tbl, bs)
                 y, lk, lv = _stage_layers(
                     cfg, eng, Lp, stage_params, lk, lv, act, pos, tbl,
-                    scatter_block, scatter_off,
+                    kv_pages,
                 )
                 act = jnp.where(valid, y, act)
                 bank = (stage == S - 1) & valid

@@ -2,8 +2,8 @@
 kernel at Llama-3.2-1B widths in every shape class the engine can select,
 and at the benchmark cells' own widths (Mistral-7B) in the decode class; and
 whole step programs at the cells' shapes (2 layers), which must hold no copy
-of a cache layer, no relayout of a weight and, on four chips, no collective
-but the layer's two.
+of a cache layer, no scatter with more updates than pages touched, no
+relayout of a weight and, on four chips, no collective but the layer's two.
 
 Interpret-mode parity (every other kernel test) cannot see what Mosaic
 refuses — a block that overflows scoped VMEM, a slice off the dtype's tile.
@@ -173,12 +173,14 @@ def test_mistral_decode_grid_tiles_compile(
 
 # ---- whole step programs at the cells' shapes: the cache keeps its layout ---
 #
-# ``forward`` writes K and V through a view whose indexed dim leads and whose
-# only window dim is ``hd`` (``model._kv_write``). The plain
+# ``forward`` writes K and V whole pages at a time, by a scatter whose indexed
+# dim leads and whose window is the page (``model._kv_write``). The plain
 # ``.at[block, :, off].set`` made XLA's layout assignment ask for the cache
 # as {3,1,2,0} and copy every layer's K and V into that layout and back, a
-# step: half of every step program on the chip (PERF.md, PR 29). Compile
-# only: Mistral-7B widths, 2 layers, the benchmark's engine arguments.
+# step: half of every step program on the chip (PERF.md, PR 29); PR 29's own
+# cure, one update per (token, head), cost ~70 ns an update: 4.4 ms of a
+# 16.3 ms T=256 chunk (PERF.md, PR 33). Compile only: Mistral-7B widths, 2
+# layers, the benchmark's engine arguments.
 
 CELL_MODEL = dict(
     vocab_size=32768, hidden_size=4096, intermediate_size=14336,
@@ -291,11 +293,71 @@ def test_the_plain_scatter_is_what_copies_the_cache(
         topo, no_compile_cache, monkeypatch):
     # the reader above sees the copies where they are: the old expression
     # brings K in, K out, V in, V out per layer
-    monkeypatch.setattr(
-        M, "_kv_write",
-        lambda plane, blocks, offs, upd, mesh=None:
-            plane.at[blocks, :, offs].set(upd))
+    monkeypatch.setattr(M, "_kv_write", _plain_write)
     assert len(_cache_sized_copies(_prefill_text(topo))) >= 4 * CELL_LAYERS
+
+
+def _rows_of_plan(pages, shift, mask, upd):
+    """(block [N], slot [N], row [N, KV, ...]) of every slot of a page plan
+    (``model._kv_pages``): slot ``j`` of a row's pages takes the row's token
+    ``j - shift``; a slot that takes none points at block 0."""
+    T = upd.shape[1]
+    bs = mask.shape[1] // pages.shape[1]
+    slot = jnp.broadcast_to(jnp.arange(mask.shape[1])[None, :], mask.shape)
+    blocks = jnp.where(mask, jnp.repeat(pages, bs, axis=1), 0)
+    tok = jnp.clip(slot - shift[:, None], 0, T - 1)
+    rows = jnp.take_along_axis(
+        upd, tok.reshape(tok.shape + (1,) * (upd.ndim - 2)), axis=1)
+    return (blocks.reshape(-1), (slot % bs).reshape(-1),
+            rows.reshape((-1,) + upd.shape[2:]))
+
+
+def _plain_write(plane, pages, shift, mask, upd, mesh=None):
+    blocks, offs, rows = _rows_of_plan(pages, shift, mask, upd)
+    return plane.at[blocks, :, offs].set(rows)
+
+
+def _row_write(plane, pages, shift, mask, upd, mesh=None):
+    """PR 29's form: one update per (token, head) through the free
+    ``[NB*KV*bs, hd]`` view."""
+    blocks, offs, rows = _rows_of_plan(pages, shift, mask, upd)
+    NB, KV, bs = plane.shape[:3]
+    at = (blocks[:, None] * KV + jnp.arange(KV)[None, :]) * bs + offs[:, None]
+    view = plane.reshape((NB * KV * bs,) + plane.shape[3:])
+    return view.at[at.reshape(-1)].set(
+        rows.reshape((-1,) + rows.shape[2:])).reshape(plane.shape)
+
+
+def _scatter_updates(text):
+    """Updates each ``scatter`` of the compiled program carries: the
+    product of its updates operand's dims outside the update window."""
+    counts = []
+    shapes = dict(re.findall(r"%?(\S+) = \S+?\[([\d,]*)\]", text))
+    for m in re.finditer(
+            r" scatter\((?:\S+ )?%?\S+, (?:\S+ )?%?\S+, (?:\S+ )?%?(\S+?)\)"
+            r", update_window_dims=\{([\d,]*)\}", text):
+        dims = [int(d) for d in shapes[m.group(1)].split(",") if d]
+        window = {int(d) for d in m.group(2).split(",") if d}
+        counts.append(math.prod(
+            d for i, d in enumerate(dims) if i not in window))
+    return counts
+
+
+def test_prefill_scatters_carry_no_more_updates_than_pages(
+        topo, no_compile_cache, monkeypatch):
+    # B x P updates a plane: 17 pages at T=256, block 16. The row form made
+    # 256 x 8 = 2048, and would be seen here
+    T, bs = 256, ENGINE.block_size
+    pages = (T + bs - 2) // bs + 1
+    counts = _scatter_updates(_prefill_text(topo, T=T))
+    cache_writes = [c for c in counts if c > 1]
+    assert len(cache_writes) >= 2 * CELL_LAYERS       # K and V a layer
+    assert max(counts) <= pages == 17
+    # the reader sees the row form where it is: one update a (token, head)
+    monkeypatch.setattr(M, "_kv_write", _row_write)
+    text = _prefill_text(topo, T=T)
+    assert _cache_sized_copies(text) == []
+    assert max(_scatter_updates(text)) >= T * CELL_MODEL["num_kv_heads"]
 
 
 def test_tp4_decode_keeps_the_cache_layout_and_adds_no_collective(

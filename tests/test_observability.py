@@ -870,6 +870,59 @@ async def test_decode_records_carry_kv_blocks_walked(
     assert (walked[128] == walked[512]) == (impl == "pallas")
 
 
+def test_kv_pages_written_counts_the_pages_rows_fall_on():
+    """By hand at block 16: (start, count) -> pages."""
+    from dynamo_tpu.observability.stepstats import kv_pages_written
+
+    by_hand = {(0, 256): 16, (5, 256): 17, (15, 2): 2, (16, 16): 1,
+               (31, 1): 1, (0, 0): 0, (100, 0): 0, (7, 9): 1, (7, 10): 2,
+               (3, 512): 33}
+    for row, pages in by_hand.items():
+        assert kv_pages_written([row], block_size=16) == pages, row
+    assert kv_pages_written(list(by_hand), block_size=16) \
+        == sum(by_hand.values())
+    assert kv_pages_written([], block_size=16) == 0
+
+
+@pytest.mark.anyio
+@pytest.mark.parametrize("spec_k", [0, 3])
+async def test_records_carry_kv_pages_written(spec_k, tmp_path, monkeypatch):
+    """Prefill, decode and spec records: the pages one layer's write
+    touches, against each row's start and count read back from the record
+    (one live row: ``context_sum`` = n x start + n(n + 1) / 2)."""
+    path = tmp_path / "steps.jsonl"
+    monkeypatch.setenv("DYNTPU_OBS_STEPSTATS_PATH", str(path))
+    kw = dict(spec_mode="ngram", spec_k=spec_k) if spec_k else {}
+    cfg = _tiny_engine_config(attention_impl="einsum", **kw)
+    engine = InferenceEngine(ModelConfig.tiny(), cfg)
+    await engine.start()
+    try:
+        # 21 tokens: a chunk of the 16 bucket and one that starts mid-cache
+        prompt = [5, 6, 7] * 7
+        assert len(await _run(engine, prompt, n=9)) == 9
+    finally:
+        await engine.stop()
+    with open(path) as fh:
+        records = load_records(fh)
+    bs = cfg.block_size
+    seen = set()
+    for r in records:
+        n = r["real_tokens"] // max(r["live_rows"], 1)
+        assert r["live_rows"] == 1 and n > 0
+        start, rem = divmod(r["context_sum"] - n * (n + 1) // 2, n)
+        assert rem == 0
+        if r["kind"] == DECODE:
+            want = n        # K steps, a page each
+        else:
+            assert r["kind"] == PREFILL or n == spec_k + 1
+            want = (start + n - 1) // bs - start // bs + 1
+        assert r["kv_pages_written"] == want, r
+        seen.add(r["kind"])
+    assert seen == {PREFILL, SPEC_VERIFY if spec_k else DECODE}
+    prefill = [r for r in records if r["kind"] == PREFILL]
+    assert [r["kv_pages_written"] for r in prefill] == [4, 2]
+
+
 def test_stepstats_jsonl_is_buffered_and_complete_on_close(tmp_path):
     """No flush per record (one a second at most), nothing lost on
     close()."""
