@@ -148,6 +148,17 @@ class PendingHandoff:
     deadline: float
 
 
+def _refuse_unpaged(engine, what: str) -> None:
+    """Both payloads carry K and V blocks; a model that keeps a latent or a
+    per-sequence state refuses them when the handler is built (a mocker
+    engine has no model)."""
+    cfg = getattr(engine, "model_config", None)
+    if cfg is not None:
+        from ..engine.model import refuse_unpaged
+
+        refuse_unpaged(cfg, what)
+
+
 class PrefillHandler(AsyncEngine):
     """Prefill worker: bounded prefill + KV push-back
     (ref: handlers.py:207 PrefillWorkerHandler)."""
@@ -155,6 +166,7 @@ class PrefillHandler(AsyncEngine):
     def __init__(self, engine: InferenceEngine,
                  plane: Optional[DevicePlane] = None,
                  config: Optional[DisaggConfig] = None):
+        _refuse_unpaged(engine, "the disaggregated prefill's KV push")
         self.engine = engine
         self.plane = plane if plane is not None else default_plane
         self.config = config or DisaggConfig()
@@ -638,6 +650,7 @@ class DecodeHandler(AsyncEngine):
         plane: Optional[DevicePlane] = None,
         store=None,
     ):
+        _refuse_unpaged(engine, "the disaggregated decode's KV injection")
         self.engine = engine
         self.prefill_client = prefill_client
         self.config = config or DisaggConfig()

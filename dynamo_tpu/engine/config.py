@@ -24,6 +24,14 @@ def _freeze(v: Any) -> Any:
     return v
 
 
+# what a layer kind keeps for a sequence (``ModelConfig.layer_types``):
+# K and V pages that grow with the context; one latent a token in pages of
+# the same block table; or a state of fixed size held by the sequence's seat
+KV_KINDS = ("full_attention", "sliding_attention")
+LATENT_KIND = "mla_attention"
+STATE_KIND = "linear_attention"
+
+
 class AttnKind(NamedTuple):
     """One kind of attention layer of a table: its stack of ``wq`` / ``wo``
     / gate, its mask and its rope."""
@@ -38,7 +46,7 @@ class LayerEntry(NamedTuple):
     """One row of the table: which stacks layer ``l`` reads, and where."""
 
     attn: int          # index into ``ModelConfig.attn_kinds``
-    attn_at: int       # row of that kind's stacks
+    attn_at: int       # row of that kind's stacks, and of its cache lists
     ffn: str           # "dense" | "sparse"
     ffn_at: int        # row of that FFN kind's stacks
 
@@ -79,6 +87,21 @@ class ModelConfig:
     # ``expert_shard["of"]`` equal ranges (parallel/moe.py: routed_ffn);
     # beside them one shared expert on every token.  "dense" layers are the
     # SwiGLU of ``intermediate_size``.
+    #
+    # Two more kinds keep no K and V (PR 34).  "mla_attention" (DeepSeek-V2):
+    # ``kv_lora_rank`` + ``qk_rope_head_dim`` values a token in a paged
+    # latent plane, queries of ``qk_nope_head_dim`` + ``qk_rope_head_dim``,
+    # values of ``v_head_dim``; its rope (``rope_parameters[kind]``) turns
+    # interleaved pairs where ``interleave`` is set.  "linear_attention"
+    # (Kimi Delta Attention): per sequence a float32 state ``[heads,
+    # head_dim, head_dim]`` and the last ``short_conv_kernel_size - 1``
+    # inputs of its convolution, whatever the context, held by the
+    # scheduler's seat; ``kda_lower_bound`` bounds a token's log decay and
+    # ``state_dtype`` is what the state is kept in between steps.
+    # The router scores by ``score_function`` ("softmax" | "sigmoid"); with
+    # ``n_group`` > 0 the choice is limited to the ``topk_group`` best
+    # groups, and ``moe_router_enable_expert_bias`` adds a bias to the
+    # scores for the choice alone (DeepSeek-V3's ``noaux_tc``).
     layer_types: Tuple[str, ...] = ()
     mlp_layer_types: Tuple[str, ...] = ()
     num_heads_per_layer: Tuple[int, ...] = ()
@@ -91,6 +114,17 @@ class ModelConfig:
     shared_expert_intermediate_size: int = 0
     norm_topk_prob: bool = True
     moe_routed_scaling_factor: float = 1.0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    short_conv_kernel_size: int = 0
+    kda_lower_bound: float = 0.0
+    state_dtype: str = "float32"
+    score_function: str = "softmax"
+    n_group: int = 0
+    topk_group: int = 0
+    moe_router_enable_expert_bias: bool = False
 
     def __post_init__(self):
         for name in ("layer_types", "mlp_layer_types", "num_heads_per_layer",
@@ -104,14 +138,31 @@ class ModelConfig:
                 raise ValueError(
                     f"{name} has {len(getattr(self, name))} entries for "
                     f"{L} layers")
-        if self.attn_gate not in ("", "per-head"):
+        if self.attn_gate not in ("", "per-head", "head_wise"):
             raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
         ropes = dict(self.rope_parameters or ())
         for kind in self.attn_kinds:
-            if kind.name not in ("full_attention", "sliding_attention"):
+            if kind.name not in KV_KINDS + (LATENT_KIND, STATE_KIND):
                 raise ValueError(f"unknown layer type {kind.name!r}")
+            if kind.name == STATE_KIND:
+                if (self.short_conv_kernel_size < 2
+                        or not self.kda_lower_bound < 0):
+                    raise ValueError(
+                        "linear_attention needs short_conv_kernel_size >= 2 "
+                        "and a negative kda_lower_bound")
+                if self.state_dtype not in ("float32", "bfloat16"):
+                    raise ValueError(
+                        f"unknown state_dtype {self.state_dtype!r}")
+                continue
             if kind.name not in ropes:
                 raise ValueError(f"rope_parameters has no {kind.name!r}")
+            if kind.name == LATENT_KIND:
+                if min(self.kv_lora_rank, self.qk_nope_head_dim,
+                       self.qk_rope_head_dim, self.v_head_dim) < 1:
+                    raise ValueError(
+                        "mla_attention needs kv_lora_rank, qk_nope_head_dim, "
+                        "qk_rope_head_dim and v_head_dim")
+                continue
             if kind.num_heads % self.num_kv_heads:
                 raise ValueError(
                     f"{kind.num_heads} query heads over "
@@ -134,6 +185,19 @@ class ModelConfig:
             if self.moe_intermediate_size < 1 \
                     or self.shared_expert_intermediate_size < 1:
                 raise ValueError("a sparse layer needs its expert widths")
+            if self.score_function not in ("softmax", "sigmoid"):
+                raise ValueError(
+                    f"unknown score_function {self.score_function!r}")
+            if self.n_group and (
+                    self.num_routed_experts % self.n_group
+                    or not 0 < self.topk_group <= self.n_group
+                    or self.num_experts_per_token
+                    > self.topk_group * (self.num_routed_experts
+                                         // self.n_group)):
+                raise ValueError(
+                    f"n_group {self.n_group} / topk_group {self.topk_group} "
+                    f"do not divide {self.num_routed_experts} routed experts "
+                    f"for {self.num_experts_per_token} a token")
 
     @property
     def head_dim_(self) -> int:
@@ -152,6 +216,24 @@ class ModelConfig:
     @property
     def has_routed_experts(self) -> bool:
         return "sparse" in self.mlp_layer_types
+
+    @property
+    def has_seat_state(self) -> bool:
+        """Some layer keeps a per-sequence state in the scheduler's seat:
+        nothing of a sequence's past can be skipped or moved without it."""
+        return STATE_KIND in self.layer_types
+
+    @property
+    def has_latent_cache(self) -> bool:
+        return LATENT_KIND in self.layer_types
+
+    @property
+    def cache_kinds(self) -> Tuple[str, ...]:
+        """What ``model.init_cache`` builds, by what the layers keep."""
+        kinds = set(self.layer_types) or {"full_attention"}
+        return tuple(name for name, on in (
+            ("kv", kinds & set(KV_KINDS)), ("latent", LATENT_KIND in kinds),
+            ("state", STATE_KIND in kinds)) if on)
 
     @property
     def attn_kinds(self) -> Tuple[AttnKind, ...]:
@@ -255,6 +337,9 @@ class ModelConfig:
         )
 
 
+DECODE_BUCKETS = (8, 16, 32, 64)
+
+
 @dataclass(frozen=True)
 class EngineConfig:
     """Serving-side engine knobs (vLLM-equivalent semantics)."""
@@ -267,8 +352,10 @@ class EngineConfig:
     max_model_len: int = 8192           # max tokens per sequence
     enable_prefix_caching: bool = True
     # decode batch sizes are padded up to the nearest bucket so XLA compiles
-    # a handful of programs, not one per batch size
-    decode_buckets: Tuple[int, ...] = (8, 16, 32, 64)
+    # a handful of programs, not one per batch size.  Left empty it is
+    # ``DECODE_BUCKETS``, and past 64 seats further powers of two up to
+    # ``max_num_seqs`` (128 seats add one rung)
+    decode_buckets: Tuple[int, ...] = ()
     # prefill chunk lengths likewise bucketed (powers of two)
     prefill_buckets: Tuple[int, ...] = (16, 32, 64, 128, 256, 512)
     # sharding: (dp, tp) or (dp, fsdp, tp) mesh axis sizes; (1, 1) =
@@ -385,6 +472,11 @@ class EngineConfig:
             mesh_devices *= n
         if self.pp_stages > 1 and mesh_devices > 1:
             raise ValueError("pp_stages and a (dp, tp) mesh are exclusive")
+        if not self.decode_buckets:
+            ladder = list(DECODE_BUCKETS)
+            while ladder[-1] < self.max_num_seqs:
+                ladder.append(2 * ladder[-1])
+            object.__setattr__(self, "decode_buckets", tuple(ladder))
         if self.max_num_seqs > max(self.decode_buckets):
             raise ValueError("max_num_seqs exceeds largest decode bucket")
         if self.spec_mode not in ("off", "ngram"):

@@ -1348,6 +1348,21 @@ class InferenceEngine(EngineCore):
         # recorder below when enabled, {} keeps every bucketing call on
         # the static grid
         self._ladders: Dict[str, Any] = {}
+        # what the engine may not assume of a sequence's memory: layers that
+        # keep something other than K and V pages, and a state a seat
+        self._unpaged = model_config.cache_kinds != ("kv",)
+        self._seat_state = model_config.has_seat_state
+        self._latent = model_config.has_latent_cache
+        if self._unpaged:
+            if engine_config.kv_dtype != "bf16":
+                raise ValueError(
+                    f"--kv-dtype {engine_config.kv_dtype} quantises K and V "
+                    f"pages; cache kinds "
+                    f"{list(model_config.cache_kinds)} have no quantised "
+                    f"form")
+            if engine_config.sp_prefill_threshold > 0:
+                model_lib.refuse_unpaged(
+                    model_config, "the sequence-parallel ring prefill")
         if engine_config.prefill_chunk_tokens > 0:
             pct = max(engine_config.prefill_chunk_tokens,
                       engine_config.block_size)
@@ -1372,6 +1387,7 @@ class InferenceEngine(EngineCore):
                 )
         super().__init__(engine_config)
         self.model_config = model_config
+        self.scheduler.seat_state = self._seat_state
         self.pp = engine_config.pp_stages
         if params is None and self.pp > 1:
             params = model_lib.init_params(
@@ -1475,15 +1491,7 @@ class InferenceEngine(EngineCore):
                 if engine_config.pipeline_depth > 1:
                     log.info("spec_mode=ngram forces pipeline_depth=1")
                 self.scheduler.spec_plan_window = self._spec_k + 1
-            repl = layout.replicated(self.mesh)
-            self._ctl = jax.device_put(
-                model_lib.init_ctl(
-                    engine_config, engine_config.max_num_seqs,
-                    self._ap_Wcap, seed=seed + 2,
-                    hist_cap=self._spec_hist_cap,
-                ),
-                repl,
-            )
+            self._ctl = self._fresh_ctl(seed)
             # host mirror of per-slot device state + seat map
             self._packed_prefill_fns: Dict[Tuple[int, int], Any] = {}
             self.num_prefill_dispatches = 0
@@ -1576,14 +1584,98 @@ class InferenceEngine(EngineCore):
         # (parallel/multihost.py); called on the executor thread
         self.step_sink: Optional[Callable[[str, Dict[str, np.ndarray]],
                                           None]] = None
-        if self.pp > 1:
-            # the transfer ops assume the per-layer list cache; disagg and
-            # KVBM on a pp engine are future work
+        if self._seat_state and self.pp == 1:
+            self._compile_step_programs(seed)
+        if self.pp > 1 or self._unpaged:
+            # the transfer ops assume the per-layer list cache of K and V
+            # pages; disagg and KVBM on a pp engine, or for a table that
+            # keeps a latent or a seat state, are future work
             self._kv_extract = self._kv_inject = None
         else:
             self._kv_extract, self._kv_inject = model_lib.make_kv_ops(
-                engine_config, self.mesh
+                model_config, engine_config, self.mesh
             )
+
+    def _fresh_ctl(self, seed: int):
+        """The decode windows' device-resident control state as a start
+        finds it: every seat dead."""
+        return jax.device_put(
+            model_lib.init_ctl(
+                self.config, self.config.max_num_seqs, self._ap_Wcap,
+                seed=seed + 2, hist_cap=self._spec_hist_cap,
+            ),
+            layout.replicated(self.mesh),
+        )
+
+    def _prefill_table_width(self, nb: int) -> int:
+        """The width ``W`` of the block table a prefill chunk that reaches
+        ``nb`` blocks is fed with: the next power of two, so that the
+        programs are O(log) of the context.  Where the model keeps a seat
+        state the programs are compiled at start-up, all of them
+        (:meth:`_compile_step_programs`), so the ladder is coarser: the
+        table of the largest chunk, then powers of four (32, 128, 512 at 16
+        tokens a block and chunks of 512): the width pads one gather and
+        the latent layers' keys, the chunk's length pads every layer."""
+        cap = self.config.max_blocks_per_seq
+        if not self._seat_state:
+            return _pow2_bucket(nb, cap)
+        w = -(-max(self.config.prefill_buckets) // self.config.block_size)
+        while w < min(nb, cap):
+            w *= 4
+        return min(w, cap)
+
+    def _compile_step_programs(self, seed: int) -> None:
+        """Compile every step program before the first request, for a model
+        some of whose layers keep a seat state.
+
+        Such a model gives no prefix hit, so nobody outside can start a
+        chunk where they like: a ``(T, W)`` prefill program is reached only
+        by a prompt of that very length, one compile (20-30 s on a v5e
+        host at the benchmark's widths) in the middle of serving for each.
+        So the engine reaches them itself: every prefill bucket at every
+        table width, every decode bucket, every size of the control
+        state's delta.  Each program runs once on a feed of pads (position
+        -1, the trash seat, block 0, nothing written to ``last_tok``),
+        which by :func:`model.forward`'s contract leaves the cache and the
+        seats as they were; the control state is made anew behind it."""
+        cfg = self.config
+        trash = cfg.max_num_seqs
+        t0 = time.monotonic()
+        key = jax.random.PRNGKey(0)
+        widths = sorted({self._prefill_table_width(nb)
+                         for nb in range(1, cfg.max_blocks_per_seq + 1)})
+        for T in cfg.prefill_buckets:
+            for W in widths:
+                fn = model_lib.make_packed_prefill_fn(
+                    self.model_config, cfg, T, W, self.mesh)
+                self._packed_prefill_fns[(T, W)] = fn
+                pint = np.zeros((1, T + W + model_lib.PP_SCALARS), np.int32)
+                pint[0, T + W + 2] = trash        # n = 0: every token a pad
+                self.cache, last_tok, _ = fn(
+                    self.params, self.cache, self._ctl["last_tok"], pint,
+                    key)
+                self._ctl = {**self._ctl, "last_tok": last_tok}
+        for B in cfg.decode_buckets:
+            self.cache, self._ctl, _ = self._ap_window_fn(
+                self.params, self.cache, self._ctl,
+                jax.device_put(np.full((B,), trash, np.int32)))
+        n = 1
+        while n <= _pow2_bucket(cfg.max_num_seqs):
+            di = np.zeros((n, model_lib.CTL_I32_FIELDS + self._ap_Wcap),
+                          np.int32)
+            di[:, 0], di[:, 5] = trash, -1
+            self._ctl = self._ap_delta_fn(self._ctl, di,
+                                          np.zeros((n, 2), np.float32))
+            n *= 2
+        # at start-up, before any request: the compiles are done when the
+        # last program has run
+        jax.block_until_ready(self.cache)  # dynalint: disable=DT102
+        self._ctl = self._fresh_ctl(seed)
+        log.info(
+            "seat state: %d prefill programs (T %r x W %r), %d decode "
+            "buckets and the control deltas compiled in %.1f s",
+            len(cfg.prefill_buckets) * len(widths), cfg.prefill_buckets,
+            widths, len(cfg.decode_buckets), time.monotonic() - t0)
 
     def device_report(self) -> dict:
         """What this engine actually runs on and with — read by the
@@ -1644,8 +1736,9 @@ class InferenceEngine(EngineCore):
         block) so XLA compiles O(log N) program variants, and the pad is
         sliced off."""
         if self._kv_extract is None:
-            raise RuntimeError("KV block transfer unsupported on a "
-                               "pipeline-parallel engine")
+            raise RuntimeError(
+                "KV block transfer unsupported on a pipeline-parallel "
+                "engine, and for a table that keeps a latent or a state")
         loop = asyncio.get_running_loop()
         n = len(block_ids)
         padded = np.zeros((_pow2_bucket(n),), np.int32)
@@ -1777,6 +1870,7 @@ class InferenceEngine(EngineCore):
         if self.pp > 1:
             raise RuntimeError("KVBM unsupported on a pipeline-parallel "
                                "engine (stacked cache has no transfer ops)")
+        model_lib.refuse_unpaged(self.model_config, "KVBM")
         from ..kvbm.manager import KvbmConfig, KvbmManager
 
         self.kvbm = KvbmManager(self, config or KvbmConfig(), remote=remote)
@@ -2100,7 +2194,7 @@ class InferenceEngine(EngineCore):
         bs = cfg.block_size
         nb = min((chunk.start + chunk.length + bs - 1) // bs,
                  len(seq.block_table))
-        W = _pow2_bucket(nb, cfg.max_blocks_per_seq)
+        W = self._prefill_table_width(nb)
         tokens = np.zeros((1, T), np.int32)
         positions = np.full((1, T), -1, np.int32)
         all_toks = seq.all_tokens()
@@ -2153,15 +2247,18 @@ class InferenceEngine(EngineCore):
             # host-known ints only — prompt tokens are goodput at dispatch;
             # context_sum = Σ attended context over the chunk's positions
             L, S = chunk.length, chunk.start
+            ctx = L * S + L * (L + 1) // 2
             obs_out.append(StepRecord(
                 kind=PREFILL, t_dispatch=time.monotonic(),
                 bucket=a["tokens"].shape[1],
                 rows=1, live_rows=1,
                 padded_tokens=a["tokens"].shape[1], real_tokens=L,
                 goodput_tokens=L,
-                context_sum=L * S + L * (L + 1) // 2,
+                context_sum=ctx,
                 kv_pages_written=kv_pages_written(
                     [(S, L)], block_size=cfg.block_size),
+                state_rows=int(self._seat_state),
+                latent_context_sum=ctx if self._latent else 0,
             ))
         slot = np.array(
             [seq.slot if seq.slot >= 0 else cfg.max_num_seqs], np.int32
@@ -2378,6 +2475,8 @@ class InferenceEngine(EngineCore):
                 kv_pages_written=kv_pages_written(
                     [(r.base, K) for r in rows], block_size=bs,
                 ) if spec else len(rows) * K,
+                state_rows=len(rows) * K if self._seat_state else 0,
+                latent_context_sum=ctx if self._latent else 0,
             ))
         fn = self._spec_window_fn if spec else self._ap_window_fn
         self.cache, self._ctl, samples = fn(

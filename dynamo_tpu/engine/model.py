@@ -70,7 +70,8 @@ from ..observability import compilewatch
 from ..parallel import layout
 from ..parallel.layout import AXIS_TP, SpecLayout, make_mesh
 from . import quant
-from .config import EngineConfig, ModelConfig
+from .config import (KV_KINDS, LATENT_KIND, STATE_KIND, EngineConfig,
+                     ModelConfig)
 
 Params = Dict[str, Any]
 Cache = Dict[str, jax.Array]
@@ -102,8 +103,17 @@ TABLE_SCOPES = (
     "moe_router",  # mlp norm, router matmul, softmax, top-k, sort
     "moe_experts",  # dispatch, grouped matmuls over the experts held, combine
     "moe_shared",  # the shared expert + residual
+    # a linear-attention (KDA) layer, whose memory is its seat's state (PR 34)
+    "kda_proj",    # attn norm + q / k / v / decay / beta matmuls
+    "kda_conv",    # the short convolution, its state, SiLU, the L2 norms
+    "kda_recurrent",  # T = 1: the token recurrence over the seats' states
+    "kda_chunk",   # T > 1: the chunked form over a prefill chunk
+    "kda_out",     # the per-head norm of the output
+    "attention_latent",  # "attention" of a layer that keeps a latent (MLA)
 )
 SCOPES += TABLE_SCOPES
+
+_LANES = 128
 
 
 # ------------------------------ init ------------------------------------
@@ -128,11 +138,21 @@ _expert_leaf = jax.jit(_normal, static_argnums=(1, 2, 3))
 EXPERT_LEAVES = ("expert_gate", "expert_up", "expert_down")
 
 
+def latent_width(cfg: ModelConfig) -> int:
+    """Values a token keeps in a latent page: ``kv_lora_rank`` +
+    ``qk_rope_head_dim``, in whole lane tiles (the chip stores a plane's
+    minor dim in tiles of 128 whatever it is declared as, and the kernel's
+    DMA takes whole tiles); what is past the two is zeros."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
+
+
 def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
     """Everything of a table's parameters but the routed experts: leaves
-    that every layer has alike are one ``[L, ...]`` stack, ``wq`` / ``wo``
-    / the gate one stack an attention kind, the dense FFN and the sparse
-    layers' router and shared expert one stack an FFN kind."""
+    that every layer has alike are one ``[L, ...]`` stack, ``wk`` / ``wv``
+    one stack over the layers that keep K and V, ``wq`` / ``wo`` / the gate
+    one stack an attention kind, a kind's own leaves (``kda_*``, ``mla_*``)
+    one stack each, the dense FFN and the sparse layers' router and shared
+    expert one stack an FFN kind."""
     dt = _dtype(cfg)
     hd, D, KV, L, V = (cfg.head_dim_, cfg.hidden_size, cfg.num_kv_heads,
                        cfg.num_layers, cfg.vocab_size)
@@ -140,20 +160,53 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
     layers: Dict[str, Any] = {
         "attn_norm": jnp.ones((L, D), dt),
         "mlp_norm": jnp.ones((L, D), dt),
-        "wk": _normal(next(key), (L, D, KV * hd), D, dt),
-        "wv": _normal(next(key), (L, D, KV * hd), D, dt),
-        "wq": {}, "wo": {},
     }
+    n_kv = sum(t in KV_KINDS for t in cfg.layer_types)
+    if n_kv:
+        layers["wk"] = _normal(next(key), (n_kv, D, KV * hd), D, dt)
+        layers["wv"] = _normal(next(key), (n_kv, D, KV * hd), D, dt)
+    layers["wq"], layers["wo"] = {}, {}
     if cfg.attn_gate:
         layers["w_attn_gate"] = {}
     for kind in cfg.attn_kinds:
         n, H = len(kind.layers), kind.num_heads
-        layers["wq"][kind.name] = _normal(next(key), (n, D, H * hd), D, dt)
+        q_dim, o_dim = hd, hd
+        if kind.name == LATENT_KIND:
+            q_dim = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            o_dim = cfg.v_head_dim
+        layers["wq"][kind.name] = _normal(
+            next(key), (n, D, H * q_dim), D, dt)
         layers["wo"][kind.name] = _normal(
-            next(key), (n, H * hd, D), H * hd, dt)
+            next(key), (n, H * o_dim, D), H * o_dim, dt)
         if cfg.attn_gate:
             layers["w_attn_gate"][kind.name] = _normal(
                 next(key), (n, D, H), D, dt)
+        if kind.name == STATE_KIND:
+            K = cfg.short_conv_kernel_size
+            for name in ("kda_wk", "kda_wv", "kda_wf"):
+                layers[name] = _normal(next(key), (n, D, H * hd), D, dt)
+            layers["kda_w_beta"] = _normal(next(key), (n, D, H), D, dt)
+            layers["kda_conv"] = _normal(next(key), (n, K, 3 * H * hd), K, dt)
+            # the decay's rate a head and bias a channel (assumed; float32
+            # leaves), drawn so that a token's decay lies near 1 as a
+            # trained layer's does and the publication's initialisation
+            # gives: with x Wf ~ N(0, 1) a channel's mean decay runs from
+            # ~0.9 (bias -4.5) to ~0.999 (bias -9), a memory of ten to a
+            # thousand tokens.  A state that forgets in two or three tokens
+            # would hide what the seat carries across steps and chunks
+            layers["kda_a_log"] = 0.2 * jax.random.normal(
+                next(key), (n, H), jnp.float32)
+            layers["kda_dt_bias"] = jax.random.uniform(
+                next(key), (n, H * hd), jnp.float32, -9.0, -4.5)
+            layers["kda_o_norm"] = jnp.ones((n, hd), dt)
+        if kind.name == LATENT_KIND:
+            r = cfg.kv_lora_rank
+            layers["mla_wdkv"] = _normal(
+                next(key), (n, D, r + cfg.qk_rope_head_dim), D, dt)
+            layers["mla_kv_norm"] = jnp.ones((n, r), dt)
+            layers["mla_wukv"] = _normal(
+                next(key),
+                (n, r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), r, dt)
     n_dense = cfg.mlp_layer_types.count("dense")
     n_sparse = L - n_dense
     if n_dense:
@@ -175,6 +228,11 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = _normal(next(key), (D, V), D, dt)
+    if n_sparse and cfg.moe_router_enable_expert_bias:
+        # drawn behind every other leaf: small beside the scores' spread,
+        # and not zero, so that the choice's bias is not a no-op (assumed)
+        layers["router_bias"] = 0.05 * jax.random.normal(
+            next(key), (n_sparse, cfg.num_routed_experts), jnp.float32)
     return params
 
 
@@ -262,47 +320,85 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
 
 
 def init_cache(cfg: ModelConfig, eng: EngineConfig) -> Cache:
-    """Paged KV cache, block-major and head-contiguous: per-layer arrays of
-    ``[num_blocks, KV, block_size, hd]`` (lists under ``"k"``/``"v"``).
+    """What the layers keep between steps, one list of per-layer arrays a
+    kind of memory (``ModelConfig.cache_kinds``); a model has the keys of
+    the kinds it has and no others.
 
-    One (block, head) tile is a contiguous ``bs*hd`` run — the DMA granule
-    the Pallas decode kernel streams HBM→VMEM, and the transfer unit for
-    disagg/KVBM block movement. Per-layer arrays (not one stacked [L, …]
-    array) are the TPU-critical choice: each layer's buffer is donated and
-    scatter-updated IN PLACE. A stacked cache threaded through ``lax.scan``
-    forces XLA to slice-out + update-in the whole cache every step —
-    measured ~90 ms/step of pure copies on v5e for a 1B model.
+    **K and V pages** (``"k"`` / ``"v"``; every layer of a model without a
+    table, a table's full and sliding layers): ``[num_blocks, KV,
+    block_size, hd]``, block-major and head-contiguous.  One (block, head)
+    tile is a contiguous ``bs*hd`` run — the DMA granule the Pallas decode
+    kernel streams HBM→VMEM, and the transfer unit for disagg/KVBM block
+    movement.  Quantized (``kv_dtype`` int8 / fp8) they are 1 byte an
+    element beside per-(slot, head) float32 scale planes ``"ks"`` / ``"vs"``.
 
-    In place also depends on HOW a step writes: only through
+    **Latent pages** (``"latent"``; a table's ``mla_attention`` layers):
+    ``[num_blocks, 1, block_size, latent_width]``, one vector a token that
+    is key and value of every head at once (the normed ``kv_lora_rank``
+    values, then the roped ``qk_rope_head_dim``, then zeros up to whole
+    lane tiles), in the pages of the same block table: the sequence's
+    blocks are counted once whatever kinds its layers are.
+
+    **Seat state** (``"state"`` / ``"conv"``; ``linear_attention`` layers):
+    ``[S + 1, H, hd, hd]`` in ``cfg.state_dtype`` and ``[S + 1, K - 1, 3 *
+    H * hd]``, indexed by the scheduler's seat (``SchedSeq.slot``, ``S =
+    max_num_seqs``): a sequence's state does not grow with its context, so
+    it has no pages.  Row ``S`` is the trash seat of pad rows.
+
+    Per-layer arrays (not one stacked [L, …] array) are the TPU-critical
+    choice for all three: each layer's buffer is donated and updated IN
+    PLACE. A stacked cache threaded through ``lax.scan`` forces XLA to
+    slice-out + update-in the whole cache every step — measured ~90 ms/step
+    of pure copies on v5e for a 1B model.
+
+    In place also depends on HOW a step writes: pages only through
     :func:`_kv_write`, whose scatter indexes the leading dim alone (whole
-    pages) and so accepts this row-major layout. A
-    ``.at[block, :, off].set`` on the 4-D array makes XLA copy the whole
-    layer to ``{3,1,2,0}`` and back around every write (module header).
+    pages) and so accepts this row-major layout (a ``.at[block, :,
+    off].set`` on the 4-D array makes XLA copy the whole layer to
+    ``{3,1,2,0}`` and back around every write: module header); seat rows
+    by a gather and a scatter on the leading dim.
 
     Block 0 is the null block and is made here: zeros, and zero scales
     for int8 / fp8, which dequantize to exact zeros. No step program
     modifies it — a write that points at it (a pad, a dead row) puts its
     own bytes back (:func:`_kv_write`); only a padded ``inject`` of
-    :func:`make_kv_ops` can still land there."""
+    :func:`make_kv_ops` can still land there.  The trash seat likewise: a
+    pad or dead row writes back what it read."""
     dt = _dtype(cfg)
-    shape = (eng.num_blocks, cfg.num_kv_heads, eng.block_size, cfg.head_dim_)
-    if quant.is_quantized(eng.kv_dtype):
-        # quantized pages (1 byte/elem) plus per-(slot, head) f32 scale
-        # planes; block 0's zero scales dequantize to exact zeros
-        dt = quant.storage_dtype(eng.kv_dtype)
-        sshape = shape[:-1]
-        return {
-            "k": [jnp.zeros(shape, dt) for _ in range(cfg.num_layers)],
-            "v": [jnp.zeros(shape, dt) for _ in range(cfg.num_layers)],
-            "ks": [jnp.zeros(sshape, jnp.float32)
-                   for _ in range(cfg.num_layers)],
-            "vs": [jnp.zeros(sshape, jnp.float32)
-                   for _ in range(cfg.num_layers)],
-        }
-    return {
-        "k": [jnp.zeros(shape, dt) for _ in range(cfg.num_layers)],
-        "v": [jnp.zeros(shape, dt) for _ in range(cfg.num_layers)],
-    }
+    kinds = cfg.cache_kinds
+    cache: Cache = {}
+    if "kv" in kinds:
+        n_kv = (sum(t in KV_KINDS for t in cfg.layer_types)
+                if cfg.has_table else cfg.num_layers)
+        shape = (eng.num_blocks, cfg.num_kv_heads, eng.block_size,
+                 cfg.head_dim_)
+        page_dt = dt
+        if quant.is_quantized(eng.kv_dtype):
+            # quantized pages (1 byte/elem) plus per-(slot, head) f32 scale
+            # planes; block 0's zero scales dequantize to exact zeros
+            page_dt = quant.storage_dtype(eng.kv_dtype)
+        cache["k"] = [jnp.zeros(shape, page_dt) for _ in range(n_kv)]
+        cache["v"] = [jnp.zeros(shape, page_dt) for _ in range(n_kv)]
+        if quant.is_quantized(eng.kv_dtype):
+            cache["ks"] = [jnp.zeros(shape[:-1], jnp.float32)
+                           for _ in range(n_kv)]
+            cache["vs"] = [jnp.zeros(shape[:-1], jnp.float32)
+                           for _ in range(n_kv)]
+    for kind in cfg.attn_kinds if cfg.has_table else ():
+        n, H, hd = len(kind.layers), kind.num_heads, cfg.head_dim_
+        if kind.name == LATENT_KIND:
+            cache["latent"] = [
+                jnp.zeros((eng.num_blocks, 1, eng.block_size,
+                           latent_width(cfg)), dt) for _ in range(n)]
+        if kind.name == STATE_KIND:
+            S = eng.max_num_seqs
+            cache["state"] = [
+                jnp.zeros((S + 1, H, hd, hd), jnp.dtype(cfg.state_dtype))
+                for _ in range(n)]
+            cache["conv"] = [
+                jnp.zeros((S + 1, cfg.short_conv_kernel_size - 1,
+                           3 * H * hd), dt) for _ in range(n)]
+    return cache
 
 
 # ---------------------------- shardings ----------------------------------
@@ -335,18 +431,34 @@ def refuse_table(cfg: ModelConfig, *, what: str = "",
     and ``quant.quantize_params`` knows the one-kind tree."""
     if not cfg.has_table:
         return
+    kinds = sorted(set(cfg.layer_types))
     if what:
         raise ValueError(
             f"{what} has no path for a table of layer kinds "
-            f"(layer_types {sorted(set(cfg.layer_types))})")
+            f"(layer_types {kinds})")
     if _multi(mesh):
         raise ValueError(
-            f"a table of layer kinds runs on --mesh 1,1; mesh "
-            f"{dict(mesh.shape)} has no sharding specs for it")
+            f"a table of layer kinds (layer_types {kinds}) runs on "
+            f"--mesh 1,1; mesh {dict(mesh.shape)} has no sharding specs "
+            f"for it")
     if weight_dtype != "bf16":
         raise ValueError(
-            f"a table of layer kinds is served in the model's dtype; "
-            f"--weight-dtype {weight_dtype} has no quantiser for it")
+            f"a table of layer kinds (layer_types {kinds}) is served in "
+            f"the model's dtype; --weight-dtype {weight_dtype} has no "
+            f"quantiser for it")
+
+
+def refuse_unpaged(cfg: ModelConfig, what: str) -> None:
+    """What moves, skips or rolls back a sequence's memory knows K and V
+    pages only.  A table with a latent page or a seat state (``cache_kinds``
+    beyond "kv") refuses it when it is built: a block without its
+    sequence's state is no prefix, and a state has no earlier version to
+    roll back to."""
+    if cfg.cache_kinds != ("kv",):
+        raise ValueError(
+            f"{what} has no path for what layer_types "
+            f"{sorted(set(cfg.layer_types))} keep (cache kinds "
+            f"{list(cfg.cache_kinds)}): it moves K and V pages only")
 
 
 def _multi(mesh: Optional[Mesh]) -> bool:
@@ -414,7 +526,13 @@ def init_params_sharded(rng: jax.Array, cfg: ModelConfig, mesh: Mesh,
 def init_cache_sharded(cfg: ModelConfig, eng: EngineConfig,
                        mesh: Mesh) -> Cache:
     """:func:`init_cache` allocated under ``cache_shardings`` (zeros are
-    born on the device that keeps them; see :func:`init_params_sharded`)."""
+    born on the device that keeps them; see :func:`init_params_sharded`).
+    A table with other memory than K and V pages is one device's
+    (``refuse_table``) and has no specs to be born under."""
+    if cfg.cache_kinds != ("kv",):
+        refuse_table(cfg, mesh=mesh)
+        return jax.jit(lambda: init_cache(cfg, eng),
+                       out_shardings=layout.replicated(mesh))()
     fn = jax.jit(
         lambda: init_cache(cfg, eng),
         out_shardings=cache_shardings(mesh, cfg, eng.kv_dtype),
@@ -484,10 +602,12 @@ def rope_frequencies(rope: Dict[str, Any], head_dim: int
     return inv.astype(np.float32), float(scale)
 
 
-def _rotate(x: jax.Array, positions: jax.Array, freqs, scale: float = 1.0
-            ) -> jax.Array:
+def _rotate(x: jax.Array, positions: jax.Array, freqs, scale: float = 1.0,
+            interleave: bool = False) -> jax.Array:
     """Rotate-half on the leading ``2 * len(freqs)`` dims of each head of
-    ``x [B, T, Hx, hd]``; the rest passes through."""
+    ``x [B, T, Hx, hd]``; the rest passes through.  ``interleave``: the
+    pairs that turn together are dims ``(2i, 2i + 1)``, not ``(i, i +
+    half)``."""
     hd = x.shape[-1]
     half = freqs.shape[0]
     pos = jnp.maximum(positions, 0).astype(jnp.float32)  # [B, T]
@@ -496,6 +616,16 @@ def _rotate(x: jax.Array, positions: jax.Array, freqs, scale: float = 1.0
     sin = jnp.sin(angles)[:, :, None, :]
     if scale != 1.0:
         cos, sin = cos * scale, sin * scale
+    if interleave:
+        pairs = x[..., :2 * half].astype(jnp.float32).reshape(
+            x.shape[:-1] + (half, 2))
+        xf1, xf2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
+                        axis=-1).reshape(x.shape[:-1] + (2 * half,)
+                                         ).astype(x.dtype)
+        if 2 * half < hd:
+            return jnp.concatenate([out, x[..., 2 * half:]], axis=-1)
+        return out
     x1, x2 = x[..., :half], x[..., half:2 * half]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(
@@ -517,7 +647,8 @@ def _rope_kind(x: jax.Array, positions: jax.Array,
                rope: Dict[str, Any]) -> jax.Array:
     """The rope of a table's attention kind (:func:`rope_frequencies`)."""
     freqs, scale = rope_frequencies(rope, x.shape[-1])
-    return _rotate(x, positions, freqs, scale)
+    return _rotate(x, positions, freqs, scale,
+                   interleave=bool(rope.get("interleave")))
 
 
 def _mm(x: jax.Array, w: Any) -> jax.Array:
@@ -558,20 +689,28 @@ class _TableSlice:
     layer ``li``'s row of whichever stack holds ``name`` for it."""
 
     _FFN = {"dense": ("w_gate", "w_up", "w_down"),
-            "sparse": ("w_router", "shared_gate", "shared_up", "shared_down")
-            + EXPERT_LEAVES}
+            "sparse": ("w_router", "router_bias", "shared_gate", "shared_up",
+                       "shared_down") + EXPERT_LEAVES}
 
-    def __init__(self, stacked: Dict[str, Any], li: int, entry, kind):
+    def __init__(self, stacked: Dict[str, Any], li: int, entry, kind,
+                 kv_at: int):
         self._stacked, self._li = stacked, li
-        self._entry, self._kind = entry, kind
+        self._entry, self._kind, self._kv_at = entry, kind, kv_at
 
     def __getitem__(self, name: str) -> Any:
         w = self._stacked[name]
         if name in ("wq", "wo", "w_attn_gate"):
             return w[self._kind.name][self._entry.attn_at]
+        if name.startswith(("kda_", "mla_")):      # a kind's own leaves
+            return w[self._entry.attn_at]
+        if name in ("wk", "wv"):
+            return w[self._kv_at]
         if name in self._FFN[self._entry.ffn]:
             return w[self._entry.ffn_at]
         return w[self._li]
+
+    def get(self, name: str) -> Any:
+        return self[name] if name in self._stacked else None
 
 
 def layer_params(cfg: ModelConfig, stacked: Dict[str, Any], li: int):
@@ -579,8 +718,17 @@ def layer_params(cfg: ModelConfig, stacked: Dict[str, Any], li: int):
     entry = cfg.layer_table[li]
     kind = cfg.attn_kinds[entry.attn]
     if cfg.has_table:
-        return _TableSlice(stacked, li, entry, kind), entry, kind
+        return _TableSlice(stacked, li, entry, kind,
+                           kv_layer_index(cfg, li)), entry, kind
     return _LayerSlice(stacked, li), entry, kind
+
+
+def kv_layer_index(cfg: ModelConfig, li: int) -> int:
+    """Layer ``li``'s place among the layers that keep K and V pages: its
+    row of ``wk`` / ``wv`` and of the cache's ``"k"`` / ``"v"`` lists."""
+    if not cfg.has_table:
+        return li
+    return sum(t in KV_KINDS for t in cfg.layer_types[:li])
 
 
 def _qkv_proj(x: jax.Array, p: Any, H: int, KV: int, hd: int
@@ -706,6 +854,9 @@ def _config_kv_tile(cfg: ModelConfig, eng: EngineConfig,
     """``_walk_tile`` of the cache this configuration builds."""
     page_dtype = quant.storage_dtype(eng.kv_dtype) \
         if quant.is_quantized(eng.kv_dtype) else _dtype(cfg)
+    if "kv" not in cfg.cache_kinds and cfg.has_latent_cache:
+        # the only pages are latent ones: one "KV head" a token wide
+        return _walk_tile(eng, mesh, 1, latent_width(cfg), page_dtype)
     return _walk_tile(eng, mesh, cfg.num_kv_heads, cfg.head_dim_, page_dtype)
 
 
@@ -730,12 +881,26 @@ def attention_choice(cfg: ModelConfig, eng: EngineConfig,
     impls = {cls: resolve_attention_impl(eng, cls)
              for cls in ("decode", "spec", "prefill")}
     kv_tile = _config_kv_tile(cfg, eng, mesh)
-    return {
+    choice = {
         "impl": impls,
         "tiles": {cls: [int(cls == "decode"), kv_tile]
                   if impl == "pallas" else [0, 0]
                   for cls, impl in impls.items()},
     }
+    if cfg.has_latent_cache:
+        # a latent layer: decode absorbed (through the kernel where decode
+        # runs it, else over the gathered table), T > 1 expanded over the
+        # gathered table whatever the K / V layers run
+        choice["latent"] = {
+            "decode": f"{impls['decode']}-absorbed",
+            "spec": "einsum-expanded", "prefill": "einsum-expanded"}
+    if cfg.has_seat_state:
+        # the token recurrence over the seat pool is a kernel where decode
+        # runs its kernels; a chunk runs the chunked form in XLA
+        choice["linear"] = {
+            "decode": ("pallas" if impls["decode"] == "pallas" else "xla")
+            + "-recurrent", "prefill": "xla-chunked"}
+    return choice
 
 
 # What each attention shape class resolved to the last time a step program
@@ -1084,6 +1249,182 @@ def _layer_attention(
 # caller runs these, so a layer kind is written once.
 
 
+def latent_inputs(cfg: ModelConfig, kind, p: Any, h: jax.Array,
+                  positions: jax.Array):
+    """A latent (MLA) layer's normed input ``x``, the queries' two parts
+    ``q_nope [B, T, H, qk_nope]`` and the roped ``q_pe [B, T, H, qk_rope]``,
+    and what the token keeps: ``[B, T, 1, latent_width]`` = the normed
+    latent ``c``, the roped key part that all heads share, zeros."""
+    B, T, _ = h.shape
+    r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope("qkv_proj"):
+        x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
+        q, ckpe = jax.lax.optimization_barrier(
+            (_mm(x, p["wq"]), _mm(x, p["mla_wdkv"])))
+        q = q.reshape(B, T, kind.num_heads, dn + dr)
+        c = _rms_norm(ckpe[..., :r], p["mla_kv_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("rope"):
+        rope = cfg.rope_of(kind)
+        q_pe = _rope_kind(q[..., dn:], positions, rope)
+        k_pe = _rope_kind(ckpe[:, :, None, r:], positions, rope)
+    latent = jnp.concatenate([c[:, :, None, :], k_pe], axis=-1)
+    latent = jnp.pad(latent, ((0, 0),) * 3
+                     + ((0, latent_width(cfg) - r - dr),))
+    return x, q[..., :dn], q_pe, latent
+
+
+def _latent_up(cfg: ModelConfig, p: Any, heads: int):
+    """``Wukv`` of a latent layer as its two halves: ``[r, H, qk_nope]``
+    that makes a latent a head's key, ``[r, H, v_head_dim]`` its value."""
+    w = p["mla_wukv"].reshape(cfg.kv_lora_rank, heads,
+                              cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def latent_attention(cfg: ModelConfig, p: Any, q_nope: jax.Array,
+                     q_pe: jax.Array, ctx: jax.Array, positions: jax.Array,
+                     absorbed: bool) -> jax.Array:
+    """Causal attention of ``q_nope`` / ``q_pe`` ``[B, T, H, .]`` over the
+    latents ``ctx [B, S, latent_width]`` of positions ``0 .. S - 1``;
+    returns ``[B, T, H, v_head_dim]``.
+
+    Expanded (a prefill chunk): every latent is multiplied out to its
+    heads' keys and values, ``k_nope | v = c Wukv``.  Absorbed (decode):
+    the query is taken to the latent instead, ``q_nope Wuk^T``, the scores
+    and the weighted sum run against ``c`` itself, all heads over the one
+    vector a token, and only the result goes through ``Wuv``: a token's 576
+    values are read once, not multiplied out ``H`` times.  Same
+    mathematics; the Pallas decode path (:func:`_paged_latent_decode`) is
+    the absorbed form over pages."""
+    B, T, H, dn = q_nope.shape
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    S = ctx.shape[1]
+    wuk, wuv = _latent_up(cfg, p, H)
+    c, k_pe = ctx[..., :r], ctx[..., r:r + dr]
+    f32 = dict(preferred_element_type=jnp.float32)
+    scores = jnp.einsum("bthd,bsd->bhts", q_pe, k_pe, **f32)
+    if absorbed:
+        q_lat = jnp.einsum("bthd,rhd->bthr", q_nope, wuk,
+                           **f32).astype(ctx.dtype)
+        scores = scores + jnp.einsum("bthr,bsr->bhts", q_lat, c, **f32)
+    else:
+        k_nope = jnp.einsum("bsr,rhd->bshd", c, wuk, **f32).astype(ctx.dtype)
+        scores = scores + jnp.einsum("bthd,bshd->bhts", q_nope, k_nope,
+                                     **f32)
+    scores = scores * (dn + dr) ** -0.5
+    valid = jnp.arange(S)[None, None, :] <= positions[:, :, None]  # [B,T,S]
+    probs = jax.nn.softmax(jnp.where(valid[:, None], scores, -1e30), axis=-1)
+    probs = probs.astype(ctx.dtype)
+    if absorbed:
+        o_lat = jnp.einsum("bhts,bsr->bthr", probs, c, **f32)
+        out = jnp.einsum("bthr,rhd->bthd", o_lat.astype(ctx.dtype), wuv,
+                         **f32)
+    else:
+        v = jnp.einsum("bsr,rhd->bshd", c, wuv, **f32).astype(ctx.dtype)
+        out = jnp.einsum("bhts,bshd->bthd", probs, v, **f32)
+    return out.astype(q_nope.dtype)
+
+
+def _paged_latent_decode(cfg: ModelConfig, eng: EngineConfig, mesh, p: Any,
+                         q_nope, q_pe, plane, block_tables, seq_lens):
+    """The absorbed decode step through the Pallas paged kernel: ``H``
+    query heads over the one latent "KV head", the page at once key (all of
+    it) and value (its first ``kv_lora_rank`` dims), walked as far as each
+    row's context and no further."""
+    from ..ops.paged_attention import paged_attention_decode
+
+    B, _, H, dn = q_nope.shape
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    wuk, wuv = _latent_up(cfg, p, H)
+    f32 = dict(preferred_element_type=jnp.float32)
+    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], wuk,
+                       **f32).astype(plane.dtype)
+    q_abs = jnp.concatenate([q_lat, q_pe[:, 0]], axis=-1)
+    q_abs = jnp.pad(q_abs, ((0, 0), (0, 0), (0, plane.shape[-1] - r - dr)))
+    interpret = pallas_interpret(mesh)
+    kv_tile = _walk_tile(eng, mesh, 1, plane.shape[-1], plane.dtype)
+    _note_attention("decode", "pallas", interpret, (1, kv_tile))
+    o_lat = paged_attention_decode(
+        q_abs, plane, plane, block_tables, seq_lens,
+        block_size=eng.block_size, kv_tile=kv_tile, interpret=interpret,
+        v_width=r, scale=float((dn + dr) ** -0.5))
+    return jnp.einsum("bhr,rhd->bhd", o_lat, wuv,
+                      **f32).astype(q_nope.dtype)[:, None]
+
+
+def linear_attention(cfg: ModelConfig, kind, p: Any, h: jax.Array,
+                     positions: jax.Array, seats: jax.Array,
+                     state: jax.Array, conv: jax.Array,
+                     kernel: Optional[bool] = None):
+    """One linear-attention (KDA) layer up to its output norm: the normed
+    input ``x``, ``o [B, T, H, hd]``, and the layer's state pools with the
+    rows' seats advanced (``ops/delta_rule.py`` has the mathematics).
+
+    A row reads its seat ``seats [B]``: the state ``[H, hd, hd]`` and the
+    convolution's last inputs.  A row whose chunk starts at position 0
+    starts from zeros instead, whatever an earlier holder left in the seat:
+    a new request, and a preempted one that is recomputed from its first
+    token.  Pads (position -1) bring decay 1 and step size 0, so a row's
+    pad tail, a dead row and the trash seat's pad rows put back what they
+    read.
+
+    ``kernel`` (None: the XLA form; else whether the kernel is interpreted):
+    the decode step's recurrence runs as the Pallas kernel over the pool
+    itself (``delta_rule.kda_step_seats``), where the decode class runs its
+    kernels at all and the pool is float32."""
+    from ..ops import delta_rule as dr
+
+    B, T, _ = h.shape
+    H, hd = kind.num_heads, cfg.head_dim_
+    valid = positions >= 0
+    n = jnp.sum(valid, axis=1).astype(jnp.int32)
+    fresh = positions[:, 0] == 0
+    with jax.named_scope("kda_proj"):
+        x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
+        q, k, v, f, b = jax.lax.optimization_barrier(
+            (_mm(x, p["wq"]), _mm(x, p["kda_wk"]), _mm(x, p["kda_wv"]),
+             _mm(x, p["kda_wf"]), _mm(x, p["kda_w_beta"])))
+    with jax.named_scope("kda_conv"):
+        prev = jnp.where(fresh[:, None, None], 0,
+                         jnp.take(conv, seats, axis=0))
+        y, nxt = dr.short_conv(jnp.concatenate([q, k, v], axis=-1), prev,
+                               p["kda_conv"], n)
+        conv = conv.at[seats].set(nxt.astype(conv.dtype))
+        y = jax.nn.silu(y).reshape(B, T, 3, H, hd)
+        q = dr.l2norm(y[:, :, 0]) * hd ** -0.5
+        k = dr.l2norm(y[:, :, 1])
+        v = y[:, :, 2]
+        rate = jnp.exp(p["kda_a_log"].astype(jnp.float32))[:, None]
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(
+            rate * (f.astype(jnp.float32) + p["kda_dt_bias"]
+                    ).reshape(B, T, H, hd))
+        g = jnp.where(valid[..., None, None], g, 0.0)
+        beta = jnp.where(valid[..., None],
+                         jax.nn.sigmoid(b.astype(jnp.float32)), 0.0)
+    with jax.named_scope("kda_recurrent" if T == 1 else "kda_chunk"):
+        if T == 1 and kernel is not None and state.dtype == jnp.float32:
+            o, state = dr.kda_step_seats(
+                state, seats, fresh, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                beta[:, 0], interpret=kernel)
+            o = o[:, None]
+        else:
+            s0 = jnp.where(fresh[:, None, None, None], 0,
+                           jnp.take(state, seats, axis=0)
+                           ).astype(jnp.float32)
+            if T == 1:
+                o, s1 = dr.kda_step(s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0])
+                o = o[:, None]
+            else:
+                o, s1 = dr.kda_chunked(s0, q, k, v, g, beta)
+            state = state.at[seats].set(s1.astype(state.dtype))
+    with jax.named_scope("kda_out"):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        o = (o * p["kda_o_norm"].astype(jnp.float32)).astype(h.dtype)
+    return x, o, state, conv
+
+
 def attn_inputs(cfg: ModelConfig, kind, p: Any, h: jax.Array,
                 positions: jax.Array):
     """Normed input ``x`` and the roped ``q [B, T, H, hd]``, ``k``, ``v
@@ -1133,6 +1474,12 @@ def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
 
         with jax.named_scope("moe_router"):
             x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
+        # what a router beyond the softmax top-k is told (route's keywords)
+        router = {}
+        if cfg.score_function != "softmax" or cfg.n_group:
+            router = dict(score=cfg.score_function, n_group=cfg.n_group,
+                          topk_group=cfg.topk_group,
+                          bias=p.get("router_bias"))
         routed, stats, chosen = routed_ffn(
             x.reshape(B * T, D), p["w_router"], p["expert_gate"],
             p["expert_up"], p["expert_down"],
@@ -1141,7 +1488,7 @@ def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
             renormalise=cfg.norm_topk_prob,
             scale=cfg.moe_routed_scaling_factor,
             live=None if live is None else live.reshape(B * T),
-            interpret=interpret,
+            interpret=interpret, **router,
         )
         if stats_out is not None:
             stats_out.append(stats)
@@ -1189,8 +1536,14 @@ def forward(
     mm_mask: Optional[jax.Array] = None,    # [B, T] True = use mm_embeds
     moe_stats: Optional[list] = None,
     moe_choices: Optional[list] = None,
+    seats: Optional[jax.Array] = None,      # [B] int32 seat of each row
 ) -> Tuple[Cache, jax.Array]:
     """Run the transformer over a token chunk, updating the paged cache.
+
+    ``seats`` names each row's seat in the state pools of a table's
+    linear-attention layers (``SchedSeq.slot``; the trash seat ``S`` for a
+    pad row) and is required where the model has such layers
+    (:func:`linear_attention`); nothing else reads it.
 
     ``moe_stats``, where a list is given, gains one int32 ``[4]`` a sparse
     layer of a table (``parallel.moe.MOE_STATS``), and ``moe_choices`` the
@@ -1213,6 +1566,10 @@ def forward(
     use_ring = ring_mesh is not None and T > 1
     if use_ring:
         refuse_table(cfg, what="the sequence-parallel ring prefill")
+    if cfg.has_seat_state and seats is None:
+        raise ValueError("a model with linear-attention layers is run with "
+                         "the rows' seats: its state is a sequence's, not "
+                         "a position's")
     ring_lay = SpecLayout.for_mesh(ring_mesh) if use_ring else None
     if use_ring and ring_lay.seq_axes() is None:
         use_ring = ring_lay = None  # single-device "ring" is dense attention
@@ -1276,6 +1633,9 @@ def forward(
     new_v: list = []
     new_ks: list = []
     new_vs: list = []
+    new_latent: list = []
+    new_state: list = []
+    new_conv: list = []
     stacked = params["layers"]
     interpret = pallas_interpret(mesh) if cfg.has_routed_experts else False
     live = positions >= 0 if cfg.has_routed_experts else None
@@ -1284,9 +1644,42 @@ def forward(
               if use_ring else None)
     for li in range(cfg.num_layers):
         p, entry, kind = layer_params(cfg, stacked, li)
-        lk, lv = cache["k"][li], cache["v"][li]   # [NB, KV, bs, hd]
-        lks = cache["ks"][li] if kv_quant else None  # [NB, KV, bs] f32
-        lvs = cache["vs"][li] if kv_quant else None
+        if kind.name in (STATE_KIND, LATENT_KIND):
+            at = entry.attn_at
+            if kind.name == STATE_KIND:
+                x, attn, state, conv = linear_attention(
+                    cfg, kind, p, h, positions, seats,
+                    cache["state"][at], cache["conv"][at],
+                    kernel=pallas_interpret(mesh) if use_pallas else None)
+                new_state.append(state)
+                new_conv.append(conv)
+            else:
+                x, q_nope, q_pe, latent = latent_inputs(
+                    cfg, kind, p, h, positions)
+                with jax.named_scope("kv_write"):
+                    plane = _kv_write(cache["latent"][at], *kv_pages, latent,
+                                      mesh)
+                with jax.named_scope("attention_latent"):
+                    if use_pallas and T == 1:
+                        attn = _paged_latent_decode(
+                            cfg, eng, mesh, p, q_nope, q_pe, plane,
+                            block_tables, seq_lens)
+                    else:
+                        B, W = block_tables.shape
+                        ctx = jnp.take(
+                            plane, block_tables.reshape(-1), axis=0
+                        ).reshape(B, W * bs, plane.shape[-1])
+                        attn = latent_attention(cfg, p, q_nope, q_pe, ctx,
+                                                positions, absorbed=T == 1)
+                new_latent.append(plane)
+            h = attn_output(cfg, p, h, x, attn)
+            h = ffn(cfg, entry, p, h, live=live, interpret=interpret,
+                    stats_out=moe_stats, choices_out=moe_choices)
+            continue
+        kv_at = kv_layer_index(cfg, li)
+        lk, lv = cache["k"][kv_at], cache["v"][kv_at]   # [NB, KV, bs, hd]
+        lks = cache["ks"][kv_at] if kv_quant else None  # [NB, KV, bs] f32
+        lvs = cache["vs"][kv_at] if kv_quant else None
 
         x, q, k, v = attn_inputs(cfg, kind, p, h, positions)
         if use_ring:
@@ -1355,10 +1748,11 @@ def forward(
 
     with jax.named_scope("final_norm"):
         h = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    out_cache: Cache = {"k": new_k, "v": new_v}
-    if kv_quant:
-        out_cache["ks"] = new_ks
-        out_cache["vs"] = new_vs
+    out_cache: Cache = {
+        key: rows for key, rows in (
+            ("k", new_k), ("v", new_v), ("ks", new_ks), ("vs", new_vs),
+            ("latent", new_latent), ("state", new_state), ("conv", new_conv))
+        if rows}
     return out_cache, h
 
 
@@ -1424,6 +1818,8 @@ def make_encode_fn(cfg: ModelConfig, mesh: Optional[Mesh] = None,
 
     ``mesh`` pins params to the canonical layout (pooled embeddings are
     tiny and come back replicated)."""
+    refuse_unpaged(cfg, "the encoder (/v1/embeddings attends a chunk's own "
+                        "K and V)")
     kw: Dict[str, Any] = {}
     if _multi(mesh):
         lay = SpecLayout.for_mesh(mesh)
@@ -1552,7 +1948,8 @@ def raw_step_fn(cfg: ModelConfig, eng: EngineConfig,
 
     Signature:
       step(params, cache, tokens[B,T], positions[B,T], block_tables[B,W],
-           last_idx[B], rng, temperature[B], top_k[B], top_p[B], seeds[B])
+           last_idx[B], rng, temperature[B], top_k[B], top_p[B], seeds[B],
+           seats[B] = None)
         -> (cache, sampled[B])
 
     ``last_idx[b]`` selects which chunk position's logits to sample (the last
@@ -1560,10 +1957,10 @@ def raw_step_fn(cfg: ModelConfig, eng: EngineConfig,
     """
 
     def step(params, cache, tokens, positions, block_tables,
-             last_idx, rng, temperature, top_k, top_p, seeds):
+             last_idx, rng, temperature, top_k, top_p, seeds, seats=None):
         cache, h = forward(
             cfg, eng, params, cache, tokens, positions, block_tables,
-            mesh=mesh, ring_mesh=ring_mesh,
+            mesh=mesh, ring_mesh=ring_mesh, seats=seats,
         )
         logits, pos_last = _last_logits(cfg, params, h, positions, last_idx)
         sampled = sample(
@@ -1648,7 +2045,7 @@ def raw_decode_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
                 pos_eff = jnp.where(pos < valid_until[:, None], pos, -1)
             cache, h = forward(
                 cfg, eng, params, cache, tok, pos_eff, block_tables,
-                mesh=mesh,
+                mesh=mesh, seats=slot_ids,
             )
             with jax.named_scope("lm_head"):
                 h_last = h[:, 0]
@@ -1814,7 +2211,7 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
                 pos_eff = jnp.where(pos < vu[:, None], pos, -1)
             cache, h = forward(
                 cfg, eng, params, cache, tok, pos_eff, tables, mesh=mesh,
-                moe_stats=moe_stats,
+                moe_stats=moe_stats, seats=rows,
             )
             with jax.named_scope("lm_head"):
                 h_last = h[:, 0]
@@ -2012,6 +2409,7 @@ def make_spec_fns(cfg: ModelConfig, eng: EngineConfig, k: int,
                   ngram_min: int, ngram_max: int,
                   mesh: Optional[Mesh] = None):
     """(spec_window_fn, hist_fill_fn) jitted with cache/ctl donated."""
+    refuse_unpaged(cfg, "speculative decoding (a rejected draft rolls back)")
     window = compilewatch.label(
         jax.jit(
             raw_spec_window_fn(cfg, eng, k, ngram_min, ngram_max, mesh),
@@ -2053,7 +2451,7 @@ def raw_ring_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
                 temperature, top_k, top_p, seeds):
         cache, sampled = base(
             params, cache, tokens, positions, block_tables, last_idx,
-            rng, temperature, top_k, top_p, seeds,
+            rng, temperature, top_k, top_p, seeds, seats=slot_ids,
         )
         with jax.named_scope("ctl"):
             S = last_tok.shape[0] - 1  # trash slot
@@ -2101,7 +2499,7 @@ def raw_packed_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
             last_idx = jnp.maximum(n - 1, 0)[None]
         cache, sampled = base(
             params, cache, tokens, positions, tables, last_idx, rng,
-            temp, top_k, tp, seed,
+            temp, top_k, tp, seed, seats=slot[None],
         )
         with jax.named_scope("ctl"):
             S = last_tok.shape[0] - 1
@@ -2150,6 +2548,7 @@ def make_mm_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
     vision embeddings over placeholder positions. Compiled lazily — only
     engines that actually see multimodal requests pay for it; decode
     never needs it (placeholders live in the prompt)."""
+    refuse_unpaged(cfg, "the multimodal prefill")
 
     def step(params, cache, tokens, positions, block_tables,
              last_idx, rng, temperature, top_k, top_p, seeds,
@@ -2177,6 +2576,7 @@ def make_mm_ring_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
                             mesh: Optional[Mesh]):
     """Ring-posting multimodal prefill (pipelined serving path): the mm
     step plus the ``last_tok`` write of ``make_ring_prefill_fn``."""
+    refuse_unpaged(cfg, "the multimodal prefill")
 
     def step(params, cache, last_tok, tokens, positions, block_tables,
              last_idx, slot_ids, write_mask, rng,
@@ -2241,15 +2641,20 @@ def make_sp_ring_prefill_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Mesh):
 # the same jitted fns ride ICI when source and destination share a mesh.
 
 
-def cache_payload_keys(eng: EngineConfig) -> Tuple[str, ...]:
+def cache_payload_keys(cfg: ModelConfig,
+                       eng: EngineConfig) -> Tuple[str, ...]:
     """The cache dict keys a block transfer must carry: quantized caches
-    add the per-(slot, head) scale planes to the K/V pages."""
+    add the per-(slot, head) scale planes to the K/V pages.  A table some
+    of whose layers keep neither K nor V is refused: its blocks are not
+    the whole of a sequence's memory."""
+    refuse_unpaged(cfg, "a KV block transfer")
     if quant.is_quantized(eng.kv_dtype):
         return ("k", "v", "ks", "vs")
     return ("k", "v")
 
 
-def make_kv_ops(eng: EngineConfig, mesh: Optional[Mesh] = None):
+def make_kv_ops(cfg: ModelConfig, eng: EngineConfig,
+                mesh: Optional[Mesh] = None):
     """(extract, inject) jitted block gather/scatter over the paged cache.
 
     extract(cache, block_ids[N]) -> {"k","v"}: [L, N, KV, bs, hd]
@@ -2263,7 +2668,7 @@ def make_kv_ops(eng: EngineConfig, mesh: Optional[Mesh] = None):
     the cache back to its serving layout, so the disagg handoff agrees
     with the cache about head placement on both ends.
     """
-    keys = cache_payload_keys(eng)
+    keys = cache_payload_keys(cfg, eng)
     kw_ex: Dict[str, Any] = {}
     kw_in: Dict[str, Any] = {}
     if _multi(mesh):

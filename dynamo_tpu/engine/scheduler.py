@@ -377,6 +377,11 @@ class Scheduler:
         # prefix_vs_index cross-check compares the two)
         self.on_prefix_match: Optional[
             Callable[[List[int], List[int]], None]] = None
+        # set by the engine for a model some of whose layers keep a state
+        # per sequence (``ModelConfig.has_seat_state``): cached blocks are
+        # then not a prefix anyone can start behind, because nobody holds
+        # the state the skipped tokens would have left
+        self.seat_state = False
 
     # -- admission --
 
@@ -659,8 +664,11 @@ class Scheduler:
         seq.num_sealed_blocks = max(seq.num_sealed_blocks, sealable)
 
     def _match_prefix(self, seq: SchedSeq) -> None:
-        """Prefix-cache lookup at admission (chained sequence hashes)."""
-        if not self.config.enable_prefix_caching or seq.num_computed:
+        """Prefix-cache lookup at admission (chained sequence hashes).  No
+        hit where the model keeps a per-sequence state (``seat_state``):
+        every sequence is computed from its first token."""
+        if (not self.config.enable_prefix_caching or seq.num_computed
+                or self.seat_state):
             return
         assert seq.token_seq is not None
         bs = self.config.block_size

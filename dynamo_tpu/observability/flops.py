@@ -21,6 +21,10 @@ from __future__ import annotations
 
 from typing import Optional
 
+# engine/config.py's names of the kinds (importing them from there would
+# import the engine, which imports this module)
+STATE_KIND, LATENT_KIND = "linear_attention", "mla_attention"
+
 # Peak dense bf16 FLOP/s per chip by device kind (public spec sheets).
 PEAK_FLOPS = {
     "v4": 275e12,
@@ -116,9 +120,21 @@ def _table_param_count(cfg, active: bool = False) -> int:
     if active or not cfg.tie_word_embeddings:
         total += D * V                                   # head
     for entry in cfg.layer_table:
-        H = kinds[entry.attn].num_heads
+        kind = kinds[entry.attn]
+        H = kind.num_heads
         total += 2 * D                                   # the two norms
-        total += 2 * D * H * hd + 2 * D * KV * hd        # wq, wo, wk, wv
+        if kind.name == STATE_KIND:
+            # wq, k, v, decay in; wo out; beta; the conv's taps; the
+            # decay's rate and bias; the output norm
+            total += 5 * D * H * hd + D * H + H + H * hd + hd
+            total += cfg.short_conv_kernel_size * 3 * H * hd
+        elif kind.name == LATENT_KIND:
+            r, dn, dr, dvh = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                              cfg.qk_rope_head_dim, cfg.v_head_dim)
+            total += D * H * (dn + dr) + D * (r + dr) + r  # wq, wdkv, norm
+            total += r * H * (dn + dvh) + H * dvh * D      # wukv, wo
+        else:
+            total += 2 * D * H * hd + 2 * D * KV * hd    # wq, wo, wk, wv
         if cfg.attn_gate:
             total += D * H
         if entry.ffn == "dense":
@@ -129,6 +145,8 @@ def _table_param_count(cfg, active: bool = False) -> int:
             experts = (cfg.num_experts_per_token * cfg.num_experts
                        / cfg.num_routed_experts)
         total += D * cfg.num_routed_experts              # router
+        if cfg.moe_router_enable_expert_bias and not active:
+            total += cfg.num_routed_experts              # its choice bias
         total += int(experts * 3 * D * cfg.moe_intermediate_size)
         total += 3 * D * cfg.shared_expert_intermediate_size
     return total
@@ -168,9 +186,21 @@ class FlopsModel:
         # (a table: each layer its kind's heads; a window's layers are
         # counted at the whole context, which is all a record carries for a
         # prefill: an upper bound there)
-        self.attn_coef = 4.0 * model_cfg.head_dim_ * sum(
-            model_cfg.attn_kinds[e.attn].num_heads
-            for e in model_cfg.layer_table)
+        # a latent layer's decode is absorbed: a position costs its heads
+        # the latent's width for the score and its rank for the sum; a
+        # linear-attention layer attends no context (its recurrence is 7
+        # passes over a head's state a token, whatever the position)
+        kinds = model_cfg.attn_kinds
+        self.attn_coef = 0.0
+        for e in model_cfg.layer_table:
+            name, H = kinds[e.attn].name, kinds[e.attn].num_heads
+            if name == STATE_KIND:
+                self.matmul_per_token += 7.0 * H * model_cfg.head_dim_ ** 2
+            elif name == LATENT_KIND:
+                self.attn_coef += 2.0 * H * (2 * model_cfg.kv_lora_rank
+                                             + model_cfg.qk_rope_head_dim)
+            else:
+                self.attn_coef += 4.0 * model_cfg.head_dim_ * H
 
     def step_flops(self, tokens: float, context_sum: float) -> float:
         return self.matmul_per_token * tokens + self.attn_coef * context_sum

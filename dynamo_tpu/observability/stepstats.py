@@ -101,6 +101,13 @@ class StepRecord:
     # and min(context, window) summed over the same rows
     kv_blocks_walked_window: int = 0
     context_sum_window: int = 0
+    # a table with linear-attention layers: seats whose state ONE such
+    # layer reads and writes in this record (a live row in each of a decode
+    # window's K steps, a prefill chunk's one row); with latent (MLA)
+    # layers: positions ONE such layer attends, context_sum's count (0
+    # where the model has no such layer)
+    state_rows: int = 0
+    latent_context_sum: int = 0
     # decode records of a table with routed experts, summed over the
     # window's steps and sparse layers, read from the window's own fetch
     # (model.moe_stats_row): (token, expert) pairs of live rows, those of

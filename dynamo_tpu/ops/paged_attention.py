@@ -128,6 +128,7 @@ def _ragged_kernel(
     scale: float,
     quantized: bool = False,
     window: int = 0,
+    v_width: int = 0,
 ):
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
@@ -183,7 +184,9 @@ def _ragged_kernel(
                 # its last entry: those positions are >= ctx_len, masked
                 blk = tables_ref[r_, jnp.minimum(pos // bs, W - 1)]
                 off = pos % bs
-            planes = [(k_hbm, k_buf, piece < bs), (v_hbm, v_buf, piece < bs)]
+            planes = [(k_hbm, k_buf, piece < bs)]
+            if not v_width:          # a latent page is its own value
+                planes.append((v_hbm, v_buf, piece < bs))
             if quantized:
                 # a page's scales are one row, whole whatever the piece
                 planes += [(ks_hbm, ks_buf, False), (vs_hbm, vs_buf, False)]
@@ -238,11 +241,16 @@ def _ragged_kernel(
         fetch(r, i, slot, wait=True)
 
         KV, TQ, G, hd = q_ref.shape
-        q = q_ref[...].astype(jnp.float32).reshape(KV, TQ * G, hd)
+        # a latent page is attended by every head at once (G = all of
+        # them): its products run in the page's own dtype with float32
+        # accumulation, or the float32 passes of H x 640 x 256 a tile are
+        # the kernel's time (2.75 ms a step at 128 rows: PERF.md, PR 34)
+        work = q_ref.dtype if v_width else jnp.float32
+        q = q_ref[...].astype(work).reshape(KV, TQ * G, hd)
         ks, vs = [], []
         for j in range(pieces):
-            kj = k_buf[slot, j].astype(jnp.float32)      # [KV, piece, hd]
-            vj = v_buf[slot, j].astype(jnp.float32)
+            kj = k_buf[slot, j].astype(work)             # [KV, piece, hd]
+            vj = kj if v_width else v_buf[slot, j].astype(jnp.float32)
             if quantized:
                 # quantized pages: dequantize with the per-(slot, head)
                 # scales BEFORE the trash-slot zeroing below, so arbitrary
@@ -275,8 +283,12 @@ def _ragged_kernel(
             jnp.int32, (1, kv_tile, 1), dimension=1
         )                                                # [1, kv_tile, 1]
         kvalid = kpos < ctx_len
-        k = jnp.where(kvalid, k, 0.0)
-        v = jnp.where(kvalid, v, 0.0)
+        if v_width:
+            k = jnp.where(kvalid, k, jnp.zeros((), k.dtype))
+            v = k[..., :v_width]
+        else:
+            k = jnp.where(kvalid, k, 0.0)
+            v = jnp.where(kvalid, v, 0.0)
 
         # batched over KV heads: [KV, TQ*G, hd] x [KV, kv_tile, hd] -> s
         s = jax.lax.dot_general(
@@ -309,7 +321,8 @@ def _ragged_kernel(
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
+            p.astype(v.dtype) if v_width else p, v,
+            (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )                                                # [KV, TQ*G, hd]
         return carry
@@ -336,7 +349,7 @@ def _ragged_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("block_size", "q_tile", "kv_tile", "max_q_len",
-                     "interpret", "window"),
+                     "interpret", "window", "v_width", "scale"),
 )
 def paged_attention_ragged(
     q: jax.Array,             # [Tq, H, hd] flat packed queries
@@ -355,6 +368,8 @@ def paged_attention_ragged(
     k_scale: jax.Array | None = None,  # [num_blocks, KV, bs] f32
     v_scale: jax.Array | None = None,  # [num_blocks, KV, bs] f32
     window: int = 0,
+    v_width: int = 0,
+    scale: float = 0.0,
 ) -> jax.Array:
     """Ragged paged attention over heterogeneous-length query rows.
 
@@ -395,12 +410,21 @@ def paged_attention_ragged(
     KV tile that holds the lowest key its first query sees instead of at
     tile 0: pages wholly behind the window are never fetched.  ``0`` traces
     the kernel without any of it.
+
+    ``v_width > 0`` (a latent cache, MLA's absorbed decode): a page holds
+    one vector a token that is key and value at once; the values are its
+    first ``v_width`` dims, ``v_cache`` is not read (pass the same plane),
+    and the result is ``[Tq, H, v_width]``.  The page is taken whole, so its
+    width need not fill whole lane tiles and nothing is padded.  ``scale``
+    (0: ``hd ** -0.5``) multiplies the scores.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
     quantized = k_scale is not None
+    if v_width and quantized:
+        raise ValueError("a latent page has no quantized form")
     hd_model = q.shape[-1]
-    if not interpret and hd_model % _LANES:
+    if not interpret and hd_model % _LANES and not v_width:
         # Mosaic slices an HBM operand only in whole 128-lane tiles: a
         # narrower head is zero-padded to the next one (zeros add nothing
         # to a score or an output) in the same copy that already hands
@@ -443,9 +467,12 @@ def paged_attention_ragged(
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [pl.BlockSpec((KV, q_tile, G, hd), q_map), hbm, hbm]
     operands = [q4, k_cache, v_cache]
+    hd_out = v_width or hd
     scratch = [
         pltpu.VMEM((2, pieces, KV, piece, hd), k_cache.dtype),
-        pltpu.VMEM((2, pieces, KV, piece, hd), v_cache.dtype),
+        # unread where the K page is the value too
+        pltpu.VMEM((2, pieces, KV, piece, hd) if not v_width
+                   else (2, 1, KV, 8, _LANES), v_cache.dtype),
     ]
     if quantized:
         ks_rows, vs_rows = _scale_rows(k_scale), _scale_rows(v_scale)
@@ -460,25 +487,26 @@ def paged_attention_ragged(
         pltpu.SMEM((1,), jnp.int32),        # slot of this step's first tile
         pltpu.VMEM((KV, q_tile * G, 1), jnp.float32),
         pltpu.VMEM((KV, q_tile * G, 1), jnp.float32),
-        pltpu.VMEM((KV, q_tile * G, hd), jnp.float32),
+        pltpu.VMEM((KV, q_tile * G, hd_out), jnp.float32),
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(R, num_t),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((KV, q_tile, G, hd), q_map),
+        out_specs=pl.BlockSpec((KV, q_tile, G, hd_out), q_map),
         scratch_shapes=scratch,
     )
 
     kernel = functools.partial(
         _ragged_kernel, block_size=bs, kv_tile=kv_tile, q_tile=q_tile,
-        scale=1.0 / (hd_model ** 0.5), quantized=quantized, window=window,
+        scale=scale or 1.0 / (hd_model ** 0.5), quantized=quantized,
+        window=window, v_width=v_width,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((KV, Tq, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((KV, Tq, G, hd_out), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # in order: a step's last iteration starts the next step's DMA
             dimension_semantics=("arbitrary", "arbitrary"),
@@ -486,9 +514,13 @@ def paged_attention_ragged(
         ),
         interpret=interpret,
     )(q_start, q_len, ctx_len, block_tables, *operands)
-    return out.transpose(1, 0, 2, 3).reshape(Tq, H, hd)[..., :hd_model]
+    out = out.transpose(1, 0, 2, 3).reshape(Tq, H, hd_out)
+    return out if v_width else out[..., :hd_model]
+
+
 @functools.partial(
-    jax.jit, static_argnames=("block_size", "kv_tile", "interpret", "window")
+    jax.jit, static_argnames=("block_size", "kv_tile", "interpret", "window",
+                              "v_width", "scale")
 )
 def paged_attention_decode(
     q: jax.Array,          # [B, H, hd]
@@ -503,6 +535,8 @@ def paged_attention_decode(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     window: int = 0,
+    v_width: int = 0,
+    scale: float = 0.0,
 ) -> jax.Array:
     """Single-token-per-sequence paged attention.  Returns ``[B, H, hd]``.
 
@@ -521,5 +555,5 @@ def paged_attention_decode(
         q, k_cache, v_cache, block_tables, q_start, q_len, seq_lens,
         block_size=block_size, max_q_len=1, q_tile=1, kv_tile=kv_tile,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale,
-        window=window,
+        window=window, v_width=v_width, scale=scale,
     )

@@ -110,15 +110,37 @@ def _tile(k: int, n: int):
 
 
 def route(x: jax.Array, w_router: jax.Array, *, top_k: int,
-          renormalise: bool, scale: float):
-    """Softmax router in float32 over ALL routed experts: the ``top_k``
-    experts of each token ``[N, k]`` and their weights on the experts'
-    outputs, ``scale * s_e / sum_top s`` (the sum over the chosen of all
-    routed experts, never over the ones held)."""
+          renormalise: bool, scale: float, score: str = "softmax",
+          bias: jax.Array | None = None, n_group: int = 0,
+          topk_group: int = 0):
+    """The router in float32 over ALL routed experts: the ``top_k`` experts
+    of each token ``[N, k]`` and their weights on the experts' outputs,
+    ``scale * s_e / sum_top s`` (the sum over the chosen of all routed
+    experts, never over the ones held).
+
+    ``score``: the scores ``s`` are a softmax over the experts, or a
+    sigmoid of each logit.  ``bias [E]`` is added to the scores for the
+    choice alone; the weights are the chosen experts' own scores.
+    ``n_group`` > 0 limits the choice to ``topk_group`` of ``n_group``
+    equal ranges of experts, a group scored by the sum of its two largest
+    (biased) scores: the devices a token may visit, where a group is a
+    device's (DeepSeek-V3's ``noaux_tc``)."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_vals, top_idx = jax.lax.top_k(probs, top_k)
+    probs = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+             else jax.nn.sigmoid(logits))
+    pick = probs if bias is None else probs + bias.astype(jnp.float32)
+    if n_group:
+        N, E = pick.shape
+        groups = pick.reshape(N, n_group, E // n_group)
+        best2 = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(best2, topk_group)             # [N, tg]
+        kept = jnp.zeros((N, n_group), bool).at[
+            jnp.arange(N)[:, None], keep].set(True)
+        pick = jnp.where(jnp.repeat(kept, E // n_group, axis=1),
+                         pick, -jnp.inf)
+    _, top_idx = jax.lax.top_k(pick, top_k)
+    top_vals = jnp.take_along_axis(probs, top_idx, axis=1)
     if renormalise:
         top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
     return top_idx, top_vals * scale
@@ -137,6 +159,7 @@ def routed_ffn(
     scale: float = 1.0,
     live: jax.Array | None = None,   # [N] bool; dead rows route nowhere
     interpret: bool = False,
+    **router,              # route's score / bias / n_group / topk_group
 ):
     """What the experts held here add for the tokens routed to them:
     ``sum_{e in top_k(x) and held} w_e * swiglu_e(x)``, ``[N, D]``; the
@@ -164,7 +187,8 @@ def routed_ffn(
 
     with jax.named_scope("moe_router"):
         top_idx, top_w = route(x, w_router, top_k=top_k,
-                               renormalise=renormalise, scale=scale)
+                               renormalise=renormalise, scale=scale,
+                               **router)
         expert = top_idx.reshape(pairs)
         alive = (jnp.ones((pairs,), bool) if live is None
                  else jnp.repeat(live, top_k))

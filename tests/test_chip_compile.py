@@ -171,6 +171,52 @@ def test_mistral_decode_grid_tiles_compile(
     assert 2 * 2 * 512 * 8 * 128 * 2 < VMEM_LIMIT_BYTES // 8
 
 
+@pytest.mark.parametrize("B", [8, 128])
+def test_the_seat_recurrence_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, B):
+    """``delta_rule.kda_step_seats`` at ling-3.0-flash-ep8's widths (32
+    heads of 128, 129 seats): a row's state is 2 MB of VMEM in and out, and
+    the row's vectors become columns by one 128 x 128 transpose."""
+    from dynamo_tpu.ops.delta_rule import kda_step_seats
+
+    H, d, seats = 32, 128, 128
+
+    def S(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    text = jax.jit(kda_step_seats).lower(
+        S((seats + 1, H, d, d)), S((B,), jnp.int32), S((B,), jnp.bool_),
+        S((B, H, d)), S((B, H, d)), S((B, H, d)), S((B, H, d)), S((B, H)),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_the_latent_decode_walk_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache):
+    """The paged decode kernel over a page that is key and value at once
+    (``v_width``): 32 absorbed query heads over one latent "KV head" 640
+    wide, 128 rows, the cell's 61440 blocks."""
+    from dynamo_tpu.ops.paged_attention import (
+        default_kv_tile, paged_attention_decode,
+    )
+
+    B, H, wide, bs = 128, 32, 640, ENGINE.block_size
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def launch(q, plane, tables, lens):
+        return paged_attention_decode(
+            q, plane, plane, tables, lens, block_size=bs,
+            kv_tile=default_kv_tile(bs, 1, wide, jnp.bfloat16),
+            interpret=False, v_width=512, scale=192 ** -0.5)
+
+    text = jax.jit(launch).lower(
+        S((B, H, wide), jnp.bfloat16), S((61440, 1, bs, wide), jnp.bfloat16),
+        S((B, 512), jnp.int32), S((B,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
 # ---- whole step programs at the cells' shapes: the cache keeps its layout ---
 #
 # ``forward`` writes K and V whole pages at a time, by a scatter whose indexed

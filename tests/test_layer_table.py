@@ -4,7 +4,11 @@ experts without drops on the share of them held here — the tiny
 configuration of ``benchmarks/chip/configs/laguna-s-2.1-ep2.json``
 (``rehearse.model``: window 16, 4 / 6 query heads over 2 KV heads, 16 routed
 experts of which 8 held, 4 a token) against ``references/laguna.py`` and
-against plain numpy.  CPU, float32; Pallas kernels interpreted."""
+against plain numpy.  CPU, float32; Pallas kernels interpreted.
+
+The cases that hold for any table run over ``TABLES``: since PR 34 also the
+tiny configuration of ``ling-3.0-flash-ep8.json``, whose layers keep no K
+and V; what only that table has is in ``test_hybrid_table.py``."""
 
 import dataclasses
 import hashlib
@@ -28,43 +32,74 @@ from dynamo_tpu.ops.paged_attention import (
 from dynamo_tpu.parallel import moe
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIG = os.path.join(ROOT, "benchmarks", "chip", "configs",
-                      "laguna-s-2.1-ep2.json")
-SEED = 2500000417
+# a table's configuration and reference, the seed of its tests, the
+# sequences ``compare`` runs at this size (laguna: 4.5 windows in chunks of
+# 32; ling: chunks of 64, the last 22 tokens), its sparse layers, and what
+# its engine is built with beside ``_engine_config``'s defaults (a table
+# with seat state compiles every program when built: one bucket suffices)
+TABLES = {
+    "laguna": dict(config="laguna-s-2.1-ep2", reference="laguna",
+                   seed=2500000417, compare=dict(T=72, chunk=32),
+                   sparse_layers=4, engine={}),
+    "ling": dict(config="ling-3.0-flash-ep8", reference="ling",
+                 seed=3400000417, compare=dict(T=150, chunk=64),
+                 sparse_layers=6, engine=dict(prefill_buckets=(64,))),
+}
+SEED = TABLES["laguna"]["seed"]
 
 
-def _file() -> dict:
-    with open(CONFIG) as f:
+def _file(table: str = "laguna") -> dict:
+    with open(os.path.join(ROOT, "benchmarks", "chip", "configs",
+                           TABLES[table]["config"] + ".json")) as f:
         return json.load(f)
 
 
-def _model(rehearse: bool = True, **replace) -> ModelConfig:
+def _model(rehearse: bool = True, table: str = "laguna",
+           **replace) -> ModelConfig:
     from benchmarks.chip import worker_launch as WL
 
-    cfg = WL.model_config_from(_file(), rehearse)
+    cfg = WL.model_config_from(_file(table), rehearse)
     return dataclasses.replace(cfg, **replace) if replace else cfg
 
 
-def _reference():
-    path = os.path.join(ROOT, "benchmarks", "chip", "references", "laguna.py")
-    spec = importlib.util.spec_from_file_location("laguna_reference", path)
+def _reference(table: str = "laguna"):
+    name = TABLES[table]["reference"]
+    path = os.path.join(ROOT, "benchmarks", "chip", "references",
+                        name + ".py")
+    spec = importlib.util.spec_from_file_location(name + "_reference", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
-def _engine_config(**kw) -> EngineConfig:
+def _engine_config(table: str = "laguna", **kw) -> EngineConfig:
     base = dict(num_blocks=96, max_model_len=256, max_num_batched_tokens=64,
                 prefill_buckets=(16, 32, 64), decode_buckets=(8,),
                 max_num_seqs=8, decode_steps=1, pipeline_depth=1,
                 attention_impl="pallas")
+    base.update(TABLES[table]["engine"])
     base.update(kw)
     return EngineConfig(**base)
 
 
 @pytest.fixture(scope="module")
-def engine():
-    return InferenceEngine(_model(), _engine_config(), seed=SEED)
+def engines():
+    """``engines(table)``: the table's engine, built once a module."""
+    built = {}
+
+    def get(table: str):
+        if table not in built:
+            built[table] = InferenceEngine(
+                _model(table=table), _engine_config(table),
+                seed=TABLES[table]["seed"])
+        return built[table]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def engine(engines):
+    return engines("laguna")
 
 
 # ------------------------- the table itself ---------------------------------
@@ -86,23 +121,36 @@ def test_table_reads_the_published_keys():
     assert second.experts_held == (128, 128)
 
 
-@pytest.mark.parametrize("change,names", [
-    ({"layer_types": ("full_attention",) * 4}, ["layer_types", "4", "5"]),
-    ({"num_heads_per_layer": (4, 6, 6, 5, 4)}, ["layer 3", "5", "6"]),
-    ({"num_heads_per_layer": (4, 5, 5, 5, 4)}, ["5 query heads", "2 KV"]),
-    ({"sliding_window": 0}, ["sliding_window"]),
-    ({"attn_gate": "per-channel"}, ["per-channel"]),
-    ({"num_experts": 16}, ["num_experts 16", "16 routed"]),
-    ({"num_experts_per_token": 17}, ["num_experts_per_token"]),
-    ({"moe_intermediate_size": 0}, ["expert widths"]),
-    ({"mlp_layer_types": ("dense", "sparse", "sparse", "sparse", "mixed")},
-     ["mixed"]),
-    ({"rope_parameters": {"full_attention": {"rope_theta": 1e4}}},
+@pytest.mark.parametrize("table,change,names", [
+    ("laguna", {"layer_types": ("full_attention",) * 4},
+     ["layer_types", "4", "5"]),
+    ("laguna", {"num_heads_per_layer": (4, 6, 6, 5, 4)},
+     ["layer 3", "5", "6"]),
+    ("laguna", {"num_heads_per_layer": (4, 5, 5, 5, 4)},
+     ["5 query heads", "2 KV"]),
+    ("laguna", {"sliding_window": 0}, ["sliding_window"]),
+    ("laguna", {"attn_gate": "per-channel"}, ["per-channel"]),
+    ("laguna", {"num_experts": 16}, ["num_experts 16", "16 routed"]),
+    ("laguna", {"num_experts_per_token": 17}, ["num_experts_per_token"]),
+    ("laguna", {"moe_intermediate_size": 0}, ["expert widths"]),
+    ("laguna", {"mlp_layer_types": ("dense", "sparse", "sparse", "sparse",
+                                    "mixed")}, ["mixed"]),
+    ("laguna", {"rope_parameters": {"full_attention": {"rope_theta": 1e4}}},
      ["rope_parameters", "sliding_attention"]),
+    ("ling", {"short_conv_kernel_size": 0}, ["short_conv_kernel_size"]),
+    ("ling", {"kda_lower_bound": 0.0}, ["kda_lower_bound"]),
+    ("ling", {"state_dtype": "float16"}, ["state_dtype", "float16"]),
+    ("ling", {"kv_lora_rank": 0}, ["kv_lora_rank"]),
+    ("ling", {"score_function": "tanh"}, ["score_function"]),
+    ("ling", {"topk_group": 9}, ["topk_group"]),
+    ("ling", {"n_group": 3}, ["n_group"]),
+    ("ling", {"rope_parameters": {}},
+     ["rope_parameters has no 'mla_attention'"]),
 ])
-def test_a_table_that_contradicts_itself_is_refused_when_built(change, names):
+def test_a_table_that_contradicts_itself_is_refused_when_built(table, change,
+                                                               names):
     with pytest.raises(ValueError) as e:
-        _model(**change)
+        _model(table=table, **change)
     for n in names:
         assert n in str(e.value), (n, str(e.value))
 
@@ -137,20 +185,35 @@ def test_parameters_are_one_stack_a_kind():
 # ------------------------- against the reference ----------------------------
 
 
-def test_chunked_prefill_and_kernel_decode_match_the_reference(engine):
-    """``forward`` in chunks of 32 into a paged cache, then the Pallas decode
-    kernel (interpreted), on sequences 4.5 windows long: logits against the
-    plain float32 forward."""
-    ref = _reference()
-    v = ref.compare(engine, SEED, T=72, chunk=32, n_decode=6)
+@pytest.mark.parametrize("table", sorted(TABLES))
+def test_chunked_prefill_and_kernel_decode_match_the_reference(engines, table):
+    """``forward`` in chunks into the table's caches (laguna: a paged cache,
+    sequences 4.5 windows long; ling: a latent cache and two seats of a
+    state pool, the last chunk ragged), then the decode path with its Pallas
+    kernels interpreted: logits against the plain float32 forward.  float32
+    against float32 at ``highest``: what is left is the order of the sums,
+    1e-4 of the largest logit at most."""
+    t = TABLES[table]
+    v = _reference(table).compare(engines(table), t["seed"], n_decode=6,
+                                  **t["compare"])
     assert v["ok"], v
     assert v["decode_attention"]["impl"] == "pallas"
     assert v["decode_attention"]["interpret"] is True
     assert max(v["prefill"]["rel"], v["decode"]["rel"]) < 1e-4, v
     assert v["both"]["rms_rel"] < 1e-4
     # float32 against float32: the served path chose the reference's experts
-    assert v["routing"]["token_layers"] == 2 * (72 + 6) * 4
+    assert v["routing"]["token_layers"] == (
+        2 * (t["compare"]["T"] + 6) * t["sparse_layers"])
     assert v["routing"]["flipped"] == 0 and v["routing"]["short_max"] == 0
+    if table == "ling":
+        # logits just behind every chunk boundary and at every chunk's end
+        assert v["probes"] == [0, 1, 2, 3, 63, 64, 65, 66, 67, 127,
+                               128, 129, 130, 131, 149]
+        # the rows' seats read back against the reference's states, a layer
+        # each; the seats no row held are as they were made
+        assert len(v["state"]["rms_rel_by_layer"]) == 6
+        assert v["state"]["rms_rel_max"] < 1e-5, v["state"]
+        assert v["state"]["stray_max"] == 0.0
 
 
 def test_the_reference_follows_the_served_choices_and_counts_the_flips(
@@ -174,12 +237,24 @@ def test_the_reference_follows_the_served_choices_and_counts_the_flips(
     assert v["ok"] == (r["short_max"] <= v["short_tol"])
 
 
-@pytest.mark.parametrize("variant", ["no_scale", "renorm_held"])
-def test_the_reference_refuses_a_broken_routing_weight(engine, variant):
-    v = _reference().compare(engine, SEED, T=72, chunk=32, n_decode=2,
-                             variant=variant)
-    assert not v["ok"] or v["both"]["rms_rel"] > 0.02, v
-    assert v["both"]["rms_rel"] > 0.02
+@pytest.mark.parametrize("table,variant,sees", [
+    ("laguna", "no_scale", "logits"), ("laguna", "renorm_held", "logits"),
+    ("ling", "no_groups", "routing"), ("ling", "no_bound", "logits")])
+def test_the_reference_sees_a_part_of_the_mathematics_left_out(
+        engines, table, variant, sees):
+    """The controls the chip's limits rest on, at the tiny size: a reference
+    that breaks a routing weight or leaves out the decay's bound reads other
+    logits altogether; one without the group limit reads the served choices
+    as flips that lie well under its own 4th score."""
+    t = TABLES[table]
+    v = _reference(table).compare(engines(table), t["seed"], n_decode=2,
+                                  variant=variant, **t["compare"])
+    if sees == "routing":
+        assert v["routing"]["flipped_share"] > 0.2, v["routing"]
+        assert v["routing"]["short_max"] > 0.05
+    else:
+        assert not v["ok"] or v["both"]["rms_rel"] > 0.02, v
+        assert v["both"]["rms_rel"] > 0.02
 
 
 def test_a_window_ignored_in_decode_is_seen(engine, monkeypatch):
@@ -515,19 +590,21 @@ def test_a_model_without_a_table_is_what_it_was(preset):
 # ------------------------- what refuses a table ------------------------------
 
 
-def test_what_cannot_run_a_table_says_so_when_it_is_built(cpu_devices):
+@pytest.mark.parametrize("table", sorted(TABLES))
+def test_what_cannot_run_a_table_says_so_when_it_is_built(cpu_devices, table):
     from dynamo_tpu.parallel import layout, pp_serving
 
-    cfg = _model()
+    cfg = _model(table=table)
     mesh = layout.make_mesh((1, 2), devices=cpu_devices[:2])
     with pytest.raises(ValueError, match="--mesh 1,1"):
         M.init_params_sharded(jax.random.PRNGKey(0), cfg, mesh)
     with pytest.raises(ValueError, match="weight-dtype int8"):
         M.init_params_sharded(jax.random.PRNGKey(0), cfg, None, "int8")
     with pytest.raises(ValueError, match="pipeline-parallel"):
-        pp_serving.raw_pp_step_fn(cfg, _engine_config(), mesh)
+        pp_serving.raw_pp_step_fn(cfg, _engine_config(table), mesh)
     with pytest.raises(ValueError, match="--mesh 1,1"):
-        InferenceEngine(cfg, _engine_config(mesh_shape=(1, 2)), seed=0)
+        InferenceEngine(cfg, _engine_config(table, mesh_shape=(1, 2)),
+                        seed=0)
 
 
 def test_the_encoder_runs_a_table(engine):

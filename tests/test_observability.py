@@ -630,15 +630,30 @@ def _tiny_engine_config(**kw):
                         decode_buckets=(8,), prefill_buckets=(16,), **kw)
 
 
-def _table_model():
-    """The benchmark's tiny table of layer kinds (its rehearsal model)."""
+# the benchmark's tables of layer kinds (their rehearsal models), and the
+# stages only each of them has: K / V kinds with a window, or linear and
+# latent kinds, whose recurrence is a decode program's and whose chunked
+# form a prefill program's
+_TABLES = {
+    "table": ("laguna-s-2.1-ep2", {
+        "attention_window", "attn_gate", "moe_router", "moe_experts",
+        "moe_shared"}),
+    "hybrid": ("ling-3.0-flash-ep8", {
+        "attn_gate", "moe_router", "moe_experts", "moe_shared", "kda_proj",
+        "kda_conv", "kda_out", "attention_latent", "kda_recurrent",
+        "kda_chunk"}),
+}
+
+
+def _table_model(name="laguna-s-2.1-ep2"):
+    """A tiny table of layer kinds of the benchmark (a rehearsal model)."""
     import os
 
     from benchmarks.chip import worker_launch
 
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks", "chip", "configs",
-        "laguna-s-2.1-ep2.json")
+        f"{name}.json")
     with open(path) as f:
         return worker_launch.model_config_from(json.load(f), True)
 
@@ -667,28 +682,36 @@ def _lowered_step_program(which, cfg=None):
     return fn.__wrapped__.lower(*args)
 
 
-@pytest.mark.parametrize("model", ["one_kind", "table"])
+@pytest.mark.parametrize("model", ["one_kind", "table", "hybrid"])
 @pytest.mark.parametrize("which", ["decode_window", "packed_prefill"])
 def test_step_programs_carry_every_scope(which, model):
     """Every name of model.SCOPES is a component of some op's ``op_name``
     in the lowered program — a refactor that drops a stage's
     ``jax.named_scope`` fails here, before a trace reads 0 for it.  A table
-    of layer kinds carries all of them; a one-kind model all but the
-    table's own, and none of those."""
+    of layer kinds carries the stages of its kinds (between them the tables
+    carry every one of ``TABLE_SCOPES``) and none of another table's; a
+    one-kind model all but the tables' own, and none of those."""
     import re
 
-    table = model == "table"
+    assert set().union(*(own for _, own in _TABLES.values())) == set(
+        model_lib.TABLE_SCOPES)
+    name, own = _TABLES.get(model, (None, set()))
+    own = own - {"kda_chunk" if which == "decode_window"
+                 else "kda_recurrent"}
+    # no layer of the hybrid keeps K and V: "attention" is only what a
+    # kernel's decode program computes its rows' lengths under
+    lacks = {"attention"} if (model, which) == (
+        "hybrid", "packed_prefill") else set()
     text = _lowered_step_program(
-        which, _table_model() if table else None).as_text(debug_info=True)
+        which, _table_model(name) if name else None).as_text(debug_info=True)
     seen = set()
     for op_name in re.findall(r'loc\("(jit\([^"]*)"', text):
         seen.update(op_name.split("/"))
     want = [s for s in model_lib.SCOPES
-            if table or s not in model_lib.TABLE_SCOPES]
+            if s in own or s not in set(model_lib.TABLE_SCOPES) | lacks]
     missing = [s for s in want if s not in seen]
     assert not missing, f"{which}: no op carries scope(s) {missing}"
-    if not table:
-        assert not seen & set(model_lib.TABLE_SCOPES)
+    assert not seen & (set(model_lib.TABLE_SCOPES) - own)
 
 
 def _host_event_names(trace_dir):
