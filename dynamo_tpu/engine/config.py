@@ -347,7 +347,10 @@ class EngineConfig:
     block_size: int = 16                # tokens per KV block
     num_blocks: int = 2048              # total KV blocks in HBM (G1 tier)
     max_num_seqs: int = 64              # max concurrently running sequences
-    max_num_batched_tokens: int = 512   # per-step token budget (chunked prefill)
+    # prompt tokens one round may prefill, in chunks of at most the largest
+    # prefill bucket; decode rows run in their own program, take none of it
+    # and are bounded by ``max_num_seqs``
+    max_num_batched_tokens: int = 512
     watermark: float = 0.01             # min free-block fraction before admit
     max_model_len: int = 8192           # max tokens per sequence
     enable_prefix_caching: bool = True
@@ -525,9 +528,9 @@ class EngineConfig:
         if (self.weight_dtype != "bf16" or self.kv_dtype != "bf16") \
                 and self.pp_stages > 1:
             raise ValueError("quantized serving requires pp_stages == 1")
-        # max_num_batched_tokens MAY exceed the largest prefill bucket:
-        # the scheduler caps each chunk at the bucket, so extra budget
-        # just lets decode seats coexist with a full-bucket prefill
+        # max_num_batched_tokens counts a round's prompt tokens only, and
+        # may exceed the largest prefill bucket: the scheduler caps each
+        # chunk at the bucket, and what is left goes to the next prompt
 
     @property
     def max_blocks_per_seq(self) -> int:

@@ -2,11 +2,19 @@
 
 Faithful to the vLLM semantics the reference encodes compactly in its mocker
 (ref: lib/llm/src/mocker/scheduler.rs:240 and kv_manager.rs:507): waiting and
-running queues, a per-step token budget with chunked prefill, a free-block
-watermark on admission, LRU eviction of sealed (hash-keyed) blocks, prefix
-caching by chained sequence hash, and preemption-by-recompute when the pool
-runs dry. KV events (stored/removed, ref: lib/llm/src/kv_router/
+running queues, a per-round budget of prompt tokens with chunked prefill, a
+free-block watermark on admission, LRU eviction of sealed (hash-keyed) blocks,
+prefix caching by chained sequence hash, and preemption-by-recompute when the
+pool runs dry. KV events (stored/removed, ref: lib/llm/src/kv_router/
 protocols.rs) are emitted for the router's radix indexer.
+
+One line of those semantics is not kept. vLLM charges a decode token to the
+budget because it rides the same forward pass as the prefill chunk. Here a
+round's decode rows are one program and each prefill chunk is another, so
+``max_num_batched_tokens`` counts the prompt tokens a round may prefill and
+nothing else; decode rows are bounded by ``max_num_seqs`` (and by seats and
+blocks). A token charged for a decode row would only cut the chunk short of
+the bucket it is then padded back up to.
 
 Token/KV invariants:
 - ``num_computed`` = tokens whose KV is written to the cache.
@@ -447,8 +455,6 @@ class Scheduler:
                             break
                         seq.block_table.append(bid)
         for seq in list(self.running):
-            if budget <= 0:
-                break
             if seq.status is not SeqStatus.RUNNING:
                 continue  # preempted by an earlier seq's _ensure_slot
             base = seq.num_computed + seq.pending_prompt + seq.pending_decode
@@ -471,9 +477,9 @@ class Scheduler:
                 tok_host=tok_host, tok_src=tok_src, slot=seq.slot,
             ))
             seq.pending_decode += accepted
-            budget -= 1
 
-        # 2. chunked prefill from the waiting queue, FIFO.  A prefill that
+        # 2. chunked prefill from the waiting queue, FIFO: the budget is
+        # this round's prompt tokens (decode rows took none).  A prefill that
         # completed admission already moved into self.running, so only count
         # in-flight prefills that are NOT yet running to avoid double-counting
         def active_seqs() -> int:
@@ -508,10 +514,9 @@ class Scheduler:
             else:
                 # chunk ≤ budget, so a partial chunk always exhausts the
                 # budget and the loop cannot schedule a token range twice.
-                # Also never exceed the largest compiled prefill bucket —
-                # that lets max_num_batched_tokens run past the bucket so
-                # decode seats don't force prompt splits (a 512 prompt
-                # split 448+64 costs a full extra dispatch + uploads).
+                # A chunk is one [1, T] program, so it never exceeds the
+                # largest compiled prefill bucket either: a budget past
+                # the bucket goes to the next prompt in the queue.
                 max_bucket = max(self.config.prefill_buckets)
                 eff_cap = max_bucket
                 pct = self.config.prefill_chunk_tokens

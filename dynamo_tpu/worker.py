@@ -60,7 +60,10 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--block-size", type=int, default=16)
     p.add_argument("--num-blocks", type=int, default=2048)
     p.add_argument("--max-num-seqs", type=int, default=64)
-    p.add_argument("--max-batched-tokens", type=int, default=512)
+    p.add_argument("--max-batched-tokens", type=int, default=512,
+                   help="prompt tokens one round may prefill, in chunks of "
+                        "at most the largest prefill bucket; decode rows "
+                        "take none of it and are bounded by --max-num-seqs")
     p.add_argument("--max-model-len", type=int, default=8192)
     p.add_argument("--mesh", default="1,1", help="dp,tp mesh axis sizes")
     p.add_argument("--pp", type=int, default=1,

@@ -1,6 +1,8 @@
 """Scheduler semantics: block pool accounting, admission, chunked prefill,
 prefix caching, preemption (the contract encoded in ref mocker/scheduler.rs)."""
 
+import pytest
+
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.scheduler import (
     BlockPool, KvEvent, SchedSeq, Scheduler, SeqStatus,
@@ -101,7 +103,7 @@ def test_chunked_prefill_budget():
     assert b3.prefills[0].completes_prompt
 
 
-def test_decode_has_priority_over_prefill_budget():
+def test_decode_rows_take_no_prefill_budget():
     sched = Scheduler(make_config(max_num_batched_tokens=4))
     a = make_seq("a", range(4))
     sched.add(a)
@@ -110,7 +112,69 @@ def test_decode_has_priority_over_prefill_budget():
     sched.add(b)
     batch = sched.schedule()
     assert batch.decodes == [a]
-    assert batch.prefills[0].length == 3  # 4 budget - 1 decode
+    # the budget is prompt tokens: a's decode row rides another program
+    assert batch.prefills[0].length == 4
+
+
+def _decoding_scheduler(rows, **kw):
+    """A scheduler with ``rows`` sequences past their prefill, each with
+    tokens left to decode."""
+    sched = Scheduler(make_config(**kw))
+    for i in range(rows):
+        seq = make_seq(f"d{i}", range(1000 + 16 * i, 1008 + 16 * i),
+                       max_tokens=10_000)
+        sched.add(seq)
+        for c in sched.schedule().prefills:
+            sched.on_prefill_executed(c, 1 if c.final else None)
+        # land the decode rows planned beside the prefill
+        for s in sched.running:
+            while s.pending_decode:
+                sched.on_decode_executed(s, 1)
+    assert len(sched.running) == rows
+    return sched
+
+
+@pytest.mark.parametrize("prompt_len", [100, 512, 513, 1100, 4096])
+@pytest.mark.parametrize("rows", [0, 8, 64, 127])
+def test_chunks_are_whole_buckets_whatever_decodes(rows, prompt_len):
+    budget = 512
+    sched = _decoding_scheduler(
+        rows, block_size=16, num_blocks=2048, max_num_seqs=128,
+        max_num_batched_tokens=budget, max_model_len=8192,
+        decode_buckets=(128,), prefill_buckets=(16, 32, 64, 128, 256, 512),
+        enable_prefix_caching=False,
+    )
+    decoding = list(sched.running)
+    seq = make_seq("p", range(prompt_len), max_tokens=4)
+    sched.add(seq)
+    chunks = []
+    while seq.status is not SeqStatus.RUNNING:
+        batch = sched.schedule()
+        assert sum(c.length for c in batch.prefills) <= budget
+        # every running row still gets its decode row
+        assert [r.seq for r in batch.decode_rows] == decoding
+        for row in batch.decode_rows:
+            for _ in range(row.accepted):
+                sched.on_decode_executed(row.seq, 1)
+        for c in batch.prefills:
+            chunks.append(c.length)
+            sched.on_prefill_executed(c, 1 if c.final else None)
+    assert len(chunks) == -(-prompt_len // 512)
+    assert all(n == 512 for n in chunks[:-1])
+    assert sum(chunks) == prompt_len
+
+
+def test_prompt_admitted_when_seats_outnumber_budget():
+    # max_num_seqs >= max_num_batched_tokens: were a decode row charged a
+    # token, nothing could prefill while every other seat decodes
+    sched = _decoding_scheduler(7, max_num_seqs=8, max_num_batched_tokens=8,
+                                num_blocks=64, prefill_buckets=(8,))
+    seq = make_seq("p", range(500, 506))
+    sched.add(seq)
+    batch = sched.schedule()
+    assert len(batch.decode_rows) == 7
+    assert [(c.seq, c.length, c.final) for c in batch.prefills] == [
+        (seq, 6, True)]
 
 
 def test_prefix_cache_reuse():

@@ -95,12 +95,12 @@ async def test_seeded_sampling_pipelined():
 
 
 async def test_starved_budget_seatmap_rebuild():
-    """Token-budget and block-pool starvation force LIVE seqs to be skipped
-    in some decode rounds. A skipped-but-live seat must NOT keep its column
-    in a reused device seat map — the window kernel would advance its
-    device-side pos/ring token K steps past the host mirror, corrupting the
-    stream when the seq is scheduled again. Greedy outputs must match the
-    unstarved synchronous engine exactly."""
+    """Block-pool starvation forces LIVE seqs to be skipped in some decode
+    rounds. A skipped-but-live seat must NOT keep its column in a reused
+    device seat map — the window kernel would advance its device-side
+    pos/ring token K steps past the host mirror, corrupting the stream when
+    the seq is scheduled again. Greedy outputs must match the unstarved
+    synchronous engine exactly."""
     import asyncio
 
     mc = ModelConfig.tiny()
@@ -112,7 +112,7 @@ async def test_starved_budget_seatmap_rebuild():
            for i, kw in enumerate(reqs)]
     await ref_engine.stop()
 
-    # 3 batched tokens/round vs 6 decoding seqs, 8 blocks vs ~12 needed
+    # 3 prompt tokens a round beside 6 decoding seqs, 8 blocks vs ~12 needed
     eng = InferenceEngine(
         mc,
         _cfg(4, 3, max_num_batched_tokens=3, num_blocks=8,
