@@ -36,7 +36,7 @@ from ..observability.stepstats import (
     DECODE, PREFILL, SPEC_VERIFY, StepRecord, StepStats, kv_blocks_walked,
     kv_pages_written,
 )
-from ..runtime import faults
+from ..runtime import faults, loop_busy
 from ..runtime.context import Context
 from ..runtime.engine import AsyncEngine
 from ..utils.config import env_flag, env_float, env_str
@@ -83,8 +83,16 @@ PHASES = (
     "engine.dispatch",                            # dispatch thread
     "engine.dispatch.prefill", "engine.dispatch.decode",
     "engine.fetch", "engine.unpack",              # fetch thread
+    # event loop, every stream's task: pack + write of one data frame
+    # (the ingress server's, which must not import JAX: serve_engine hands
+    # it ``stream_out_phase``)
+    "worker.stream_out",
 )
 _phase = jax.profiler.TraceAnnotation
+
+
+def stream_out_phase():
+    return _phase("worker.stream_out")
 
 
 class _LoopClock:
@@ -94,12 +102,25 @@ class _LoopClock:
     the account of one batch: its busy seconds ride the batch to its step
     record as ``host_s``, its waits are added to ``wait_s``. So at any
     moment ``sum(host_s handed off) + sum(wait_s.values())`` equals
-    ``t_handoff - t_start``."""
+    ``t_handoff - t_start``.
 
-    def __init__(self):
+    The task's waits are when every OTHER task of the loop runs (each
+    stream's queue, pack, write and drain), so beside its own busy seconds a
+    handoff reads what the whole loop was busy since the last one, from the
+    loop's ``runtime.loop_busy`` counter: ``loop_busy_s``, of which
+    ``host_s`` is a part (0 where the loop has no counter). Busy there is
+    "not in a blocking select", so it holds the loop thread's waits for the
+    interpreter lock too (the dispatch and fetch threads take it every
+    step); ``loop_cpu_s``, the thread's CPU seconds over the same stretch,
+    is the part that was work."""
+
+    def __init__(self, loop_counter=None):
         self.t_start = self.t_handoff = time.monotonic()
         self.wait_s = {"land": 0.0, "idle": 0.0, "executor": 0.0}
         self._open = dict(self.wait_s)  # waits since the last handoff
+        self._loop_counter = loop_counter
+        self._loop_busy_at = loop_counter.busy_s() if loop_counter else 0.0
+        self._cpu_at = time.thread_time()   # made and read on the loop thread
 
     @contextlib.contextmanager
     def waiting(self, what: str):
@@ -109,14 +130,23 @@ class _LoopClock:
         finally:
             self._open[what] += time.monotonic() - t0
 
-    def handoff(self) -> float:
+    def handoff(self) -> Tuple[float, float, float]:
+        """(``host_s``, ``loop_busy_s``, ``loop_cpu_s``) since the previous
+        handoff."""
         now = time.monotonic()
         busy = now - self.t_handoff - sum(self._open.values())
         for what, s in self._open.items():
             self.wait_s[what] += s
             self._open[what] = 0.0
         self.t_handoff = now
-        return max(busy, 0.0)
+        loop_busy = 0.0
+        if self._loop_counter is not None:
+            total = self._loop_counter.busy_s()
+            loop_busy = total - self._loop_busy_at
+            self._loop_busy_at = total
+        cpu = time.thread_time()
+        loop_cpu, self._cpu_at = cpu - self._cpu_at, cpu
+        return max(busy, 0.0), max(loop_busy, 0.0), loop_cpu
 
 
 @contextlib.contextmanager
@@ -232,6 +262,11 @@ class StepOutput:
     finish_reason: Optional[str] = None
     num_prompt_tokens: int = 0
     cached_prompt_tokens: int = 0
+    # monotonic stamp of the fetch that brought this token to the host (the
+    # fetch thread's, right after its device_get returned); None where no
+    # fetch did (the mocker, a remotely sampled first token). Stays in the
+    # process: the stream's task reads how long the token took to reach it.
+    t_land: Optional[float] = None
 
 
 def _seed31(seed) -> int:
@@ -662,8 +697,18 @@ class EngineCore(AsyncEngine):
         watcher = asyncio.create_task(_on_stop())
         t_submit = time.monotonic()
         seq_ref: Optional[SchedSeq] = None
+        # landed -> this task holds the token (unpack, postprocess, the
+        # queue, the loop's backlog): [sum, max], floats only, and only
+        # where an exporter may take the span that carries them
+        timed = tracing.get_tracer().keeps(context.trace.trace_id)
+        wake = [0.0, 0.0]
         try:
             async for out in self.submit(req):
+                if timed and out.t_land is not None:
+                    lag = time.monotonic() - out.t_land
+                    wake[0] += lag
+                    if lag > wake[1]:
+                        wake[1] = lag
                 if seq_ref is None:
                     # grab the scheduler-side state before _drop can pop it;
                     # its t_scheduled/t_first_token stamps feed the spans
@@ -681,10 +726,11 @@ class EngineCore(AsyncEngine):
                     return
         finally:
             watcher.cancel()
-            self._record_stage_spans(context, t_submit, seq_ref)
+            self._record_stage_spans(context, t_submit, seq_ref, wake)
 
     def _record_stage_spans(
-        self, context: Context, t_submit: float, seq: Optional[SchedSeq]
+        self, context: Context, t_submit: float, seq: Optional[SchedSeq],
+        wake: Tuple[float, float] = (0.0, 0.0),
     ) -> None:
         """Attribute engine time to worker.queue / engine.prefill /
         engine.decode spans from the scheduler's monotonic stamps. Recorded
@@ -711,6 +757,8 @@ class EngineCore(AsyncEngine):
                           end_mono=(t_first or end), events=events)
         if t_first is not None:
             attrs = {"num_tokens": len(seq.output_ids)}
+            if wake[0] > 0:   # absent, not zero, where no fetch was stamped
+                attrs["wake_sum_s"], attrs["wake_max_s"] = wake
             if getattr(self, "spec_stats", None) is not None:
                 attrs["spec_drafted"] = seq.spec_drafted
                 attrs["spec_accepted"] = seq.spec_accepted
@@ -763,7 +811,7 @@ class EngineCore(AsyncEngine):
         raise NotImplementedError
 
     async def _run_loop(self) -> None:
-        self.loop_clock = _LoopClock()
+        self.loop_clock = _LoopClock(loop_busy.install())
         if self.pipeline_depth > 1:
             await self._run_loop_pipelined()
         else:
@@ -842,7 +890,8 @@ class EngineCore(AsyncEngine):
                     await self._wake.wait()
                 continue
             self._arm_stall_fault(batch)
-            batch.host_s = clock.handoff()
+            (batch.host_s, batch.loop_busy_s,
+             batch.loop_cpu_s) = clock.handoff()
             try:
                 with clock.waiting("executor"):
                     fut = await self._dispatch_batch_async(batch)
@@ -1200,7 +1249,8 @@ class EngineCore(AsyncEngine):
                     await self._wake.wait()
                 continue
             self._arm_stall_fault(batch)
-            batch.host_s = clock.handoff()
+            (batch.host_s, batch.loop_busy_s,
+             batch.loop_cpu_s) = clock.handoff()
             inner = asyncio.ensure_future(self._execute_batch_async(batch))
             try:
                 # one executor turn dispatches AND fetches here, so the
@@ -1254,7 +1304,7 @@ class EngineCore(AsyncEngine):
                 chunk, sampled if chunk.final else None
             )
             if chunk.final:
-                self._emit_token(seq)
+                self._emit_token(seq, batch.t_landed)
         for i, row in enumerate(batch.decode_rows):
             seq = row.seq
             window = decode_samples[i]
@@ -1266,7 +1316,7 @@ class EngineCore(AsyncEngine):
                     break  # aborted / stopped mid-window
                 self.scheduler.on_decode_executed(seq, tok)
                 applied += 1
-                self._emit_token(seq)
+                self._emit_token(seq, batch.t_landed)
             if applied < row.accepted:
                 self.scheduler.on_tokens_discarded(
                     seq, row.accepted - applied
@@ -1274,7 +1324,8 @@ class EngineCore(AsyncEngine):
             if seq.status == SeqStatus.FINISHED:
                 self._ap_mark_dead(row.slot)
 
-    def _emit_token(self, seq: SchedSeq) -> None:
+    def _emit_token(self, seq: SchedSeq,
+                    t_land: Optional[float] = None) -> None:
         self.num_generated_tokens += 1
         if seq.t_first_token is None:
             seq.t_first_token = time.monotonic()
@@ -1286,6 +1337,7 @@ class EngineCore(AsyncEngine):
             finished=reason is not None,
             finish_reason=reason,
             num_prompt_tokens=seq.prompt_len,
+            t_land=t_land,
         )
         if reason is not None:
             self.scheduler.finish(seq, reason)
@@ -1980,6 +2032,7 @@ class InferenceEngine(EngineCore):
 
     @hot_path
     def _unpack(self, batch, handles, got, t_got: float):
+        batch.t_landed = t_got
         prefill_handles, decode_handle = handles
         prefill_samples = [
             int(np.asarray(g)[0]) for g in got[:len(prefill_handles)]
@@ -2026,6 +2079,8 @@ class InferenceEngine(EngineCore):
         emitted = sum(len(w) for w in decode_samples)
         owner = next((r for r in recs if r.kind != PREFILL), recs[-1])
         owner.host_s = batch.host_s
+        owner.loop_busy_s = batch.loop_busy_s
+        owner.loop_cpu_s = batch.loop_cpu_s
         owner.unpack_s = t_land - t_got
         if moe_stats is not None and owner.kind == DECODE:
             for name, v in zip(MOE_STATS, moe_stats):

@@ -322,8 +322,15 @@ class ScheduledBatch:
     # with several pipelined windows in flight
     obs_records: List = field(default_factory=list)
     # seconds the engine-loop task was busy since it handed the previous
-    # batch to the dispatch thread (stamped by the loop at handoff)
+    # batch to the dispatch thread (stamped by the loop at handoff), the
+    # seconds the whole event loop was busy over the same stretch, and the
+    # loop thread's CPU seconds over it
     host_s: float = 0.0
+    loop_busy_s: float = 0.0
+    loop_cpu_s: float = 0.0
+    # monotonic stamp of the fetch that landed this batch's samples (fetch
+    # thread, as its device_get returned); None until then
+    t_landed: Optional[float] = None
 
     @property
     def decodes(self) -> List[SchedSeq]:

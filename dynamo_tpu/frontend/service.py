@@ -710,6 +710,9 @@ class HttpService:
             "frontend.request", trace=ctx.trace, parent_span_id=upstream,
             attrs={"model": model, "endpoint": endpoint}, root=True,
         )
+        # rides the wire: the worker's ingress span says how long the way
+        # from here to there was (upstream_s)
+        ctx.accepted_unix = root.start_unix
         shed = await self._admit(endpoint, model, ctx,
                                  tier=self._request_tier(request, body))
         if shed is not None:

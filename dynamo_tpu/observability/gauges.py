@@ -39,6 +39,14 @@ class EngineObsGauges:
             "share of the trailing window the engine-loop task was busy "
             "(StepRecord.host_s sums): what a faster device would uncover",
         )
+        self._g_loop_busy = registry.gauge(
+            "engine_event_loop_busy_ratio",
+            "share of the trailing window the worker's whole event loop was "
+            "busy (StepRecord.loop_busy_s sums): the engine-loop task, every "
+            "stream's way out, and the loop thread's waits for the "
+            "interpreter lock; near 1 the loop, not the device, paces the "
+            "tokens",
+        )
         self._g_pad_waste = registry.gauge(
             "engine_padding_waste_ratio",
             "fraction of dispatched FLOPs burnt on bucket padding",
@@ -88,6 +96,7 @@ class EngineObsGauges:
             self._g_mfu_class.labels(step="decode").set(snap["mfu_decode"])
         self._g_goodput.set(snap.get("goodput_tok_s", 0.0))
         self._g_host_busy.set(snap.get("host_busy_ratio", 0.0))
+        self._g_loop_busy.set(snap.get("loop_busy_ratio", 0.0))
         self._g_pad_waste.set(snap.get("padding_waste_ratio", 0.0))
         self._g_waste.labels(cause="padding").set(
             snap.get("padding_waste_ratio", 0.0))

@@ -84,11 +84,20 @@ def render_report(records: List[dict]) -> str:
         # one record of it, the dispatch thread's on every record
         n = len(batches)
         host = sum(r["host_s"] for r in batches)
+        # the engine-loop task is one task of the worker's event loop: the
+        # whole loop's busy share beside it, where the records carry it
+        # (of that, the loop thread's CPU seconds: the rest it stood
+        # waiting for the interpreter lock)
+        whole = sum(r.get("loop_busy_s", 0.0) for r in batches)
+        cpu = sum(r.get("loop_cpu_s", 0.0) for r in batches)
         lines.append(
             f"host per step:     loop {1e3 * host / n:.2f} ms  dispatch "
             f"{1e3 * sum(r.get('dispatch_s', 0.0) for r in records) / n:.2f}"
             f" ms  unpack {1e3 * sum(r['unpack_s'] for r in batches) / n:.2f}"
-            f" ms  (loop busy {_pct(host, wall).strip()} of wall)"
+            f" ms  (loop busy {_pct(host, wall).strip()} of wall"
+            + (f"; the whole event loop busy {_pct(whole, wall).strip()},"
+               f" on the CPU {_pct(cpu, wall).strip()}"
+               if whole else "") + ")"
         )
     return "\n".join(lines) + "\n"
 

@@ -19,6 +19,9 @@ host syncs to the hot path (dynalint-enforced).
   non-goodput FLOPs went
 * ``host_busy_ratio`` — share of the window the engine-loop task was busy
   (``host_s`` sums), the floor a faster device would uncover
+* ``loop_busy_ratio`` — share of the window the worker's whole event loop
+  was busy (``loop_busy_s`` sums): the engine-loop task and every stream's
+  way out on the one thread they share
 
 The FLOPs accounting uses the shared analytic model
 (:mod:`.flops` — attention term included, not just ``2·N·params``).
@@ -125,7 +128,16 @@ class StepRecord:
     # decode record (its last record when it has none): host_s the
     # engine-loop task's busy time since it handed off the previous batch,
     # unpack_s the fetch thread from the device_get's return to commit.
+    # loop_busy_s has host_s's meaning for the whole event loop: seconds
+    # the loop ran callbacks (this task's and every stream's) rather than
+    # waiting in its selector since the previous handoff, so host_s is a
+    # part of it (0 where the loop has no counter: runtime.loop_busy).
+    # "Not waiting in its selector" includes waiting for the interpreter
+    # lock another thread holds; loop_cpu_s is the loop thread's CPU
+    # seconds over the same stretch, the part of loop_busy_s that was work.
     host_s: float = 0.0
+    loop_busy_s: float = 0.0
+    loop_cpu_s: float = 0.0
     dispatch_s: float = 0.0
     unpack_s: float = 0.0
     # filled by StepStats.commit from the shared FLOPs model
@@ -151,10 +163,12 @@ class _Window:
     spec_drafted: int = 0
     spec_accepted: int = 0
     host_s: float = 0.0
+    loop_busy_s: float = 0.0
 
     def add(self, r: StepRecord, sign: int = 1) -> None:
         self.steps += sign
         self.host_s += sign * r.host_s
+        self.loop_busy_s += sign * r.loop_busy_s
         self.goodput_tokens += sign * r.goodput_tokens
         self.real_tokens += sign * r.real_tokens
         self.padded_tokens += sign * r.padded_tokens
@@ -333,6 +347,7 @@ class StepStats:
             snap.update({
                 "goodput_tok_s": w.goodput_tokens / elapsed,
                 "host_busy_ratio": max(w.host_s, 0.0) / elapsed,
+                "loop_busy_ratio": max(w.loop_busy_s, 0.0) / elapsed,
                 "padding_waste_ratio": (
                     w.flops_padding_waste / dispatched if dispatched else 0.0),
                 "spec_reject_waste_ratio": (

@@ -21,6 +21,7 @@ from .. import tracing
 from ..utils.config import RuntimeConfig
 from ..utils.logging import get_logger
 from ..utils.metrics import MetricsRegistry
+from . import loop_busy
 from .context import Context
 from .engine import AsyncEngine, FnEngine
 from .store import StoreClient
@@ -76,6 +77,18 @@ class DistributedRuntime:
         # per-stage latency histograms from trace spans land in this
         # process's registry regardless of the span-export sampling knob
         tracing.get_tracer().attach_metrics(self.metrics)
+        # store, frontend and worker are one event loop each: how busy it is
+        # is on every process's /metrics (above ~0.8 of a second a second,
+        # split or replicate the process)
+        try:
+            counter = loop_busy.install()
+        except RuntimeError:   # built outside a running loop: nothing to count
+            counter = None
+        if counter is not None:
+            self.metrics.counter_fn(
+                "event_loop_busy_seconds",
+                "seconds this process's event loop spent running callbacks "
+                "rather than waiting in its selector", counter.busy_s)
 
     @staticmethod
     async def from_settings(
@@ -187,11 +200,16 @@ class Endpoint:
         port: int = 0,
         max_inflight: Optional[int] = None,
         metadata: Optional[dict] = None,
+        send_phase: Optional[Callable] = None,
     ) -> "ServedEndpoint":
         """Start a TCP ingress for ``handler`` and register the instance
-        (ref: bindings _core.pyi:216 ``serve_endpoint``)."""
+        (ref: bindings _core.pyi:216 ``serve_endpoint``). ``send_phase``
+        is the profiler annotation a process with a profiler wants around
+        each data frame's send (``IngressServer``)."""
         engine = handler if isinstance(handler, AsyncEngine) else FnEngine(handler)
-        server = IngressServer(engine, host=host, port=port, max_inflight=max_inflight)
+        server = IngressServer(engine, host=host, port=port,
+                               max_inflight=max_inflight,
+                               send_phase=send_phase)
         await server.start()
         self.runtime._ingress_servers.append(server)
         instance = Instance(

@@ -12,6 +12,11 @@ seconds): the total wall-clock budget the request may spend across every
 retry, migration, and queue it rides. The deadline propagates to children
 and across the transport (as a remaining-budget header), so a worker stops
 generating for a request whose client has already given up.
+
+For tracing a context carries ``accepted_unix``, None unless a front door
+sets it: the wall-clock moment it took the request in (the frontend's root
+span start; the transport client sends it on, so the worker's ingress span
+can say how long the way in was).
 """
 
 from __future__ import annotations
@@ -34,6 +39,7 @@ class Context:
         self.id: str = request_id or uuid.uuid4().hex
         self.trace: TraceContext = trace or TraceContext.new()
         self.deadline: Optional[float] = deadline
+        self.accepted_unix: Optional[float] = None
         self._stopped = asyncio.Event()
         self._killed = asyncio.Event()
         self._children: List["Context"] = []
@@ -68,6 +74,8 @@ class Context:
             child.deadline = self.deadline
         elif self.deadline is not None:
             child.deadline = min(child.deadline, self.deadline)
+        if child.accepted_unix is None:
+            child.accepted_unix = self.accepted_unix
         if self.is_stopped():
             child.stop_generating()
         if self.is_killed():

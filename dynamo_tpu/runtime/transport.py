@@ -24,13 +24,17 @@ Frame types:
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import itertools
 import time
-from typing import AsyncIterator, Awaitable, Callable, Dict, Optional
+from typing import (
+    AsyncIterator, Awaitable, Callable, ContextManager, Dict, Optional,
+)
 
 import msgpack
 
 from .. import tracing
+from ..tracing.hist import GapHistogram
 from ..utils.logging import TraceContext, get_logger
 from . import faults
 from .context import Context
@@ -51,6 +55,13 @@ ERR_DRAINING = "draining"
 # request header carrying the remaining deadline budget in milliseconds;
 # relative (not absolute) so clocks never need to agree across hosts
 DEADLINE_HEADER = "x-deadline-ms"
+# wall-clock stamps (``time.time()``, the span model's cross-host anchor) of
+# a request's way in: when the front door's root span started, and when the
+# transport client wrote the request frame. The worker's ingress span turns
+# them into ``upstream_s`` / ``wire_s``; exact on one host, as good as the
+# hosts' clocks across two
+ACCEPTED_HEADER = "x-accepted-unix"
+SENT_HEADER = "x-sent-unix"
 
 
 class EngineError(RuntimeError):
@@ -70,10 +81,15 @@ class IngressServer:
         host: str = "0.0.0.0",
         port: int = 0,
         max_inflight: Optional[int] = None,
+        send_phase: Optional[Callable[[], ContextManager]] = None,
     ):
         self._engine = engine
         self.host = host
         self.port = port
+        # a profiler annotation around the synchronous half of every data
+        # frame's send (pack + write), handed in by the process that owns a
+        # profiler: this package runs in processes that never import JAX
+        self._send_phase = send_phase or contextlib.nullcontext
         self._server: Optional[asyncio.AbstractServer] = None
         self._inflight: Dict[str, asyncio.Task] = {}
         self._contexts: Dict[str, Context] = {}
@@ -214,6 +230,38 @@ class IngressServer:
         self._active += 1
         ctx: Optional[Context] = None
         span = None
+        # a token's way out, as plain floats per frame: how long each send
+        # took (lock + pack + write + drain), when it completed, and the
+        # gaps between completions: the gap tail as this stream's socket
+        # saw it. They reach the span once, when the request ends; taken
+        # only where an exporter may take the span (``timed``).
+        timed = False
+        frames = 0
+        send_sum = send_max = 0.0
+        t_sent = 0.0
+        gaps = GapHistogram()
+
+        async def send_data(item: object) -> None:
+            nonlocal frames, send_sum, send_max, t_sent
+            t0 = time.monotonic()
+            async with write_lock:
+                with self._send_phase():   # never across an await
+                    write_frame(writer, {
+                        "t": "data", "rid": rid,
+                        "payload": msgpack.packb(item, use_bin_type=True)})
+                await writer.drain()
+            t1 = time.monotonic()
+            took = t1 - t0
+            send_sum += took
+            if took > send_max:
+                send_max = took
+            if frames:
+                gaps.add(t1 - t_sent)
+            else:
+                span.events.append((t1 - span.start_mono, "first_sent", None))
+            frames += 1
+            t_sent = t1
+
         try:
             headers = msg.get("headers") or {}
             if not isinstance(headers, dict):
@@ -231,6 +279,13 @@ class IngressServer:
                 parent_span_id=(trace.span_id if trace is not None else None),
                 attrs={"rid": rid}, root=True,
             )
+            timed = tracing.get_tracer().keeps(span.trace_id)
+            # the way in: absent, not zero, when the caller sent no stamps
+            for attr, header in (("upstream_s", ACCEPTED_HEADER),
+                                 ("wire_s", SENT_HEADER)):
+                stamp = headers.get(header)
+                if isinstance(stamp, (int, float)):
+                    span.set_attr(attr, span.start_unix - float(stamp))
             if ing_trace is None:
                 ing_trace = TraceContext(
                     trace_id=span.trace_id, span_id=span.span_id
@@ -273,10 +328,13 @@ class IngressServer:
                     ctx.kill()
                     writer.close()
                     return
-                await send(
-                    {"t": "data", "rid": rid,
-                     "payload": msgpack.packb(item, use_bin_type=True)}
-                )
+                if timed:
+                    await send_data(item)
+                else:
+                    await send(
+                        {"t": "data", "rid": rid,
+                         "payload": msgpack.packb(item, use_bin_type=True)}
+                    )
             if not ctx.is_killed():
                 await send({"t": "end", "rid": rid})
         except asyncio.CancelledError:
@@ -305,6 +363,10 @@ class IngressServer:
                 pass
         finally:
             if span is not None:
+                if frames:
+                    span.attrs.update(
+                        frames=frames, send_sum_s=send_sum,
+                        send_max_s=send_max, sent_gaps=gaps.to_dict())
                 span.end()
             self._active -= 1
 
@@ -414,6 +476,8 @@ class TransportClient:
             "traceparent": wire.traceparent(),
             "x-request-id": context.id,
         }
+        if context.accepted_unix is not None:
+            headers[ACCEPTED_HEADER] = context.accepted_unix
         if remaining is not None:
             headers[DEADLINE_HEADER] = int(remaining * 1000)
         fault = faults.active("client.send", addr)
@@ -425,6 +489,7 @@ class TransportClient:
             )
         try:
             async with conn.write_lock:
+                headers[SENT_HEADER] = time.time()
                 write_frame(
                     conn.writer,
                     {"t": "req", "rid": rid, "headers": headers,

@@ -10,7 +10,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 from .engine.config import EngineConfig
-from .engine.engine import EngineCore
+from .engine.engine import EngineCore, stream_out_phase
 from .llm.discovery import ModelDeploymentCard, register_llm
 from .llm.tokenizer import Tokenizer
 from .runtime.component import DistributedRuntime
@@ -57,6 +57,7 @@ async def serve_engine(
         handler if handler is not None else engine,
         advertise_host=opts.advertise_host,
         metadata={"model": opts.name},
+        send_phase=stream_out_phase,
     )
 
     # KV events + load metrics for the KV-aware router / aggregator

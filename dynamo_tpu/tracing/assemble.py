@@ -11,6 +11,10 @@ CLI (also ``python -m dynamo_tpu.tracing``)::
 
     python -m dynamo_tpu.tracing.assemble front.jsonl worker-*.jsonl
     python -m dynamo_tpu.tracing.assemble front.jsonl --trace-id 4bf9...
+    python -m dynamo_tpu.tracing.assemble worker.jsonl --summary
+
+``--summary`` ends with the road between the socket and the engine, read
+from the attrs the worker's spans carry (:func:`road_summary`).
 """
 
 from __future__ import annotations
@@ -109,6 +113,89 @@ def render_summary(stages: Dict[str, dict]) -> str:
     return "\n".join(lines)
 
 
+def _gap_edges_ms(hist: dict, qs=(50, 95)) -> Optional[List[float]]:
+    """Upper edges (ms) of the buckets that hold the ``qs`` percentiles of
+    an exported :class:`.hist.GapHistogram` (bucket ``i`` ends at
+    ``lo_s * ratio**i``; the overflow bucket answers with its lower edge);
+    None when it is empty."""
+    counts = hist["counts"]
+    total = sum(counts)
+    if not total:
+        return None
+    edges = []
+    for q in qs:
+        seen = 0
+        for i, c in enumerate(counts):
+            seen += c
+            if c and seen >= q / 100.0 * total:
+                edges.append(1e3 * hist["lo_s"]
+                             * hist["ratio"] ** min(i, len(counts) - 2))
+                break
+    return edges
+
+
+def road_summary(spans: Iterable[dict]) -> Dict[str, float]:
+    """The road between the socket and the engine across a span dump, from
+    the attrs ``worker.ingress`` and ``engine.decode`` carry: the way in
+    (``upstream_s``, ``wire_s``: medians), what a data frame's send costs
+    (``send_sum_s`` over ``frames``, worst ``send_max_s``), how long a
+    landed token waits for its stream's task (``wake_sum_s`` over
+    ``num_tokens``, worst ``wake_max_s``) and the gap tail at the worker's
+    socket (every span's ``sent_gaps`` added bucket by bucket). Keys whose
+    attrs no span carries are left out."""
+    ups: List[float] = []
+    wires: List[float] = []
+    frames = tokens = 0
+    send_sum = send_max = wake_sum = wake_max = 0.0
+    gaps: Optional[dict] = None
+    for s in spans:
+        a = s.get("attrs") or {}
+        if s.get("name") == "worker.ingress":
+            if "upstream_s" in a:
+                ups.append(a["upstream_s"])
+            if "wire_s" in a:
+                wires.append(a["wire_s"])
+            if "frames" in a:
+                frames += a["frames"]
+                send_sum += a["send_sum_s"]
+                send_max = max(send_max, a["send_max_s"])
+                h = a["sent_gaps"]
+                if gaps is None:
+                    gaps = dict(h, counts=list(h["counts"]))
+                elif len(h["counts"]) == len(gaps["counts"]):
+                    gaps["counts"] = [x + y for x, y in
+                                      zip(gaps["counts"], h["counts"])]
+        elif s.get("name") == "engine.decode" and "wake_sum_s" in a:
+            tokens += a.get("num_tokens", 0)
+            wake_sum += a["wake_sum_s"]
+            wake_max = max(wake_max, a["wake_max_s"])
+
+    def median(vals: List[float]) -> float:
+        return sorted(vals)[len(vals) // 2]
+
+    out: Dict[str, float] = {}
+    if ups:
+        out["upstream_p50_ms"] = 1e3 * median(ups)
+    if wires:
+        out["wire_p50_ms"] = 1e3 * median(wires)
+    if frames:
+        out.update(frames=frames, send_mean_us=1e6 * send_sum / frames,
+                   send_max_ms=1e3 * send_max)
+    if tokens:
+        out.update(wake_mean_us=1e6 * wake_sum / tokens,
+                   wake_max_ms=1e3 * wake_max)
+    edges = _gap_edges_ms(gaps) if gaps else None
+    if edges:
+        out["sent_gap_p50_ms"], out["sent_gap_p95_ms"] = edges
+    return out
+
+
+def render_road(road: Dict[str, float]) -> str:
+    return "\n".join(
+        f"{key:<24} {value:>12.3f}" if isinstance(value, float)
+        else f"{key:<24} {value:>12}" for key, value in road.items())
+
+
 def assemble_trace(spans: List[dict]) -> dict:
     """One trace's spans → {trace_id, duration_s, spans, stages}.
 
@@ -156,7 +243,11 @@ def render_trace(assembled: dict) -> str:
         dur = s.get("duration_s")
         dur_txt = f"{dur * 1000:8.2f} ms" if dur is not None else "   open    "
         status = "" if s.get("status", "ok") == "ok" else f"  [{s['status']}]"
-        attrs = s.get("attrs") or {}
+        attrs = dict(s.get("attrs") or {})
+        if "sent_gaps" in attrs:   # a 50-bucket histogram: its tail
+            edges = _gap_edges_ms(attrs["sent_gaps"])
+            attrs["sent_gaps"] = ("p50<={:.1f}ms,p95<={:.1f}ms".format(*edges)
+                                  if edges else "none")
         attr_txt = ("  " + " ".join(f"{k}={v}" for k, v in attrs.items())
                     if attrs else "")
         lines.append(
@@ -191,11 +282,16 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = p.parse_args(argv)
 
     if args.summary:
-        stages = stage_percentiles(load_spans(args.files))
+        spans = load_spans(args.files)
+        stages = stage_percentiles(spans)
         if args.as_json:
             print(json.dumps(stages))
         else:
             print(render_summary(stages))
+            road = road_summary(spans)
+            if road:
+                print("\nroad between the socket and the engine:")
+                print(render_road(road))
         return 0
 
     traces = group_traces(load_spans(args.files))

@@ -40,11 +40,9 @@ class SpanCollector:
         sample_ratio: float = 0.0,
         slow_threshold_s: Optional[float] = None,
         buffer_size: int = DEFAULT_BUFFER_SIZE,
-        sample_salt: int = 0,
     ):
         self.sample_ratio = sample_ratio
         self.slow_threshold_s = slow_threshold_s
-        self.sample_salt = sample_salt
         self._ring: Deque[Span] = deque(maxlen=buffer_size)
         self._exporters: List[Any] = []
         # always-on metric sinks, keyed by id(registry) so frontend and
@@ -60,7 +58,6 @@ class SpanCollector:
         sample_ratio: Optional[float] = None,
         slow_threshold_s: Optional[float] = None,
         buffer_size: Optional[int] = None,
-        sample_salt: Optional[int] = None,
     ) -> "SpanCollector":
         if sample_ratio is not None:
             self.sample_ratio = sample_ratio
@@ -69,8 +66,6 @@ class SpanCollector:
             self.slow_threshold_s = (
                 slow_threshold_s if slow_threshold_s > 0 else None
             )
-        if sample_salt is not None:
-            self.sample_salt = sample_salt
         if buffer_size is not None and buffer_size != self._ring.maxlen:
             self._ring = deque(self._ring, maxlen=max(1, buffer_size))
         return self
@@ -117,14 +112,22 @@ class SpanCollector:
 
     def sampled(self, trace_id: str) -> bool:
         """Deterministic head-sampling decision for a trace id: the same id
-        and salt hash identically in every process, so a trace is either
-        exported everywhere or nowhere."""
+        hashes identically in every process, so a trace is either exported
+        everywhere or nowhere."""
         if self.sample_ratio <= 0:
             return False
         if self.sample_ratio >= 1:
             return True
-        h = xxhash.xxh3_64_intdigest(trace_id, seed=self.sample_salt)
+        h = xxhash.xxh3_64_intdigest(trace_id)
         return h / 2.0 ** 64 < self.sample_ratio
+
+    def keeps(self, trace_id: str) -> bool:
+        """Whether an exporter may take this trace's spans: it is head-
+        sampled, or a slow threshold could dump it after the fact. The
+        per-token stamps that only a span's attrs carry (a stream's way
+        out) are taken where this holds and skipped where it does not."""
+        return bool(self._exporters) and (
+            self.slow_threshold_s is not None or self.sampled(trace_id))
 
     # --------------------------- span minting --------------------------
 

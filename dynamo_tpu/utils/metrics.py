@@ -19,6 +19,7 @@ from prometheus_client import (
     Histogram,
     generate_latest,
 )
+from prometheus_client.core import CounterMetricFamily
 
 # Frequency buckets tuned for LLM serving latencies (TTFT/ITL in seconds),
 # same role as the reference's http/service/metrics.rs histograms.
@@ -98,6 +99,16 @@ class MetricsRegistry:
         )
         return self._bind(h, extra_labels)
 
+    def counter_fn(self, name: str, doc: str, fn) -> None:
+        """A counter whose value is ``fn()`` at scrape time: for a total
+        some other object already keeps (idempotent per name)."""
+        key = self._full_name(name)
+        with self._lock:
+            if key not in self._metrics:
+                self._metrics[key] = _FnCounter(key, doc, fn,
+                                                self.const_labels)
+                self.registry.register(self._metrics[key])
+
     def render(self) -> bytes:
         """Prometheus text exposition of every metric in this process scope."""
         return generate_latest(self.registry)
@@ -125,6 +136,20 @@ def validate_exposition(body: bytes) -> list:
             seen.add(key)
             samples.append(s)
     return samples
+
+
+class _FnCounter:
+    """Collector behind :meth:`MetricsRegistry.counter_fn`."""
+
+    def __init__(self, name: str, doc: str, fn, const_labels: Dict[str, str]):
+        self._name, self._doc, self._fn = name, doc, fn
+        self._labels = dict(const_labels)
+
+    def collect(self):
+        fam = CounterMetricFamily(self._name, self._doc,
+                                  labels=list(self._labels))
+        fam.add_metric(list(self._labels.values()), float(self._fn()))
+        yield fam
 
 
 class _Bound:

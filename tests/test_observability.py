@@ -588,6 +588,13 @@ def test_report_prints_host_ms_per_step():
     assert line == (
         "host per step:     loop 3.00 ms  dispatch 3.00 ms  unpack 1.00 ms"
         f"  (loop busy {100 * 0.006 / wall:.1f}% of wall)")
+    # the whole event loop's busy seconds, where the records carry them,
+    # and the part of them the loop thread spent on the CPU
+    recs[1].update(loop_busy_s=0.010, loop_cpu_s=0.006)
+    recs[2].update(loop_busy_s=0.005, loop_cpu_s=0.003)
+    assert render_report(recs).splitlines()[-1] == line[:-1] + (
+        f"; the whole event loop busy {100 * 0.015 / wall:.1f}%,"
+        f" on the CPU {100 * 0.009 / wall:.1f}%)")
 
 
 def test_selfcheck_scopes_and_vocabulary():
@@ -973,3 +980,210 @@ def test_stepstats_jsonl_is_buffered_and_complete_on_close(tmp_path):
     assert len(records) == 7
     assert [round(r["host_s"], 3) for r in records[:5]] == [
         0.0, 0.001, 0.002, 0.003, 0.004]
+
+
+# ---------------------------------------------------------------------------
+# The road between the socket and the engine (PR 39): the loop's own busy
+# counter, loop-wide busy seconds on the step records, a token's wake on
+# engine.decode, and the benchmark's readers of all of it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.anyio
+async def test_loop_busy_counter_reads_a_known_share():
+    """A loop kept busy for 6 ms of every 20 (on an absolute schedule, so a
+    late wake shortens the next sleep) reads 0.25-0.35 busy, and the runtime
+    exports the same counter as ``event_loop_busy_seconds_total``. On a
+    crowded machine a spin is preempted and holds the loop longer than its 6
+    ms, so the test sums what its spins really held: the counter must agree
+    with that sum whatever the machine does, and with the nominal 30% when
+    the machine kept the schedule."""
+    import asyncio
+    import time
+
+    from dynamo_tpu.runtime import loop_busy
+
+    counter = loop_busy.install()
+    assert counter is not None and loop_busy.install() is counter
+    registry = MetricsRegistry(prefix="dynamo")
+    registry.counter_fn("event_loop_busy_seconds", "doc", counter.busy_s)
+    b0, t0 = counter.busy_s(), time.monotonic()
+    held = 0.0
+    for i in range(1, 51):
+        t = time.monotonic()
+        while time.monotonic() - t < 0.006:
+            pass
+        held += time.monotonic() - t
+        await asyncio.sleep(max(0.0, t0 + 0.02 * i - time.monotonic()))
+    wall = time.monotonic() - t0
+    share = (counter.busy_s() - b0) / wall
+    assert held / wall - 0.01 <= share <= held / wall + 0.05
+    if held <= 0.32:
+        assert 0.25 <= share <= 0.35
+    [sample] = [s for s in validate_exposition(registry.render())
+                if s.name == "dynamo_event_loop_busy_seconds_total"]
+    assert sample.value >= counter.busy_s() - 1.0 and sample.value > 0.29
+
+
+@pytest.mark.anyio
+async def test_records_carry_loop_busy_and_decode_spans_the_wake(
+        tmp_path, monkeypatch):
+    """``loop_busy_s`` sits beside ``host_s`` on a batch's record: the
+    engine-loop task is one of the loop's tasks, so ``host_s <=
+    loop_busy_s`` a batch, and the loop cannot have been busy longer than
+    the wall; ``loop_cpu_s`` is the part of it the loop thread was on the
+    CPU. ``engine.decode`` carries the landed -> stream wake sums."""
+    from dynamo_tpu import tracing
+    from dynamo_tpu.runtime.context import Context
+
+    path = tmp_path / "steps.jsonl"
+    monkeypatch.setenv("DYNTPU_OBS_STEPSTATS_PATH", str(path))
+    exporter = tracing.InMemorySpanExporter()
+    tracer = tracing.reset()
+    tracer.configure(sample_ratio=1.0)
+    tracer.add_exporter(exporter)
+    engine = InferenceEngine(ModelConfig.tiny(), _tiny_engine_config())
+    await engine.start()
+    try:
+        for prompt in ([5, 6, 7, 8, 9], [9, 8, 7], [1, 2, 3, 4, 5, 6]):
+            outs = [o async for o in engine.generate(
+                {"token_ids": prompt, "max_tokens": 6, "ignore_eos": True},
+                Context())]
+            assert len(outs) == 6
+        clock = engine.loop_clock
+        snap = engine.obs_snapshot()
+    finally:
+        await engine.stop()
+        tracing.reset()
+    with open(path) as fh:
+        records = load_records(fh)
+    owners = [r for r in records if r["host_s"] > 0]
+    assert owners and all(r["kind"] == DECODE or r is records[-1]
+                          or r["loop_busy_s"] > 0 for r in owners)
+    for r in records:
+        assert 0.0 <= r["host_s"] <= r["loop_busy_s"] + 1e-6
+        assert r["loop_cpu_s"] >= 0.0
+    wall = clock.t_handoff - clock.t_start
+    busy = sum(r["loop_busy_s"] for r in records)
+    assert 0.0 < busy <= wall + 1e-6
+    # two clocks, so a little slack: CPU seconds are busy seconds
+    assert 0.0 < sum(r["loop_cpu_s"] for r in records) <= busy * 1.05 + 1e-3
+    assert snap["host_busy_ratio"] <= snap["loop_busy_ratio"] <= 1.0
+    gauges = EngineObsGauges(MetricsRegistry(), engine)
+    assert gauges.refresh()["loop_busy_ratio"] == snap["loop_busy_ratio"]
+    decode = [s for s in exporter.spans if s.name == "engine.decode"]
+    assert len(decode) == 3
+    for s in decode:
+        # six tokens, each a wake; none can outlast the whole request
+        assert 0.0 < s.attrs["wake_max_s"] <= s.attrs["wake_sum_s"]
+        assert s.attrs["wake_sum_s"] <= 6 * s.attrs["wake_max_s"] + 1e-9
+    # the assembler's road summary is their reader: mean over 18 tokens
+    from dynamo_tpu.tracing.assemble import road_summary
+
+    road = road_summary([s.to_dict() for s in exporter.spans])
+    assert road["wake_mean_us"] == pytest.approx(
+        1e6 * sum(s.attrs["wake_sum_s"] for s in decode) / 18)
+    assert road["wake_max_ms"] == pytest.approx(
+        1e3 * max(s.attrs["wake_max_s"] for s in decode))
+
+
+def _reader(name):
+    from benchmarks.chip.run import load_reader
+
+    return load_reader(name).read
+
+
+def test_road_readers_on_a_hand_made_window():
+    """The eight readers of PR 39 on spans and step records written by
+    hand: each value, and each None (no spans, no counter, ``decode_steps``
+    above 1, a parent that stamps nothing)."""
+    hist = {"lo_s": 1e-3, "ratio": 2 ** 0.25, "counts": [0] * 50}
+
+    def ingress(trace, start, end, upstream, submitted, first_sent, counts):
+        h = dict(hist, counts=list(hist["counts"]))
+        for i, n in counts.items():
+            h["counts"][i] = n
+        return {"name": "worker.ingress", "trace_id": trace,
+                "start_mono": start, "end_mono": end,
+                "attrs": {"upstream_s": upstream, "wire_s": 0.0002,
+                          "frames": 1 + sum(counts.values()),
+                          "sent_gaps": h},
+                "events": [{"offset_s": first_sent, "name": "first_sent"}]
+                }, {"name": "worker.queue", "trace_id": trace,
+                    "start_mono": start + submitted,
+                    "end_mono": start + submitted + 0.007}
+
+    def prefill(trace, end):
+        return {"name": "engine.prefill", "trace_id": trace,
+                "start_mono": end - 0.04, "end_mono": end}
+
+    # window [100, 150): four requests whose first frame left inside it
+    # (way in 3.0, 5.0, 4.0, 8.0 ms; emit -> sent 0.5, 0.9, 0.7, 0.5 ms),
+    # the last of them still running at its close; one whose first frame
+    # left before it
+    spans = [
+        *ingress("a", 101.0, 110.0, 0.0025, 0.0005, 0.050, {17: 90, 25: 10}),
+        prefill("a", 101.0495),
+        *ingress("b", 120.0, 130.0, 0.0040, 0.0010, 0.060, {17: 95, 29: 5}),
+        prefill("b", 120.0591),
+        *ingress("c", 140.0, 149.0, 0.0036, 0.0004, 0.055, {17: 100}),
+        prefill("c", 140.0543),
+        *ingress("early", 99.0, 105.0, 0.9, 0.1, 0.5, {40: 3}),
+        prefill("early", 99.4),
+        *ingress("late", 149.5, 160.0, 0.0070, 0.0010, 0.052, {45: 50}),
+        prefill("late", 149.5515),
+    ]
+
+    def decode(t_land, live, **kw):
+        return {"kind": "decode", "t_dispatch": t_land - 0.02,
+                "t_land": t_land, "rows": 64, "live_rows": live,
+                "padded_tokens": 64, "real_tokens": live, **kw}
+
+    steps = [decode(100.000, 10, host_s=0.001, loop_busy_s=0.004),
+             decode(100.016, 10, host_s=0.001, loop_busy_s=0.005),
+             decode(100.032, 10, host_s=0.001, loop_busy_s=0.006),
+             {"kind": "prefill", "t_dispatch": 100.03, "t_land": 100.05,
+              "rows": 1, "live_rows": 1, "padded_tokens": 512,
+              "real_tokens": 384},
+             {"kind": "prefill", "t_dispatch": 100.05, "t_land": 100.07,
+              "rows": 1, "live_rows": 1, "padded_tokens": 512,
+              "real_tokens": 512},
+             decode(100.082, 1, host_s=0.002, loop_busy_s=0.010)]
+    ctx = {"window": (100.0, 150.0), "spans": spans, "steps": steps}
+    assert _reader("ingress_lag_p50_ms")(ctx) == pytest.approx(4.5)
+    assert _reader("first_emit_lag_p50_ms")(ctx) == pytest.approx(0.6)
+    # streams a, b, c ended in the window: 285 gaps in bucket 17, 10 in 25,
+    # 5 in 29; the 95th percentile (the 285.25th of 300) is in bucket 25
+    edge = 2 ** (25 / 4)
+    assert _reader("sent_gap_p95_ms")(ctx) == pytest.approx(edge)
+    assert _reader("sent_gap_p95_ms.longgen")(ctx) == pytest.approx(edge)
+    # three landing gaps: 16 ms (10 rows), 16 ms (10 rows), 50 ms (1 row);
+    # 95% of 21 row-gaps is reached inside the second
+    assert _reader("land_gap_p95_ms")(ctx) == pytest.approx(16.0)
+    assert _reader("land_gap_p95_ms.longgen")(ctx) == pytest.approx(16.0)
+    steps[-1]["live_rows"] = 10      # the long gap now weighs a third
+    assert _reader("land_gap_p95_ms")(ctx) == pytest.approx(50.0)
+    assert _reader("worker_loop_busy_share")(ctx) == pytest.approx(
+        100 * 0.025 / 50)
+    assert _reader("prefill_real_token_share")(ctx) == pytest.approx(87.5)
+
+    # a decode window of more than one step: a landing is several tokens
+    multi = dict(ctx, steps=[dict(s, padded_tokens=128) if s["kind"] ==
+                             "decode" else s for s in steps])
+    assert _reader("land_gap_p95_ms")(multi) is None
+    # a parent of PR 39: spans without the attrs and events, records
+    # without the counter; and a run that kept nothing at all
+    bare = [{k: v for k, v in s.items() if k not in ("attrs", "events")}
+            for s in spans]
+    old = {"window": (100.0, 150.0), "spans": bare,
+           "steps": [{k: v for k, v in s.items() if k != "loop_busy_s"}
+                     for s in steps]}
+    empty = {"window": (100.0, 150.0), "spans": [], "steps": []}
+    for name in ("ingress_lag_p50_ms", "first_emit_lag_p50_ms",
+                 "sent_gap_p95_ms", "sent_gap_p95_ms.longgen",
+                 "worker_loop_busy_share"):
+        assert _reader(name)(old) is None and _reader(name)(empty) is None
+    assert _reader("land_gap_p95_ms")(old) == pytest.approx(50.0)
+    assert _reader("prefill_real_token_share")(old) == pytest.approx(87.5)
+    for name in ("land_gap_p95_ms", "land_gap_p95_ms.longgen",
+                 "prefill_real_token_share"):
+        assert _reader(name)(empty) is None

@@ -77,19 +77,16 @@ def test_traceparent_rejects_malformed(bad):
 
 
 def test_sampling_deterministic_across_collectors():
-    """Two collectors with the same salt make identical keep/drop decisions
-    for every trace id — the cluster-wide coordination-free property."""
-    a = SpanCollector(sample_ratio=0.5, sample_salt=42)
-    b = SpanCollector(sample_ratio=0.5, sample_salt=42)
+    """Two collectors make identical keep/drop decisions for every trace
+    id — the cluster-wide coordination-free property."""
+    a = SpanCollector(sample_ratio=0.5)
+    b = SpanCollector(sample_ratio=0.5)
     ids = [f"{i:032x}" for i in range(1, 401)]
     decisions = [a.sampled(t) for t in ids]
     assert decisions == [b.sampled(t) for t in ids]
     # the hash actually splits the population near the ratio
     kept = sum(decisions)
     assert 120 < kept < 280
-    # a different salt re-shuffles the decision boundary
-    c = SpanCollector(sample_ratio=0.5, sample_salt=43)
-    assert [c.sampled(t) for t in ids] != decisions
 
 
 def test_sampling_edges():
@@ -574,6 +571,58 @@ async def test_debug_trace_endpoint(cluster):
         await server.stop()
 
 
+@pytest.mark.e2e
+async def test_e2e_road_stamps_reach_the_worker_span(cluster):
+    """The frontend's accept stamp survives the pipeline's child contexts
+    (migration) and the wire: ``worker.ingress`` carries ``upstream_s >=
+    wire_s >= 0``, the same trace's ``worker.queue`` starts inside it
+    before ``first_sent``, and the assembler's road summary reads every
+    attr of the way in and the way out."""
+    from dynamo_tpu.tracing.assemble import road_summary
+    async with aiohttp.ClientSession() as s:
+        async with s.post(
+            f"http://127.0.0.1:{cluster['service'].port}/v1/completions",
+            json={"model": "tiny-chat", "prompt": "hello there",
+                  "max_tokens": 5},
+            timeout=aiohttp.ClientTimeout(total=60),
+        ) as r:
+            assert r.status == 200, await r.text()
+            assert (await r.json())["usage"]["completion_tokens"] == 5
+    exporter = cluster["exporter"]
+    for _ in range(200):
+        if {"frontend.request", "worker.ingress", "worker.queue",
+                "engine.decode"} <= {s.name for s in exporter.spans}:
+            break
+        await asyncio.sleep(0.02)
+    [root] = [s for s in exporter.spans if s.name == "frontend.request"]
+    [ing] = [s for s in exporter.spans if s.name == "worker.ingress"]
+    assert root.trace_id == ing.trace_id
+    assert 0.0 <= ing.attrs["wire_s"] <= ing.attrs["upstream_s"]
+    assert ing.attrs["upstream_s"] <= root.duration_s
+    assert ing.start_unix - ing.attrs["upstream_s"] == pytest.approx(
+        root.start_unix, abs=1e-6)
+    [queue] = [s for s in exporter.spans if s.name == "worker.queue"]
+    at = {name: off for off, name, _ in ing.events}
+    assert queue.trace_id == ing.trace_id
+    assert (0.0 <= queue.start_mono - ing.start_mono <= at["first_sent"]
+            <= ing.duration_s)
+    assert ing.attrs["frames"] >= 5
+    road = road_summary([s.to_dict() for s in exporter.spans])
+    # (this cluster's engine is the mocker: no fetch lands, so no wake)
+    assert set(road) == {
+        "upstream_p50_ms", "wire_p50_ms", "frames", "send_mean_us",
+        "send_max_ms", "sent_gap_p50_ms", "sent_gap_p95_ms"}
+    assert road["frames"] == ing.attrs["frames"]
+    assert road["upstream_p50_ms"] == pytest.approx(
+        1e3 * ing.attrs["upstream_s"])
+    assert 0.0 < road["send_mean_us"] <= 1e3 * road["send_max_ms"]
+    assert road["sent_gap_p50_ms"] <= road["sent_gap_p95_ms"]
+    text = render_trace(assemble_trace(
+        [s.to_dict() for s in exporter.spans if s.trace_id == ing.trace_id]))
+    assert "sent_gaps=p50<=" in text and "counts" not in text
+    assert road_summary([root.to_dict()]) == {}
+
+
 # ------------- PR 24: inside engine.prefill, buffered export -------------
 
 
@@ -617,7 +666,9 @@ async def test_prefill_span_events_and_queue_hit_attrs(tracer):
         prefills[1].to_dict())))
     assert [e[1] for e in back.events] == ["dispatched", "landed"]
     decode = [s for s in exporter.spans if s.name == "engine.decode"]
-    assert decode and all(set(s.attrs) == {"num_tokens"} for s in decode)
+    assert decode and all(
+        set(s.attrs) == {"num_tokens", "wake_sum_s", "wake_max_s"}
+        for s in decode)
 
 
 def test_jsonl_span_export_is_buffered_and_complete_on_close(tracer, tmp_path):
@@ -691,3 +742,52 @@ async def test_worker_sigterm_path_flushes_span_export(tmp_path):
     names = [s["name"] for s in load_spans([str(spans_path)])]
     for stage in ("worker.queue", "engine.prefill", "engine.decode"):
         assert names.count(stage) == n, (stage, names)
+
+
+# ------------- PR 39: the per-stream gap histogram -------------
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_gap_histogram_tracks_exact_percentiles(seed):
+    """A seeded sample of gaps (a body near 16 ms, a tail of chunked 60-200
+    ms, a few strays under 1 ms and over 4 s): every percentile's bucket
+    edge is at or above the exact value and less than one ratio above it;
+    two histograms add bucket by bucket; the export describes itself."""
+    import random
+
+    from dynamo_tpu.tracing.hist import GapHistogram
+
+    rng = random.Random(seed)
+    xs = ([rng.gauss(0.016, 0.001) for _ in range(3000)]
+          + [rng.uniform(0.06, 0.2) for _ in range(300)]
+          + [rng.uniform(1e-4, 9e-4) for _ in range(20)]
+          + [rng.uniform(4.2, 9.0) for _ in range(3)])
+    rng.shuffle(xs)
+    a, b = GapHistogram(), GapHistogram()
+    for i, x in enumerate(xs):
+        (a if i % 2 else b).add(x)
+    da, db = a.to_dict(), b.to_dict()
+    assert set(da) == {"lo_s", "ratio", "counts"}
+    assert da["lo_s"] == 1e-3 and da["ratio"] == 2 ** 0.25
+    assert len(da["counts"]) == 50      # < 1 ms, 48 buckets to 4.096 s, over
+    both = {"lo_s": da["lo_s"], "ratio": da["ratio"],
+            "counts": [x + y for x, y in zip(da["counts"], db["counts"])]}
+    assert sum(both["counts"]) == len(xs)
+    assert both["counts"][0] == 20 and both["counts"][-1] == 3
+
+    def edge_of(q):
+        """Bucket i ends at lo_s * ratio**i; the overflow bucket answers
+        with where it starts."""
+        seen = 0
+        for i, n in enumerate(both["counts"]):
+            seen += n
+            if n and seen >= q / 100.0 * len(xs):
+                return da["lo_s"] * da["ratio"] ** min(i, 48)
+
+    v = sorted(xs)
+    for q in (50, 90, 95, 99):
+        exact = v[-(-len(v) * q // 100) - 1]        # nearest rank
+        assert exact <= edge_of(q) * (1 + 1e-9)
+        assert edge_of(q) < exact * da["ratio"]
+    assert edge_of(100) == pytest.approx(4.096)
+    assert json.loads(json.dumps(da)) == da

@@ -176,3 +176,167 @@ async def test_draining_rejects_new_requests(served):
     # draining is its own retryable code: routers divert instead of
     # counting it against the worker's circuit breaker
     assert exc_info.value.code == ERR_DRAINING
+
+
+# ------------- PR 39: a request's way in, a token's way out --------------
+
+
+@pytest.fixture
+def spans():
+    """Every span this test's requests end, from an isolated collector."""
+    from dynamo_tpu import tracing
+
+    exporter = tracing.InMemorySpanExporter()
+    tracer = tracing.reset()
+    tracer.configure(sample_ratio=1.0)
+    tracer.add_exporter(exporter)
+    yield exporter.spans
+    tracing.reset()
+
+
+async def test_ingress_span_stamps_way_in_and_way_out(spans):
+    """One request through TransportClient -> IngressServer: the ingress span
+    says how long the way in was (``upstream_s`` from the front door's
+    stamp, ``wire_s`` from the client's write) and what the stream's own
+    socket saw of the way out: ``frames``, ``send_sum_s`` / ``send_max_s``,
+    ``first_sent``
+    and the gaps between send completions as a histogram whose counts sum
+    to ``frames - 1`` and whose p95 is within one bucket of the exact."""
+    import contextlib
+    import time
+
+    from benchmarks.chip.metrics import percentile
+
+    pauses = [0.02] * 18 + [0.08] * 2      # the tail is the two long ones
+    wrote = []                             # when each data frame was written
+
+    @contextlib.contextmanager
+    def send_phase():
+        yield
+        wrote.append(time.monotonic())
+
+    async def slow_engine(request, context):
+        yield {"i": -1}
+        for i, pause in enumerate(request["pauses"]):
+            await asyncio.sleep(pause)
+            yield {"i": i}
+
+    server = IngressServer(FnEngine(slow_engine), host="127.0.0.1",
+                           send_phase=send_phase)
+    await server.start()
+    client = TransportClient()
+    try:
+        ctx = Context()
+        ctx.accepted_unix = time.time() - 0.25   # the front door, 250 ms ago
+        out = [x["i"] async for x in client.generate(
+            f"127.0.0.1:{server.port}", {"pauses": pauses}, ctx)]
+    finally:
+        await client.close()
+        await server.stop()
+    assert out == [-1] + list(range(len(pauses)))
+    [ing] = [s for s in spans if s.name == "worker.ingress"]
+    a = ing.attrs
+    assert 0.25 <= a["upstream_s"] < 1.0
+    assert 0.0 <= a["wire_s"] < a["upstream_s"]
+    at = {name: off for off, name, _ in ing.events}
+    assert 0.0 < at["first_sent"] <= ing.duration_s
+    assert a["frames"] == len(pauses) + 1
+    assert 0.0 < a["send_max_s"] <= a["send_sum_s"] < ing.duration_s
+    hist = a["sent_gaps"]
+    assert set(hist) == {"lo_s", "ratio", "counts"}
+    assert hist["ratio"] <= 2 ** 0.25 and hist["lo_s"] == 1e-3
+    assert sum(hist["counts"]) == a["frames"] - 1
+    exact = percentile([b - c for c, b in zip(wrote, wrote[1:])], 95)
+    # bucket i ends at lo_s * ratio**i: the first whose running count
+    # reaches 95% of the gaps
+    need, seen = 0.95 * sum(hist["counts"]), 0
+    for i, n in enumerate(hist["counts"]):
+        seen += n
+        if n and seen >= need:
+            break
+    edge = hist["lo_s"] * hist["ratio"] ** i
+    assert edge / hist["ratio"] ** 2 <= exact <= edge * hist["ratio"]
+    assert 0.07 < edge < 0.2
+
+
+async def test_ingress_span_without_stamps_has_no_way_in_attrs(spans):
+    """A caller that sends neither stamp (here: a bare frame, no headers)
+    gets a span without ``upstream_s`` / ``wire_s`` (absent, not zero); a
+    TransportClient caller that was never stamped at a front door gets
+    ``wire_s`` alone."""
+    import msgpack
+
+    from dynamo_tpu.runtime.store import read_frame, write_frame
+
+    server = IngressServer(FnEngine(echo_engine), host="127.0.0.1")
+    await server.start()
+    client = TransportClient()
+    try:
+        reader, writer = await asyncio.open_connection("127.0.0.1",
+                                                       server.port)
+        write_frame(writer, {"t": "req", "rid": "bare", "payload":
+                             msgpack.packb({"n": 2, "msg": "m"})})
+        await writer.drain()
+        kinds = [(await read_frame(reader))["t"] for _ in range(3)]
+        writer.close()
+        assert kinds == ["data", "data", "end"]
+        async for _ in client.generate(f"127.0.0.1:{server.port}",
+                                       {"n": 2, "msg": "m"}, Context()):
+            pass
+    finally:
+        await client.close()
+        await server.stop()
+    bare, plain = [s for s in spans if s.name == "worker.ingress"]
+    assert bare.attrs["rid"] == "bare" and bare.attrs["frames"] == 2
+    assert "upstream_s" not in bare.attrs and "wire_s" not in bare.attrs
+    assert "upstream_s" not in plain.attrs and plain.attrs["wire_s"] >= 0.0
+    assert [name for _, name, _ in bare.events] == ["first_sent"]
+
+
+async def test_unsampled_stream_takes_no_per_frame_stamps():
+    """The way out is stamped per frame only where an exporter may take the
+    span (``SpanCollector.keeps``): with an exporter but the trace not
+    sampled and no slow threshold, the ingress span carries the way in
+    (stamped once a request) and nothing per frame, and the profiler
+    annotation is never entered; a slow threshold turns the stamps on."""
+    import contextlib
+
+    from dynamo_tpu import tracing
+
+    entered = []
+
+    @contextlib.contextmanager
+    def send_phase():
+        entered.append(1)
+        yield
+
+    async def one(tracer):
+        server = IngressServer(FnEngine(echo_engine), host="127.0.0.1",
+                               send_phase=send_phase)
+        await server.start()
+        client = TransportClient()
+        try:
+            ctx = Context()
+            out = [x async for x in client.generate(
+                f"127.0.0.1:{server.port}", {"n": 3, "msg": "m"}, ctx)]
+        finally:
+            await client.close()
+            await server.stop()
+        assert len(out) == 3
+        [span] = [s for s in tracer.get_trace(ctx.trace.trace_id)
+                  if s.name == "worker.ingress"]
+        return span
+
+    tracer = tracing.reset()
+    try:
+        tracer.add_exporter(tracing.InMemorySpanExporter())
+        assert not tracer.keeps("0" * 32)
+        span = await one(tracer)
+        assert "wire_s" in span.attrs and "frames" not in span.attrs
+        assert not span.events and not entered
+        tracer.configure(slow_threshold_s=60.0)
+        assert tracer.keeps("0" * 32)
+        span = await one(tracer)
+        assert span.attrs["frames"] == 3 and len(entered) == 3
+    finally:
+        tracing.reset()
