@@ -30,7 +30,7 @@ from .. import tracing
 from ..observability import compilewatch, profiling
 from ..observability import flops as obs_flops
 from ..parallel import layout
-from ..parallel.moe import MOE_STATS
+from ..parallel.moe import GMM_TILES_TRACED, MOE_STATS
 from ..observability.flops import FlopsModel
 from ..observability.stepstats import (
     DECODE, PREFILL, SPEC_VERIFY, StepRecord, StepStats, kv_blocks_walked,
@@ -1759,6 +1759,10 @@ class InferenceEngine(EngineCore):
             "attention": {k: dict(v) for k, v in
                           model_lib.ATTENTION_TRACES.items()},
             "attention_choice": self.attention_impl_choice,
+            # a table's grouped matmuls, one entry a distinct call as traced
+            "expert_tiles": [
+                {"rows": r, "k": k, "n": n, "tile": list(tile)}
+                for (r, k, n), tile in sorted(GMM_TILES_TRACED.items())],
             "native": native.implementation(),
             "compile_cache": compile_cache_stats(),
             "compile": compilewatch.snapshot(),

@@ -23,6 +23,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.logging import get_logger
+
+log = get_logger("moe")
+
 
 def moe_capacity(num_tokens: int, num_experts: int, top_k: int,
                  capacity_factor: float) -> int:
@@ -93,20 +97,81 @@ def moe_ffn(
 # layers).  moe_ffn above stays for a model without a table; that there are
 # two is debt (ROADMAP.md, Queue 3).
 
-# the grouped matmul's (m, k, n) tile for x @ [D, F] and h @ [F, D]; the
-# sorted pairs are padded to a multiple of m.  Read on the chip at D 3072,
-# F 1024, 8 rows, 38 experts touched (PERF.md, PR 32): 1.01 ms = 710 GB/s of
-# expert weights, against 1.02-1.10 for ten other tilings with k, n >= 512
-# and 1.18 for three ``jax.lax.ragged_dot``
-GMM_TILE = (128, 1024, 1024)
+# The default scoped VMEM of a Pallas call on the chip (16 MiB on a v5e):
+# megablox ``gmm`` sets no limit of its own, so its blocks must fit it.
+GMM_VMEM_BYTES = 16 * 2 ** 20
+
+
+def gmm_block_bytes(tm: int, tk: int, tn: int, itemsize: int = 2) -> int:
+    """VMEM one grouped-matmul step holds: the lhs ``[tm, tk]`` and rhs
+    ``[tk, tn]`` blocks double-buffered, the float32 output block
+    double-buffered, the accumulator and the product before it is added
+    (Mosaic took (128, 3072, 1024) and refused (512, 3072, 512), which this
+    count puts 0.5 MiB under and exactly at the limit; it also took
+    (128, 1024, 3072), which the count puts over: it errs to the safe
+    side)."""
+    return 2 * (tm * tk + tk * tn) * itemsize + 4 * tm * tn * 4
+
+
+def _cuts(dim: int):
+    """``dim`` and its divisors that are whole lane tiles, largest first."""
+    return [dim] + [d for d in range(dim - 128, 0, -128)
+                    if dim % d == 0 and d % 128 == 0]
+
+
+def gmm_tile(rows: int, k: int, n: int, itemsize: int = 2):
+    """The grouped matmul's ``(tm, tk, tn)`` for ``rows`` sorted pairs times
+    ``[groups, k, n]``, from the shape alone (static at trace time).
+
+    tk = K wherever a block of K fits the scoped VMEM: gmm's grid is
+    (n tiles, visits, k tiles) and a weight block's index (group, k_i, n_i),
+    so with one k step a group that spans two row tiles keeps its block
+    between the visits, and with three it is fetched again; a K that the
+    tile does not divide (2560 by 1024) also costs a masked remainder step.
+    tn = N, else its largest divisor of whole lane tiles that fits, 512 at
+    least.  tm = 64 up to 1024 rows (a decode window: a group holds a few
+    rows, half the row tile halves a visit's matmul and lhs block and adds
+    hardly a visit), 128 beyond (a chunk: 64 doubles the row tiles).
+
+    Read on the chip (PERF.md, PR 40), us a call = group metadata + kernel,
+    medians.  "all E": the parent's form (sizes over all routed experts, a
+    group offset, gmm's zeroing select) at PR 32's tile (128, 1024, 1024),
+    k and n cut to the matrix; "held": the held sizes alone at that tile;
+    then the tile chosen here:
+
+    (pairs, K, N), touched         all E   held  chosen
+    (320, 3072, 1024), 93 of 128     931    927  (64,3072,1024) 913-922
+    (320, 1024, 3072)                932    919  (64,1024,3072) 921-924
+    (5120, 3072, 1024), 128         1575   1499  (128,3072,1024) 1384-1403
+    (5120, 1024, 3072)              1594   1367  (128,1024,1536) 1351
+    (1024, 2560, 768), 29 of 64      363    331  (64,2560,768) 287-302
+    (1024, 768, 2560)                341    327  (64,768,2560) 279-293
+    (4096, 2560, 768), 44            580    467  (128,2560,768) 419-429
+    (4096, 768, 2560)                552    424  (128,768,2560) 377-388
+
+    Beside them, at 5120 pairs: (128,3072,512) 1413, (128,1536,1024) 1491,
+    (128,1024,3072) 1325-1359, tm 256 1463-1518, tm 64 1413-1425 and
+    1362-1364; at 4096 pairs tm 64 equals 128; at 1024 pairs tm 128
+    304-321 and 293-316, tm 32 291-292 and 280-282; tn = N/2 within 10 us
+    of tn = N everywhere.  At 320 pairs no tile differs by more than the
+    runs do: PR 32's, read at 8 rows (1.01 ms), is as fast there as any
+    and loses only at a chunk's rows."""
+    tm = 64 if rows <= 1024 else 128
+    for tk in _cuts(k):
+        for tn in (c for c in _cuts(n) if c >= min(n, 512)):
+            if gmm_block_bytes(tm, tk, tn, itemsize) < GMM_VMEM_BYTES:
+                return tm, tk, tn
+    raise ValueError(f"no grouped-matmul tile of [{k}, {n}] fits the "
+                     f"scoped VMEM")
+
+
+# (rows, K, N) -> (tm, tk, tn) of every distinct grouped-matmul call traced
+# in this process (one serving engine a process, as ATTENTION_TRACES):
+# ``InferenceEngine.device_report`` shows it under ``/health``
+GMM_TILES_TRACED: Dict[tuple, tuple] = {}
 
 MOE_STATS = ("moe_pairs", "moe_pairs_held", "moe_experts_touched",
              "moe_load_max")
-
-
-def _tile(k: int, n: int):
-    """``GMM_TILE`` with its k and n tiles no larger than the matrix."""
-    return (GMM_TILE[0], min(GMM_TILE[1], k), min(GMM_TILE[2], n))
 
 
 def route(x: jax.Array, w_router: jax.Array, *, top_k: int,
@@ -183,7 +248,15 @@ def routed_ffn(
     Eh, _, F = w_gate.shape
     dt = x.dtype
     pairs = N * top_k
-    rows = -(-pairs // GMM_TILE[0]) * GMM_TILE[0]
+    tiles = {(K, Nn): gmm_tile(pairs, K, Nn, w_gate.dtype.itemsize)
+             for K, Nn in ((D, F), (F, D))}
+    tm = tiles[D, F][0]
+    rows = -(-pairs // tm) * tm
+    for (K, Nn), tile in tiles.items():
+        if (rows, K, Nn) not in GMM_TILES_TRACED:
+            GMM_TILES_TRACED[rows, K, Nn] = tile
+            log.info("grouped matmul [%d, %d] @ [%d, %d, %d]: tile %s",
+                     rows, K, Eh, K, Nn, tile)
 
     with jax.named_scope("moe_router"):
         top_idx, top_w = route(x, w_router, top_k=top_k,
@@ -207,15 +280,17 @@ def routed_ffn(
     with jax.named_scope("moe_experts"):
         xs = jnp.take(x, token, axis=0)
         xs = jnp.pad(xs, ((0, rows - pairs), (0, 0)))
-        start = jnp.asarray(held_start, jnp.int32)
-        mm = functools.partial(gmm, group_sizes=sizes,
+        # the held groups alone, from row 0 on: metadata over Eh groups and
+        # no offset, so gmm neither rolls it nor zeroes the rows behind them
+        mm = functools.partial(gmm, group_sizes=here,
                                preferred_element_type=jnp.float32,
-                               group_offset=start, interpret=interpret)
-        gate = jax.nn.silu(mm(xs, w_gate, tiling=_tile(D, F)))
-        up = mm(xs, w_up, tiling=_tile(D, F))
+                               interpret=interpret)
+        gate = jax.nn.silu(mm(xs, w_gate, tiling=tiles[D, F]))
+        up = mm(xs, w_up, tiling=tiles[D, F])
         y = mm((gate * up).astype(dt), w_down,
-               tiling=_tile(F, D))[:pairs]                   # [pairs, D] f32
-        # rows of no group come back as they were left: select, not multiply
+               tiling=tiles[F, D])[:pairs]                   # [pairs, D] f32
+        # rows of no group come back as they were left (whatever the VMEM
+        # held, a NaN too): select, not multiply
         w_sorted = jnp.take(top_w.reshape(pairs), order)
         y = jnp.where(held_sorted[:, None], y * w_sorted[:, None], 0.0)
         # back to (token, slot) order, then the sum over a token's slots

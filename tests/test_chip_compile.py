@@ -217,6 +217,36 @@ def test_the_latent_decode_walk_compiles_at_the_cells_shapes(
     assert text.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+@pytest.mark.parametrize("D,F,E,Eh,k,seqs,router", [
+    (3072, 1024, 256, 128, 10, 32, {}),
+    (2560, 768, 512, 64, 8, 128,
+     dict(score="sigmoid", n_group=8, topk_group=4)),
+])
+def test_the_expert_layer_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, D, F, E, Eh, k, seqs, router, phase):
+    """``moe.routed_ffn`` at laguna-s-2.1-ep2's and ling-3.0-flash-ep8's
+    widths, a decode window's rows and a T=512 chunk's: the tiles
+    ``gmm_tile`` returns there (tk = K: a block of a whole gate matrix)
+    fit the scoped VMEM as Mosaic counts it, and the layer is three grouped
+    matmuls."""
+    from dynamo_tpu.parallel.moe import routed_ffn
+
+    rows = seqs if phase == "decode" else 512
+
+    def S(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def layer(x, wr, wg, wu, wd):
+        return routed_ffn(x, wr, wg, wu, wd, top_k=k, held_start=0,
+                          **router)[0]
+
+    text = jax.jit(layer).lower(
+        S((rows, D)), S((D, E), jnp.float32), S((Eh, D, F)), S((Eh, D, F)),
+        S((Eh, F, D))).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
 # ---- whole step programs at the cells' shapes: the cache keeps its layout ---
 #
 # ``forward`` writes K and V whole pages at a time, by a scatter whose indexed

@@ -419,6 +419,246 @@ def test_the_new_layer_has_no_capacity():
     assert "one_hot" not in src
 
 
+# ------------------------- the grouped matmul's call and tile ---------------
+
+# (D, F) of the two tables at the benchmark's widths and the rows (pairs) of
+# the benchmark's decode window and T=512 chunk: gate and up see (rows, D, F),
+# down (rows, F, D): the eight shapes the tile was read at (PERF.md, PR 40)
+WIDTHS = {"laguna": dict(D=3072, F=1024, decode=320, prefill=5120),
+          "ling": dict(D=2560, F=768, decode=1024, prefill=4096)}
+EIGHT_SHAPES = [(w[phase], k, n) for w in WIDTHS.values()
+                for phase in ("decode", "prefill")
+                for k, n in ((w["D"], w["F"]), (w["F"], w["D"]))]
+
+
+@pytest.mark.parametrize("rows,k,n", EIGHT_SHAPES + [
+    (148, 24, 16), (2048, 64, 32), (5120, 4096, 14336), (4096, 7168, 2048)])
+def test_the_tile_follows_the_shape(rows, k, n):
+    """tk is K or a divisor of it in whole lane tiles, and K wherever a
+    block of K fits; tn divides N; the blocks fit the scoped VMEM; the
+    row tile follows the rows (``routed_ffn`` pads the sorted pairs to it:
+    ``test_health_names_the_tile_of_each_distinct_call``)."""
+    tm, tk, tn = moe.gmm_tile(rows, k, n)
+    assert k % tk == 0 and n % tn == 0
+    assert tk == k or tk % 128 == 0
+    assert tn == n or (tn % 128 == 0 and tn >= 512)
+    assert moe.gmm_block_bytes(tm, tk, tn) < moe.GMM_VMEM_BYTES
+    if moe.gmm_block_bytes(tm, k, min(n, 512)) < moe.GMM_VMEM_BYTES:
+        assert tk == k                      # no k step, nothing read twice
+    assert tm == (64 if rows <= 1024 else 128)
+
+
+def _three_classes(D, F, held_start, seed=0):
+    """8 routed experts of which the 4 from ``held_start`` are held, 2 a
+    token; 330 tokens in three classes the router tells apart by one
+    feature: 100 choose held 0 and one not held, 200 held 1 and held 3, 30
+    held 3 and one not held; held 2 gets no token; every 7th row is dead.
+    bfloat16, as served."""
+    rng = np.random.default_rng(seed)
+    h, o = held_start, (held_start + 4) % 8
+    cls = np.repeat([0, 1, 2], [100, 200, 30])
+    x = 0.5 * rng.standard_normal((330, D))
+    x[:, :3] = np.eye(3)[cls]
+    wr = np.zeros((D, 8))
+    for c, (a, b) in enumerate(((h, o), (h + 1, h + 3), (h + 3, o + 1))):
+        wr[c, a], wr[c, b] = 40.0, 20.0
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)          # noqa: E731
+    w = [bf(rng.standard_normal(s) / np.sqrt(s[1]))
+         for s in ((4, D, F), (4, D, F), (4, F, D))]
+    live = np.arange(330) % 7 != 3
+    return bf(x), jnp.asarray(wr, jnp.float32), w, live, cls
+
+
+def _swiglu_by_expert(x, wr, w, live, held_start, scale):
+    """Per token and per chosen held expert, written out in float64 on the
+    bfloat16 values; the product rounded to bfloat16 as the layer does."""
+    f64 = lambda a: np.asarray(a.astype(jnp.float32), np.float64)  # noqa
+    x, (wg, wu, wd) = f64(x), (f64(a) for a in w)
+    logits = x @ np.asarray(wr, np.float64)
+    s = np.exp(logits - logits.max(-1, keepdims=True))
+    s /= s.sum(-1, keepdims=True)
+    top = np.argsort(-s, axis=-1, kind="stable")[:, :2]
+    out = np.zeros_like(x)
+    for e in range(4):
+        rows = np.flatnonzero(live & (top == held_start + e).any(-1))
+        g = x[rows] @ wg[e]
+        hid = f64(jnp.asarray(g / (1 + np.exp(-g)) * (x[rows] @ wu[e]),
+                              jnp.bfloat16))
+        weight = scale * s[rows, held_start + e] / np.take_along_axis(
+            s[rows], top[rows], axis=1).sum(-1)
+        out[rows] += weight[:, None] * (hid @ wd[e])
+    return out, top
+
+
+@pytest.mark.parametrize("held_start", [0, 4])
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+@pytest.mark.parametrize("table", sorted(WIDTHS))
+def test_routed_ffn_at_the_tiles_of_the_eight_shapes(table, phase,
+                                                     held_start, monkeypatch):
+    """The layer at the benchmark's widths and at the tile its call gets
+    there (the rows cut down to 660 pairs), against the per-expert SwiGLU
+    written out: a shard that starts at expert 0 and one that does not,
+    dead rows, a group that spans three row tiles or four, an expert
+    without a token, rows of no group behind the last one."""
+    wd = WIDTHS[table]
+    D, F = wd["D"], wd["F"]
+    tile = moe.gmm_tile
+    monkeypatch.setattr(moe, "gmm_tile", lambda rows, k, n, itemsize=2:
+                        tile(wd[phase], k, n, itemsize))
+    x, wr, w, live, cls = _three_classes(D, F, held_start)
+    got, stats, chosen = moe.routed_ffn(
+        x, wr, *w, top_k=2, held_start=held_start, scale=2.5,
+        live=jnp.asarray(live), interpret=True)
+    want, top = _swiglu_by_expert(x, wr, w, live, held_start, 2.5)
+    assert np.array_equal(np.asarray(chosen), top)
+    n = [int((live & (cls == c)).sum()) for c in range(3)]
+    assert [int(v) for v in stats] == [
+        2 * sum(n), n[0] + 2 * n[1] + n[2], 3, n[1] + n[2]]
+    tm = tile(wd[phase], D, F)[0]
+    assert (n[0] + n[1] - 1) // tm - n[0] // tm >= 2     # 3 row tiles or 4
+    got = np.asarray(got.astype(jnp.float32), np.float64)
+    assert np.all(got[~live] == 0)
+    scale = np.abs(want).max()
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=1e-2 * scale)
+    assert np.abs(want[live]).max(axis=1).min() > 1e-3 * scale
+
+
+def _poisoned_gmm(monkeypatch):
+    """megablox ``gmm`` with every output row behind the last group set to
+    NaN, which is what a row no group owns may hold on the chip."""
+    import sys
+
+    mod = sys.modules["jax.experimental.pallas.ops.tpu.megablox.gmm"]
+    real = mod.gmm
+
+    def gmm(lhs, rhs, group_sizes, **kw):
+        out = real(lhs, rhs, group_sizes, **kw)
+        behind = jnp.arange(out.shape[0]) >= jnp.sum(group_sizes)
+        return jnp.where(behind[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(mod, "gmm", gmm)
+
+
+@pytest.mark.parametrize("held_start", [0, 8])
+def test_rows_of_no_group_may_hold_anything(held_start, monkeypatch):
+    """The grouped matmul is told the held groups alone and leaves the rows
+    behind them as they were: a NaN there reaches no token."""
+    w = _experts()
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((37, 24)),
+                    jnp.float32)
+    live = jnp.asarray(np.arange(37) % 5 != 2)
+    want, s0, c0 = _routed(x, w, held_start, held_start + 8, live=live)
+    _poisoned_gmm(monkeypatch)
+    got, s1, c1 = _routed(x, w, held_start, held_start + 8, live=live)
+    assert 0 < int(s1[1]) < int(s1[0])              # some rows are behind
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(c1), np.asarray(c0))
+
+
+# sha256 of ``top_idx`` and the four ``MOE_STATS`` as the commit before PR 40
+# (b5c78fa) gave them: the call of the grouped matmul changed, the choices
+# and the counters did not
+CHOICES_BEFORE = {
+    ("softmax", 0, 8): "5d34d183ecc78a0d", ("softmax", 8, 16):
+    "f3f81bc789ee5136", ("softmax", 6, 8): "6a88d42e8d91705b",
+    ("sigmoid", 0, 8): "c23f1038c7c22954", ("sigmoid", 8, 16):
+    "4e92fa99ae03aed1", ("sigmoid", 6, 8): "3f1f37c48f07fbba",
+}
+
+
+@pytest.mark.parametrize("router,lo,hi", sorted(CHOICES_BEFORE))
+def test_choices_and_counters_are_what_they_were(router, lo, hi):
+    rng = np.random.default_rng(40)
+    f = lambda *s: jnp.asarray(rng.normal(size=s) / np.sqrt(s[-2]),  # noqa
+                               jnp.float32)
+    x, wr, wg, wu, wd = (f(40, 24), f(24, 16), f(16, 24, 16), f(16, 24, 16),
+                         f(16, 16, 24))
+    bias = jnp.asarray(0.05 * rng.normal(size=(16,)), jnp.float32)
+    kw = {} if router == "softmax" else dict(
+        score="sigmoid", bias=bias, n_group=8, topk_group=4)
+    _, stats, chosen = moe.routed_ffn(
+        x, wr, wg[lo:hi], wu[lo:hi], wd[lo:hi], top_k=4, held_start=lo,
+        scale=2.5, live=jnp.asarray(np.arange(40) % 7 != 3), interpret=True,
+        **kw)
+    assert hashlib.sha256(
+        np.asarray(chosen).tobytes() + np.asarray(stats).tobytes()
+    ).hexdigest()[:16] == CHOICES_BEFORE[router, lo, hi]
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, but a Pallas
+    kernel's own body."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("B,T", [(8, 1), (1, 512)])
+@pytest.mark.parametrize("table", sorted(TABLES))
+def test_a_sparse_layer_is_three_grouped_matmuls_and_one_select(table, B, T):
+    """A decode step and a T=512 chunk of each table, as traced: three
+    grouped matmuls a sparse layer's body, and no select over a whole
+    grouped-matmul output but ``routed_ffn``'s own over the down
+    projection's (megablox zeroes the rows behind a SHARD's groups with one
+    more; the call no longer poses as a shard)."""
+    cfg = _model(table=table)
+    eng = _engine_config(table, max_model_len=1024,
+                         max_num_batched_tokens=512)
+    params = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, eng))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)      # noqa: E731
+    seats = dict(seats=jnp.zeros((B,), jnp.int32)) if cfg.has_seat_state \
+        else {}
+    jaxpr = jax.make_jaxpr(
+        lambda p, c, t, po, b: M.forward(cfg, eng, p, c, t, po, b, **seats)
+    )(params, cache, i32(B, T), i32(B, T), i32(B, 64)).jaxpr
+    pairs = B * T * cfg.num_experts_per_token
+    D, F = cfg.hidden_size, cfg.moe_intermediate_size
+    tm = moe.gmm_tile(pairs, D, F)[0]
+    rows = -(-pairs // tm) * tm
+    gmms = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"
+            and tuple(e.outvars[0].aval.shape) in ((rows, F), (rows, D))
+            and e.outvars[0].aval.dtype == jnp.float32]
+    assert gmms and len(gmms) % 3 == 0
+    assert sorted(e.outvars[0].aval.shape[1] for e in gmms) == sorted(
+        [F, F, D] * (len(gmms) // 3))
+    whole = [e for e in _eqns(jaxpr) if e.primitive.name == "select_n"
+             and tuple(e.outvars[0].aval.shape) in ((rows, F), (rows, D))
+             and e.outvars[0].aval.dtype == jnp.float32]
+    # routed_ffn's own is over [pairs, D]: a whole output's where no row
+    # was padded
+    assert len(whole) == (len(gmms) // 3 if pairs == rows else 0)
+
+
+@pytest.mark.parametrize("table", sorted(TABLES))
+def test_health_names_the_tile_of_each_distinct_call(engines, table):
+    """``/health`` (``device_report``) lists every distinct grouped-matmul
+    call traced in the process with the tile it got, the one ``gmm_tile``
+    gives for its shape: here at least a decode step's two."""
+    eng = engines(table)
+    cfg = eng.model_config
+    B, k = 8, cfg.num_experts_per_token
+    D, F = cfg.hidden_size, cfg.moe_intermediate_size
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)                 # noqa: E731
+    seats = dict(seats=i32(B)) if cfg.has_seat_state else {}
+    jax.eval_shape(lambda p, c: M.forward(
+        cfg, eng.config, p, c, i32(B, 1), i32(B, 1), i32(B, 4), **seats),
+        eng.params, eng.cache)
+    # the record is the process's: this model's calls are those at its widths
+    calls = [c for c in eng.device_report()["expert_tiles"]
+             if (c["k"], c["n"]) in ((D, F), (F, D))]
+    assert all(tuple(c["tile"]) == moe.gmm_tile(c["rows"], c["k"], c["n"])
+               and c["rows"] % c["tile"][0] == 0 for c in calls)
+    tm = moe.gmm_tile(B * k, D, F)[0]
+    rows = -(-B * k // tm) * tm
+    assert {(rows, D, F), (rows, F, D)} <= {
+        (c["rows"], c["k"], c["n"]) for c in calls}
+
+
 # ------------------------- the window in the kernels ------------------------
 
 
