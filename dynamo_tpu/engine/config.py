@@ -1,7 +1,9 @@
 """Model + engine configuration.
 
-``ModelConfig`` describes a Llama-class decoder-only transformer (the shapes
-cover Llama 2/3 and TinyLlama-style test models). ``EngineConfig`` carries the
+``ModelConfig`` describes a decoder-only transformer: the one Llama-class
+block in every layer (Llama 2/3, Mistral, TinyLlama-style test models), or a
+table of layer kinds under the published keys of a ``config.json`` that has
+one. ``EngineConfig`` carries the
 serving-side knobs that the reference exposes through engine flags and the
 ModelRuntimeConfig (ref: lib/llm/src/local_model/runtime_config.rs:9 —
 ``total_kv_blocks``, ``max_num_seqs``, ``max_num_batched_tokens``).
@@ -53,7 +55,17 @@ class LayerEntry(NamedTuple):
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """Llama-class decoder-only transformer shapes."""
+    """A decoder-only transformer's shapes.
+
+    Without ``layer_types`` every layer is the Llama-class block: RMSNorm,
+    grouped-query attention with rope, a SwiGLU (or the capacity experts of
+    ``num_experts``).  With it the model is a table, one row a layer: K / V
+    attention with or without a window, latent (MLA) attention with or
+    without a q-LoRA, delta-rule linear attention; a dense SwiGLU or routed
+    experts beside a shared one, whose router may also choose zero-compute
+    experts and whose sum may join the stream a row later (``moe_shortcut``:
+    two rows are one published double layer).  The comment below says which
+    field is whose."""
 
     vocab_size: int = 128256
     hidden_size: int = 4096
@@ -102,6 +114,19 @@ class ModelConfig:
     # ``n_group`` > 0 the choice is limited to the ``topk_group`` best
     # groups, and ``moe_router_enable_expert_bias`` adds a bias to the
     # scores for the choice alone (DeepSeek-V3's ``noaux_tc``).
+    #
+    # A latent layer with ``q_lora_rank`` > 0 makes its queries in two steps,
+    # ``rmsnorm(x Wqa) Wqb``; ``mla_scale_q_lora`` / ``mla_scale_kv_lora``
+    # multiply the queries by ``sqrt(hidden_size / q_lora_rank)`` and the
+    # normed latent by ``sqrt(hidden_size / kv_lora_rank)`` (LongCat-Flash).
+    # ``zero_expert_num`` > 0 widens the router behind the routed experts:
+    # an index >= ``num_routed_experts`` is a zero-compute expert whose
+    # output is its input (``zero_expert_type`` "identity"), computed where
+    # the token lives, on every shard alike.  ``moe_shortcut`` (shortcut-
+    # connected experts, arXiv:2509.01322): a "sparse" row's routed sum does
+    # not join the stream at that row but after the FFN of the "dense" row
+    # that follows it, so that row's attention and FFN never see it; the
+    # sparse row's shared expert is then the double layer's first dense FFN.
     layer_types: Tuple[str, ...] = ()
     mlp_layer_types: Tuple[str, ...] = ()
     num_heads_per_layer: Tuple[int, ...] = ()
@@ -125,6 +150,12 @@ class ModelConfig:
     n_group: int = 0
     topk_group: int = 0
     moe_router_enable_expert_bias: bool = False
+    q_lora_rank: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    zero_expert_num: int = 0
+    zero_expert_type: str = "identity"
+    moe_shortcut: bool = False
 
     def __post_init__(self):
         for name in ("layer_types", "mlp_layer_types", "num_heads_per_layer",
@@ -188,6 +219,23 @@ class ModelConfig:
             if self.score_function not in ("softmax", "sigmoid"):
                 raise ValueError(
                     f"unknown score_function {self.score_function!r}")
+            if self.zero_expert_num < 0 or (
+                    self.zero_expert_num
+                    and self.zero_expert_type != "identity"):
+                raise ValueError(
+                    f"zero-compute experts are identities, not "
+                    f"{self.zero_expert_type!r}")
+            if self.zero_expert_num and self.n_group:
+                raise ValueError("a group limit over a router with "
+                                 "zero-compute experts is not defined")
+            if self.moe_shortcut and any(
+                    f == "sparse" and self.mlp_layer_types[li + 1:li + 2]
+                    != ("dense",)
+                    for li, f in enumerate(self.mlp_layer_types)):
+                raise ValueError(
+                    "moe_shortcut: every sparse row is followed by the "
+                    "dense row its routed sum joins behind, not "
+                    f"{self.mlp_layer_types}")
             if self.n_group and (
                     self.num_routed_experts % self.n_group
                     or not 0 < self.topk_group <= self.n_group
@@ -273,6 +321,12 @@ class ModelConfig:
                                    f, seen[f]))
             seen[f] += 1
         return tuple(rows)
+
+    @property
+    def router_width(self) -> int:
+        """Outputs of a sparse layer's router: the routed experts, then the
+        zero-compute ones."""
+        return self.num_routed_experts + self.zero_expert_num
 
     @property
     def experts_held(self) -> Tuple[int, int]:

@@ -110,6 +110,10 @@ TABLE_SCOPES = (
     "kda_chunk",   # T > 1: the chunked form over a prefill chunk
     "kda_out",     # the per-head norm of the output
     "attention_latent",  # "attention" of a layer that keeps a latent (MLA)
+    # a sparse layer whose router also chooses zero-compute experts, and
+    # whose sum joins the stream a row later (``moe_shortcut``; PR 41)
+    "moe_zero",    # the identity experts' part: (sum of their weights) * x
+    "moe_shortcut",  # the pending routed sum joins behind the next row's FFN
 )
 SCOPES += TABLE_SCOPES
 
@@ -174,8 +178,17 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
         if kind.name == LATENT_KIND:
             q_dim = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
             o_dim = cfg.v_head_dim
+        # behind a q-LoRA ``wq`` is Wqb, on Wqa's normed output.  The
+        # published scale sqrt(hidden / rank) is there to give a projection
+        # drawn for ``hidden`` inputs unit variance behind ``rank`` of them:
+        # draw it so.  Drawn for ``rank`` the scaled scores spread 6 wide,
+        # attention is an argmax, and a bfloat16 rounding grows eightfold a
+        # layer (PERF.md, PR 41)
+        q_in = (cfg.q_lora_rank if kind.name == LATENT_KIND
+                and cfg.q_lora_rank else D)
+        q_fan = D if cfg.mla_scale_q_lora else q_in
         layers["wq"][kind.name] = _normal(
-            next(key), (n, D, H * q_dim), D, dt)
+            next(key), (n, q_in, H * q_dim), q_fan, dt)
         layers["wo"][kind.name] = _normal(
             next(key), (n, H * o_dim, D), H * o_dim, dt)
         if cfg.attn_gate:
@@ -206,7 +219,12 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
             layers["mla_kv_norm"] = jnp.ones((n, r), dt)
             layers["mla_wukv"] = _normal(
                 next(key),
-                (n, r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), r, dt)
+                (n, r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                D if cfg.mla_scale_kv_lora else r, dt)   # as Wqb above
+            if cfg.q_lora_rank:
+                layers["mla_wqa"] = _normal(
+                    next(key), (n, D, cfg.q_lora_rank), D, dt)
+                layers["mla_q_norm"] = jnp.ones((n, cfg.q_lora_rank), dt)
     n_dense = cfg.mlp_layer_types.count("dense")
     n_sparse = L - n_dense
     if n_dense:
@@ -217,7 +235,7 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
     if n_sparse:
         Fs = cfg.shared_expert_intermediate_size
         layers["w_router"] = _normal(
-            next(key), (n_sparse, D, cfg.num_routed_experts), D, dt)
+            next(key), (n_sparse, D, cfg.router_width), D, dt)
         layers["shared_gate"] = _normal(next(key), (n_sparse, D, Fs), D, dt)
         layers["shared_up"] = _normal(next(key), (n_sparse, D, Fs), D, dt)
         layers["shared_down"] = _normal(next(key), (n_sparse, Fs, D), Fs, dt)
@@ -230,9 +248,13 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
         params["lm_head"] = _normal(next(key), (D, V), D, dt)
     if n_sparse and cfg.moe_router_enable_expert_bias:
         # drawn behind every other leaf: small beside the scores' spread,
-        # and not zero, so that the choice's bias is not a no-op (assumed)
-        layers["router_bias"] = 0.05 * jax.random.normal(
-            next(key), (n_sparse, cfg.num_routed_experts), jnp.float32)
+        # and not zero, so that the choice's bias is not a no-op (assumed).
+        # A sigmoid's scores spread over tenths; a softmax's over E outputs
+        # lie near 1 / E, where 0.05 would pin every token to one set
+        std = (0.05 if cfg.score_function == "sigmoid"
+               else 0.25 / cfg.router_width)
+        layers["router_bias"] = std * jax.random.normal(
+            next(key), (n_sparse, cfg.router_width), jnp.float32)
     return params
 
 
@@ -1254,15 +1276,32 @@ def latent_inputs(cfg: ModelConfig, kind, p: Any, h: jax.Array,
     """A latent (MLA) layer's normed input ``x``, the queries' two parts
     ``q_nope [B, T, H, qk_nope]`` and the roped ``q_pe [B, T, H, qk_rope]``,
     and what the token keeps: ``[B, T, 1, latent_width]`` = the normed
-    latent ``c``, the roped key part that all heads share, zeros."""
+    latent ``c``, the roped key part that all heads share, zeros.  With
+    ``q_lora_rank`` the queries are ``rmsnorm(x Wqa) Wqb`` (``wq`` is
+    ``Wqb``); ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` scale them and
+    ``c`` by ``sqrt(hidden_size / rank)``, so the page holds the scaled
+    ``c``."""
     B, T, _ = h.shape
     r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     with jax.named_scope("qkv_proj"):
         x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
-        q, ckpe = jax.lax.optimization_barrier(
-            (_mm(x, p["wq"]), _mm(x, p["mla_wdkv"])))
+        if cfg.q_lora_rank:
+            cq, ckpe = jax.lax.optimization_barrier(
+                (_mm(x, p["mla_wqa"]), _mm(x, p["mla_wdkv"])))
+            q = _mm(_rms_norm(cq, p["mla_q_norm"], cfg.rms_norm_eps),
+                    p["wq"])
+            if cfg.mla_scale_q_lora:
+                q = q * (cfg.hidden_size / cfg.q_lora_rank) ** 0.5
+        else:
+            q, ckpe = jax.lax.optimization_barrier(
+                (_mm(x, p["wq"]), _mm(x, p["mla_wdkv"])))
         q = q.reshape(B, T, kind.num_heads, dn + dr)
-        c = _rms_norm(ckpe[..., :r], p["mla_kv_norm"], cfg.rms_norm_eps)
+        c_norm = p["mla_kv_norm"]
+        if cfg.mla_scale_kv_lora:
+            # the scale rides the norm's float32 weight: one rounding
+            c_norm = (c_norm.astype(jnp.float32)
+                      * (cfg.hidden_size / r) ** 0.5)
+        c = _rms_norm(ckpe[..., :r], c_norm, cfg.rms_norm_eps)
     with jax.named_scope("rope"):
         rope = cfg.rope_of(kind)
         q_pe = _rope_kind(q[..., dn:], positions, rope)
@@ -1461,14 +1500,26 @@ def attn_output(cfg: ModelConfig, p: Any, h: jax.Array, x: jax.Array,
 def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
         live: Optional[jax.Array] = None, interpret: bool = False,
         ff_pin=None, stats_out: Optional[list] = None,
-        choices_out: Optional[list] = None) -> jax.Array:
+        choices_out: Optional[list] = None,
+        shortcut: Optional[list] = None) -> jax.Array:
     """``h`` plus the layer's FFN on its norm: the dense SwiGLU, the
     capacity experts of a model without a table (``cfg.is_moe``), or a
     table's sparse layer: the routed experts held here (``live [B, T]``
     rows only; their counters appended to ``stats_out``, every token's
     chosen experts ``[B, T, k]`` to ``choices_out``) plus the shared
-    expert.  ``ff_pin`` pins the dense intermediates (the ring path)."""
+    expert.  ``ff_pin`` pins the dense intermediates (the ring path).
+
+    ``cfg.moe_shortcut``: a sparse row's routed sum (held experts and
+    identities) is left in ``shortcut`` instead of joining ``h``, and the
+    dense row that follows takes it from there and adds it behind its own
+    FFN: the two rows are one double layer whose expert branch starts at the
+    first FFN's input and ends after the second, so the second attention
+    and FFN never see it.  The sparse row's shared expert is then the
+    double layer's first dense FFN (scope ``mlp``)."""
     B, T, D = h.shape
+    if cfg.moe_shortcut and shortcut is None:
+        raise ValueError("a moe_shortcut table is run by forward, which "
+                         "carries a sparse row's sum to the row behind it")
     if entry.ffn == "sparse":
         from ..parallel.moe import routed_ffn
 
@@ -1476,7 +1527,8 @@ def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
             x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
         # what a router beyond the softmax top-k is told (route's keywords)
         router = {}
-        if cfg.score_function != "softmax" or cfg.n_group:
+        if (cfg.score_function != "softmax" or cfg.n_group
+                or cfg.moe_router_enable_expert_bias):
             router = dict(score=cfg.score_function, n_group=cfg.n_group,
                           topk_group=cfg.topk_group,
                           bias=p.get("router_bias"))
@@ -1488,16 +1540,19 @@ def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
             renormalise=cfg.norm_topk_prob,
             scale=cfg.moe_routed_scaling_factor,
             live=None if live is None else live.reshape(B * T),
-            interpret=interpret, **router,
+            n_zero=cfg.zero_expert_num, interpret=interpret, **router,
         )
         if stats_out is not None:
             stats_out.append(stats)
         if choices_out is not None:
             choices_out.append(chosen.reshape(B, T, -1))
-        with jax.named_scope("moe_shared"):
+        with jax.named_scope("mlp" if cfg.moe_shortcut else "moe_shared"):
             gate = jax.nn.silu(_mm(x, p["shared_gate"]).astype(jnp.float32))
             up = _mm(x, p["shared_up"]).astype(jnp.float32)
             shared = _mm((gate * up).astype(h.dtype), p["shared_down"])
+            if cfg.moe_shortcut:
+                shortcut.append(routed.reshape(B, T, D))
+                return h + shared
             return h + routed.reshape(B, T, D) + shared
     with jax.named_scope("mlp"):
         x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
@@ -1519,7 +1574,11 @@ def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
         if ff_pin is not None:
             gate = jax.lax.with_sharding_constraint(gate, ff_pin)
             up = jax.lax.with_sharding_constraint(up, ff_pin)
-        return h + _mm((gate * up).astype(h.dtype), p["w_down"])
+        h = h + _mm((gate * up).astype(h.dtype), p["w_down"])
+    if cfg.moe_shortcut and shortcut:
+        with jax.named_scope("moe_shortcut"):
+            h = h + shortcut.pop()
+    return h
 
 
 def forward(
@@ -1545,7 +1604,7 @@ def forward(
     pad row) and is required where the model has such layers
     (:func:`linear_attention`); nothing else reads it.
 
-    ``moe_stats``, where a list is given, gains one int32 ``[4]`` a sparse
+    ``moe_stats``, where a list is given, gains one int32 row a sparse
     layer of a table (``parallel.moe.MOE_STATS``), and ``moe_choices`` the
     layer's chosen experts ``[B, T, k]``: traced values of the caller's own
     trace.
@@ -1642,6 +1701,7 @@ def forward(
     ff_pin = (NamedSharding(ring_mesh,
                             layout.spec(None, ring_lay.seq_axes(), None))
               if use_ring else None)
+    shortcut: list = []      # a double layer's pending routed sum (ffn)
     for li in range(cfg.num_layers):
         p, entry, kind = layer_params(cfg, stacked, li)
         if kind.name in (STATE_KIND, LATENT_KIND):
@@ -1674,7 +1734,8 @@ def forward(
                 new_latent.append(plane)
             h = attn_output(cfg, p, h, x, attn)
             h = ffn(cfg, entry, p, h, live=live, interpret=interpret,
-                    stats_out=moe_stats, choices_out=moe_choices)
+                    stats_out=moe_stats, choices_out=moe_choices,
+                    shortcut=shortcut)
             continue
         kv_at = kv_layer_index(cfg, li)
         lk, lv = cache["k"][kv_at], cache["v"][kv_at]   # [NB, KV, bs, hd]
@@ -1736,7 +1797,8 @@ def forward(
         # intermediates so w_down's row sharding can't pull a head-style
         # spec onto them
         h = ffn(cfg, entry, p, h, live=live, interpret=interpret,
-                ff_pin=ff_pin, stats_out=moe_stats, choices_out=moe_choices)
+                ff_pin=ff_pin, stats_out=moe_stats, choices_out=moe_choices,
+                shortcut=shortcut)
         if h_pin is not None:
             with jax.named_scope("mlp"):
                 h = jax.lax.with_sharding_constraint(h, h_pin)
@@ -2243,17 +2305,17 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
 
 def moe_stats_row(stats: list, width: int) -> jax.Array:
     """The routing counters of a window's sparse layers and steps (each
-    int32 ``[4]``, ``parallel.moe.MOE_STATS``) as one ``[1, width]`` int32
-    row: sums of pairs, pairs held and experts touched, the largest load;
-    zeros behind them."""
+    an int32 row of ``parallel.moe.MOE_STATS``) as one ``[1, width]`` int32
+    row: sums of pairs, pairs held, experts touched and identity pairs, the
+    largest load; zeros behind them."""
     from ..parallel.moe import MOE_STATS
 
     if width < len(MOE_STATS):
         raise ValueError(f"a decode bucket of {width} rows cannot carry "
                          f"the {len(MOE_STATS)} routing counters")
-    per = jnp.stack(stats)                                 # [n, 4]
-    row = jnp.concatenate([jnp.sum(per[:, :3], axis=0),
-                           jnp.max(per[:, 3:], axis=0)])
+    per = jnp.stack(stats)                         # [n, len(MOE_STATS)]
+    row = jnp.concatenate([jnp.sum(per[:, :-1], axis=0),
+                           jnp.max(per[:, -1:], axis=0)])
     return jnp.pad(row, (0, width - row.shape[0]))[None, :]
 
 

@@ -104,13 +104,16 @@ def param_count(cfg) -> int:
 def _table_param_count(cfg, active: bool = False) -> int:
     """``param_count`` of a table of layer kinds (``ModelConfig.
     layer_types``), layer by layer: the attention of the layer's kind (its
-    own query heads, the per-head gate), then the dense SwiGLU or the
-    router over every routed expert, the routed experts held here and the
-    shared expert.  ``active``: what multiplies one token instead — of the
-    routed experts the ``num_experts_per_token`` a token chooses, times the
-    share of the routed experts that is held (the rest of its choices are
-    computed on the shards that hold them), and the head but not the
-    embedding's gather."""
+    own query heads, the per-head gate, a latent layer's q-LoRA), then the
+    dense SwiGLU or the router over every output (routed and zero-compute),
+    the routed experts held here and the shared expert (a ``moe_shortcut``
+    double layer is its two rows: two latent attentions, the shared expert
+    as its first dense FFN, the dense row's as its second).  ``active``:
+    what multiplies one token instead — of the routed experts the
+    ``num_experts_per_token`` a token chooses, times the share of the
+    router's outputs that is held (the rest of its choices are computed on
+    the shards that hold them, or are zero-compute), and the head but not
+    the embedding's gather."""
     hd, D, KV, V = (cfg.head_dim_, cfg.hidden_size, cfg.num_kv_heads,
                     cfg.vocab_size)
     kinds = cfg.attn_kinds
@@ -131,7 +134,12 @@ def _table_param_count(cfg, active: bool = False) -> int:
         elif kind.name == LATENT_KIND:
             r, dn, dr, dvh = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                               cfg.qk_rope_head_dim, cfg.v_head_dim)
-            total += D * H * (dn + dr) + D * (r + dr) + r  # wq, wdkv, norm
+            ql = cfg.q_lora_rank
+            if ql:                       # Wqa, its norm, Wqb
+                total += D * ql + ql + ql * H * (dn + dr)
+            else:
+                total += D * H * (dn + dr)                 # wq
+            total += D * (r + dr) + r                      # wdkv, norm
             total += r * H * (dn + dvh) + H * dvh * D      # wukv, wo
         else:
             total += 2 * D * H * hd + 2 * D * KV * hd    # wq, wo, wk, wv
@@ -142,11 +150,13 @@ def _table_param_count(cfg, active: bool = False) -> int:
             continue
         experts = cfg.num_experts
         if active:
+            # a choice falls on a held expert with the held share of the
+            # router's outputs; a zero-compute expert multiplies nothing
             experts = (cfg.num_experts_per_token * cfg.num_experts
-                       / cfg.num_routed_experts)
-        total += D * cfg.num_routed_experts              # router
+                       / cfg.router_width)
+        total += D * cfg.router_width                    # router
         if cfg.moe_router_enable_expert_bias and not active:
-            total += cfg.num_routed_experts              # its choice bias
+            total += cfg.router_width                    # its choice bias
         total += int(experts * 3 * D * cfg.moe_intermediate_size)
         total += 3 * D * cfg.shared_expert_intermediate_size
     return total

@@ -115,10 +115,13 @@ class StepRecord:
     # window's steps and sparse layers, read from the window's own fetch
     # (model.moe_stats_row): (token, expert) pairs of live rows, those of
     # them whose expert is held here, held experts with at least one token,
-    # and the largest token count on one held expert in a layer
+    # those whose choice is a zero-compute (identity) expert, which reads no
+    # expert's weights (0 where the router has none), and the largest token
+    # count on one held expert in a layer
     moe_pairs: int = 0
     moe_pairs_held: int = 0
     moe_experts_touched: int = 0
+    moe_pairs_zero: int = 0
     moe_load_max: int = 0
     spec_drafted: int = 0
     spec_accepted: int = 0

@@ -170,18 +170,21 @@ def gmm_tile(rows: int, k: int, n: int, itemsize: int = 2):
 # ``InferenceEngine.device_report`` shows it under ``/health``
 GMM_TILES_TRACED: Dict[tuple, tuple] = {}
 
+# the counters a sparse layer hands back, in ``routed_ffn``'s order: the sums
+# first, the one maximum last (``model.moe_stats_row`` reduces them so)
 MOE_STATS = ("moe_pairs", "moe_pairs_held", "moe_experts_touched",
-             "moe_load_max")
+             "moe_pairs_zero", "moe_load_max")
 
 
 def route(x: jax.Array, w_router: jax.Array, *, top_k: int,
           renormalise: bool, scale: float, score: str = "softmax",
           bias: jax.Array | None = None, n_group: int = 0,
           topk_group: int = 0):
-    """The router in float32 over ALL routed experts: the ``top_k`` experts
-    of each token ``[N, k]`` and their weights on the experts' outputs,
-    ``scale * s_e / sum_top s`` (the sum over the chosen of all routed
-    experts, never over the ones held).
+    """The router in float32 over every router output, routed and
+    zero-compute (``w_router``'s whole width): the ``top_k`` outputs of each
+    token ``[N, k]`` and their weights on the experts' outputs, ``scale *
+    s_e`` over ``sum_top s`` where ``renormalise`` (the sum over all the
+    chosen, never over the ones held), else ``scale * s_e`` as scored.
 
     ``score``: the scores ``s`` are a softmax over the experts, or a
     sigmoid of each logit.  ``bias [E]`` is added to the scores for the
@@ -213,7 +216,7 @@ def route(x: jax.Array, w_router: jax.Array, *, top_k: int,
 
 def routed_ffn(
     x: jax.Array,          # [N, D] tokens (flattened batch)
-    w_router: jax.Array,   # [D, E] over every routed expert
+    w_router: jax.Array,   # [D, E] every router output: routed, then zero
     w_gate: jax.Array,     # [Eh, D, F] the experts held here
     w_up: jax.Array,       # [Eh, D, F]
     w_down: jax.Array,     # [Eh, F, D]
@@ -223,22 +226,31 @@ def routed_ffn(
     renormalise: bool = True,
     scale: float = 1.0,
     live: jax.Array | None = None,   # [N] bool; dead rows route nowhere
+    n_zero: int = 0,       # the router's last ``n_zero`` are identities
     interpret: bool = False,
     **router,              # route's score / bias / n_group / topk_group
 ):
     """What the experts held here add for the tokens routed to them:
     ``sum_{e in top_k(x) and held} w_e * swiglu_e(x)``, ``[N, D]``; the
-    four ``MOE_STATS`` as int32; and every token's ``top_k`` choices among
-    all routed experts, ``[N, top_k]`` int32 (what a reference check reads:
-    a top-k is a discrete choice).
+    ``MOE_STATS`` as int32; and every token's ``top_k`` choices over every
+    router output, routed and zero-compute, ``[N, top_k]`` int32 (what a
+    reference check reads: a top-k is a discrete choice).
+
+    With ``n_zero`` > 0 a choice ``e >= E - n_zero`` is a zero-compute
+    expert, an identity: its pair adds ``w_e * x`` and costs no expert
+    bytes, on this shard as on every other (a deployment computes it where
+    the token lives, without an exchange).  The sum of a live token's
+    identity weights times the token is added here, under ``moe_zero``.
 
     Every token keeps all its experts: there is no capacity.  The
     ``N * top_k`` (token, expert) pairs are sorted by expert; pairs of
-    experts not held, and of dead rows, sort behind every held group into
-    rows no group owns, which the grouped matmuls neither read weights for
-    nor compute.  The grouped matmul (megablox ``gmm``) visits only the
-    (group, row tile) pairs that hold a row, so an expert no token chose is
-    not read.  Shapes are static at the worst case of ``N * top_k`` rows.
+    experts not held, identity pairs, and those of dead rows, sort behind
+    every held group into rows no group owns, which the grouped matmuls
+    neither read weights for nor compute: a token whose choices are all
+    identities adds no row to a group.  The grouped matmul (megablox
+    ``gmm``) visits only the (group, row tile) pairs that hold a row, so an
+    expert no token chose is not read.  Shapes are static at the worst case
+    of ``N * top_k`` rows.
     One device: an ``ep`` mesh would exchange tokens before and after, and
     this layer has no such exchange (PERF.md, Open questions)."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
@@ -255,8 +267,9 @@ def routed_ffn(
     for (K, Nn), tile in tiles.items():
         if (rows, K, Nn) not in GMM_TILES_TRACED:
             GMM_TILES_TRACED[rows, K, Nn] = tile
-            log.info("grouped matmul [%d, %d] @ [%d, %d, %d]: tile %s",
-                     rows, K, Eh, K, Nn, tile)
+            log.info("grouped matmul (rows %d, K %d, N %d) = [%d, %d] @ "
+                     "[%d, %d, %d]: tile %s", rows, K, Nn, rows, K, Eh, K,
+                     Nn, tile)
 
     with jax.named_scope("moe_router"):
         top_idx, top_w = route(x, w_router, top_k=top_k,
@@ -273,8 +286,10 @@ def routed_ffn(
         token = order // top_k
         held_sorted = jnp.take(held, order)
         here = sizes[held_start:held_start + Eh]
+        zero = alive & (expert >= E - n_zero)
         stats = jnp.stack([
-            jnp.sum(alive), jnp.sum(held), jnp.sum(here > 0), jnp.max(here),
+            jnp.sum(alive), jnp.sum(held), jnp.sum(here > 0), jnp.sum(zero),
+            jnp.max(here),
         ]).astype(jnp.int32)
 
     with jax.named_scope("moe_experts"):
@@ -295,5 +310,10 @@ def routed_ffn(
         y = jnp.where(held_sorted[:, None], y * w_sorted[:, None], 0.0)
         # back to (token, slot) order, then the sum over a token's slots
         y = jnp.take(y, jnp.argsort(order), axis=0)
-        out = jnp.sum(y.reshape(N, top_k, D), axis=1).astype(dt)
-    return out, stats, top_idx
+        out = jnp.sum(y.reshape(N, top_k, D), axis=1)
+    if n_zero:
+        with jax.named_scope("moe_zero"):
+            w_zero = jnp.sum(jnp.where(zero.reshape(N, top_k), top_w, 0.0),
+                             axis=1)
+            out = out + w_zero[:, None] * x.astype(jnp.float32)
+    return out.astype(dt), stats, top_idx

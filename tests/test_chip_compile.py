@@ -191,16 +191,18 @@ def test_the_seat_recurrence_compiles_at_the_cells_shapes(
     assert text.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("B,H,blocks", [(128, 32, 61440), (64, 64, 16384)])
 def test_the_latent_decode_walk_compiles_at_the_cells_shapes(
-        one_chip, no_compile_cache):
+        one_chip, no_compile_cache, B, H, blocks):
     """The paged decode kernel over a page that is key and value at once
-    (``v_width``): 32 absorbed query heads over one latent "KV head" 640
-    wide, 128 rows, the cell's 61440 blocks."""
+    (``v_width``): the absorbed query heads over one latent "KV head" 640
+    wide, at ling-3.0-flash-ep8's shape (32 heads, 128 rows, 61440 blocks)
+    and longcat-flash-omni-ep32's (64 heads, 64 rows, 16384 blocks)."""
     from dynamo_tpu.ops.paged_attention import (
         default_kv_tile, paged_attention_decode,
     )
 
-    B, H, wide, bs = 128, 32, 640, ENGINE.block_size
+    wide, bs = 640, ENGINE.block_size
 
     def S(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -212,7 +214,7 @@ def test_the_latent_decode_walk_compiles_at_the_cells_shapes(
             interpret=False, v_width=512, scale=192 ** -0.5)
 
     text = jax.jit(launch).lower(
-        S((B, H, wide), jnp.bfloat16), S((61440, 1, bs, wide), jnp.bfloat16),
+        S((B, H, wide), jnp.bfloat16), S((blocks, 1, bs, wide), jnp.bfloat16),
         S((B, 512), jnp.int32), S((B,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") == 1
 
@@ -222,11 +224,14 @@ def test_the_latent_decode_walk_compiles_at_the_cells_shapes(
     (3072, 1024, 256, 128, 10, 32, {}),
     (2560, 768, 512, 64, 8, 128,
      dict(score="sigmoid", n_group=8, topk_group=4)),
+    (6144, 2048, 768, 16, 12, 64, dict(n_zero=256, renormalise=False)),
 ])
 def test_the_expert_layer_compiles_at_the_cells_shapes(
         one_chip, no_compile_cache, D, F, E, Eh, k, seqs, router, phase):
-    """``moe.routed_ffn`` at laguna-s-2.1-ep2's and ling-3.0-flash-ep8's
-    widths, a decode window's rows and a T=512 chunk's: the tiles
+    """``moe.routed_ffn`` at laguna-s-2.1-ep2's, ling-3.0-flash-ep8's and
+    longcat-flash-omni-ep32's widths (the last: a router of 512 + 256
+    zero-compute outputs over 16 held experts), a decode window's rows and
+    a T=512 chunk's: the tiles
     ``gmm_tile`` returns there (tk = K: a block of a whole gate matrix)
     fit the scoped VMEM as Mosaic counts it, and the layer is three grouped
     matmuls."""

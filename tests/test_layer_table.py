@@ -8,7 +8,10 @@ against plain numpy.  CPU, float32; Pallas kernels interpreted.
 
 The cases that hold for any table run over ``TABLES``: since PR 34 also the
 tiny configuration of ``ling-3.0-flash-ep8.json``, whose layers keep no K
-and V; what only that table has is in ``test_hybrid_table.py``."""
+and V (what only that table has is in ``test_hybrid_table.py``), and since
+PR 41 that of ``longcat-flash-omni-ep32.json``: double layers of latent rows
+with a shortcut expert layer and zero-compute experts
+(``test_shortcut_table.py``)."""
 
 import dataclasses
 import hashlib
@@ -44,6 +47,9 @@ TABLES = {
     "ling": dict(config="ling-3.0-flash-ep8", reference="ling",
                  seed=3400000417, compare=dict(T=150, chunk=64),
                  sparse_layers=6, engine=dict(prefill_buckets=(64,))),
+    "longcat": dict(config="longcat-flash-omni-ep32", reference="longcat",
+                    seed=4100000417, compare=dict(T=150, chunk=64),
+                    sparse_layers=2, engine={}),
 }
 SEED = TABLES["laguna"]["seed"]
 
@@ -239,13 +245,17 @@ def test_the_reference_follows_the_served_choices_and_counts_the_flips(
 
 @pytest.mark.parametrize("table,variant,sees", [
     ("laguna", "no_scale", "logits"), ("laguna", "renorm_held", "logits"),
-    ("ling", "no_groups", "routing"), ("ling", "no_bound", "logits")])
+    ("ling", "no_groups", "routing"), ("ling", "no_bound", "logits"),
+    ("longcat", "no_identity", "logits"), ("longcat", "no_scales", "logits"),
+    ("longcat", "early_join", "logits")])
 def test_the_reference_sees_a_part_of_the_mathematics_left_out(
         engines, table, variant, sees):
     """The controls the chip's limits rest on, at the tiny size: a reference
-    that breaks a routing weight or leaves out the decay's bound reads other
-    logits altogether; one without the group limit reads the served choices
-    as flips that lie well under its own 4th score."""
+    that breaks a routing weight, leaves out the decay's bound, the identity
+    experts' part or the latent scales, or joins a double layer's routed sum
+    a row early, reads other logits altogether; one without the group limit
+    reads the served choices as flips that lie well under its own 4th
+    score."""
     t = TABLES[table]
     v = _reference(table).compare(engines(table), t["seed"], n_decode=2,
                                   variant=variant, **t["compare"])
@@ -393,8 +403,8 @@ def test_no_token_is_dropped_when_all_choose_the_same_expert():
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
     assert np.abs(want).min(axis=1).max() > 0 and np.all(
         np.abs(np.asarray(got)).sum(axis=1) > 0)       # every token served
-    pairs, held, touched, load = (int(v) for v in np.asarray(stats))
-    assert (pairs, held, touched, load) == (80, 40, 1, 40)
+    pairs, held, touched, zero, load = (int(v) for v in np.asarray(stats))
+    assert (pairs, held, touched, zero, load) == (80, 40, 1, 0, 40)
 
 
 def test_dead_rows_route_nowhere():
@@ -513,7 +523,7 @@ def test_routed_ffn_at_the_tiles_of_the_eight_shapes(table, phase,
     assert np.array_equal(np.asarray(chosen), top)
     n = [int((live & (cls == c)).sum()) for c in range(3)]
     assert [int(v) for v in stats] == [
-        2 * sum(n), n[0] + 2 * n[1] + n[2], 3, n[1] + n[2]]
+        2 * sum(n), n[0] + 2 * n[1] + n[2], 3, 0, n[1] + n[2]]
     tm = tile(wd[phase], D, F)[0]
     assert (n[0] + n[1] - 1) // tm - n[0] // tm >= 2     # 3 row tiles or 4
     got = np.asarray(got.astype(jnp.float32), np.float64)
@@ -558,7 +568,8 @@ def test_rows_of_no_group_may_hold_anything(held_start, monkeypatch):
 
 # sha256 of ``top_idx`` and the four ``MOE_STATS`` as the commit before PR 40
 # (b5c78fa) gave them: the call of the grouped matmul changed, the choices
-# and the counters did not
+# and the counters did not.  PR 41 put a fifth counter between them
+# (``moe_pairs_zero``), which reads 0 where the router has no such output
 CHOICES_BEFORE = {
     ("softmax", 0, 8): "5d34d183ecc78a0d", ("softmax", 8, 16):
     "f3f81bc789ee5136", ("softmax", 6, 8): "6a88d42e8d91705b",
@@ -581,8 +592,12 @@ def test_choices_and_counters_are_what_they_were(router, lo, hi):
         x, wr, wg[lo:hi], wu[lo:hi], wd[lo:hi], top_k=4, held_start=lo,
         scale=2.5, live=jnp.asarray(np.arange(40) % 7 != 3), interpret=True,
         **kw)
+    stats = np.asarray(stats)
+    four = [moe.MOE_STATS.index(n) for n in (
+        "moe_pairs", "moe_pairs_held", "moe_experts_touched", "moe_load_max")]
+    assert stats[moe.MOE_STATS.index("moe_pairs_zero")] == 0
     assert hashlib.sha256(
-        np.asarray(chosen).tobytes() + np.asarray(stats).tobytes()
+        np.asarray(chosen).tobytes() + stats[four].tobytes()
     ).hexdigest()[:16] == CHOICES_BEFORE[router, lo, hi]
 
 

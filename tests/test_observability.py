@@ -16,6 +16,7 @@ from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from dynamo_tpu.engine import model as model_lib
@@ -649,6 +650,11 @@ _TABLES = {
         "attn_gate", "moe_router", "moe_experts", "moe_shared", "kda_proj",
         "kda_conv", "kda_out", "attention_latent", "kda_recurrent",
         "kda_chunk"}),
+    # double layers of latent rows: the first dense FFN runs as the sparse
+    # row's shared expert under "mlp", so no "moe_shared" and no gate
+    "shortcut": ("longcat-flash-omni-ep32", {
+        "moe_router", "moe_experts", "moe_zero", "moe_shortcut",
+        "attention_latent"}),
 }
 
 
@@ -689,7 +695,7 @@ def _lowered_step_program(which, cfg=None):
     return fn.__wrapped__.lower(*args)
 
 
-@pytest.mark.parametrize("model", ["one_kind", "table", "hybrid"])
+@pytest.mark.parametrize("model", ["one_kind", "table", "hybrid", "shortcut"])
 @pytest.mark.parametrize("which", ["decode_window", "packed_prefill"])
 def test_step_programs_carry_every_scope(which, model):
     """Every name of model.SCOPES is a component of some op's ``op_name``
@@ -707,8 +713,8 @@ def test_step_programs_carry_every_scope(which, model):
                  else "kda_recurrent"}
     # no layer of the hybrid keeps K and V: "attention" is only what a
     # kernel's decode program computes its rows' lengths under
-    lacks = {"attention"} if (model, which) == (
-        "hybrid", "packed_prefill") else set()
+    lacks = {"attention"} if (model in ("hybrid", "shortcut")
+                              and which == "packed_prefill") else set()
     text = _lowered_step_program(
         which, _table_model(name) if name else None).as_text(debug_info=True)
     seen = set()
@@ -719,6 +725,29 @@ def test_step_programs_carry_every_scope(which, model):
     missing = [s for s in want if s not in seen]
     assert not missing, f"{which}: no op carries scope(s) {missing}"
     assert not seen & (set(model_lib.TABLE_SCOPES) - own)
+
+
+def test_the_routing_counters_ride_one_row_in_their_own_order():
+    """``moe_stats_row`` sums every counter of ``MOE_STATS`` over the
+    window's sparse layers but the last, the load, of which it keeps the
+    largest; the engine reads the row back by the same tuple, and a
+    ``StepRecord`` has a field for each (``moe_pairs_zero`` since PR 41)."""
+    from dynamo_tpu.observability.stepstats import StepRecord
+    from dynamo_tpu.parallel.moe import MOE_STATS
+
+    assert MOE_STATS[-1] == "moe_load_max" and "moe_pairs_zero" in MOE_STATS
+    fields = {f.name for f in dataclasses.fields(StepRecord)}
+    assert set(MOE_STATS) <= fields
+    stats = [jnp.asarray([96, 10, 7, 30, 3], jnp.int32),
+             jnp.asarray([96, 14, 9, 34, 5], jnp.int32)]
+    row = np.asarray(model_lib.moe_stats_row(stats, 8))
+    assert row.tolist() == [[192, 24, 16, 64, 5, 0, 0, 0]]
+    rec = StepRecord(kind="decode", t_dispatch=0.0)
+    for name, v in zip(MOE_STATS, row[0]):
+        setattr(rec, name, int(v))
+    assert (rec.moe_pairs_zero, rec.moe_load_max) == (64, 5)
+    with pytest.raises(ValueError, match="routing counters"):
+        model_lib.moe_stats_row(stats, 4)
 
 
 def _host_event_names(trace_dir):
