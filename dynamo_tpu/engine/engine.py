@@ -1760,9 +1760,10 @@ class InferenceEngine(EngineCore):
                           model_lib.ATTENTION_TRACES.items()},
             "attention_choice": self.attention_impl_choice,
             # a table's grouped matmuls, one entry a distinct call as traced
+            # (rows < pairs: a slab of parallel.moe.held_rows)
             "expert_tiles": [
-                {"rows": r, "k": k, "n": n, "tile": list(tile)}
-                for (r, k, n), tile in sorted(GMM_TILES_TRACED.items())],
+                {"pairs": p, "rows": r, "k": k, "n": n, "tile": list(tile)}
+                for (p, r, k, n), tile in sorted(GMM_TILES_TRACED.items())],
             "native": native.implementation(),
             "compile_cache": compile_cache_stats(),
             "compile": compilewatch.snapshot(),

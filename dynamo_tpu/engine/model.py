@@ -2305,17 +2305,22 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
 
 def moe_stats_row(stats: list, width: int) -> jax.Array:
     """The routing counters of a window's sparse layers and steps (each
-    an int32 row of ``parallel.moe.MOE_STATS``) as one ``[1, width]`` int32
-    row: sums of pairs, pairs held, experts touched and identity pairs, the
-    largest load; zeros behind them."""
+    an int32 row of ``parallel.moe.MOE_STATS``, or of all but its last
+    where a call's rows are all its pairs) as one ``[1, width]`` int32 row:
+    sums of pairs, pairs held, experts touched and identity pairs, the
+    largest load, the sum of the held pairs behind a call's first slab;
+    zeros behind them."""
     from ..parallel.moe import MOE_STATS
 
     if width < len(MOE_STATS):
         raise ValueError(f"a decode bucket of {width} rows cannot carry "
                          f"the {len(MOE_STATS)} routing counters")
-    per = jnp.stack(stats)                         # [n, len(MOE_STATS)]
-    row = jnp.concatenate([jnp.sum(per[:, :-1], axis=0),
-                           jnp.max(per[:, -1:], axis=0)])
+    per = jnp.stack(stats)               # [n, len(MOE_STATS) or one less]
+    at = MOE_STATS.index("moe_load_max")
+    row = [jnp.sum(per[:, :at], axis=0), jnp.max(per[:, at:at + 1], axis=0)]
+    if per.shape[1] > at + 1:
+        row.append(jnp.sum(per[:, at + 1:], axis=0))
+    row = jnp.concatenate(row)
     return jnp.pad(row, (0, width - row.shape[0]))[None, :]
 
 

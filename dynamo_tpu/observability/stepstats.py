@@ -116,13 +116,16 @@ class StepRecord:
     # (model.moe_stats_row): (token, expert) pairs of live rows, those of
     # them whose expert is held here, held experts with at least one token,
     # those whose choice is a zero-compute (identity) expert, which reads no
-    # expert's weights (0 where the router has none), and the largest token
-    # count on one held expert in a layer
+    # expert's weights (0 where the router has none), the largest token
+    # count on one held expert in a layer, and the held pairs behind the
+    # first slab of a call whose rows follow the held pairs
+    # (parallel.moe.held_rows; 0 where the rows are all the pairs)
     moe_pairs: int = 0
     moe_pairs_held: int = 0
     moe_experts_touched: int = 0
     moe_pairs_zero: int = 0
     moe_load_max: int = 0
+    moe_pairs_overflow: int = 0
     spec_drafted: int = 0
     spec_accepted: int = 0
     # host seconds (``time.monotonic()`` differences, no device access).

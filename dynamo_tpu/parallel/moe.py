@@ -165,15 +165,69 @@ def gmm_tile(rows: int, k: int, n: int, itemsize: int = 2):
                      f"scoped VMEM")
 
 
-# (rows, K, N) -> (tm, tk, tn) of every distinct grouped-matmul call traced
-# in this process (one serving engine a process, as ATTENTION_TRACES):
-# ``InferenceEngine.device_report`` shows it under ``/health``
+# the room a slab of rows has over the held experts' even share of the pairs
+HELD_ROOM = 2
+# a call's rows follow the held pairs where a slab is at most one part in
+# HELD_SHARE of all its pairs
+HELD_SHARE = 8
+
+
+def held_rows(pairs: int, held: int, width: int, tm: int) -> int:
+    """The rows a sparse call is shaped for, from the shapes alone (static
+    at trace time): ``HELD_ROOM`` times the even share that ``held`` of the
+    router's ``width`` outputs (routed and zero-compute) get of the
+    ``pairs`` sorted pairs, one row tile ``tm`` at least, in whole row
+    tiles: a slab.  It is room, not a capacity: ``routed_ffn`` walks the
+    held pairs slab by slab, one slab nearly always, and drops none.
+
+    The one rule of engagement: where a slab is more than one part in
+    ``HELD_SHARE`` of the pairs the rows are all the pairs in whole tiles,
+    and ``routed_ffn`` traces the body it always had.
+
+    (pairs, held of width, tm) -> rows: a chunk of 512 tokens and the
+    largest decode window of the benchmark's three tables:
+    (6144, 16 of 768, 128) -> 256      (768, 16 of 768, 64) -> 64
+    (4096, 64 of 512, 128) -> 4096     (1024, 64 of 512, 64) -> 1024
+    (5120, 128 of 256, 128) -> 5120    (320, 128 of 256, 64) -> 320
+
+    Read on the chip (PERF.md, PR 43), us a call = router + sorts +
+    experts of one layer at real widths, medians of 30 device spans; "all
+    pairs": the body as it was; then slabs, the rule forced where it does
+    not engage; "(a)": the same rows under a ``cond`` whose other branch is
+    the worst case's body, the form this loop was chosen over:
+
+    (tokens, held of width)      all pairs  slabs of
+    (512, 16 of 768) agent         4429     256: 2047  512: 2096  (a) 2080, 2117
+    (64, 16 of 768)                 882      64:  793             (a)  808
+    (512, 64 of 512) longgen       1267    1024: 1158  1536: 1194
+    (128, 64 of 512)                873     256:  836   384:  850
+
+    A slab of a 24th or a 12th of the pairs takes 54% and 10% off a call;
+    one of a quarter 9% and 4% (108 and 37 us), which is under what
+    longgen's runs can tell apart: hence one part in 8, and longgen's and
+    codegen's programs stay text for text what they were.  Room: at the
+    model's own hidden states a layer's T=512 call held 15 to 284 pairs
+    (mean 80-143 by layer and seed; an even draw would give 128 +- 11) and
+    a 64-row step 14 +- 4, at most 22.  A second pass over a few rows costs
+    ~150 us (a slab of 128 under 141 held pairs: 2193), twice the rows
+    ~50 us every call: so twice the share, not four times."""
+    up = lambda n: -(-n // tm) * tm                          # noqa: E731
+    slab = up(max(-(-HELD_ROOM * pairs * held // width), 1))
+    return slab if HELD_SHARE * slab <= pairs else up(pairs)
+
+
+# (pairs, rows, K, N) -> (tm, tk, tn) of every distinct grouped-matmul call
+# traced in this process (one serving engine a process, as
+# ATTENTION_TRACES): ``InferenceEngine.device_report`` shows it under
+# ``/health``.  ``rows`` < ``pairs``: a slab of ``held_rows``
 GMM_TILES_TRACED: Dict[tuple, tuple] = {}
 
-# the counters a sparse layer hands back, in ``routed_ffn``'s order: the sums
-# first, the one maximum last (``model.moe_stats_row`` reduces them so)
+# the counters a sparse layer hands back, in ``routed_ffn``'s order: four
+# sums, the one maximum, then a sum that only a call whose rows follow the
+# held pairs hands back (``model.moe_stats_row`` reduces them so, and a
+# window without it reads 0): the held pairs beyond the call's first slab
 MOE_STATS = ("moe_pairs", "moe_pairs_held", "moe_experts_touched",
-             "moe_pairs_zero", "moe_load_max")
+             "moe_pairs_zero", "moe_load_max", "moe_pairs_overflow")
 
 
 def route(x: jax.Array, w_router: jax.Array, *, top_k: int,
@@ -249,10 +303,22 @@ def routed_ffn(
     neither read weights for nor compute: a token whose choices are all
     identities adds no row to a group.  The grouped matmul (megablox
     ``gmm``) visits only the (group, row tile) pairs that hold a row, so an
-    expert no token chose is not read.  Shapes are static at the worst case
-    of ``N * top_k`` rows.
+    expert no token chose is not read.
+
+    Shapes are static.  Where the held experts are a small share of the
+    router's width (``held_rows``) what follows the sort (the gather, the
+    three grouped matmuls, the select, the weights and the sum into ``[N,
+    D]``) is shaped for a slab of ``held_rows`` sorted pairs and walks the
+    held pairs, which sort first, slab by slab under a loop of
+    ``ceil(held pairs / slab)`` passes: one nearly always, none where no
+    pair is held, and as many as it takes otherwise, so no pair is dropped
+    (``moe_pairs_overflow`` counts the held pairs behind the first slab).
+    Everywhere else the rows are the worst case's ``N * top_k`` and there
+    is no loop: the program is the one it was.
     One device: an ``ep`` mesh would exchange tokens before and after, and
-    this layer has no such exchange (PERF.md, Open questions)."""
+    this layer has no such exchange (PERF.md, Open questions); the exchange
+    would hand this shard the held pairs alone, which is what a slab stands
+    in for."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     N, D = x.shape
@@ -260,16 +326,17 @@ def routed_ffn(
     Eh, _, F = w_gate.shape
     dt = x.dtype
     pairs = N * top_k
-    tiles = {(K, Nn): gmm_tile(pairs, K, Nn, w_gate.dtype.itemsize)
+    tm = gmm_tile(pairs, D, F, w_gate.dtype.itemsize)[0]
+    rows = held_rows(pairs, Eh, E, tm)
+    slabs = rows < pairs
+    tiles = {(K, Nn): gmm_tile(rows, K, Nn, w_gate.dtype.itemsize)
              for K, Nn in ((D, F), (F, D))}
-    tm = tiles[D, F][0]
-    rows = -(-pairs // tm) * tm
     for (K, Nn), tile in tiles.items():
-        if (rows, K, Nn) not in GMM_TILES_TRACED:
-            GMM_TILES_TRACED[rows, K, Nn] = tile
-            log.info("grouped matmul (rows %d, K %d, N %d) = [%d, %d] @ "
-                     "[%d, %d, %d]: tile %s", rows, K, Nn, rows, K, Eh, K,
-                     Nn, tile)
+        if (pairs, rows, K, Nn) not in GMM_TILES_TRACED:
+            GMM_TILES_TRACED[pairs, rows, K, Nn] = tile
+            log.info("grouped matmul (rows %d of %d pairs, K %d, N %d) = "
+                     "[%d, %d] @ [%d, %d, %d]: tile %s", rows, pairs, K, Nn,
+                     rows, K, Eh, K, Nn, tile)
 
     with jax.named_scope("moe_router"):
         top_idx, top_w = route(x, w_router, top_k=top_k,
@@ -287,30 +354,69 @@ def routed_ffn(
         held_sorted = jnp.take(held, order)
         here = sizes[held_start:held_start + Eh]
         zero = alive & (expert >= E - n_zero)
-        stats = jnp.stack([
+        stats = [
             jnp.sum(alive), jnp.sum(held), jnp.sum(here > 0), jnp.sum(zero),
             jnp.max(here),
-        ]).astype(jnp.int32)
+        ]
+        n_held = stats[1]
+        if slabs:
+            stats.append(jnp.maximum(n_held - rows, 0))
+        stats = jnp.stack(stats).astype(jnp.int32)
 
-    with jax.named_scope("moe_experts"):
-        xs = jnp.take(x, token, axis=0)
-        xs = jnp.pad(xs, ((0, rows - pairs), (0, 0)))
-        # the held groups alone, from row 0 on: metadata over Eh groups and
-        # no offset, so gmm neither rolls it nor zeroes the rows behind them
-        mm = functools.partial(gmm, group_sizes=here,
-                               preferred_element_type=jnp.float32,
-                               interpret=interpret)
-        gate = jax.nn.silu(mm(xs, w_gate, tiling=tiles[D, F]))
-        up = mm(xs, w_up, tiling=tiles[D, F])
-        y = mm((gate * up).astype(dt), w_down,
-               tiling=tiles[F, D])[:pairs]                   # [pairs, D] f32
-        # rows of no group come back as they were left (whatever the VMEM
-        # held, a NaN too): select, not multiply
-        w_sorted = jnp.take(top_w.reshape(pairs), order)
-        y = jnp.where(held_sorted[:, None], y * w_sorted[:, None], 0.0)
-        # back to (token, slot) order, then the sum over a token's slots
-        y = jnp.take(y, jnp.argsort(order), axis=0)
-        out = jnp.sum(y.reshape(N, top_k, D), axis=1)
+    # the held groups alone, from row 0 on: metadata over Eh groups and no
+    # offset, so gmm neither rolls it nor zeroes the rows behind them
+    mm = functools.partial(gmm, preferred_element_type=jnp.float32,
+                           interpret=interpret)
+
+    def swiglu(xs, group_sizes):
+        gate = jax.nn.silu(mm(xs, w_gate, group_sizes, tiling=tiles[D, F]))
+        up = mm(xs, w_up, group_sizes, tiling=tiles[D, F])
+        return mm((gate * up).astype(dt), w_down, group_sizes,
+                  tiling=tiles[F, D])                        # [rows, D] f32
+
+    if not slabs:
+        with jax.named_scope("moe_experts"):
+            xs = jnp.take(x, token, axis=0)
+            xs = jnp.pad(xs, ((0, rows - pairs), (0, 0)))
+            y = swiglu(xs, here)[:pairs]                     # [pairs, D] f32
+            # rows of no group come back as they were left (whatever the
+            # VMEM held, a NaN too): select, not multiply
+            w_sorted = jnp.take(top_w.reshape(pairs), order)
+            y = jnp.where(held_sorted[:, None], y * w_sorted[:, None], 0.0)
+            # back to (token, slot) order, then the sum over a token's slots
+            y = jnp.take(y, jnp.argsort(order), axis=0)
+            out = jnp.sum(y.reshape(N, top_k, D), axis=1)
+    else:
+        with jax.named_scope("moe_experts"):
+            ends = jnp.cumsum(here)
+            # a last slab may reach past the pairs
+            behind = jnp.pad(order, (0, rows))
+
+        def slab(i, out):
+            """Adds the sorted pairs ``[i * rows, (i + 1) * rows)``: a
+            group's rows in the slab are its clipped ends; the sum over a
+            token's rows is a one-hot ``[N, rows] @ [rows, D]`` in float32
+            ``HIGHEST`` (the MXU adds them, as exactly as float32 adds: a
+            product by 0 or 1)."""
+            with jax.named_scope("moe_experts"):
+                first = i * rows
+                inside = lambda e: jnp.clip(e - first, 0, rows)  # noqa: E731
+                at = jax.lax.dynamic_slice(behind, (first,), (rows,))
+                tok = at // top_k
+                y = swiglu(jnp.take(x, tok, axis=0),
+                           inside(ends) - inside(ends - here))
+                # as above: select, not multiply
+                sel = first + jnp.arange(rows) < n_held
+                w_sorted = jnp.take(top_w.reshape(pairs), at)
+                y = jnp.where(sel[:, None], y * w_sorted[:, None], 0.0)
+                hot = jnp.arange(N)[:, None] == tok[None, :]
+                return out + jnp.dot(hot.astype(jnp.float32), y,
+                                     precision=jax.lax.Precision.HIGHEST)
+
+        # the loop's own op stays outside the scope: a trace counts it
+        # beside the ops of its body
+        out = jax.lax.fori_loop(0, (n_held + rows - 1) // rows, slab,
+                                jnp.zeros((N, D), jnp.float32))
     if n_zero:
         with jax.named_scope("moe_zero"):
             w_zero = jnp.sum(jnp.where(zero.reshape(N, top_k), top_w, 0.0),

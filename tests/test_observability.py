@@ -729,13 +729,16 @@ def test_step_programs_carry_every_scope(which, model):
 
 def test_the_routing_counters_ride_one_row_in_their_own_order():
     """``moe_stats_row`` sums every counter of ``MOE_STATS`` over the
-    window's sparse layers but the last, the load, of which it keeps the
-    largest; the engine reads the row back by the same tuple, and a
-    ``StepRecord`` has a field for each (``moe_pairs_zero`` since PR 41)."""
+    window's sparse layers but the load, of which it keeps the largest; the
+    engine reads the row back by the same tuple, and a ``StepRecord`` has a
+    field for each (``moe_pairs_zero`` since PR 41; ``moe_pairs_overflow``
+    since PR 43, behind the load: a row of five, from calls over all their
+    pairs, is the row it was and reads 0 there)."""
     from dynamo_tpu.observability.stepstats import StepRecord
     from dynamo_tpu.parallel.moe import MOE_STATS
 
-    assert MOE_STATS[-1] == "moe_load_max" and "moe_pairs_zero" in MOE_STATS
+    assert MOE_STATS[4:] == ("moe_load_max", "moe_pairs_overflow")
+    assert "moe_pairs_zero" in MOE_STATS
     fields = {f.name for f in dataclasses.fields(StepRecord)}
     assert set(MOE_STATS) <= fields
     stats = [jnp.asarray([96, 10, 7, 30, 3], jnp.int32),
@@ -746,6 +749,7 @@ def test_the_routing_counters_ride_one_row_in_their_own_order():
     for name, v in zip(MOE_STATS, row[0]):
         setattr(rec, name, int(v))
     assert (rec.moe_pairs_zero, rec.moe_load_max) == (64, 5)
+    assert rec.moe_pairs_overflow == 0
     with pytest.raises(ValueError, match="routing counters"):
         model_lib.moe_stats_row(stats, 4)
 
