@@ -115,6 +115,21 @@ class ModelConfig:
     # groups, and ``moe_router_enable_expert_bias`` adds a bias to the
     # scores for the choice alone (DeepSeek-V3's ``noaux_tc``).
     #
+    # A second rule for "linear_attention" (the gated delta rule,
+    # arXiv:2412.06464; PR 45), chosen by ``linear_decay`` "softplus_head":
+    # ``linear_key_head_dim`` x ``linear_value_head_dim`` states a head (not
+    # ``head_dim`` square), ONE log decay a head ``-exp(A_log) softplus(x Wa
+    # + dt_bias)`` (no bound: a scalar gate needs none),
+    # ``linear_conv_kernel_dim`` taps, a step size ``sigmoid(x Wb)`` times 2
+    # where ``linear_allow_neg_eigval``, and ``linear_gate`` "silu": the
+    # normed output times ``silu(x Wg)`` a channel.  "" is KDA, which reads
+    # none of them.  A K / V kind whose ``rope_parameters`` entry has
+    # ``rope_type`` "none" turns nothing (the recurrent layers carry order).
+    # ``qk_norm`` "projection": RMSNorm over the whole q and the whole k
+    # projection before the heads are split.  ``norm_placement`` "post": a
+    # sublayer reads the raw stream and its OUTPUT is normed, ``h + norm(
+    # Mixer(h))`` (Olmo 2's reordered norm); "pre" is everyone else's.
+    #
     # A latent layer with ``q_lora_rank`` > 0 makes its queries in two steps,
     # ``rmsnorm(x Wqa) Wqb``; ``mla_scale_q_lora`` / ``mla_scale_kv_lora``
     # multiply the queries by ``sqrt(hidden_size / q_lora_rank)`` and the
@@ -156,6 +171,14 @@ class ModelConfig:
     zero_expert_num: int = 0
     zero_expert_type: str = "identity"
     moe_shortcut: bool = False
+    linear_decay: str = ""
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    linear_allow_neg_eigval: bool = False
+    linear_gate: str = ""
+    qk_norm: str = ""
+    norm_placement: str = "pre"
 
     def __post_init__(self):
         for name in ("layer_types", "mlp_layer_types", "num_heads_per_layer",
@@ -171,22 +194,54 @@ class ModelConfig:
                     f"{L} layers")
         if self.attn_gate not in ("", "per-head", "head_wise"):
             raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
+        if self.qk_norm not in ("", "projection"):
+            raise ValueError(f"unknown qk_norm {self.qk_norm!r}")
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(
+                f"unknown norm_placement {self.norm_placement!r}")
         ropes = dict(self.rope_parameters or ())
         for kind in self.attn_kinds:
             if kind.name not in KV_KINDS + (LATENT_KIND, STATE_KIND):
                 raise ValueError(f"unknown layer type {kind.name!r}")
             if kind.name == STATE_KIND:
+                if self.state_dtype not in ("float32", "bfloat16"):
+                    raise ValueError(
+                        f"unknown state_dtype {self.state_dtype!r}")
+                if self.linear_decay not in ("", "softplus_head"):
+                    raise ValueError(
+                        f"unknown linear_decay {self.linear_decay!r}")
+                if self.gated_delta:
+                    if min(self.linear_key_head_dim,
+                           self.linear_value_head_dim) < 1 \
+                            or self.linear_conv_kernel_dim < 2:
+                        raise ValueError(
+                            "linear_decay 'softplus_head' (the gated delta "
+                            "rule) needs linear_key_head_dim, "
+                            "linear_value_head_dim and "
+                            "linear_conv_kernel_dim >= 2")
+                    if self.linear_gate not in ("", "silu"):
+                        raise ValueError(
+                            f"unknown linear_gate {self.linear_gate!r}")
+                    continue
+                if (self.linear_key_head_dim or self.linear_value_head_dim
+                        or self.linear_conv_kernel_dim
+                        or self.linear_allow_neg_eigval or self.linear_gate):
+                    raise ValueError(
+                        "the linear_* fields belong to linear_decay "
+                        "'softplus_head'; Kimi Delta Attention has square "
+                        "states of head_dim and reads none of them")
                 if (self.short_conv_kernel_size < 2
                         or not self.kda_lower_bound < 0):
                     raise ValueError(
                         "linear_attention needs short_conv_kernel_size >= 2 "
                         "and a negative kda_lower_bound")
-                if self.state_dtype not in ("float32", "bfloat16"):
-                    raise ValueError(
-                        f"unknown state_dtype {self.state_dtype!r}")
                 continue
             if kind.name not in ropes:
-                raise ValueError(f"rope_parameters has no {kind.name!r}")
+                raise ValueError(
+                    f"rope_parameters has no {kind.name!r} (a kind without "
+                    f"a rotary embedding says so: rope_type 'none')")
+            if self.qk_norm and kind.name == LATENT_KIND:
+                raise ValueError("qk_norm is written for K / V kinds")
             if kind.name == LATENT_KIND:
                 if min(self.kv_lora_rank, self.qk_nope_head_dim,
                        self.qk_rope_head_dim, self.v_head_dim) < 1:
@@ -201,6 +256,14 @@ class ModelConfig:
             if kind.window < 0 or (kind.name == "sliding_attention"
                                    and kind.window == 0):
                 raise ValueError("sliding_attention needs sliding_window")
+        if self.norm_placement == "post" and (
+                "sparse" in self.mlp_layer_types
+                or LATENT_KIND in self.layer_types
+                or (STATE_KIND in self.layer_types
+                    and not self.gated_delta)):
+            raise ValueError(
+                "norm_placement 'post' is written for K / V and gated-"
+                "delta-rule layers with a dense FFN")
         if set(self.mlp_layer_types) - {"dense", "sparse"}:
             raise ValueError(f"unknown mlp layer type in "
                              f"{self.mlp_layer_types}")
@@ -270,6 +333,13 @@ class ModelConfig:
         """Some layer keeps a per-sequence state in the scheduler's seat:
         nothing of a sequence's past can be skipped or moved without it."""
         return STATE_KIND in self.layer_types
+
+    @property
+    def gated_delta(self) -> bool:
+        """The linear layers follow the gated delta rule (one decay a head,
+        ``linear_key_head_dim`` x ``linear_value_head_dim`` states), not
+        Kimi Delta Attention."""
+        return self.linear_decay == "softplus_head"
 
     @property
     def has_latent_cache(self) -> bool:

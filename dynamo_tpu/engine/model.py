@@ -114,6 +114,14 @@ TABLE_SCOPES = (
     # whose sum joins the stream a row later (``moe_shortcut``; PR 41)
     "moe_zero",    # the identity experts' part: (sum of their weights) * x
     "moe_shortcut",  # the pending routed sum joins behind the next row's FFN
+    # a gated-delta-rule layer (one decay a head, dk x dv states; PR 45).
+    # A sublayer's output norm (``norm_placement`` "post") is in "o_proj" /
+    # "mlp", the QK-norm over the projections in "qkv_proj"
+    "gdn_proj",    # q / k / v / decay / beta matmuls on the raw stream
+    "gdn_conv",    # the short convolution, its state, SiLU, the L2 norms
+    "gdn_recurrent",  # T = 1: the token recurrence over the seats' states
+    "gdn_chunk",   # T > 1: the scalar-gate chunked form over a chunk
+    "gdn_out",     # the per-head norm of the output times its SiLU gate
 )
 SCOPES += TABLE_SCOPES
 
@@ -169,6 +177,11 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
     if n_kv:
         layers["wk"] = _normal(next(key), (n_kv, D, KV * hd), D, dt)
         layers["wv"] = _normal(next(key), (n_kv, D, KV * hd), D, dt)
+    if n_kv and cfg.qk_norm:
+        layers["k_norm"] = jnp.ones((n_kv, KV * hd), dt)
+        layers["q_norm"] = {
+            kind.name: jnp.ones((len(kind.layers), kind.num_heads * hd), dt)
+            for kind in cfg.attn_kinds if kind.name in KV_KINDS}
     layers["wq"], layers["wo"] = {}, {}
     if cfg.attn_gate:
         layers["w_attn_gate"] = {}
@@ -178,6 +191,8 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
         if kind.name == LATENT_KIND:
             q_dim = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
             o_dim = cfg.v_head_dim
+        if kind.name == STATE_KIND and cfg.gated_delta:
+            q_dim, o_dim = cfg.linear_key_head_dim, cfg.linear_value_head_dim
         # behind a q-LoRA ``wq`` is Wqb, on Wqa's normed output.  The
         # published scale sqrt(hidden / rank) is there to give a projection
         # drawn for ``hidden`` inputs unit variance behind ``rank`` of them:
@@ -194,7 +209,27 @@ def _init_table_small(rng: jax.Array, cfg: ModelConfig) -> Params:
         if cfg.attn_gate:
             layers["w_attn_gate"][kind.name] = _normal(
                 next(key), (n, D, H), D, dt)
-        if kind.name == STATE_KIND:
+        if kind.name == STATE_KIND and cfg.gated_delta:
+            K, dk, dv = (cfg.linear_conv_kernel_dim, q_dim, o_dim)
+            layers["gdn_wk"] = _normal(next(key), (n, D, H * dk), D, dt)
+            layers["gdn_wv"] = _normal(next(key), (n, D, H * dv), D, dt)
+            layers["gdn_wa"] = _normal(next(key), (n, D, H), D, dt)
+            layers["gdn_wb"] = _normal(next(key), (n, D, H), D, dt)
+            layers["gdn_conv"] = _normal(
+                next(key), (n, K, H * (2 * dk + dv)), K, dt)
+            # one rate and one bias a head (float32 leaves), drawn as KDA's
+            # below and for its reason: g = -exp(A_log) softplus(x Wa +
+            # dt_bias) with x Wa ~ N(0, 1) gives a head a mean decay of
+            # ~0.98 (bias -4.5) to ~0.9998 (bias -9) a token, lower where
+            # the raw stream a post-norm model's layers read has grown
+            layers["gdn_a_log"] = 0.2 * jax.random.normal(
+                next(key), (n, H), jnp.float32)
+            layers["gdn_dt_bias"] = jax.random.uniform(
+                next(key), (n, H), jnp.float32, -9.0, -4.5)
+            layers["gdn_o_norm"] = jnp.ones((n, dv), dt)
+            if cfg.linear_gate:
+                layers["gdn_wg"] = _normal(next(key), (n, D, H * dv), D, dt)
+        elif kind.name == STATE_KIND:
             K = cfg.short_conv_kernel_size
             for name in ("kda_wk", "kda_wv", "kda_wf"):
                 layers[name] = _normal(next(key), (n, D, H * hd), D, dt)
@@ -365,7 +400,11 @@ def init_cache(cfg: ModelConfig, eng: EngineConfig) -> Cache:
     ``[S + 1, H, hd, hd]`` in ``cfg.state_dtype`` and ``[S + 1, K - 1, 3 *
     H * hd]``, indexed by the scheduler's seat (``SchedSeq.slot``, ``S =
     max_num_seqs``): a sequence's state does not grow with its context, so
-    it has no pages.  Row ``S`` is the trash seat of pad rows.
+    it has no pages.  Row ``S`` is the trash seat of pad rows.  A gated-
+    delta-rule layer's states are ``dk`` x ``dv``, laid out with heads side
+    by side in whole lane tiles (``ops.gated_delta.pool_shape``: ``[S + 1,
+    15, 96, 384]`` for 30 heads of 96 x 192), and its convolution runs over
+    ``H * (2 dk + dv)`` channels.
 
     Per-layer arrays (not one stacked [L, …] array) are the TPU-critical
     choice for all three: each layer's buffer is donated and updated IN
@@ -412,7 +451,18 @@ def init_cache(cfg: ModelConfig, eng: EngineConfig) -> Cache:
             cache["latent"] = [
                 jnp.zeros((eng.num_blocks, 1, eng.block_size,
                            latent_width(cfg)), dt) for _ in range(n)]
-        if kind.name == STATE_KIND:
+        if kind.name == STATE_KIND and cfg.gated_delta:
+            from ..ops.gated_delta import pool_shape
+
+            dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+            S = eng.max_num_seqs
+            cache["state"] = [
+                jnp.zeros(pool_shape(S + 1, H, dk, dv),
+                          jnp.dtype(cfg.state_dtype)) for _ in range(n)]
+            cache["conv"] = [
+                jnp.zeros((S + 1, cfg.linear_conv_kernel_dim - 1,
+                           H * (2 * dk + dv)), dt) for _ in range(n)]
+        elif kind.name == STATE_KIND:
             S = eng.max_num_seqs
             cache["state"] = [
                 jnp.zeros((S + 1, H, hd, hd), jnp.dtype(cfg.state_dtype))
@@ -721,11 +771,11 @@ class _TableSlice:
 
     def __getitem__(self, name: str) -> Any:
         w = self._stacked[name]
-        if name in ("wq", "wo", "w_attn_gate"):
+        if name in ("wq", "wo", "w_attn_gate", "q_norm"):
             return w[self._kind.name][self._entry.attn_at]
-        if name.startswith(("kda_", "mla_")):      # a kind's own leaves
+        if name.startswith(("kda_", "mla_", "gdn_")):  # a kind's own leaves
             return w[self._entry.attn_at]
-        if name in ("wk", "wv"):
+        if name in ("wk", "wv", "k_norm"):
             return w[self._kv_at]
         if name in self._FFN[self._entry.ffn]:
             return w[self._entry.ffn_at]
@@ -922,6 +972,8 @@ def attention_choice(cfg: ModelConfig, eng: EngineConfig,
         choice["linear"] = {
             "decode": ("pallas" if impls["decode"] == "pallas" else "xla")
             + "-recurrent", "prefill": "xla-chunked"}
+        if cfg.gated_delta:
+            choice["linear"]["rule"] = "gated-delta"
     return choice
 
 
@@ -1464,19 +1516,103 @@ def linear_attention(cfg: ModelConfig, kind, p: Any, h: jax.Array,
     return x, o, state, conv
 
 
+def gated_delta_attention(cfg: ModelConfig, kind, p: Any, h: jax.Array,
+                          positions: jax.Array, seats: jax.Array,
+                          state: jax.Array, conv: jax.Array,
+                          kernel: Optional[bool] = None):
+    """One gated-delta-rule layer up to its output projection
+    (``cfg.gated_delta``; ``ops/gated_delta.py`` has the mathematics): its
+    input ``x``, the normed and gated ``o [B, T, H, dv]``, and the layer's
+    pools with the rows' seats advanced.  Seats, fresh rows, pads and
+    ``kernel`` as in :func:`linear_attention`; what differs is the rule:
+    heads of ``dk`` x ``dv``, one log decay a head ``-exp(A_log) softplus(x
+    Wa + dt_bias)``, a step size ``sigmoid(x Wb)`` (times 2 where
+    ``linear_allow_neg_eigval``), the output's per-head norm times ``silu(x
+    Wg)`` a channel, and a state pool that lays heads side by side
+    (``gated_delta.to_pool``)."""
+    from ..ops import delta_rule as dr
+    from ..ops import gated_delta as gd
+
+    B, T, _ = h.shape
+    H, dk, dv = (kind.num_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    valid = positions >= 0
+    n = jnp.sum(valid, axis=1).astype(jnp.int32)
+    fresh = positions[:, 0] == 0
+    with jax.named_scope("gdn_proj"):
+        x = (h if cfg.norm_placement == "post"
+             else _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps))
+        q, k, v, a, b = jax.lax.optimization_barrier(
+            (_mm(x, p["wq"]), _mm(x, p["gdn_wk"]), _mm(x, p["gdn_wv"]),
+             _mm(x, p["gdn_wa"]), _mm(x, p["gdn_wb"])))
+    with jax.named_scope("gdn_conv"):
+        prev = jnp.where(fresh[:, None, None], 0,
+                         jnp.take(conv, seats, axis=0))
+        y, nxt = dr.short_conv(jnp.concatenate([q, k, v], axis=-1), prev,
+                               p["gdn_conv"], n)
+        conv = conv.at[seats].set(nxt.astype(conv.dtype))
+        y = jax.nn.silu(y)
+        q = dr.l2norm(y[..., :H * dk].reshape(B, T, H, dk)) * dk ** -0.5
+        k = dr.l2norm(y[..., H * dk:2 * H * dk].reshape(B, T, H, dk))
+        v = y[..., 2 * H * dk:].reshape(B, T, H, dv)
+        g = -jnp.exp(p["gdn_a_log"].astype(jnp.float32)) * jax.nn.softplus(
+            a.astype(jnp.float32) + p["gdn_dt_bias"])
+        g = jnp.where(valid[..., None], g, 0.0)
+        beta = jax.nn.sigmoid(b.astype(jnp.float32))
+        if cfg.linear_allow_neg_eigval:
+            beta = 2.0 * beta
+        beta = jnp.where(valid[..., None], beta, 0.0)
+    with jax.named_scope("gdn_recurrent" if T == 1 else "gdn_chunk"):
+        if T == 1 and kernel is not None and state.dtype == jnp.float32:
+            o, state = gd.gdn_step_seats(
+                state, seats, fresh, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                beta[:, 0], interpret=kernel)
+            o = o[:, None]
+        else:
+            s0 = jnp.where(
+                fresh[:, None, None, None], 0,
+                gd.from_pool(jnp.take(state, seats, axis=0), H)
+            ).astype(jnp.float32)
+            if T == 1:
+                o, s1 = gd.gdn_step(s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0])
+                o = o[:, None]
+            else:
+                o, s1 = gd.gdn_chunked(s0, q, k, v, g, beta)
+            state = state.at[seats].set(gd.to_pool(s1).astype(state.dtype))
+    with jax.named_scope("gdn_out"):
+        o = _rms_norm(o, p["gdn_o_norm"], cfg.rms_norm_eps)   # float32
+        if cfg.linear_gate:
+            gate = jax.nn.silu(_mm(x, p["gdn_wg"]).astype(jnp.float32))
+            o = o * gate.reshape(B, T, H, dv)
+        o = o.astype(h.dtype)
+    return x, o, state, conv
+
+
 def attn_inputs(cfg: ModelConfig, kind, p: Any, h: jax.Array,
                 positions: jax.Array):
-    """Normed input ``x`` and the roped ``q [B, T, H, hd]``, ``k``, ``v
-    [B, T, KV, hd]`` of one layer, ``H`` and the rope its kind's."""
+    """Normed input ``x`` (the raw stream where the model norms a
+    sublayer's output instead) and the roped ``q [B, T, H, hd]``, ``k``,
+    ``v [B, T, KV, hd]`` of one layer, ``H`` and the rope its kind's (none
+    where its ``rope_type`` is "none"); ``q`` and ``k`` normed over the
+    whole projection first where ``cfg.qk_norm``."""
     with jax.named_scope("qkv_proj"):
-        x = _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
+        x = (h if cfg.norm_placement == "post"
+             else _rms_norm(h, p["attn_norm"], cfg.rms_norm_eps))
         q, k, v = _qkv_proj(x, p, kind.num_heads, cfg.num_kv_heads,
                             cfg.head_dim_)
+        if cfg.qk_norm:      # over the whole projection, heads together
+            B, T = h.shape[:2]
+            q = _rms_norm(q.reshape(B, T, -1), p["q_norm"],
+                          cfg.rms_norm_eps).reshape(q.shape)
+            k = _rms_norm(k.reshape(B, T, -1), p["k_norm"],
+                          cfg.rms_norm_eps).reshape(k.shape)
     with jax.named_scope("rope"):
         if cfg.has_table:
             rope = cfg.rope_of(kind)
-            q = _rope_kind(q, positions, rope)
-            k = _rope_kind(k, positions, rope)
+            if rope.get("rope_type") != "none":
+                q = _rope_kind(q, positions, rope)
+                k = _rope_kind(k, positions, rope)
         else:
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
@@ -1485,8 +1621,9 @@ def attn_inputs(cfg: ModelConfig, kind, p: Any, h: jax.Array,
 
 def attn_output(cfg: ModelConfig, p: Any, h: jax.Array, x: jax.Array,
                 attn: jax.Array) -> jax.Array:
-    """``h`` plus the output projection of ``attn [B, T, H, hd]``, each
-    head first times its gate ``sigmoid(x Wg)`` where the model has one."""
+    """``h`` plus the output projection of ``attn [B, T, H, hd]`` (normed
+    where ``cfg.norm_placement`` is "post"), each head first times its gate
+    ``sigmoid(x Wg)`` where the model has one."""
     B, T, H, hd = attn.shape
     if cfg.attn_gate:
         with jax.named_scope("attn_gate"):
@@ -1494,7 +1631,10 @@ def attn_output(cfg: ModelConfig, p: Any, h: jax.Array, x: jax.Array,
             attn = (attn.astype(jnp.float32) * g[..., None]).astype(
                 attn.dtype)
     with jax.named_scope("o_proj"):
-        return h + _mm(attn.reshape(B, T, H * hd), p["wo"])
+        out = _mm(attn.reshape(B, T, H * hd), p["wo"])
+        if cfg.norm_placement == "post":
+            out = _rms_norm(out, p["attn_norm"], cfg.rms_norm_eps)
+        return h + out
 
 
 def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
@@ -1554,8 +1694,9 @@ def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
                 shortcut.append(routed.reshape(B, T, D))
                 return h + shared
             return h + routed.reshape(B, T, D) + shared
+    post = cfg.norm_placement == "post"
     with jax.named_scope("mlp"):
-        x = _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
+        x = h if post else _rms_norm(h, p["mlp_norm"], cfg.rms_norm_eps)
         if cfg.is_moe:
             from ..parallel.moe import moe_ffn
 
@@ -1574,7 +1715,10 @@ def ffn(cfg: ModelConfig, entry, p: Any, h: jax.Array, *,
         if ff_pin is not None:
             gate = jax.lax.with_sharding_constraint(gate, ff_pin)
             up = jax.lax.with_sharding_constraint(up, ff_pin)
-        h = h + _mm((gate * up).astype(h.dtype), p["w_down"])
+        out = _mm((gate * up).astype(h.dtype), p["w_down"])
+        if post:
+            out = _rms_norm(out, p["mlp_norm"], cfg.rms_norm_eps)
+        h = h + out
     if cfg.moe_shortcut and shortcut:
         with jax.named_scope("moe_shortcut"):
             h = h + shortcut.pop()
@@ -1707,7 +1851,9 @@ def forward(
         if kind.name in (STATE_KIND, LATENT_KIND):
             at = entry.attn_at
             if kind.name == STATE_KIND:
-                x, attn, state, conv = linear_attention(
+                mixer = (gated_delta_attention if cfg.gated_delta
+                         else linear_attention)
+                x, attn, state, conv = mixer(
                     cfg, kind, p, h, positions, seats,
                     cache["state"][at], cache["conv"][at],
                     kernel=pallas_interpret(mesh) if use_pallas else None)

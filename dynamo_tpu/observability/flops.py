@@ -126,7 +126,16 @@ def _table_param_count(cfg, active: bool = False) -> int:
         kind = kinds[entry.attn]
         H = kind.num_heads
         total += 2 * D                                   # the two norms
-        if kind.name == STATE_KIND:
+        if kind.name == STATE_KIND and cfg.gated_delta:
+            # the gated delta rule: q, k of dk and v of dv a head in, the
+            # output gate, wo out; decay and beta a head; the conv's taps;
+            # the decay's rate and bias a head; the output norm
+            dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+            total += D * H * (2 * dk + 2 * dv) + 2 * D * H + 2 * H + dv
+            if cfg.linear_gate:
+                total += D * H * dv
+            total += cfg.linear_conv_kernel_dim * H * (2 * dk + dv)
+        elif kind.name == STATE_KIND:
             # wq, k, v, decay in; wo out; beta; the conv's taps; the
             # decay's rate and bias; the output norm
             total += 5 * D * H * hd + D * H + H + H * hd + hd
@@ -143,6 +152,8 @@ def _table_param_count(cfg, active: bool = False) -> int:
             total += r * H * (dn + dvh) + H * dvh * D      # wukv, wo
         else:
             total += 2 * D * H * hd + 2 * D * KV * hd    # wq, wo, wk, wv
+            if cfg.qk_norm:                              # over a projection
+                total += H * hd + KV * hd
         if cfg.attn_gate:
             total += D * H
         if entry.ffn == "dense":
@@ -204,7 +215,11 @@ class FlopsModel:
         self.attn_coef = 0.0
         for e in model_cfg.layer_table:
             name, H = kinds[e.attn].name, kinds[e.attn].num_heads
-            if name == STATE_KIND:
+            if name == STATE_KIND and model_cfg.gated_delta:
+                self.matmul_per_token += 7.0 * H * (
+                    model_cfg.linear_key_head_dim
+                    * model_cfg.linear_value_head_dim)
+            elif name == STATE_KIND:
                 self.matmul_per_token += 7.0 * H * model_cfg.head_dim_ ** 2
             elif name == LATENT_KIND:
                 self.attn_coef += 2.0 * H * (2 * model_cfg.kv_lora_rank

@@ -219,6 +219,111 @@ def test_the_latent_decode_walk_compiles_at_the_cells_shapes(
     assert text.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("B", [8, 128])
+def test_the_gated_delta_seat_kernel_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, B):
+    """``gated_delta.gdn_step_seats`` at olmo-hybrid-7b-l8's widths (30
+    heads of 96 x 192, 97 seats): the pool lays two heads side by side,
+    ``[97, 15, 96, 384]``, whole tiles; a row's state is 2.2 MB of VMEM in
+    and out and nothing is transposed in the kernel.  Beside it the paged
+    decode kernel at that table's full layers: 30 KV heads with ONE query
+    head each."""
+    from dynamo_tpu.ops.gated_delta import gdn_step_seats, pool_shape
+    from dynamo_tpu.ops.paged_attention import (
+        default_kv_tile, paged_attention_decode,
+    )
+
+    H, dk, dv, seats, bs = 30, 96, 192, 96, ENGINE.block_size
+
+    def S(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert pool_shape(seats + 1, H, dk, dv) == (97, 15, 96, 384)
+    text = jax.jit(gdn_step_seats).lower(
+        S(pool_shape(seats + 1, H, dk, dv)), S((B,), jnp.int32),
+        S((B,), jnp.bool_), S((B, H, dk)), S((B, H, dk)), S((B, H, dv)),
+        S((B, H)), S((B, H))).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+    def launch(q, k, v, tables, lens):
+        return paged_attention_decode(
+            q, k, v, tables, lens, block_size=bs,
+            kv_tile=default_kv_tile(bs, H, 128, jnp.bfloat16),
+            interpret=False)
+
+    page = S((12544, H, bs, 128), jnp.bfloat16)
+    text = jax.jit(launch).lower(
+        S((B, H, 128), jnp.bfloat16), page, page, S((B, 256), jnp.int32),
+        S((B,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("program", ["decode_window_b128", "prefill_T512"])
+def test_the_gated_delta_table_compiles_whole_at_its_real_size(
+        topo, one_chip, no_compile_cache, monkeypatch, program, capsys):
+    """olmo-hybrid-7b-l8 as the cell builds it (8 layers at the published
+    widths, 12544 blocks, 96 seats): the decode window of the bucket that
+    serves 96 rows (128: the ladder past 64 doubles) and the T = 512 chunk
+    at the widest table, compiled for a described v5e.  Both kernels are in
+    the decode program (6 seat recurrences, 2 paged walks); the arguments
+    are what the cell keeps resident, ~12.4 GB, and the program's own
+    temporaries must fit beside them in 15.75 GB."""
+    import json
+
+    from benchmarks.chip import worker_launch as WL
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "chip", "configs",
+            "olmo-hybrid-7b-l8.json")) as f:
+        file = json.load(f)
+    cfg = WL.model_config_from(file, False)
+    args = dict(zip(file["engine_args"][::2], file["engine_args"][1::2]))
+    eng = EngineConfig(
+        block_size=int(args["--block-size"]),
+        num_blocks=int(args["--num-blocks"]),
+        max_num_seqs=int(args["--max-num-seqs"]),
+        max_num_batched_tokens=int(args["--max-batched-tokens"]),
+        max_model_len=int(args["--max-model-len"]))
+    assert eng.decode_buckets[-1] == 128
+    # the CPU backend would have the kernels interpreted
+    monkeypatch.setattr(M, "pallas_interpret", lambda mesh: False)
+
+    def place(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = place(jax.eval_shape(
+        lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: M.init_cache(cfg, eng)))
+    Wcap = eng.max_blocks_per_seq
+    if program == "decode_window_b128":
+        ctl = place(jax.eval_shape(
+            lambda: M.init_ctl(eng, eng.max_num_seqs, Wcap)))
+        window, _ = M.make_autopilot_fns(cfg, eng, 1, Wcap, None)
+        compiled = window.__wrapped__.lower(
+            params, cache, ctl, S((128,), jnp.int32)).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 8
+    else:
+        T, W = 512, Wcap
+        fn = M.make_packed_prefill_fn(cfg, eng, T, W, None)
+        compiled = fn.__wrapped__.lower(
+            params, cache, S((eng.max_num_seqs + 1,), jnp.int32),
+            S((1, T + W + M.PP_SCALARS), jnp.int32),
+            S((2,), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nolmo-hybrid-7b-l8 {program}: arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert 12.0e9 < mem.argument_size_in_bytes < 12.8e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75e9)
+
+
 @pytest.mark.parametrize("phase", ["decode", "prefill"])
 @pytest.mark.parametrize("D,F,E,Eh,k,seqs,router", [
     (3072, 1024, 256, 128, 10, 32, {}),

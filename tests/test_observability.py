@@ -655,6 +655,11 @@ _TABLES = {
     "shortcut": ("longcat-flash-omni-ep32", {
         "moe_router", "moe_experts", "moe_zero", "moe_shortcut",
         "attention_latent"}),
+    # gated-delta-rule layers beside full attention over K / V pages, dense
+    # FFNs: the output norms are in "o_proj" / "mlp", the QK-norm in
+    # "qkv_proj"
+    "gated_delta": ("olmo-hybrid-7b-l8", {
+        "gdn_proj", "gdn_conv", "gdn_out", "gdn_recurrent", "gdn_chunk"}),
 }
 
 
@@ -695,7 +700,8 @@ def _lowered_step_program(which, cfg=None):
     return fn.__wrapped__.lower(*args)
 
 
-@pytest.mark.parametrize("model", ["one_kind", "table", "hybrid", "shortcut"])
+@pytest.mark.parametrize("model", ["one_kind", "table", "hybrid", "shortcut",
+                                   "gated_delta"])
 @pytest.mark.parametrize("which", ["decode_window", "packed_prefill"])
 def test_step_programs_carry_every_scope(which, model):
     """Every name of model.SCOPES is a component of some op's ``op_name``
@@ -709,12 +715,14 @@ def test_step_programs_carry_every_scope(which, model):
     assert set().union(*(own for _, own in _TABLES.values())) == set(
         model_lib.TABLE_SCOPES)
     name, own = _TABLES.get(model, (None, set()))
-    own = own - {"kda_chunk" if which == "decode_window"
-                 else "kda_recurrent"}
+    own = own - ({"kda_chunk", "gdn_chunk"} if which == "decode_window"
+                 else {"kda_recurrent", "gdn_recurrent"})
     # no layer of the hybrid keeps K and V: "attention" is only what a
     # kernel's decode program computes its rows' lengths under
     lacks = {"attention"} if (model in ("hybrid", "shortcut")
                               and which == "packed_prefill") else set()
+    if model == "gated_delta":       # no layer of it has a rotary embedding
+        lacks = lacks | {"rope"}
     text = _lowered_step_program(
         which, _table_model(name) if name else None).as_text(debug_info=True)
     seen = set()
