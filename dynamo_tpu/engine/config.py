@@ -500,25 +500,12 @@ class EngineConfig:
     # there yet, only tests do
     attention_impl_spec: str = ""
     attention_impl_prefill: str = ""
-    # adaptive bucket ladders (engine/ladder.py): let the engine split hot
-    # decode/prefill buckets and retire cold ones from the flight recorder's
-    # live per-bucket occupancy, under ladder_compile_budget extra rungs per
-    # ladder. Off by default — static buckets stay fully deterministic.
-    adaptive_buckets: bool = False
-    # max rungs each ladder may ADD over its lifetime; bounds steady-state
-    # recompiles (one program per new rung, watchdog-attributed)
-    ladder_compile_budget: int = 4
     # chunked prefill: cap each prefill chunk at this many tokens so long
     # prompts are admitted in slices interleaved with running decodes under
     # max_num_batched_tokens, instead of one whole-prompt stall that blows
     # up TTFT p99 for everyone behind it. 0 = off (chunks capped only by
     # the largest prefill bucket).
     prefill_chunk_tokens: int = 0
-    # tokens generated per decode window (>1 chains steps on device via an
-    # UNROLLED window fed from the device token ring, amortising the
-    # host↔device roundtrip; tokens past a sequence's EOS/capacity inside
-    # a window are discarded)
-    decode_steps: int = 1
     # run-ahead: how many scheduled windows may be in flight before the
     # engine loop waits for a landing. >1 dispatches window N+1 (decode
     # input tokens read from the device ring) while window N's sampled
@@ -527,14 +514,10 @@ class EngineConfig:
     # measured on an earlier transport; re-measured by chip_smoke.py, see
     # CHANGES). 1 = classic synchronous loop (pp engines force 1).
     pipeline_depth: int = 2
-    # decode block lookahead: best-effort extra blocks reserved past each
-    # window so autopilot table/valid_until deltas (2 host uploads each)
-    # amortise over lookahead*block_size tokens instead of per-block
-    block_lookahead: int = 0
     # pipeline parallelism: >1 runs the unified step GPipe-style over a
     # ``pp`` mesh of that many stages (layers stage-sharded, decode
     # batches microbatched; parallel/pp_serving.py). Mutually exclusive
-    # with (dp, tp) mesh_shape > (1, 1) and with decode_steps > 1.
+    # with (dp, tp) mesh_shape > (1, 1).
     pp_stages: int = 1
     pp_microbatches: int = 4
     # sequence-parallel prefill: a fresh prompt at least this long is
@@ -618,8 +601,6 @@ class EngineConfig:
                 raise ValueError(
                     f"unknown attention_impl_{cls} {v!r}"
                 )
-        if self.ladder_compile_budget < 0:
-            raise ValueError("ladder_compile_budget must be >= 0")
         if self.prefill_chunk_tokens < 0:
             raise ValueError("prefill_chunk_tokens must be >= 0")
         if self.spec_mode != "off":

@@ -115,11 +115,11 @@ class _LoopClock:
     is the part that was work."""
 
     def __init__(self, loop_counter=None):
-        self.t_start = self.t_handoff = time.monotonic()
+        now = self.t_start = self.t_handoff = time.monotonic()
         self.wait_s = {"land": 0.0, "idle": 0.0, "executor": 0.0}
         self._open = dict(self.wait_s)  # waits since the last handoff
         self._loop_counter = loop_counter
-        self._loop_busy_at = loop_counter.busy_s() if loop_counter else 0.0
+        self._loop_busy_at = loop_counter.busy_s(now) if loop_counter else 0.0
         self._cpu_at = time.thread_time()   # made and read on the loop thread
 
     @contextlib.contextmanager
@@ -141,7 +141,10 @@ class _LoopClock:
         self.t_handoff = now
         loop_busy = 0.0
         if self._loop_counter is not None:
-            total = self._loop_counter.busy_s()
+            # at the instant host_s was closed: a thread switch between two
+            # reads of the clock would make this handoff's stretch longer
+            # and the next one's shorter than its host_s
+            total = self._loop_counter.busy_s(now)
             loop_busy = total - self._loop_busy_at
             self._loop_busy_at = total
         cpu = time.thread_time()
@@ -782,17 +785,6 @@ class EngineCore(AsyncEngine):
         snap["pressure_peak"] = self.pressure_peak
         snap["pressure_spills_total"] = self.num_pressure_spills
         snap["pressure_shed_total"] = self.num_pressure_shed
-        # adaptive bucket ladders (InferenceEngine only): scalar gauges by
-        # the exact keys observability.gauges reads; the rungs tuple is
-        # non-scalar and stays off the wire dict
-        for kind, lad in getattr(self, "_ladders", {}).items():
-            ls = lad.snapshot()
-            snap[f"ladder_{kind}_rungs"] = ls["rungs"]
-            snap[f"ladder_{kind}_rungs_n"] = len(ls["rungs"])
-            snap[f"ladder_{kind}_splits_total"] = ls["splits_total"]
-            snap[f"ladder_{kind}_retires_total"] = ls["retires_total"]
-            snap[f"ladder_{kind}_budget_remaining"] = ls["budget_remaining"]
-            snap[f"ladder_{kind}_converged"] = int(ls["converged"])
         return snap
 
     def mark_obs_warmup_done(self) -> None:
@@ -1378,10 +1370,6 @@ class EngineCore(AsyncEngine):
             except Exception:
                 log.exception("kv event sink failed")
 
-    def drain_kv_events(self) -> List[dict]:
-        events, self._pending_events = self._pending_events, []
-        return events
-
 
 class InferenceEngine(EngineCore):
     """The JAX device engine: jitted unified prefill/decode steps over a
@@ -1396,10 +1384,6 @@ class InferenceEngine(EngineCore):
         seed: int = 0,
         devices: Optional[list] = None,
     ):
-        # adaptive bucket ladders (engine/ladder.py); built after the
-        # recorder below when enabled, {} keeps every bucketing call on
-        # the static grid
-        self._ladders: Dict[str, Any] = {}
         # what the engine may not assume of a sequence's memory: layers that
         # keep something other than K and V pages, and a state a seat
         self._unpaged = model_config.cache_kinds != ("kv",)
@@ -1474,9 +1458,6 @@ class InferenceEngine(EngineCore):
                 ),
                 "pp_step",
             )
-            if engine_config.decode_steps > 1:
-                log.warning("decode_steps > 1 is unsupported with "
-                            "pp_stages — running single-step decode")
         else:
             self.mesh = model_lib.make_mesh(
                 engine_config.mesh_shape, devices
@@ -1502,12 +1483,8 @@ class InferenceEngine(EngineCore):
             self.cache = model_lib.init_cache_sharded(
                 model_config, engine_config, self.mesh
             )
-            self._step_fn = model_lib.make_step_fn(
-                model_config, engine_config, self.mesh
-            )
             # pipelined serving path: packed ring prefill + autopilot
             # decode windows running on device-resident control state
-            self._window_K = max(1, engine_config.decode_steps)
             self._ap_Wcap = engine_config.max_blocks_per_seq
             # what StepRecord.kv_blocks_walked counts with: the decode
             # kernel's tile as the window traces it (0 = the einsum path,
@@ -1516,8 +1493,7 @@ class InferenceEngine(EngineCore):
                 model_config, engine_config, self.mesh)
             self._ap_window_fn, self._ap_delta_fn = (
                 model_lib.make_autopilot_fns(
-                    model_config, engine_config, self._window_K,
-                    self._ap_Wcap, self.mesh,
+                    model_config, engine_config, self._ap_Wcap, self.mesh,
                 )
             )
             # speculative decoding: drafter history + draft/verify window
@@ -1585,36 +1561,6 @@ class InferenceEngine(EngineCore):
                 jsonl_path=env_str("DYNTPU_OBS_STEPSTATS_PATH", ""),
             )
             compilewatch.install()
-        # waste-driven adaptive bucket ladders: consume the recorder's
-        # per-bucket occupancy, split hot rungs / retire cold ones under
-        # an explicit compile budget. Needs the recorder (occupancy
-        # source) and the single-engine path (pp keeps static buckets).
-        if (self.obs is not None and self.pp == 1
-                and (engine_config.adaptive_buckets
-                     or env_flag("DYNTPU_LADDER_ENABLED", False))):
-            from .ladder import BucketLadder
-            budget = engine_config.ladder_compile_budget
-            self._ladders = {
-                # decode windows and spec verify windows share the row
-                # bucket grid (and its compiled programs)
-                "decode": BucketLadder(
-                    "decode", engine_config.decode_buckets,
-                    kinds=(DECODE, SPEC_VERIFY),
-                    compile_budget=budget, step=8,
-                ),
-                "prefill": BucketLadder(
-                    "prefill", engine_config.prefill_buckets,
-                    kinds=(PREFILL,),
-                    compile_budget=budget, step=16,
-                ),
-            }
-            # the scheduler snaps chunked-prefill caps onto live rungs
-            self.scheduler.prefill_ladder = self._ladders["prefill"]
-            log.info(
-                "adaptive bucket ladders on: budget=%d rungs decode=%r "
-                "prefill=%r", budget, engine_config.decode_buckets,
-                engine_config.prefill_buckets,
-            )
         self._rng = jax.random.PRNGKey(seed + 1)
         # one-shot einsum rebuild when the largest decode bucket stalls
         self._stall_einsum_fallback = False
@@ -1626,8 +1572,8 @@ class InferenceEngine(EngineCore):
         # fetches (device_get of sampled-token handles) run OFF the
         # dispatch thread on the batching fetcher: a fetch is a host sync
         # (see _BatchingFetcher) and must never delay the next window's
-        # enqueue; grouped gets keep the landing rate above the K=1
-        # window rate.
+        # enqueue; grouped gets keep the landing rate above the window
+        # rate.
         self._fetcher = _BatchingFetcher(
             self._unpack_results, on_sync=self._count_fetch_sync
         )
@@ -2051,12 +1997,12 @@ class InferenceEngine(EngineCore):
             col_of = {}
             for col, slot in enumerate(decode_handle[1]):
                 col_of.setdefault(slot, col)
-            out = np.asarray(got[-1])  # [K, B] (spec: [k+3, B] packed)
+            out = np.asarray(got[-1])  # [1, B] (spec: [k+3, B] packed)
             if len(decode_handle) > 2 and decode_handle[2]:
                 decode_samples = self._unpack_spec(batch, out, col_of)
             else:
                 if self._moe_stats:
-                    # the routing counters' row behind the K sample rows
+                    # the routing counters' row behind the sample row
                     out, moe_stats = out[:-1], out[-1]
                 for row in batch.decode_rows:
                     col = col_of[row.slot]
@@ -2096,18 +2042,6 @@ class InferenceEngine(EngineCore):
                 rec.goodput_tokens = emitted
             self.obs.commit(rec)
         recs.clear()
-        if self._ladders:
-            self._ladder_tick()
-
-    @hot_path
-    def _ladder_tick(self) -> None:
-        """Feed the recorder's occupancy histogram to the bucket ladders
-        and run one (cheap, host-int) adaptation check. Called on every
-        landing; BucketLadder.min_dispatches gates actual epochs."""
-        occ = self.obs.bucket_occupancy()
-        for lad in self._ladders.values():
-            lad.ingest(occ)
-            lad.maybe_adapt()
 
     @hot_path
     def _unpack_spec(self, batch, out, col_of) -> List[List[int]]:
@@ -2161,19 +2095,13 @@ class InferenceEngine(EngineCore):
         return sub
 
     def _bucket_for(self, kind: str, n: int) -> int:
-        """Bucket ``n`` on the live ladder grid for ``kind`` (adaptive
-        rungs when the ladder is on, the static config grid otherwise).
+        """Bucket ``n`` on the config's grid for ``kind``.
         Stall-quarantined buckets route to the next rung up — a different
         compiled program doing the same work with padding."""
-        lad = self._ladders.get(kind)
-        if lad is not None:
-            b = lad.bucket_for(n)
-            grid = tuple(sorted(lad.snapshot()["rungs"]))
-        else:
-            cfg = self.config
-            grid = (cfg.decode_buckets if kind == "decode"
-                    else cfg.prefill_buckets)
-            b = _bucket(n, grid)
+        cfg = self.config
+        grid = (cfg.decode_buckets if kind == "decode"
+                else cfg.prefill_buckets)
+        b = _bucket(n, grid)
         if self._shape_quarantine and (kind, b) in self._shape_quarantine:
             for g in grid:
                 if g >= b and (kind, g) not in self._shape_quarantine:
@@ -2191,18 +2119,14 @@ class InferenceEngine(EngineCore):
         if (self.pp == 1 and kind == "decode"
                 and not self._stall_einsum_fallback):
             cfg = self.config
-            grid = cfg.decode_buckets
-            lad = self._ladders.get("decode")
-            if lad is not None:
-                grid = tuple(sorted(lad.snapshot()["rungs"]))
-            if bucket >= max(grid):
+            if bucket >= max(cfg.decode_buckets):
                 try:
                     import dataclasses as _dc
                     fb_cfg = _dc.replace(cfg, attention_impl="einsum")
                     self._ap_window_fn, self._ap_delta_fn = (
                         model_lib.make_autopilot_fns(
-                            self.model_config, fb_cfg, self._window_K,
-                            self._ap_Wcap, self.mesh,
+                            self.model_config, fb_cfg, self._ap_Wcap,
+                            self.mesh,
                         )
                     )
                     self._stall_einsum_fallback = True
@@ -2428,7 +2352,7 @@ class InferenceEngine(EngineCore):
         no growth) dispatches with ZERO fresh host arrays — all control
         state is device-resident; the host sends packed deltas only on
         joins, block growth, resumes, and seat-map changes. Returns
-        (samples_handle [K, B], col_map, spec) where col_map[device
+        (samples_handle [1, B], col_map, spec) where col_map[device
         column] is the slot computed there and ``spec`` marks a packed
         spec-window handle."""
         cfg = self.config
@@ -2437,7 +2361,7 @@ class InferenceEngine(EngineCore):
         # spec windows land a data-dependent 1..k+1 tokens; mirror the
         # device's advance pessimistically here (max) and correct it in
         # _unpack_spec before the next dispatch (synchronous loop)
-        K = (self._spec_k + 1) if spec else self._window_K
+        K = (self._spec_k + 1) if spec else 1
         deltas: Dict[int, Dict[str, Any]] = {}
         reset_rows: List[Any] = []
         for r in rows:
@@ -2480,7 +2404,7 @@ class InferenceEngine(EngineCore):
         # are exactly the scheduled set. Dead seats idle at vu=0, but a
         # LIVE slot the scheduler skipped this round (pool pressure) must
         # not keep its column — the window would advance its device pos/ring
-        # token K steps behind the host mirror's back. Rebuild + upload
+        # token behind the host mirror's back. Rebuild + upload
         # excludes it; its device state is untouched until re-scheduled.
         needed = [r.slot for r in rows]
         B = self._bucket_for("decode", len(needed))
@@ -2500,27 +2424,21 @@ class InferenceEngine(EngineCore):
             # realized goodput (emitted tokens; spec accept counts) is
             # stamped at landing — only padded/real shapes are known here
             ctx = sum(K * r.base + K * (K + 1) // 2 for r in rows)
-            walked = 0
-            if self._decode_kv_tile and not spec:
-                # step k of the window attends base + k + 1 positions
-                walked = sum(kv_blocks_walked(
-                    [r.base + k + 1 for r in rows],
-                    kv_tile=self._decode_kv_tile, block_size=bs,
-                ) for k in range(K))
-            elif not spec:
-                walked = B * self._ap_Wcap * K
-            walked_w = ctx_w = 0
-            if self._attn_window and not spec:
+            walked = walked_w = ctx_w = 0
+            if not spec:
+                # a plain window is one step, attending base + 1 positions
+                attended = [r.base + 1 for r in rows]
+                tile = self._decode_kv_tile
+                # the einsum path (no tile) gathers every column
+                walked = kv_blocks_walked(
+                    attended, kv_tile=tile, block_size=bs,
+                ) if tile else B * self._ap_Wcap
                 win = self._attn_window
-                ctx_w = sum(min(r.base + k + 1, win)
-                            for r in rows for k in range(K))
-                walked_w = walked   # the einsum path gathers every column
-                if self._decode_kv_tile:
-                    walked_w = sum(kv_blocks_walked(
-                        [r.base + k + 1 for r in rows],
-                        kv_tile=self._decode_kv_tile, block_size=bs,
-                        window=win,
-                    ) for k in range(K))
+                if win:
+                    ctx_w = sum(min(n, win) for n in attended)
+                    walked_w = kv_blocks_walked(
+                        attended, kv_tile=tile, block_size=bs, window=win,
+                    ) if tile else walked
             obs_out.append(StepRecord(
                 kind=SPEC_VERIFY if spec else DECODE,
                 t_dispatch=time.monotonic(),
@@ -2531,10 +2449,10 @@ class InferenceEngine(EngineCore):
                 kv_blocks_walked_window=walked_w,
                 context_sum_window=ctx_w,
                 # a spec window writes a row's K fed positions at once, a
-                # decode window one page a row in each of its K steps
+                # decode window one page a row
                 kv_pages_written=kv_pages_written(
                     [(r.base, K) for r in rows], block_size=bs,
-                ) if spec else len(rows) * K,
+                ) if spec else len(rows),
                 state_rows=len(rows) * K if self._seat_state else 0,
                 latent_context_sum=ctx if self._latent else 0,
             ))

@@ -2215,86 +2215,6 @@ def make_step_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Optional[Mesh]):
 # to). TPU-first redesign: same tokens, no sync.
 
 
-def raw_decode_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
-                         mesh: Optional[Mesh] = None):
-    """K decode steps, UNROLLED, fed from the device token ring.
-
-    Unrolled rather than ``lax.scan``: the paged cache must not be a scan
-    carry (whole-cache copies every iteration — see ``init_cache``). K is
-    static; each iteration's scatter updates the donated cache in place.
-
-    Signature:
-      window(params, cache, last_tok[S+1], tok_host[B], tok_src[B],
-             slot_ids[B], positions[B,1], block_tables[B,W],
-             valid_until[B], rngs[K], temperature[B], top_k[B],
-             top_p[B], seeds[B])
-        -> (cache, last_tok, samples[K, B])
-
-    Input token per row: ``last_tok[slot]`` when ``tok_src > 0`` (the
-    previous window / prefill wrote it there — the host may not know it
-    yet), else ``tok_host`` (resumed / injected sequences). Rows whose
-    position reaches ``valid_until`` write nothing (their page is block 0,
-    which gets its own bytes back); their
-    garbage tokens are discarded by the scheduler. After the window, each
-    row's LAST VALID sample is written back to its slot so the next window
-    can chain without the host ever seeing a token.
-    """
-
-    def window(params, cache, last_tok, tok_host, tok_src, slot_ids,
-               positions, block_tables, valid_until, rngs,
-               temperature, top_k, top_p, seeds):
-        with jax.named_scope("ctl"):
-            tok = jnp.where(
-                tok_src > 0, last_tok[slot_ids], tok_host)[:, None]
-        pos = positions
-        outs = []
-        for k in range(K):
-            with jax.named_scope("ctl"):
-                pos_eff = jnp.where(pos < valid_until[:, None], pos, -1)
-            cache, h = forward(
-                cfg, eng, params, cache, tok, pos_eff, block_tables,
-                mesh=mesh, seats=slot_ids,
-            )
-            with jax.named_scope("lm_head"):
-                h_last = h[:, 0]
-            logits = logits_fn(cfg, params, h_last)
-            s = sample(
-                logits, rngs[k], temperature, top_k, top_p, seeds,
-                pos[:, 0],
-            )
-            outs.append(s)
-            with jax.named_scope("ctl"):
-                tok, pos = s[:, None], pos + 1
-        # write each row's last in-capacity sample back to its ring slot; a
-        # row already at/over capacity (acc == 0 — e.g. a padding row whose
-        # valid_until <= pos) produced ONLY garbage samples, so route its
-        # write to the trash slot S instead of corrupting a live ring entry
-        with jax.named_scope("ctl"):
-            samples = jnp.stack(outs)                            # [K, B]
-            acc = jnp.clip(valid_until - positions[:, 0], 0, K)  # [B]
-            final = jnp.take_along_axis(
-                samples, jnp.maximum(acc - 1, 0)[None, :], axis=0
-            )[0]
-            S = last_tok.shape[0] - 1
-            write_slots = jnp.where(acc > 0, slot_ids, S)
-            last_tok = last_tok.at[write_slots].set(final)
-        return cache, last_tok, samples
-
-    return window
-
-
-def make_decode_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
-                          mesh: Optional[Mesh] = None):
-    """Jitted ring decode window; cache and ring buffer donated."""
-    return compilewatch.label(
-        jax.jit(
-            raw_decode_window_fn(cfg, eng, K, mesh), donate_argnums=(1, 2),
-            **_io_kwargs(mesh, cfg, 12, ("cache", "repl", "repl"), eng=eng),
-        ),
-        "ring_decode_window",
-    )
-
-
 # ------------------- decode autopilot (device-resident control) -----------
 #
 # The token ring removed the host from the token FEED; the autopilot
@@ -2381,22 +2301,22 @@ def raw_ctl_delta_fn(Wcap: int):
     return apply
 
 
-def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
+def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig,
                             mesh: Optional[Mesh] = None):
-    """K unrolled decode steps reading EVERYTHING from device state.
+    """One decode step reading EVERYTHING from device state.
 
     Signature: window(params, cache, ctl, slot_rows[B]) ->
-    (cache, ctl, samples[K, B]).
+    (cache, ctl, samples[1, B]).
 
     Dead seats (valid_until <= pos) compute garbage, write none of it to
-    the cache and advance nothing; their sample columns are discarded by
-    the host.
-    Step rngs derive from the carried key + counter, so a window dispatch
-    carries zero fresh host arrays.
+    the cache or the ring and advance nothing; their sample columns are
+    discarded by the host.
+    The step's rng derives from the carried key + counter, so a window
+    dispatch carries zero fresh host arrays.
 
-    A table with routed experts adds one row to ``samples`` (``[K + 1,
-    B]``, :func:`moe_stats_row`): the window's routing counters ride the
-    one fetch the sampled tokens already make.
+    A table with routed experts adds one row to ``samples`` (``[2, B]``,
+    :func:`moe_stats_row`): the window's routing counters ride the one
+    fetch the sampled tokens already make.
     """
 
     def window(params, cache, ctl, slot_rows):
@@ -2412,26 +2332,23 @@ def raw_autopilot_window_fn(cfg: ModelConfig, eng: EngineConfig, K: int,
             sd = ctl["seed"][rows]
             tables = ctl["tables"][rows]
             pos = pos0[:, None]
-        outs = []
-        for k in range(K):
-            with jax.named_scope("ctl"):
-                rng_k = jax.random.fold_in(ctl["key"], ctl["ctr"] * K + k)
-                pos_eff = jnp.where(pos < vu[:, None], pos, -1)
-            cache, h = forward(
-                cfg, eng, params, cache, tok, pos_eff, tables, mesh=mesh,
-                moe_stats=moe_stats, seats=rows,
-            )
-            with jax.named_scope("lm_head"):
-                h_last = h[:, 0]
-            logits = logits_fn(cfg, params, h_last)
-            s = sample(logits, rng_k, temp, tk, tp, sd, pos[:, 0])
-            outs.append(s)
-            with jax.named_scope("ctl"):
-                tok, pos = s[:, None], pos + 1
+            rng = jax.random.fold_in(ctl["key"], ctl["ctr"])
+            pos_eff = jnp.where(pos < vu[:, None], pos, -1)
+        cache, h = forward(
+            cfg, eng, params, cache, tok, pos_eff, tables, mesh=mesh,
+            moe_stats=moe_stats, seats=rows,
+        )
+        with jax.named_scope("lm_head"):
+            h_last = h[:, 0]
+        logits = logits_fn(cfg, params, h_last)
+        s = sample(logits, rng, temp, tk, tp, sd, pos[:, 0])
         ctl = dict(ctl)
         with jax.named_scope("ctl"):
-            samples = jnp.stack(outs)                          # [K, B]
-            acc = jnp.clip(vu - pos0, 0, K)                    # [B]
+            samples = s[None, :]                               # [1, B]
+            acc = jnp.clip(vu - pos0, 0, 1)                    # [B]
+            # row 0 of one.  Written as the take it was under a loop of one,
+            # so that the windows compile to the programs they were, hash
+            # for hash (CHANGES.md, PR 51)
             final = jnp.take_along_axis(
                 samples, jnp.maximum(acc - 1, 0)[None, :], axis=0
             )[0]
@@ -2470,12 +2387,12 @@ def moe_stats_row(stats: list, width: int) -> jax.Array:
     return jnp.pad(row, (0, width - row.shape[0]))[None, :]
 
 
-def make_autopilot_fns(cfg: ModelConfig, eng: EngineConfig, K: int,
-                       Wcap: int, mesh: Optional[Mesh] = None):
+def make_autopilot_fns(cfg: ModelConfig, eng: EngineConfig, Wcap: int,
+                       mesh: Optional[Mesh] = None):
     """(window_fn, delta_fn) jitted with cache/ctl donated."""
     window = compilewatch.label(
         jax.jit(
-            raw_autopilot_window_fn(cfg, eng, K, mesh), donate_argnums=(1, 2),
+            raw_autopilot_window_fn(cfg, eng, mesh), donate_argnums=(1, 2),
             **_io_kwargs(mesh, cfg, 2, ("cache", "repl", "repl"), eng=eng),
         ),
         "decode_window",
@@ -2492,7 +2409,7 @@ def make_autopilot_fns(cfg: ModelConfig, eng: EngineConfig, K: int,
 
 # ------------------- speculative decode window (draft + verify) -----------
 #
-# One autopilot window lands at most K tokens per host sync. The spec
+# One autopilot window lands one token per host sync. The spec
 # window raises the per-sync yield without a draft model: an on-device
 # prompt-lookup drafter (spec/ngram.py) proposes up to k continuation
 # tokens from the seat's own token history, and ONE [B, k+1] ragged
@@ -2817,8 +2734,8 @@ def make_mm_ring_prefill_fn(cfg: ModelConfig, eng: EngineConfig,
     )
 
 
-def make_sp_prefill_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Mesh):
-    """Jitted full-prompt sequence-parallel prefill step.
+def make_sp_ring_prefill_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Mesh):
+    """Jitted full-prompt sequence-parallel prefill, posting to the ring.
 
     The ring runs over the SERVING mesh itself: the chunk's T axis is
     sharded over the composite (dp, tp) [..fsdp] axes (``SpecLayout.
@@ -2829,18 +2746,6 @@ def make_sp_prefill_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Mesh):
     steps see an unchanged (donated) cache. SURVEY §5 long-context;
     exact — ring attention accumulates online softmax in f32.
     """
-    return compilewatch.label(
-        jax.jit(
-            raw_step_fn(cfg, eng, mesh, ring_mesh=mesh),
-            donate_argnums=(1,),
-            **_io_kwargs(mesh, cfg, 9, ("cache", "repl"), eng=eng),
-        ),
-        "sp_prefill",
-    )
-
-
-def make_sp_ring_prefill_fn(cfg: ModelConfig, eng: EngineConfig, mesh: Mesh):
-    """Ring-posting variant of the sp prefill (pipelined serving path)."""
     return make_ring_prefill_fn(cfg, eng, mesh, ring_mesh=mesh)
 
 

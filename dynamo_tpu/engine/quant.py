@@ -63,19 +63,6 @@ def np_storage_dtype(dtype: str) -> np.dtype:
     return _NP_STORAGE[dtype]
 
 
-def kv_bytes_per_elem(dtype: str, model_dtype: str = "bfloat16") -> float:
-    """KV-cache bytes per stored element, scale overhead included.
-
-    Quantized pages cost 1 byte/elem plus one float32 scale per head_dim
-    elements; callers pass head_dim via the capacity helpers below when
-    the exact figure matters. Here we report the page byte only — the
-    scale adds 4/head_dim bytes/elem (reported separately by bench).
-    """
-    if is_quantized(dtype):
-        return 1.0
-    return float(jnp.dtype(model_dtype).itemsize)
-
-
 # --------------------------- weight quantization ---------------------------
 
 # matmul weights quantized at load time; everything else (norms, embed,

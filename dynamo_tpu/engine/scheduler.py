@@ -248,7 +248,8 @@ class SchedSeq:
     # blocks (they may have been recycled to another request)
     kv_epoch: int = 0
     # ---- pipelined (run-ahead) serving state ----
-    # device token-ring slot (-1 = unassigned); see model.raw_decode_window_fn
+    # device token-ring slot (-1 = unassigned); see
+    # model.raw_autopilot_window_fn
     slot: int = -1
     # slot held when this seq was last preempted (engine kills the seat)
     preempted_slot: int = -1
@@ -306,7 +307,7 @@ class DecodeRow:
 
     seq: SchedSeq
     base: int        # input position (num_computed seen through pendings)
-    accepted: int    # tokens this window contributes (<= decode_steps)
+    accepted: int    # tokens this window contributes (<= its width)
     tok_host: int    # input token when the host knows it, else 0
     tok_src: int     # 1 = read the device ring, 0 = tok_host
     slot: int
@@ -377,15 +378,10 @@ class Scheduler:
         # bypass max_num_batched_tokens entirely
         self.sp_enabled = False
         # speculative decoding: when set (spec_k + 1), decode windows are
-        # planned this many tokens wide instead of decode_steps — the spec
+        # planned this many tokens wide instead of one — the spec
         # window may land anywhere from 1 to spec_k+1 of them; the engine
         # clears it again on adaptive auto-disable
         self.spec_plan_window: Optional[int] = None
-        # adaptive prefill bucket ladder (engine/ladder.py) when the
-        # engine enables it: chunk caps snap DOWN to a live rung so a
-        # chunked-prefill cap retired from the grid doesn't keep padding
-        # chunks up to a stale bucket
-        self.prefill_ladder = None
         # prefix cache manager hook: called with (queried_hashes,
         # matched_hashes) after every admission-time prefix match so the
         # radix index keeps its own hit accounting (the replay
@@ -420,47 +416,13 @@ class Scheduler:
         budget = self.config.max_num_batched_tokens
         bs = self.config.block_size
 
-        # 1. decodes: every running sequence advances up to ``decode_steps``
-        # tokens per round (multi-token windows amortise the host↔device
-        # roundtrip; capacity is reserved for the whole window up front).
+        # 1. decodes: every running sequence advances one token per round
+        # (a speculative window up to ``spec_plan_window``; capacity is
+        # reserved for the whole window up front).
         # Scheduling reads *through* in-flight work (pending_*): a window
         # can be planned before the previous one lands, with the input
         # token fed from the device ring (run-ahead pipelining).
-        window = self.spec_plan_window or max(1, self.config.decode_steps)
-        if self.config.block_lookahead:
-            # SYNCHRONISED lookahead: when any running seq's runway drops
-            # below half the lookahead, top up EVERY running seq to the
-            # full lookahead in the same round — growth then lands in ONE
-            # device-state delta (2 uploads) per cycle instead of one
-            # per seq per round (uploads were the serving bottleneck at
-            # ~15 ms each — measured on an earlier transport; re-measured
-            # by chip_smoke.py, see CHANGES)
-            la = self.config.block_lookahead * bs
-            trigger = False
-            for seq in self.running:
-                if seq.status is not SeqStatus.RUNNING:
-                    continue
-                base = (seq.num_computed + seq.pending_prompt
-                        + seq.pending_decode)
-                if base >= self.config.max_model_len:
-                    continue
-                if len(seq.block_table) * bs - base < max(window, la // 2):
-                    trigger = True
-                    break
-            if trigger:
-                for seq in self.running:
-                    if seq.status is not SeqStatus.RUNNING:
-                        continue
-                    base = (seq.num_computed + seq.pending_prompt
-                            + seq.pending_decode)
-                    tgt = min(base + window - 1 + la,
-                              self.config.max_model_len - 1)
-                    while (len(seq.block_table) * bs <= tgt
-                           and self._can_allocate(1)):
-                        bid = self.pool.allocate()
-                        if bid is None:
-                            break
-                        seq.block_table.append(bid)
+        window = self.spec_plan_window or 1
         for seq in list(self.running):
             if seq.status is not SeqStatus.RUNNING:
                 continue  # preempted by an earlier seq's _ensure_slot
@@ -534,13 +496,6 @@ class Scheduler:
                     # boundaries can't strand a partial block's worth of
                     # budget forever.
                     eff_cap = min(max_bucket, max(pct, bs))
-                    if self.prefill_ladder is not None:
-                        # snap to the largest live rung ≤ cap: every chunk
-                        # pads up to a compiled bucket, so an off-grid cap
-                        # burns (bucket - cap) tokens per dispatch
-                        rung = self.prefill_ladder.rung_at_most(eff_cap)
-                        if rung is not None and rung >= bs:
-                            eff_cap = rung
                 chunk = min(budget, remaining, eff_cap)
                 if (chunk < remaining and chunk < eff_cap
                         and batch.prefills):

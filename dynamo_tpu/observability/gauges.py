@@ -64,25 +64,6 @@ class EngineObsGauges:
             "engine_involuntary_remats_total",
             "XLA [SPMD] involuntary full rematerialization warnings seen",
         )
-        self._g_ladder = registry.gauge(
-            "engine_ladder_rungs",
-            "live bucket-ladder rung count per dispatch kind "
-            "(engine/ladder.py; static buckets when the ladder is off)",
-            ["kind"]
-        )
-        self._g_ladder_splits = registry.gauge(
-            "engine_ladder_splits_total",
-            "bucket-ladder rungs added (each costs one steady-state "
-            "compile per consuming jit family)", ["kind"]
-        )
-        self._g_ladder_retires = registry.gauge(
-            "engine_ladder_retires_total",
-            "bucket-ladder rungs retired for cold occupancy", ["kind"]
-        )
-        self._g_ladder_budget = registry.gauge(
-            "engine_ladder_budget_remaining",
-            "bucket-ladder compile budget left (0 = grid frozen)", ["kind"]
-        )
 
     def refresh(self) -> Dict[str, float]:
         """Pull one recorder snapshot, set every gauge, return the wire
@@ -105,17 +86,6 @@ class EngineObsGauges:
         for fn, n in (snap.get("recompiles_by_fn") or {}).items():
             self._g_recompiles.labels(fn=fn).set(n)
         self._g_remats.set(snap.get("involuntary_remats_total", 0))
-        for kind in ("decode", "prefill"):
-            n_rungs = snap.get(f"ladder_{kind}_rungs_n")
-            if n_rungs is None:
-                continue
-            self._g_ladder.labels(kind=kind).set(n_rungs)
-            self._g_ladder_splits.labels(kind=kind).set(
-                snap.get(f"ladder_{kind}_splits_total", 0))
-            self._g_ladder_retires.labels(kind=kind).set(
-                snap.get(f"ladder_{kind}_retires_total", 0))
-            self._g_ladder_budget.labels(kind=kind).set(
-                snap.get(f"ladder_{kind}_budget_remaining", 0))
         # the wire snapshot carries scalars only (msgpack-friendly, and the
         # aggregator's zero-default reads stay flat)
         return {

@@ -227,12 +227,6 @@ class StepStats:
         # lifetime totals (never pruned) — survive window rollover
         self.total_steps = 0
         self.total_goodput_tokens = 0
-        # per-(kind, bucket) occupancy, cumulative since warmup:
-        # "kind:bucket" -> [dispatches, real_units, padded_units] in bucket
-        # units (rows for decode/spec windows, tokens for prefill chunks).
-        # The adaptive bucket ladder (engine/ladder.py) consumes this via
-        # bucket_occupancy() and takes its own deltas.
-        self._bucket_occ: Dict[str, list] = {}
         # snapshot cache: span recording reads this per request; recomputing
         # the window sums each time would scale with request rate
         self._snap_cache: Optional[Dict[str, float]] = None
@@ -261,13 +255,6 @@ class StepStats:
             self._win.add(rec)
             self.total_steps += 1
             self.total_goodput_tokens += rec.goodput_tokens
-            if rec.bucket > 0:
-                occ = self._bucket_occ.setdefault(
-                    f"{rec.kind}:{rec.bucket}", [0, 0, 0])
-                occ[0] += 1
-                occ[1] += (rec.real_tokens if rec.kind == PREFILL
-                           else rec.live_rows)
-                occ[2] += rec.bucket
             self._snap_cache = None
             self._prune_locked(self._clock())
         if self.jsonl_path:
@@ -313,18 +300,7 @@ class StepStats:
             self._warmup_done = True
             self.total_steps = 0
             self.total_goodput_tokens = 0
-            self._bucket_occ.clear()
             self._snap_cache = None
-
-    def bucket_occupancy(self) -> Dict[str, tuple]:
-        """Cumulative per-(kind, bucket) occupancy since warmup.
-
-        ``"kind:bucket" -> (dispatches, real_units, padded_units)`` with
-        units native to the bucket axis (rows for decode/spec, tokens for
-        prefill).  Monotonic between warmup resets, so consumers (the
-        bucket ladder) can delta it safely."""
-        with self._lock:
-            return {k: tuple(v) for k, v in self._bucket_occ.items()}
 
     # ---------------------------- snapshot -----------------------------
 

@@ -14,7 +14,7 @@ Axes:
 
 - ``dp``   data parallel — independent batch shards
 - ``tp``   tensor parallel — attention/MLP heads split per chip
-- ``sp``   sequence parallel — ring/Ulysses attention over long prompts
+- ``sp``   sequence parallel — ring attention over long prompts
 - ``ep``   expert parallel — MoE experts spread over chips
 - ``pp``   pipeline parallel — layer stages
 - ``fsdp`` fully-sharded data parallel (parameter storage sharding)
@@ -149,18 +149,6 @@ def make_mesh(shape: Sequence[int], devices=None) -> Mesh:
             f"mesh shape must be (dp, tp) or (dp, fsdp, tp), got {shape}")
     n = int(np.prod(shape))
     return Mesh(_mesh_devices(n, devices).reshape(shape), axes)
-
-
-def make_flat_mesh(devices, axis_name: str = AXIS_SP) -> Mesh:
-    """View a device set as one flat ring.
-
-    NOTE: a flat mesh over devices that already carry a serving mesh is a
-    cross-mesh boundary GSPMD pays for with involuntary rematerialization;
-    serving-path sequence parallelism shards over the serving mesh's own
-    composite axes (:meth:`SpecLayout.seq_axes`) instead.  This stays for
-    standalone single-purpose rings (tests, research harnesses).
-    """
-    return Mesh(np.asarray(devices).flatten(), (axis_name,))
 
 
 def make_axes_mesh(shape: Sequence[int], axis_names: Sequence[str],
@@ -364,18 +352,6 @@ class SpecLayout:
         heads over tp, matching :meth:`cache_block` minus the hd axis."""
         return spec(None, self.tp, None)
 
-    def cache_specs(self, cfg, kv_dtype: str = "bf16") -> Dict[str, Any]:
-        from ..engine import quant
-
-        specs = {
-            "k": [self.cache_block()] * cfg.num_layers,
-            "v": [self.cache_block()] * cfg.num_layers,
-        }
-        if quant.is_quantized(kv_dtype):
-            specs["ks"] = [self.cache_scale_block()] * cfg.num_layers
-            specs["vs"] = [self.cache_scale_block()] * cfg.num_layers
-        return specs
-
     def cache_shardings(self, mesh: Mesh, cfg,
                         kv_dtype: str = "bf16") -> Dict[str, Any]:
         from ..engine import quant
@@ -417,11 +393,6 @@ class SpecLayout:
     def logits(self) -> PartitionSpec:
         """[B, V] — vocab over tp, matching the column-sharded lm_head."""
         return spec(None, self.tp)
-
-
-def kv_blocks_sharding(mesh: Mesh) -> NamedSharding:
-    """Sharding for a KV block-transfer payload landing on ``mesh``."""
-    return NamedSharding(mesh, SpecLayout.for_mesh(mesh).kv_blocks())
 
 
 def kv_payload_shardings(mesh: Mesh, keys) -> Dict[str, NamedSharding]:

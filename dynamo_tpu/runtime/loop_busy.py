@@ -28,10 +28,14 @@ class LoopBusyCounter:
         self.t_start = time.monotonic()
         self.wait_s = 0.0
 
-    def busy_s(self) -> float:
-        """Seconds the loop was not waiting since the counter was installed
-        (read between two selects, so no wait is half counted)."""
-        return time.monotonic() - self.t_start - self.wait_s
+    def busy_s(self, now: Optional[float] = None) -> float:
+        """Seconds the loop was not waiting between the counter's install
+        and ``now`` (a ``time.monotonic()`` the caller has already read:
+        two clocks closed at one instant cannot drift apart; default: this
+        instant). Read between two selects, so no wait is half counted."""
+        if now is None:
+            now = time.monotonic()
+        return now - self.t_start - self.wait_s
 
 
 def install(

@@ -27,7 +27,6 @@
 #                                 # (engine/attention_parity.py: every
 #                                 # tile of its grid bit-exact on CPU, in
 #                                 # a fusion-disabled subprocess)
-#                                 # + adaptive bucket ladders
 #   scripts/verify.sh mesh        # SpecLayout sharding parity: 1x8 / 2x4 /
 #                                 # 2x2x2 CPU meshes byte-identical to
 #                                 # single-device across decode, chunked

@@ -303,7 +303,7 @@ def test_the_gated_delta_table_compiles_whole_at_its_real_size(
     if program == "decode_window_b128":
         ctl = place(jax.eval_shape(
             lambda: M.init_ctl(eng, eng.max_num_seqs, Wcap)))
-        window, _ = M.make_autopilot_fns(cfg, eng, 1, Wcap, None)
+        window, _ = M.make_autopilot_fns(cfg, eng, Wcap, None)
         compiled = window.__wrapped__.lower(
             params, cache, ctl, S((128,), jnp.int32)).compile()
         assert compiled.as_text().count("tpu_custom_call") == 8
@@ -411,7 +411,7 @@ def _decode_window_text(topo):
     Wcap = eng.max_blocks_per_seq
     ctl = jax.tree.map(lambda a: S(a.shape, a.dtype),
                        M.init_ctl(eng, eng.max_num_seqs, Wcap))
-    window, _ = M.make_autopilot_fns(cfg, eng, 1, Wcap, mesh)
+    window, _ = M.make_autopilot_fns(cfg, eng, Wcap, mesh)
     return window.__wrapped__.lower(
         params, cache, ctl, S((64,), jnp.int32)).compile().as_text()
 
@@ -614,7 +614,7 @@ def _asked_layouts(topo, mesh_shape):
     kw = M._io_kwargs(mesh, cfg, 2, ("cache", "repl", "repl"), eng=eng)
     rest = kw.pop("in_shardings", (None,) * 4)[1:]
     compiled = jax.jit(
-        M.raw_autopilot_window_fn(cfg, eng, 1, mesh), donate_argnums=(1, 2),
+        M.raw_autopilot_window_fn(cfg, eng, mesh), donate_argnums=(1, 2),
         in_shardings=(ask,) + tuple(rest), **kw,
     ).lower(bare, cache, ctl, S((64,), jnp.int32)).compile()
     return {name: tuple(fmt.layout.major_to_minor)

@@ -21,7 +21,7 @@ def _cfg(**kw):
     base = dict(
         num_blocks=128, max_model_len=256, max_num_batched_tokens=64,
         prefill_buckets=(16, 32, 64), decode_buckets=(8,), max_num_seqs=8,
-        decode_steps=1, pipeline_depth=1,
+        pipeline_depth=1,
     )
     base.update(kw)
     return EngineConfig(**base)

@@ -81,7 +81,7 @@ def _reference(table: str = "laguna"):
 def _engine_config(table: str = "laguna", **kw) -> EngineConfig:
     base = dict(num_blocks=96, max_model_len=256, max_num_batched_tokens=64,
                 prefill_buckets=(16, 32, 64), decode_buckets=(8,),
-                max_num_seqs=8, decode_steps=1, pipeline_depth=1,
+                max_num_seqs=8, pipeline_depth=1,
                 attention_impl="pallas")
     base.update(TABLES[table]["engine"])
     base.update(kw)

@@ -686,7 +686,7 @@ def _lowered_step_program(which, cfg=None):
         ctl = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype),
             model_lib.init_ctl(eng, S, wcap))
-        fn, _ = model_lib.make_autopilot_fns(cfg, eng, 2, wcap)
+        fn, _ = model_lib.make_autopilot_fns(cfg, eng, wcap)
         args = (params, cache, ctl, jax.ShapeDtypeStruct((8,), jnp.int32))
     else:
         T, W = 16, 8
@@ -1125,6 +1125,43 @@ async def test_records_carry_loop_busy_and_decode_spans_the_wake(
         1e6 * sum(s.attrs["wake_sum_s"] for s in decode) / 18)
     assert road["wake_max_ms"] == pytest.approx(
         1e3 * max(s.attrs["wake_max_s"] for s in decode))
+
+
+def test_loop_clock_handoff_closes_both_clocks_at_one_instant(monkeypatch):
+    """``host_s`` and ``loop_busy_s`` of a handoff end at the same reading of
+    the clock. Read twice, a thread switch between the reads went to this
+    handoff's ``loop_busy_s`` and came off the next one's, which then fell
+    short of its ``host_s`` (the load failure of the test above)."""
+    import time
+
+    from dynamo_tpu.engine import engine as engine_mod
+    from dynamo_tpu.runtime import loop_busy
+
+    class Clock:
+        """``time`` with a scripted ``monotonic``: ``drift`` seconds pass
+        behind every reading (the thread was switched out)."""
+        t, drift = 100.0, 0.0
+
+        def monotonic(self):
+            now = self.t
+            self.t += self.drift
+            return now
+
+        def __getattr__(self, name):
+            return getattr(time, name)
+
+    clock = Clock()
+    monkeypatch.setattr(engine_mod, "time", clock)
+    monkeypatch.setattr(loop_busy, "time", clock)
+    loop_clock = engine_mod._LoopClock(loop_busy.LoopBusyCounter())
+    clock.t += 2.0
+    clock.drift = 5.0           # switched out behind each reading
+    assert loop_clock.handoff()[:2] == (2.0, 2.0)
+    clock.drift = 0.0
+    clock.t += 3.0
+    host_s, loop_busy_s, _ = loop_clock.handoff()
+    assert host_s == 8.0        # the 5 s it was switched out, then 3 s
+    assert loop_busy_s == host_s
 
 
 def _reader(name):

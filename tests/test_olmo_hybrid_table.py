@@ -66,7 +66,7 @@ def _engine_config(**kw) -> EngineConfig:
     # a table with seat state compiles every program when built: one bucket
     base = dict(num_blocks=96, max_model_len=256, max_num_batched_tokens=64,
                 prefill_buckets=(64,), decode_buckets=(8,), max_num_seqs=8,
-                decode_steps=1, pipeline_depth=1, attention_impl="pallas")
+                pipeline_depth=1, attention_impl="pallas")
     base.update(kw)
     return EngineConfig(**base)
 

@@ -1,6 +1,6 @@
-"""Run-ahead (pipelined) engine: deep pipelines with multi-token windows
-produce the same tokens as the synchronous engine, EOS mid-window reaps
-cleanly, and slots/blocks are recycled. CPU."""
+"""Run-ahead (pipelined) engine: deep pipelines produce the same tokens as
+the synchronous engine, EOS with windows in flight reaps cleanly, and
+slots/blocks are recycled. CPU."""
 
 import numpy as np
 import pytest
@@ -11,14 +11,13 @@ from dynamo_tpu.engine.engine import InferenceEngine, Request
 pytestmark = pytest.mark.anyio
 
 
-def _cfg(decode_steps=1, pipeline_depth=2, **kw):
+def _cfg(pipeline_depth=2, **kw):
     base = dict(
         num_blocks=128, max_model_len=256, max_num_batched_tokens=64,
         prefill_buckets=(64,), decode_buckets=(8,), max_num_seqs=8,
     )
     base.update(kw)
-    return EngineConfig(decode_steps=decode_steps,
-                        pipeline_depth=pipeline_depth, **base)
+    return EngineConfig(pipeline_depth=pipeline_depth, **base)
 
 
 async def _collect(engine, req):
@@ -38,15 +37,15 @@ def _mk_req(i, n_prompt=10, max_tokens=12, **kw):
 
 
 async def test_pipelined_matches_sync():
-    """Same prompts, greedy: depth-3 K-4 pipelined == depth-1 K-1 sync."""
+    """Same prompts, greedy: depth-3 pipelined == depth-1 sync."""
     mc = ModelConfig.tiny()
     import asyncio
 
-    ref_engine = InferenceEngine(mc, _cfg(1, 1), seed=0)
+    ref_engine = InferenceEngine(mc, _cfg(1), seed=0)
     ref = [await _collect(ref_engine, _mk_req(i)) for i in range(4)]
     await ref_engine.stop()
 
-    eng = InferenceEngine(mc, _cfg(4, 3), seed=0)
+    eng = InferenceEngine(mc, _cfg(3), seed=0)
     got = await asyncio.gather(*(
         _collect(eng, _mk_req(i)) for i in range(4)
     ))
@@ -55,13 +54,13 @@ async def test_pipelined_matches_sync():
 
 
 async def test_eos_mid_window_reaps():
-    """A seq that stops mid-window (EOS honoured) discards the window tail;
-    its slot and blocks come back once in-flight windows land."""
+    """A seq that stops (EOS honoured) while later windows are in flight
+    discards their tokens; its slot and blocks come back once they land."""
     mc = ModelConfig.tiny()
-    eng = InferenceEngine(mc, _cfg(4, 3), seed=0)
+    eng = InferenceEngine(mc, _cfg(3), seed=0)
     # run one greedy request to learn its token stream
     probe = await _collect(eng, _mk_req(0, max_tokens=16))
-    eos = probe[5]  # force EOS at output index 5 (mid 4-token window)
+    eos = probe[5]  # force EOS at output index 5 (windows 6, 7 in flight)
     req = _mk_req(0, max_tokens=16, ignore_eos=False)
     req.eos_token_ids = (eos,)
     toks = await _collect(eng, req)
@@ -85,7 +84,7 @@ async def test_seeded_sampling_pipelined():
     """Per-request seeded stochastic decode is reproducible under the
     pipelined loop (position-keyed row rngs)."""
     mc = ModelConfig.tiny()
-    eng = InferenceEngine(mc, _cfg(4, 3), seed=0)
+    eng = InferenceEngine(mc, _cfg(3), seed=0)
     a = await _collect(eng, _mk_req(1, temperature=0.9, seed=42))
     b = await _collect(eng, _mk_req(1, temperature=0.9, seed=42))
     c = await _collect(eng, _mk_req(1, temperature=0.9, seed=43))
@@ -98,7 +97,7 @@ async def test_starved_budget_seatmap_rebuild():
     """Block-pool starvation forces LIVE seqs to be skipped in some decode
     rounds. A skipped-but-live seat must NOT keep its column in a reused
     device seat map — the window kernel would advance its device-side
-    pos/ring token K steps past the host mirror, corrupting the stream when
+    pos/ring token past the host mirror, corrupting the stream when
     the seq is scheduled again. Greedy outputs must match the unstarved
     synchronous engine exactly."""
     import asyncio
@@ -107,7 +106,7 @@ async def test_starved_budget_seatmap_rebuild():
     reqs = [
         dict(n_prompt=6 + i % 3, max_tokens=8 + i % 5) for i in range(6)
     ]
-    ref_engine = InferenceEngine(mc, _cfg(1, 1), seed=0)
+    ref_engine = InferenceEngine(mc, _cfg(1), seed=0)
     ref = [await _collect(ref_engine, _mk_req(i, **kw))
            for i, kw in enumerate(reqs)]
     await ref_engine.stop()
@@ -115,7 +114,7 @@ async def test_starved_budget_seatmap_rebuild():
     # 3 prompt tokens a round beside 6 decoding seqs, 8 blocks vs ~12 needed
     eng = InferenceEngine(
         mc,
-        _cfg(4, 3, max_num_batched_tokens=3, num_blocks=8,
+        _cfg(3, max_num_batched_tokens=3, num_blocks=8,
              prefill_buckets=(8,), max_model_len=64),
         seed=0,
     )
@@ -135,7 +134,7 @@ async def test_many_requests_slot_churn():
     import asyncio
 
     mc = ModelConfig.tiny()
-    eng = InferenceEngine(mc, _cfg(2, 4), seed=0)
+    eng = InferenceEngine(mc, _cfg(4), seed=0)
 
     async def one(i):
         await asyncio.sleep(0.01 * (i % 5))

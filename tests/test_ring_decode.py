@@ -1,10 +1,10 @@
-"""Device token ring: ring prefill + unrolled decode windows reproduce the
-synchronous step path token-for-token (greedy and seeded sampling), cap
-write-back, and trash-slot semantics. CPU, single device."""
+"""Device token ring: packed prefill + autopilot decode windows (the path
+that serves) reproduce the synchronous step path token-for-token (greedy
+and seeded sampling), cap write-back, and trash-slot semantics. CPU, single
+device."""
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 import pytest
 
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
@@ -27,15 +27,16 @@ def _prompt(n, vocab, seed=0):
     return rng.integers(1, vocab, size=n).astype(np.int32)
 
 
+T, W = 32, 8     # the prefill chunk and its table's width
+
+
 def _sync_generate(mc, ec, params, prompt, n_decode, temperature=0.0,
                    seed=-1):
     """Reference: the synchronous unified-step path."""
     step = model_lib.make_step_fn(mc, ec, None)
     cache = model_lib.init_cache(mc, ec)
-    T = 32
     bs = ec.block_size
     table = list(range(1, 1 + (len(prompt) + n_decode) // bs + 2))
-    W = 8
     tokens = np.zeros((1, T), np.int32)
     positions = np.full((1, T), -1, np.int32)
     tokens[0, :len(prompt)] = prompt
@@ -65,60 +66,55 @@ def _sync_generate(mc, ec, params, prompt, n_decode, temperature=0.0,
     return out
 
 
-def _ring_generate(mc, ec, params, prompt, n_decode, K, temperature=0.0,
+def _join(ec, delta_fn, ctl, rows):
+    """One packed control delta: ``rows`` of (slot, pos, valid_until,
+    last_tok (-1 = keep the ring's), table row, temperature, seed)."""
+    Wcap = ec.max_blocks_per_seq
+    di = np.zeros((len(rows), model_lib.CTL_I32_FIELDS + Wcap), np.int32)
+    df = np.ones((len(rows), 2), np.float32)
+    for i, (slot, pos, vu, lt, table, temp, seed) in enumerate(rows):
+        di[i, :6] = (slot, pos, vu, 0, seed, lt)
+        di[i, 6:6 + len(table)] = table
+        df[i, 0] = temp
+    return delta_fn(ctl, di, df)
+
+
+def _ring_generate(mc, ec, params, prompt, n_decode, temperature=0.0,
                    seed=-1):
-    """Ring path: ring prefill writes slot, decode windows chain on device.
-    The host feeds NO tokens after the prompt (tok_host=0, tok_src=1)."""
+    """Ring path: the packed prefill writes the slot, decode windows chain
+    on device. The host feeds NO tokens after the prompt (the join's delta
+    keeps the ring's token)."""
     S = ec.max_num_seqs
-    prefill = model_lib.make_ring_prefill_fn(mc, ec, None)
-    window_fn = model_lib.make_decode_window_fn(mc, ec, K, None)
+    prefill = model_lib.make_packed_prefill_fn(mc, ec, T, W, None)
+    window_fn, delta_fn = model_lib.make_autopilot_fns(
+        mc, ec, ec.max_blocks_per_seq, None)
     cache = model_lib.init_cache(mc, ec)
-    last_tok = jnp.zeros((S + 1,), jnp.int32)
-    T = 32
+    ctl = jax.device_put(model_lib.init_ctl(
+        ec, S, ec.max_blocks_per_seq, seed=7))
     bs = ec.block_size
     table = list(range(1, 1 + (len(prompt) + n_decode) // bs + 2))
-    W = 8
-    tokens = np.zeros((1, T), np.int32)
-    positions = np.full((1, T), -1, np.int32)
-    tokens[0, :len(prompt)] = prompt
-    positions[0, :len(prompt)] = np.arange(len(prompt))
-    tables = np.zeros((1, W), np.int32)
-    tables[0, :len(table)] = table
-    temp = np.array([temperature], np.float32)
-    tk = np.zeros((1,), np.int32)
-    tp = np.ones((1,), np.float32)
-    sd = np.array([seed], np.int32)
-    slot = np.array([2], np.int32)   # arbitrary live slot
-    rng = jax.random.PRNGKey(7)
-    cache, last_tok, sampled = prefill(
-        params, cache, last_tok, tokens, positions, tables,
-        np.array([len(prompt) - 1], np.int32), slot,
-        np.ones((1,), np.int32), rng, temp, tk, tp, sd,
+    slot = 2   # arbitrary live slot
+    pint = np.zeros((1, T + W + model_lib.PP_SCALARS), np.int32)
+    pint[0, :len(prompt)] = prompt
+    pint[0, T:T + len(table)] = table
+    pint[0, T + W:] = (
+        len(prompt), 0, slot, 1, 0, seed,
+        int(round(temperature * model_lib.PP_QUANT)),
+        int(round(1.0 * model_lib.PP_QUANT)),
     )
+    cache, last_tok, sampled = prefill(
+        params, cache, ctl["last_tok"], pint, jax.random.PRNGKey(7))
     out = [int(np.asarray(sampled)[0])]
-    assert int(np.asarray(last_tok)[2]) == out[0]
-    pos = len(prompt)
-    remaining = n_decode - 1
-    while remaining > 0:
-        rng, sub = jax.random.split(rng)
-        rngs = jax.random.split(sub, K)[::1]
-        # keep per-step rng identical to the sync path: the sync loop
-        # splits once per step; here we split once per step too by
-        # chaining — only meaningful for unseeded stochastic rows, which
-        # this test does not assert token-exactness for
-        cache, last_tok, samples = window_fn(
-            params, cache, last_tok,
-            np.zeros((1,), np.int32),          # tok_host unused
-            np.ones((1,), np.int32),           # tok_src = ring
-            slot, np.array([[pos]], np.int32), tables,
-            np.full((1,), ec.max_model_len, np.int32), rngs,
-            temp, tk, tp, sd,
-        )
-        got = np.asarray(samples)[:, 0]
-        take = min(K, remaining)
-        out.extend(int(t) for t in got[:take])
-        pos += take
-        remaining -= take
+    assert int(np.asarray(last_tok)[slot]) == out[0]
+    ctl = _join(ec, delta_fn, {**ctl, "last_tok": last_tok}, [
+        (slot, len(prompt), ec.max_model_len, -1, table, temperature, seed),
+    ])
+    rows = np.array([slot, S, S, S], np.int32)   # pads ride the trash seat
+    for _ in range(n_decode - 1):
+        cache, ctl, samples = window_fn(params, cache, ctl, rows)
+        assert samples.shape == (1, 4)
+        out.append(int(np.asarray(samples)[0, 0]))
+    assert int(np.asarray(ctl["pos"])[slot]) == len(prompt) + n_decode - 1
     return out
 
 
@@ -126,9 +122,8 @@ def test_ring_matches_sync_greedy(setup):
     mc, ec, params = setup
     prompt = _prompt(12, mc.vocab_size)
     ref = _sync_generate(mc, ec, params, prompt, 9)
-    for K in (1, 4):
-        got = _ring_generate(mc, ec, params, prompt, 9, K)
-        assert got == ref, (K, got, ref)
+    got = _ring_generate(mc, ec, params, prompt, 9)
+    assert got == ref, (got, ref)
 
 
 def test_ring_matches_sync_seeded(setup):
@@ -138,59 +133,56 @@ def test_ring_matches_sync_seeded(setup):
     prompt = _prompt(10, mc.vocab_size, seed=3)
     ref = _sync_generate(mc, ec, params, prompt, 8, temperature=0.8,
                          seed=1234)
-    got = _ring_generate(mc, ec, params, prompt, 8, K=4, temperature=0.8,
+    got = _ring_generate(mc, ec, params, prompt, 8, temperature=0.8,
                          seed=1234)
     assert got == ref
 
 
-def test_window_capacity_writeback(setup):
-    """Rows at capacity write their LAST VALID sample to the ring, not the
-    garbage computed past valid_until."""
-    mc, ec, params = setup
-    K = 4
-    window_fn = model_lib.make_decode_window_fn(mc, ec, K, None)
-    cache = model_lib.init_cache(mc, ec)
+def _four_seats(mc, ec, ring_fill, rows):
+    """An autopilot window over four joined seats (input token 5, position
+    ``pos0``, ``valid_until`` as ``rows`` gives it) on a ring pre-filled
+    with ``ring_fill``."""
     S = ec.max_num_seqs
-    last_tok = jnp.zeros((S + 1,), jnp.int32)
-    B, W = 4, 8
-    tables = np.tile(np.arange(1, W + 1, dtype=np.int32), (B, 1))
+    window_fn, delta_fn = model_lib.make_autopilot_fns(
+        mc, ec, ec.max_blocks_per_seq, None)
+    cache = model_lib.init_cache(mc, ec)
+    ctl = model_lib.init_ctl(ec, S, ec.max_blocks_per_seq)
+    ctl["last_tok"] = np.full((S + 1,), ring_fill, np.int32)
+    ctl = _join(ec, delta_fn, jax.device_put(ctl), rows)
+    return window_fn, cache, ctl
+
+
+def test_window_capacity_writeback(setup):
+    """A row at capacity keeps its LAST VALID sample in the ring and its
+    position where it stands, not the garbage computed past valid_until."""
+    mc, ec, params = setup
     pos0 = 10
-    # row 0: only 2 of 4 steps fit (valid_until = pos0 + 2)
-    vu = np.array([pos0 + 2, 128, 128, 128], np.int32)
-    slots = np.arange(B, dtype=np.int32)
-    rngs = jax.random.split(jax.random.PRNGKey(0), K)
-    cache, last_tok, samples = window_fn(
-        params, cache, last_tok,
-        np.full((B,), 5, np.int32), np.zeros((B,), np.int32), slots,
-        np.full((B, 1), pos0, np.int32), tables, vu, rngs,
-        np.zeros((B,), np.float32), np.zeros((B,), np.int32),
-        np.ones((B,), np.float32), np.full((B,), -1, np.int32),
-    )
-    samples = np.asarray(samples)
-    lt = np.asarray(last_tok)
-    assert lt[0] == samples[1, 0]      # capped at 2 accepted steps
-    assert lt[1] == samples[K - 1, 1]  # full window
+    table = list(range(1, 9))
+    # seat 0: one more step fits (valid_until = pos0 + 1), the rest have room
+    window_fn, cache, ctl = _four_seats(mc, ec, 0, [
+        (b, pos0, pos0 + 1 if b == 0 else 128, 5, table, 0.0, -1)
+        for b in range(4)
+    ])
+    slots = np.arange(4, dtype=np.int32)
+    cache, ctl, first = window_fn(params, cache, ctl, slots)
+    cache, ctl, second = window_fn(params, cache, ctl, slots)
+    first, second = np.asarray(first), np.asarray(second)
+    lt, pos = np.asarray(ctl["last_tok"]), np.asarray(ctl["pos"])
+    assert lt[0] == first[0, 0] and pos[0] == pos0 + 1   # capped at one
+    assert (lt[1:4] == second[0, 1:]).all()              # both windows
+    assert (pos[1:4] == pos0 + 2).all()
 
 
 def test_trash_slot(setup):
-    """slot -1 → writes land in the trash slot; live slots unaffected."""
+    """Rows on the trash seat write there; live slots are unaffected."""
     mc, ec, params = setup
-    window_fn = model_lib.make_decode_window_fn(mc, ec, 2, None)
-    cache = model_lib.init_cache(mc, ec)
     S = ec.max_num_seqs
-    last_tok = jnp.full((S + 1,), 77, jnp.int32)
-    B, W = 4, 8
-    tables = np.tile(np.arange(1, W + 1, dtype=np.int32), (B, 1))
-    slots = np.array([0, S, S, S], np.int32)  # rows 1-3 disowned
-    rngs = jax.random.split(jax.random.PRNGKey(0), 2)
-    cache, last_tok, samples = window_fn(
-        params, cache, last_tok,
-        np.full((B,), 5, np.int32), np.zeros((B,), np.int32), slots,
-        np.full((B, 1), 4, np.int32), tables,
-        np.full((B,), 128, np.int32), rngs,
-        np.zeros((B,), np.float32), np.zeros((B,), np.int32),
-        np.ones((B,), np.float32), np.full((B,), -1, np.int32),
-    )
-    lt = np.asarray(last_tok)
-    assert lt[0] == np.asarray(samples)[1, 0]
+    window_fn, cache, ctl = _four_seats(mc, ec, 77, [
+        (0, 4, 128, 5, list(range(1, 9)), 0.0, -1),
+    ])
+    rows = np.array([0, S, S, S], np.int32)  # rows 1-3 disowned
+    cache, ctl, samples = window_fn(params, cache, ctl, rows)
+    lt = np.asarray(ctl["last_tok"])
+    assert lt[0] == np.asarray(samples)[0, 0]
     assert all(lt[i] == 77 for i in range(1, S))  # untouched live slots
+    assert np.asarray(ctl["pos"])[S] == 0          # the trash seat stands
