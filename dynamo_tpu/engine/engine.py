@@ -34,7 +34,7 @@ from ..parallel.moe import GMM_TILES_TRACED, MOE_STATS
 from ..observability.flops import FlopsModel
 from ..observability.stepstats import (
     DECODE, PREFILL, SPEC_VERIFY, StepRecord, StepStats, kv_blocks_walked,
-    kv_pages_written,
+    kv_pages_written, latent_keys_walked,
 )
 from ..runtime import faults, loop_busy
 from ..runtime.context import Context
@@ -2232,17 +2232,24 @@ class InferenceEngine(EngineCore):
             # context_sum = Σ attended context over the chunk's positions
             L, S = chunk.length, chunk.start
             ctx = L * S + L * (L + 1) // 2
+            T = a["tokens"].shape[1]
+            keys = a["tables"].shape[1] * cfg.block_size if self._latent \
+                else 0
             obs_out.append(StepRecord(
                 kind=PREFILL, t_dispatch=time.monotonic(),
-                bucket=a["tokens"].shape[1],
+                bucket=T,
                 rows=1, live_rows=1,
-                padded_tokens=a["tokens"].shape[1], real_tokens=L,
+                padded_tokens=T, real_tokens=L,
                 goodput_tokens=L,
                 context_sum=ctx,
                 kv_pages_written=kv_pages_written(
                     [(S, L)], block_size=cfg.block_size),
                 state_rows=int(self._seat_state),
                 latent_context_sum=ctx if self._latent else 0,
+                latent_keys_walked=keys and latent_keys_walked(
+                    S, L, model_lib.latent_chunk_tiles(self.mesh, T, keys),
+                    keys),
+                latent_keys_gathered=keys,
             ))
         slot = np.array(
             [seq.slot if seq.slot >= 0 else cfg.max_num_seqs], np.int32

@@ -962,10 +962,19 @@ def attention_choice(cfg: ModelConfig, eng: EngineConfig,
     if cfg.has_latent_cache:
         # a latent layer: decode absorbed (through the kernel where decode
         # runs it, else over the gathered table), T > 1 expanded over the
-        # gathered table whatever the K / V layers run
+        # gathered table whatever the K / V layers run: key tile by key
+        # tile where the platform compiles kernels (latent_chunk_tiles;
+        # "tiles" are the largest chunk's over the widest table), else the
+        # einsum
+        S = eng.max_blocks_per_seq * eng.block_size
+        chunk = {cls: latent_chunk_tiles(mesh, T, S) for cls, T in (
+            ("spec", eng.spec_k + 1), ("prefill", max(eng.prefill_buckets)))}
         choice["latent"] = {
             "decode": f"{impls['decode']}-absorbed",
-            "spec": "einsum-expanded", "prefill": "einsum-expanded"}
+            **{cls: "pallas-tiled-expanded" if tiles else "einsum-expanded"
+               for cls, tiles in chunk.items()}}
+        if chunk["prefill"]:
+            choice["latent"]["tiles"] = list(chunk["prefill"])
     if cfg.has_seat_state:
         # the token recurrence over the seat pool is a kernel where decode
         # runs its kernels; a chunk runs the chunked form in XLA
@@ -1008,6 +1017,22 @@ def _note_attention(attn_class: str, impl: str, interpret: bool,
     ATTENTION_TRACES[attn_class] = {
         "impl": impl, "interpret": interpret, "tile": list(tile),
     }
+
+
+def latent_chunk_tiles(mesh: Optional[Mesh], T: int,
+                       S: int) -> Optional[Tuple[int, int]]:
+    """``(q_tile, kv_tile)`` of the kernel a latent layer's ``[T]`` chunk
+    over ``S`` gathered keys runs (``ops/latent_chunk_attention.py``), or
+    None where it runs :func:`latent_attention`'s einsum: a decode step
+    (absorbed), the CPU (the einsum is the tests' oracle, and an
+    interpreted kernel in every test's prefill would be pointlessly slow),
+    and the shapes the kernel leaves to it (a spec window's few rows, a
+    table under a lane tile of keys).  The one place that decides."""
+    from ..ops.latent_chunk_attention import chunk_tiles
+
+    if T == 1 or pallas_interpret(mesh):
+        return None
+    return chunk_tiles(T, S)
 
 
 def _paged_decode_attention(
@@ -1414,6 +1439,23 @@ def latent_attention(cfg: ModelConfig, p: Any, q_nope: jax.Array,
         v = jnp.einsum("bsr,rhd->bshd", c, wuv, **f32).astype(ctx.dtype)
         out = jnp.einsum("bhts,bshd->bthd", probs, v, **f32)
     return out.astype(q_nope.dtype)
+
+
+def _latent_chunk(cfg: ModelConfig, mesh, p: Any, q_nope, q_pe, ctx,
+                  positions, tiles: Tuple[int, int]):
+    """:func:`latent_attention`'s expanded form through the tiled kernel:
+    no ``[H, T, S]`` array in memory, and keys walked as far as the chunk's
+    context and no further."""
+    from ..ops.latent_chunk_attention import latent_chunk_attention
+
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    interpret = pallas_interpret(mesh)
+    _note_attention("latent_prefill", "pallas-tiled-expanded", interpret,
+                    tiles)
+    return latent_chunk_attention(
+        q_nope, q_pe, ctx, p["mla_wukv"], positions, rank=cfg.kv_lora_rank,
+        rope=dr, scale=float((dn + dr) ** -0.5), tiles=tiles,
+        interpret=interpret)
 
 
 def _paged_latent_decode(cfg: ModelConfig, eng: EngineConfig, mesh, p: Any,
@@ -1875,8 +1917,14 @@ def forward(
                         ctx = jnp.take(
                             plane, block_tables.reshape(-1), axis=0
                         ).reshape(B, W * bs, plane.shape[-1])
-                        attn = latent_attention(cfg, p, q_nope, q_pe, ctx,
-                                                positions, absorbed=T == 1)
+                        tiles = latent_chunk_tiles(mesh, T, W * bs)
+                        if tiles:
+                            attn = _latent_chunk(cfg, mesh, p, q_nope, q_pe,
+                                                 ctx, positions, tiles)
+                        else:
+                            attn = latent_attention(
+                                cfg, p, q_nope, q_pe, ctx, positions,
+                                absorbed=T == 1)
                 new_latent.append(plane)
             h = attn_output(cfg, p, h, x, attn)
             h = ffn(cfg, entry, p, h, live=live, interpret=interpret,

@@ -73,6 +73,20 @@ def kv_pages_written(rows, *, block_size: int) -> int:
                for s, n in rows if n > 0)
 
 
+def latent_keys_walked(start: int, length: int, tiles, width: int) -> int:
+    """Keys ONE latent layer's attention visits for a prefill chunk of
+    ``length`` tokens at positions ``start ..`` whose table gathers
+    ``width`` keys, from host integers (``ops.latent_chunk_attention``: each
+    ``q_tile`` of the chunk with a valid row walks whole ``kv_tile``s up to
+    its last position).  With no ``tiles`` (the einsum, which attends all
+    it is given) ``width``."""
+    if tiles is None:
+        return width
+    q_tile, kv_tile = tiles
+    return sum(-(-(start + min(length, t + q_tile)) // kv_tile) * kv_tile
+               for t in range(0, length, q_tile))
+
+
 @dataclass
 class StepRecord:
     """One dispatched unit of device work, host-side metadata only."""
@@ -111,6 +125,13 @@ class StepRecord:
     # where the model has no such layer)
     state_rows: int = 0
     latent_context_sum: int = 0
+    # prefill records of a table with latent layers: keys ONE such layer's
+    # attention visits for this chunk (latent_keys_walked above: whole key
+    # tiles up to the chunk's last position; the einsum attends all it is
+    # given) beside the keys its table gathers (width x block_size): their
+    # ratio is the share of the padded width that is walked
+    latent_keys_walked: int = 0
+    latent_keys_gathered: int = 0
     # decode records of a table with routed experts, summed over the
     # window's steps and sparse layers, read from the window's own fetch
     # (model.moe_stats_row): (token, expert) pairs of live rows, those of

@@ -219,6 +219,29 @@ def test_the_latent_decode_walk_compiles_at_the_cells_shapes(
     assert text.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("T,S,H", [(512, 2048, 64), (16, 4096, 64),
+                                   (512, 8192, 32), (16, 512, 32)])
+def test_the_latent_chunk_kernel_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, T, S, H):
+    """A prefill chunk's tiled latent attention (PR 54) at the largest and
+    smallest bucket of longcat-flash-omni-ep32 (64 heads, tables up to 4096
+    keys) and ling-3.0-flash-ep8 (32 heads, 512 to 8192): one custom call,
+    and no value of heads x T x S elements in the compiled program."""
+    from dynamo_tpu.ops.latent_chunk_attention import (
+        chunk_tiles, latent_chunk_attention,
+    )
+
+    def SD(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = latent_chunk_attention.lower(
+        SD((1, T, H, 128)), SD((1, T, H, 64)), SD((1, S, 640)),
+        SD((512, H * 256)), SD((1, T), jnp.int32), rank=512, rope=64,
+        scale=192 ** -0.5, tiles=chunk_tiles(T, S), interpret=False).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < H * T * S
+
+
 @pytest.mark.parametrize("B", [8, 128])
 def test_the_gated_delta_seat_kernel_compiles_at_the_cells_shapes(
         one_chip, no_compile_cache, B):

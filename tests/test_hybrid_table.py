@@ -417,6 +417,10 @@ def test_the_engine_serves_the_reference_and_counts_its_seats(monkeypatch):
     # a chunk runs its own bucket's program, a tail of 6 tokens the 16's
     assert sorted((r.real_tokens, r.bucket) for r in prefill) == [
         (6, 16), (49, 64), (61, 64), (64, 64)]
+    # the CPU's einsum attends all the table gathers: 4 or 16 blocks
+    assert sorted({(r.latent_keys_walked, r.latent_keys_gathered)
+                   for r in prefill}) == [(64, 64), (256, 256)]
+    assert all(r.latent_keys_walked == 0 for r in decode)
     assert all(r.moe_pairs == r.live_rows * 4 * 6 for r in decode)
     S = eng.config.max_num_seqs
     for key in ("state", "conv"):
@@ -578,11 +582,23 @@ def test_lagunas_parameters_and_outputs_are_what_they_were(monkeypatch):
 # ------------------------- what the engine decides --------------------------
 
 
-def test_attention_choice_names_the_new_kinds(engine):
+def test_attention_choice_names_the_new_kinds(engine, monkeypatch):
     choice = engine.attention_impl_choice
+    # the CPU leaves a chunk's latent attention to the einsum
     assert choice["latent"] == {"decode": "pallas-absorbed",
                                 "spec": "einsum-expanded",
                                 "prefill": "einsum-expanded"}
+    # where kernels are compiled a chunk runs the tiled form (PR 54): the
+    # largest bucket's tiles over the widest table; a spec window's few
+    # rows stay the einsum's
+    monkeypatch.setattr(M, "pallas_interpret", lambda mesh: False)
+    assert M.attention_choice(
+        engine.model_config, engine.config, None)["latent"] == {
+            "decode": "pallas-absorbed", "spec": "einsum-expanded",
+            "prefill": "pallas-tiled-expanded", "tiles": [64, 256]}
+    assert M.latent_chunk_tiles(None, 512, 2048) == (512, 512)
+    assert M.latent_chunk_tiles(None, 1, 2048) is None      # absorbed
+    monkeypatch.undo()
     assert choice["linear"] == {"decode": "pallas-recurrent",
                                 "prefill": "xla-chunked"}
     assert M.attention_choice(
@@ -592,6 +608,111 @@ def test_attention_choice_names_the_new_kinds(engine):
     assert choice["tiles"]["decode"] == [1, 256]
     assert "latent" not in M.attention_choice(
         ModelConfig.tiny(), EngineConfig(), None)
+
+
+def test_a_chunk_through_the_tiled_kernel_is_the_einsums(engine, monkeypatch):
+    """``forward``'s wiring of ``ops/latent_chunk_attention.py``, which the
+    CPU leaves to the einsum: the same chunk (behind a prefix, padded, a
+    table twice the context) with the choice forced reads the same hidden
+    states, and the trace notes the form with its tiles."""
+    cfg, eng = engine.model_config, engine.config
+    T, n, start = 64, 50, 70
+    tok = jnp.asarray(np.random.default_rng(5).integers(
+        1, cfg.vocab_size, (1, T)), jnp.int32)
+    pos = jnp.where(jnp.arange(T) < n, start + jnp.arange(T), -1)[None]
+    bt = jnp.arange(1, 17, dtype=jnp.int32)[None]
+
+    def run():
+        f = jax.jit(lambda p, c: M.forward(
+            cfg, eng, p, c, tok, pos.astype(jnp.int32), bt,
+            seats=jnp.zeros((1,), jnp.int32))[1])
+        return np.asarray(f(engine.params, engine.cache))[:, :n]
+
+    want = run()
+    M.ATTENTION_TRACES.pop("latent_prefill", None)
+    monkeypatch.setattr(
+        M, "latent_chunk_tiles",
+        lambda mesh, T, S: None if T == 1 else (T, 128))
+    got = run()
+    assert M.ATTENTION_TRACES.pop("latent_prefill") == {
+        "impl": "pallas-tiled-expanded", "interpret": True,
+        "tile": [64, 128]}
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+def _values(jaxpr, inside_kernels=False):
+    """Every value a jaxpr names, nested jaxprs included; a Pallas kernel's
+    body (what lives on chip) only where asked."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        if eqn.primitive.name == "pallas_call" and not inside_kernels:
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _values(sub, inside_kernels)
+
+
+def test_no_score_array_leaves_the_kernel_at_agents_shapes():
+    """T = 512, S = 2048, 64 heads (``longcat-flash-omni-ep32``): the
+    einsum names values of ``heads x T x S`` elements (the scores, their
+    mask, the weights); the tiled function names none outside its
+    kernel."""
+    from dynamo_tpu.ops.latent_chunk_attention import (
+        chunk_tiles, latent_chunk_attention)
+
+    H, T, S = 64, 512, 2048
+    bf = jnp.bfloat16
+    sds = jax.ShapeDtypeStruct
+    args = (sds((1, T, H, 128), bf), sds((1, T, H, 64), bf),
+            sds((1, S, 640), bf), sds((512, H * 256), bf),
+            sds((1, T), jnp.int32))
+    cfg = types.SimpleNamespace(kv_lora_rank=512, qk_nope_head_dim=128,
+                                qk_rope_head_dim=64, v_head_dim=128)
+    einsum = jax.make_jaxpr(
+        lambda qn, qp, ctx, w, pos: M.latent_attention(
+            cfg, {"mla_wukv": w}, qn, qp, ctx, pos, absorbed=False))(*args)
+    scores = [a for a in _values(einsum.jaxpr) if a.shape == (1, H, T, S)]
+    assert len(scores) >= 4
+    tiled = jax.make_jaxpr(
+        lambda *a: latent_chunk_attention(
+            *a, rank=512, rope=64, scale=192 ** -0.5,
+            tiles=chunk_tiles(T, S)))(*args)
+    sizes = [a.size for a in _values(tiled.jaxpr)]
+    assert "pallas_call" in str(tiled) and max(sizes) < H * T * S // 8
+    # and the largest thing on chip is a q tile by a key tile of one head
+    assert max(a.size for a in _values(tiled.jaxpr, True)) < H * T * S // 8
+
+
+# sha256 (16 hex) of the lowered T = 512 packed prefill program (W = 32) on
+# the commit before PR 54 (401024f): a model that has no latent row runs
+# nothing this PR touched
+PREFILL_BEFORE_PR54 = {"tiny": "04a897115addd4e3",
+                       "laguna": "ccb372fe1cfff844"}
+
+
+@pytest.mark.parametrize("name", sorted(PREFILL_BEFORE_PR54))
+def test_a_model_without_a_latent_row_lowers_its_chunk_as_before(name):
+    import hashlib
+
+    if name == "tiny":
+        cfg = ModelConfig.tiny()
+        eng = EngineConfig(num_blocks=96, max_model_len=1024)
+    else:
+        cfg = _table_model(True, name)
+        eng = _table_engine_config(name, max_model_len=1024)
+    assert not cfg.has_latent_cache
+    T, W = 512, 32
+    sds = jax.ShapeDtypeStruct
+    text = M.make_packed_prefill_fn(cfg, eng, T, W, None).__wrapped__.lower(
+        jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)),
+        jax.eval_shape(lambda: M.init_cache(cfg, eng)),
+        sds((eng.max_num_seqs + 1,), jnp.int32),
+        sds((1, T + W + M.PP_SCALARS), jnp.int32),
+        sds((2,), jnp.uint32)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PREFILL_BEFORE_PR54[name]
 
 
 def test_a_state_table_keeps_its_buckets_and_few_table_widths(engine):

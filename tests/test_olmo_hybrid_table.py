@@ -334,6 +334,9 @@ def test_the_engine_serves_the_reference_and_counts_its_seats(monkeypatch):
     assert all(r.context_sum > 0 and r.latent_context_sum == 0
                for r in decode)
     assert all(r.state_rows == 1 for r in prefill)
+    # no latent row: nothing walked, nothing gathered for one
+    assert all(r.latent_keys_walked == r.latent_keys_gathered == 0
+               for r in prefill)
     S = eng.config.max_num_seqs
     for key in ("state", "conv"):
         for layer in eng.cache[key]:
