@@ -962,10 +962,9 @@ def attention_choice(cfg: ModelConfig, eng: EngineConfig,
     if cfg.has_latent_cache:
         # a latent layer: decode absorbed (through the kernel where decode
         # runs it, else over the gathered table), T > 1 expanded over the
-        # gathered table whatever the K / V layers run: key tile by key
-        # tile where the platform compiles kernels (latent_chunk_tiles;
-        # "tiles" are the largest chunk's over the widest table), else the
-        # einsum
+        # gathered table whatever the K / V layers run: key tile by key tile
+        # where the platform compiles kernels (latent_chunk_tiles; "tiles":
+        # the largest chunk's over the widest table), else the einsum
         S = eng.max_blocks_per_seq * eng.block_size
         chunk = {cls: latent_chunk_tiles(mesh, T, S) for cls, T in (
             ("spec", eng.spec_k + 1), ("prefill", max(eng.prefill_buckets)))}
@@ -976,13 +975,14 @@ def attention_choice(cfg: ModelConfig, eng: EngineConfig,
         if chunk["prefill"]:
             choice["latent"]["tiles"] = list(chunk["prefill"])
     if cfg.has_seat_state:
-        # the token recurrence over the seat pool is a kernel where decode
-        # runs its kernels; a chunk runs the chunked form in XLA
+        # over the seat pool: a kernel where decode runs them; a chunk: XLA
+        kern = impls["decode"] == "pallas"
         choice["linear"] = {
-            "decode": ("pallas" if impls["decode"] == "pallas" else "xla")
-            + "-recurrent", "prefill": "xla-chunked"}
-        if cfg.gated_delta:
-            choice["linear"]["rule"] = "gated-delta"
+            "decode": ("pallas" if kern else "xla") + "-recurrent",
+            "prefill": "xla-chunked", "conv": "xla-gather"}
+        if cfg.gated_delta:  # its decode step's conv tails: a kernel too
+            choice["linear"].update(rule="gated-delta", conv=(
+                "pallas-seats" if kern else "xla-gather"))
     return choice
 
 
@@ -1588,11 +1588,11 @@ def gated_delta_attention(cfg: ModelConfig, kind, p: Any, h: jax.Array,
             (_mm(x, p["wq"]), _mm(x, p["gdn_wk"]), _mm(x, p["gdn_wv"]),
              _mm(x, p["gdn_wa"]), _mm(x, p["gdn_wb"])))
     with jax.named_scope("gdn_conv"):
-        prev = jnp.where(fresh[:, None, None], 0,
-                         jnp.take(conv, seats, axis=0))
-        y, nxt = dr.short_conv(jnp.concatenate([q, k, v], axis=-1), prev,
-                               p["gdn_conv"], n)
-        conv = conv.at[seats].set(nxt.astype(conv.dtype))
+        # a decode step where kernels run advances the seats' tails in the
+        # pool itself (gd.conv_step_seats); a chunk gathers them (short_conv)
+        y, conv = gd.conv_seats(
+            conv, seats, fresh, n, (q, k, v), p,    # p: reads "gdn_conv"
+            kernel=kernel)
         y = jax.nn.silu(y)
         q = dr.l2norm(y[..., :H * dk].reshape(B, T, H, dk)) * dk ** -0.5
         k = dr.l2norm(y[..., H * dk:2 * H * dk].reshape(B, T, H, dk))

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .delta_rule import _mm, _unit_lower_inverse
+from .delta_rule import _mm, _unit_lower_inverse, short_conv
 
 CHUNK = 64
 _LANES = 128
@@ -265,3 +265,126 @@ def gdn_step_seats(pool, seats, fresh, q, k, v, g, beta, *,
         name="gdn_step",
     )(seats.astype(jnp.int32), fresh.astype(jnp.int32), cols, rows, pool)
     return o.reshape(B, H, dv), pool
+
+
+# ------------------------------ the conv tails ------------------------------
+#
+# The device keeps a pool ``[S + 1, K - 1, C]`` with the SEATS on the
+# sublanes: a shape whose second-minor dimension is under a tile gets the
+# order ``[K - 1][S + 1][C]`` from XLA's TPU client (``{2,0,1:T(8,128)(2,1)}``
+# for ``bf16[97, 3, 11520]``).  A kernel that takes a seat's ``[1, K - 1, C]``
+# block by ``seats[b]`` therefore has the whole pool relaid before and after
+# every call, as XLA's own gather and scatter by seat do.  So the step below
+# takes the pool as it lies, whole, a lane chunk at a time, and moves the
+# rows' tails between seat order and row order on chip, by 0 / 1 products.
+
+_CONV_LANES = 1280           # lanes a grid step holds of every seat's tail
+
+
+def _conv_kernel(seats_ref, fresh_ref, valid_ref, u_ref, w_ref, tail_ref,
+                 y_ref, tail_out_ref):
+    """One lane chunk of every seat: ``tail_ref [K - 1, S + 1, c]`` the
+    pool's taps, oldest first, ``u_ref [B, c]`` the rows' new inputs,
+    ``w_ref [K, c]`` float32 taps; ``seats``, ``fresh``, ``valid`` ``[B,
+    1]``.  ``take [B, S + 1]`` has a one where a row sits, ``put [S + 1,
+    B]`` where a row with a valid token does: a product with either moves
+    whole rows and changes no value (a one times ``x`` plus zeros, summed
+    in float32).  The sum runs oldest tap first, as ``short_conv``'s."""
+    f32 = jnp.float32
+    taps, S1, _ = tail_ref.shape
+    B = u_ref.shape[0]
+    u = u_ref[...]
+    dt = u.dtype             # the tails go through it, as short_conv's do
+    hot = jax.lax.broadcasted_iota(jnp.int32, (B, S1), 1) == seats_ref[...]
+    take = hot.astype(dt)
+    put = jnp.logical_and(hot, valid_ref[...] > 0).astype(dt).T
+    held = jnp.sum(put.astype(f32), axis=1, keepdims=True) > 0   # [S1, 1]
+    # bfloat16 times a one is exact as it is; float32 needs every pass
+    exact = dict(preferred_element_type=f32, precision=(
+        jax.lax.Precision.HIGHEST if dt == f32 else None))
+    fresh = fresh_ref[...] > 0
+    x = [jnp.where(fresh, 0.0, jnp.dot(take, tail_ref[j].astype(dt), **exact))
+         for j in range(taps)] + [u.astype(f32)]
+    w = w_ref[...]
+    y = x[0] * w[0:1]
+    for j in range(1, taps + 1):
+        y = y + x[j] * w[j:j + 1]
+    y_ref[...] = y
+    for j in range(taps):
+        new = jnp.dot(put, x[j + 1].astype(dt), **exact)
+        tail_out_ref[j] = jnp.where(held, new.astype(tail_out_ref.dtype),
+                                    tail_ref[j])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def conv_step_seats(pool, seats, fresh, valid, u, w, *,
+                    interpret: bool = False):
+    """``delta_rule.short_conv`` for one token a row on the rows' seats of
+    ``pool [S + 1, K - 1, C]``, in place and in one pass over the pool: row
+    ``b`` reads ``pool[seats[b]]`` (zeros where ``fresh[b]``) and, where
+    ``valid[b]``, leaves there its last ``K - 2`` inputs and ``u[b]``.  A
+    seat that no valid row holds (a pad's, a dead row's, the trash seat
+    with its many rows) keeps its bytes; at most one valid row holds a
+    seat.  ``u [B, C]`` the rows' new inputs, ``w [K, C]`` the taps, oldest
+    first.  Returns ``y [B, C]`` float32, ``short_conv``'s sum in its
+    order, and the pool: the values are ``short_conv``'s, the tails to the
+    bit (but a ``-0.0`` comes back ``+0.0``).
+
+    What a product costs in exchange for the gathers: a seat's NaN or
+    infinity reaches every row's ``y`` (``0 * inf``), where the gather kept
+    it to the row that holds the seat.
+
+    Jitted, so the layers of one step program share ONE lowered function
+    (a kernel lowered at every call site is paid for in every compile)."""
+    B, C = u.shape
+    K, S1 = w.shape[0], pool.shape[0]
+    c = C
+    if C % _LANES == 0:      # the largest whole-tile divisor within reach
+        c = max(d for d in range(_LANES, min(C, _CONV_LANES) + 1, _LANES)
+                if C % d == 0)
+    rows = jnp.swapaxes(pool, 0, 1)     # [K - 1, S + 1, C]: as it lies
+    col = lambda a: a.astype(jnp.int32)[:, None]            # noqa: E731
+    whole = lambda i: (0, 0)                                # noqa: E731
+    lanes = lambda i: (0, i)                                # noqa: E731
+    y, rows = pl.pallas_call(
+        _conv_kernel,
+        grid=(C // c,),
+        in_specs=[pl.BlockSpec((B, 1), whole), pl.BlockSpec((B, 1), whole),
+                  pl.BlockSpec((B, 1), whole), pl.BlockSpec((B, c), lanes),
+                  pl.BlockSpec((K, c), lanes),
+                  pl.BlockSpec((K - 1, S1, c), lambda i: (0, 0, i))],
+        out_specs=[pl.BlockSpec((B, c), lanes),
+                   pl.BlockSpec((K - 1, S1, c), lambda i: (0, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct((B, C), jnp.float32),
+                   jax.ShapeDtypeStruct(rows.shape, rows.dtype)],
+        # operands: seats, fresh, valid, u, w, rows -> rows is output 1
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="gdn_conv_step",
+    )(col(seats), col(fresh), col(valid), u, w.astype(jnp.float32), rows)
+    return y, jnp.swapaxes(rows, 0, 1)
+
+
+def conv_seats(pool, seats, fresh, n, parts, p, *, kernel=None):
+    """The layer's short convolution over ``u [B, T, C]``, the ``parts``
+    (``q``, ``k``, ``v``) side by side, on the rows' seats of ``pool [S + 1,
+    K - 1, C]``: ``y [B, T, C]`` float32 and the pool with each row's seat
+    holding the ``K - 1`` inputs before position ``n[b]`` (``n [B]`` counts
+    a row's valid tokens).  ``p`` the layer's parameters, of which this
+    reads the taps ``gdn_conv`` (here and not at the call: a table's slice
+    is an op of its own, and a chunk's program keeps the order it had).
+    ``kernel`` as in ``model.gated_delta_attention``: where it is given a
+    decode step (``T`` 1) runs :func:`conv_step_seats` ("pallas-seats" in
+    ``/health``); a chunk, and the XLA path, gather the seats' tails and
+    hand them to ``short_conv`` ("xla-gather")."""
+    if parts[0].shape[1] == 1 and kernel is not None:
+        u = jnp.concatenate(parts, axis=-1)
+        y, pool = conv_step_seats(pool, seats, fresh, n > 0, u[:, 0],
+                                  p["gdn_conv"], interpret=kernel)
+        return y[:, None], pool
+    prev = jnp.where(fresh[:, None, None], 0, jnp.take(pool, seats, axis=0))
+    y, nxt = short_conv(jnp.concatenate(parts, axis=-1), prev, p["gdn_conv"],
+                        n)
+    return y, pool.at[seats].set(nxt.astype(pool.dtype))

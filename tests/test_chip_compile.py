@@ -281,16 +281,47 @@ def test_the_gated_delta_seat_kernel_compiles_at_the_cells_shapes(
     assert text.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("B,pool,rows", [
+    (8, jnp.bfloat16, jnp.bfloat16), (128, jnp.bfloat16, jnp.bfloat16),
+    (128, jnp.float32, jnp.float32)])
+def test_the_conv_step_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, B, pool, rows):
+    """``gated_delta.conv_step_seats`` (PR 55) at olmo-hybrid-7b-l8's widths
+    (97 seats of 3 x 11520): one custom call, and NO copy of the pool, of
+    the rows' inputs or of the result around it.  The device keeps the pool
+    with its seats on the sublanes, and the kernel takes it as it lies (a
+    kernel over one seat's block a step has it relaid twice a call).  In
+    float32, what a float32 model gives it: its 0 / 1 products take every
+    pass."""
+    from dynamo_tpu.ops.gated_delta import conv_step_seats
+
+    C, seats = 11520, 96
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    text = jax.jit(conv_step_seats, donate_argnums=0).lower(
+        S((seats + 1, 3, C), pool), S((B,), jnp.int32), S((B,), jnp.bool_),
+        S((B,), jnp.bool_), S((B, C), rows), S((4, C), rows)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    wide = [line for line in text.splitlines()
+            if " copy(" in line and f"{C}]" in line.split(" copy(")[0]]
+    assert not wide, wide
+
+
 @pytest.mark.parametrize("program", ["decode_window_b128", "prefill_T512"])
 def test_the_gated_delta_table_compiles_whole_at_its_real_size(
         topo, one_chip, no_compile_cache, monkeypatch, program, capsys):
     """olmo-hybrid-7b-l8 as the cell builds it (8 layers at the published
     widths, 12544 blocks, 96 seats): the decode window of the bucket that
     serves 96 rows (128: the ladder past 64 doubles) and the T = 512 chunk
-    at the widest table, compiled for a described v5e.  Both kernels are in
-    the decode program (6 seat recurrences, 2 paged walks); the arguments
-    are what the cell keeps resident, ~12.4 GB, and the program's own
-    temporaries must fit beside them in 15.75 GB."""
+    at the widest table, compiled for a described v5e.  Three kernels are
+    in the decode program (6 conv steps, 6 seat recurrences, 2 paged
+    walks), no loop (``short_conv``'s slice a row was one of 128 trips a
+    layer: PR 55) and no copy of a conv pool; the arguments are what the
+    cell keeps resident, ~12.4 GB, and the program's own temporaries must
+    fit beside them in 15.75 GB."""
     import json
 
     from benchmarks.chip import worker_launch as WL
@@ -329,7 +360,11 @@ def test_the_gated_delta_table_compiles_whole_at_its_real_size(
         window, _ = M.make_autopilot_fns(cfg, eng, Wcap, None)
         compiled = window.__wrapped__.lower(
             params, cache, ctl, S((128,), jnp.int32)).compile()
-        assert compiled.as_text().count("tpu_custom_call") == 8
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 14
+        assert " while(" not in text
+        assert not [line for line in text.splitlines()
+                    if " copy(" in line and "[97,3,11520]" in line]
     else:
         T, W = 512, Wcap
         fn = M.make_packed_prefill_fn(cfg, eng, T, W, None)

@@ -1,7 +1,12 @@
 """The gated delta rule (``ops/gated_delta.py``; PR 45): the chunked form
 and the seat kernel against the token-by-token recurrence, at small sizes
-with ``dk != dv`` and a head count that is no power of two.  CPU, float32;
-the Pallas kernel interpreted."""
+with ``dk != dv`` and a head count that is no power of two; the conv tails'
+decode step (PR 55) against ``short_conv``.  CPU, float32; the Pallas
+kernels interpreted.  ``python -m tests.test_gated_delta`` runs the conv
+step's cases compiled, on the chip."""
+
+import json
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -9,6 +14,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.ops import gated_delta as GD
+from dynamo_tpu.ops.delta_rule import short_conv
 
 
 def _inputs(seed, B, T, H, dk, dv, beta_hi=2.0, decay=(0.9, 0.999)):
@@ -134,3 +140,101 @@ def test_the_seat_kernel_is_the_step_on_the_rows_seats(H, dk, dv):
     for seat in (0, 3, 5, 6):           # never held, or held by pads alone
         assert np.array_equal(np.asarray(got[seat]),
                               np.asarray(states[seat]))
+
+
+# ------------------------------ the conv tails ------------------------------
+
+CONV_CASES = [
+    # B, C, seats, the pool's dtype
+    (8, 256, 12, jnp.bfloat16),
+    (8, 256, 12, jnp.float32),
+    (8, 11520, 9, jnp.bfloat16),        # the published width, few rows
+    (128, 1280, 96, jnp.bfloat16),      # the cell's rows and seats
+    (128, 11520, 96, jnp.bfloat16),     # the cell's shapes
+    (128, 11520, 96, jnp.float32),
+]
+
+
+def _conv_case(B, C, S, dtype, K=4):
+    """Rows on seats out of order; rows 0 and 2 fresh (from zeros whatever
+    the seat held); row 1 live with no valid token; the last three rows (at
+    least) dead on the trash seat ``S``; some seats held by nobody.  The
+    pool holds what a bfloat16 model wrote, in ``dtype``."""
+    ks = jax.random.split(jax.random.PRNGKey(B + C + S), 4)
+    pool = jax.random.normal(ks[0], (S + 1, K - 1, C), jnp.float32).astype(
+        jnp.bfloat16).astype(dtype)
+    live = min(B - 3, S - 2)
+    seats = jnp.concatenate([jax.random.permutation(ks[1], S)[:live],
+                             jnp.full((B - live,), S)]).astype(jnp.int32)
+    valid = (jnp.arange(B) < live).at[1].set(False)
+    fresh = jnp.zeros((B,), bool).at[0].set(True).at[2].set(True)
+    u = jax.random.normal(ks[2], (B, C), jnp.float32).astype(jnp.bfloat16)
+    w = jax.random.normal(ks[3], (K, C), jnp.float32).astype(jnp.bfloat16)
+    return pool, seats, fresh, valid, u, w
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint16 if a.dtype.itemsize == 2 else np.uint32)
+
+
+def _conv_check(B, C, S, dtype, interpret):
+    """``conv_step_seats`` against ``short_conv`` at ``T = 1`` over the
+    gathered tails: what differs, by name (nothing, where it is right)."""
+    pool, seats, fresh, valid, u, w = _conv_case(B, C, S, dtype)
+    y, pool1 = GD.conv_step_seats(pool, seats, fresh, valid, u, w,
+                                  interpret=interpret)
+    prev = jnp.where(fresh[:, None, None], 0, pool[seats])
+    y_ref, nxt = short_conv(u[:, None], prev, w, valid.astype(jnp.int32))
+    held = np.asarray(seats)[np.asarray(valid)]
+    idle = np.setdiff1d(np.arange(S + 1), held)
+    assert S in idle and len(idle) > 1
+    return {
+        "y": bool(y.shape == (B, C) and y.dtype == jnp.float32
+                  and np.array_equal(np.asarray(y), np.asarray(y_ref[:, 0]))),
+        "tails": np.array_equal(
+            _bits(pool1[held]),
+            _bits(nxt.astype(dtype)[np.asarray(valid)])),
+        "idle_seats": np.array_equal(_bits(pool1[idle]), _bits(pool[idle])),
+    }
+
+
+@pytest.mark.parametrize("B,C,S,dtype", CONV_CASES)
+def test_the_conv_step_is_short_conv_on_the_rows_seats(B, C, S, dtype):
+    """Interpreted: the new tails bit-equal, ``y`` equal, and every seat no
+    valid row holds (the trash seat with its several rows among them)
+    untouched."""
+    assert _conv_check(B, C, S, dtype, True) == {
+        "y": True, "tails": True, "idle_seats": True}
+
+
+def test_conv_seats_takes_the_kernel_for_a_decode_step_alone():
+    """``conv_seats`` is the one place that decides: the kernel at ``T = 1``
+    where ``kernel`` is given, else ``short_conv`` over the gathered tails;
+    both give the same bits."""
+    pool, seats, fresh, valid, u, w = _conv_case(8, 256, 12, jnp.bfloat16)
+    n = valid.astype(jnp.int32)
+    parts = (u[:, None, :64], u[:, None, 64:192], u[:, None, 192:])
+    p = {"gdn_conv": w}
+    y0, p0 = GD.conv_seats(pool, seats, fresh, n, parts, p)
+    y1, p1 = GD.conv_seats(pool, seats, fresh, n, parts, p, kernel=True)
+    assert y1.shape == y0.shape == (8, 1, 256)
+    assert np.array_equal(np.asarray(y0), np.asarray(y1))
+    # the gather's rows on the trash seat scatter what they read there too
+    assert np.array_equal(_bits(p0), _bits(p1))
+
+
+def main() -> int:
+    """The cases compiled, on the device this process holds."""
+    bad = 0
+    for B, C, S, dtype in CONV_CASES:
+        got = _conv_check(B, C, S, dtype, False)
+        bad += not all(got.values())
+        print(json.dumps({"device": jax.devices()[0].device_kind, "B": B,
+                          "C": C, "seats": S,
+                          "pool": jnp.dtype(dtype).name, **got}), flush=True)
+    return 1 if bad else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

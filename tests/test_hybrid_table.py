@@ -599,8 +599,10 @@ def test_attention_choice_names_the_new_kinds(engine, monkeypatch):
     assert M.latent_chunk_tiles(None, 512, 2048) == (512, 512)
     assert M.latent_chunk_tiles(None, 1, 2048) is None      # absorbed
     monkeypatch.undo()
+    # KDA keeps short_conv's gather in its decode step too (PR 55)
     assert choice["linear"] == {"decode": "pallas-recurrent",
-                                "prefill": "xla-chunked"}
+                                "prefill": "xla-chunked",
+                                "conv": "xla-gather"}
     assert M.attention_choice(
         engine.model_config, EngineConfig(attention_impl="einsum"), None
     )["linear"]["decode"] == "xla-recurrent"

@@ -205,13 +205,65 @@ def test_attention_choice_names_the_rule_and_the_full_kind(engine):
     choice = engine.attention_impl_choice
     assert choice["linear"] == {"decode": "pallas-recurrent",
                                 "prefill": "xla-chunked",
+                                "conv": "pallas-seats",
                                 "rule": "gated-delta"}
     assert choice["impl"]["decode"] == "pallas"
     assert choice["impl"]["prefill"] == "einsum"
     assert "latent" not in choice
-    assert M.attention_choice(
+    xla = M.attention_choice(
         engine.model_config, EngineConfig(attention_impl="einsum"), None
-    )["linear"]["decode"] == "xla-recurrent"
+    )["linear"]
+    assert (xla["decode"], xla["conv"]) == ("xla-recurrent", "xla-gather")
+    assert engine.device_report()["attention_choice"]["linear"] == (
+        choice["linear"])
+
+
+def _lowered(cfg, eng, program):
+    """The StableHLO of a step program of ``cfg`` with the ops' names: the
+    8-row decode window, or the ``T = 64`` packed prefill."""
+    params = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0),
+                                                  cfg))
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, eng))
+    Wcap, S = eng.max_blocks_per_seq, eng.max_num_seqs
+
+    def SD(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    if program == "window":
+        ctl = jax.eval_shape(lambda: M.init_ctl(eng, S, Wcap))
+        window, _ = M.make_autopilot_fns(cfg, eng, Wcap, None)
+        low = window.__wrapped__.lower(params, cache, ctl,
+                                       SD((8,), jnp.int32))
+    else:
+        T, W = 64, 4
+        fn = M.make_packed_prefill_fn(cfg, eng, T, W, None)
+        low = fn.__wrapped__.lower(
+            params, cache, SD((S + 1,), jnp.int32),
+            SD((1, T + W + M.PP_SCALARS), jnp.int32), SD((2,), jnp.uint32))
+    return low.as_text(debug_info=True)
+
+
+# short_conv's tail: a slice a row at the row's own offset, which lowers to
+# a gather under the layer's conv scope; at 128 rows the chip runs it as a
+# loop of 128 trips a layer (PR 55)
+ROW_GATHER = "gdn_conv/vmap()/gather"
+
+
+@pytest.mark.parametrize("impl,conv", [("pallas", "pallas-seats"),
+                                       ("einsum", "xla-gather")])
+def test_the_conv_form_named_is_the_form_traced(impl, conv):
+    """``attention_choice()["linear"]["conv"]`` against the lowered decode
+    window: where decode runs its kernels the window holds the kernel
+    ``gdn_conv_step`` and no gather with an offset a row under ``gdn_conv``;
+    on the XLA path it holds ``short_conv``'s and no such kernel.  A chunk
+    (``T > 1``) keeps ``short_conv`` either way."""
+    cfg, eng = _period(), _engine_config(attention_impl=impl)
+    assert M.attention_choice(cfg, eng, None)["linear"]["conv"] == conv
+    window = _lowered(cfg, eng, "window")
+    assert ("gdn_conv_step" in window) == (conv == "pallas-seats")
+    assert (ROW_GATHER in window) == (conv == "xla-gather")
+    chunk = _lowered(cfg, eng, "prefill")
+    assert ROW_GATHER in chunk and "gdn_conv_step" not in chunk
 
 
 def test_flops_count_the_rule():
@@ -345,6 +397,31 @@ def test_the_engine_serves_the_reference_and_counts_its_seats(monkeypatch):
         np.testing.assert_array_equal(eng.cache[key][0][0], 0)
     assert any(float(jnp.abs(layer[:S]).max()) > 0
                for layer in eng.cache["state"])
+
+
+def test_the_kernel_path_and_the_xla_path_serve_the_same():
+    """The same requests through an engine whose decode runs its kernels
+    (the recurrence and the conv step, interpreted) and through one on the
+    XLA path: the same tokens; the first layer's conv tails to the bit (a
+    tail is the layer's own inputs, whatever form moved them, and the first
+    layer's come from the tokens' embeddings alone); the later layers'
+    tails and every state within the order of the recurrence's sums."""
+    prompts = _prompts((70, 49, 61), seed=3)
+    caches, tokens = [], []
+    for impl in ("pallas", "einsum"):
+        eng = InferenceEngine(_period(), _engine_config(attention_impl=impl),
+                              seed=SEED)
+        tokens.append(_run(eng, prompts, [9, 4, 7]))
+        caches.append(eng.cache)
+    assert tokens[0] == tokens[1]
+    kernel, xla = caches
+    assert any(float(jnp.abs(t).max()) > 0 for t in kernel["conv"])
+    np.testing.assert_array_equal(np.asarray(kernel["conv"][0]),
+                                  np.asarray(xla["conv"][0]))
+    for key in ("conv", "state"):
+        for a, b in zip(kernel[key], xla[key]):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=1e-5)
 
 
 def test_a_seat_handed_to_a_new_sequence_starts_from_zeros():
